@@ -1,8 +1,8 @@
 """Regenerate docs/api.md from the public API surface.
 
 Usage (CPU-safe):
-    env PYTHONPATH=/root/repo JAX_PLATFORMS=cpu python scripts/gen_api_md.py
-    env PYTHONPATH=/root/repo JAX_PLATFORMS=cpu python scripts/gen_api_md.py --check
+    env JAX_PLATFORMS=cpu python scripts/gen_api_md.py
+    env JAX_PLATFORMS=cpu python scripts/gen_api_md.py --check
 
 ``--check`` (ISSUE 15 satellite) renders the current surface and
 compares it to the committed file WITHOUT writing: exit 2 on drift, so

@@ -63,7 +63,7 @@ from stoke_tpu.facade import Stoke
 from stoke_tpu.resilience import PreemptedError
 from stoke_tpu.status import StokeStatus, StokeValidationError
 from stoke_tpu.telemetry.health import HealthHaltError
-from stoke_tpu.utils import force_cpu, init_module
+from stoke_tpu.utils import init_module
 
 __version__ = "0.1.0"
 
@@ -73,7 +73,6 @@ __all__ = [
     "StokeValidationError",
     "HealthHaltError",
     "PreemptedError",
-    "force_cpu",
     "init_module",
     "StokeOptimizer",
     "StokeDataLoader",

@@ -39,8 +39,9 @@ Checks (rule ids; every finding names the remedy):
   count exceeds the churn threshold (ragged batches / drifting pad
   lengths), or approaches the engine's 1024-entry memo cap, beyond
   which recompile detection and the AOT ledger disengage.
-- ``audit-replicated-bytes`` — tensors annotated ``{replicated}`` above
-  a byte threshold in a partitioned (``mhlo.num_partitions > 1``)
+- ``audit-replicated-bytes`` — tensors whose Shardy annotation
+  (``sdy.sharding`` / ``sdy.sharding_constraint``) names no mesh axis,
+  above a byte threshold in a partitioned (``mhlo.num_partitions > 1``)
   program: each device holds a full copy of something the mesh was
   supposed to shard.
 - ``audit-comm-bytes`` — cross-check against the gradient transport's
@@ -112,15 +113,17 @@ _DONOR_ATTR_RE = re.compile(r"tf\.aliasing_output|jax\.buffer_donor")
 _PARTITIONS_RE = re.compile(r"mhlo\.num_partitions = (\d+)")
 _ARG_SPLIT_RE = re.compile(r"(?=%arg\d+: )")
 _ARG_NUM_RE = re.compile(r"%arg(\d+): ")
-#: a tensor type IMMEDIATELY followed by its attr dict (arg/result
-#: annotations) — attr values may be quoted strings containing braces
-#: (mhlo.sharding = "{replicated}"), hence the quote-aware body.
-#: Single-char alternation branch: a ``[^{}"]+`` run inside the star
-#: is ambiguous and backtracks exponentially on large program texts
-_TENSOR_ATTRS_RE = re.compile(
-    r'tensor<([^>]+)>\s\{((?:[^{}"]|"[^"]*")*)\}'
+#: a tensor type IMMEDIATELY followed by the opening brace of its attr
+#: dict (arg/result annotations); the dict itself is brace-matched by
+#: :func:`_annotated_tensors` because Shardy attrs nest braces
+_TENSOR_ATTRS_OPEN_RE = re.compile(r"tensor<([^>]+)>\s\{")
+#: the per-dimension axis lists of a Shardy sharding: ``[{"data"}, {}]``
+_SDY_SHARDING_RE = re.compile(r"#sdy\.sharding<@\w+, \[([^\]]*)\]")
+#: ``sdy.sharding_constraint %x <@mesh, [{}, {}]> : tensor<...>``
+_SDY_CONSTRAINT_RE = re.compile(
+    r"sdy\.sharding_constraint\s+%\S+\s+<@\w+, \[([^\]]*)\][^:\n]*:"
+    r"\s*tensor<([^>]+)>"
 )
-_SHARDING_RESULT_RE = re.compile(r"->\s*tensor<([^>]+)>")
 
 
 @dataclass
@@ -230,6 +233,34 @@ def _main_signature(text: str) -> str:
             if depth == 0:
                 return text[i : j + 1]
     return ""
+
+
+def _annotated_tensors(text: str):
+    """``(tensor payload, attr-dict text)`` for every tensor type directly
+    followed by its attr dict.  Brace-matched and quote-aware: a Shardy
+    annotation (``sdy.sharding = #sdy.sharding<@mesh, [{"data"}, {}]>``)
+    nests braces inside the dict, which no flat regex can delimit."""
+    for m in _TENSOR_ATTRS_OPEN_RE.finditer(text):
+        depth, j = 1, m.end()
+        while j < len(text) and depth:
+            c = text[j]
+            if c == '"':
+                j = text.find('"', j + 1)
+                if j < 0:
+                    break
+            elif c == "{":
+                depth += 1
+            elif c == "}":
+                depth -= 1
+            j += 1
+        if depth == 0:
+            yield m.group(1), text[m.end() : j - 1]
+
+
+def _sdy_replicated(dims: str) -> bool:
+    """True when a Shardy dimension-sharding list names no mesh axis
+    (axis names are the only quoted tokens in it): ``{}, {?}``."""
+    return '"' not in dims
 
 
 def _tensor_bytes(content: str) -> Optional[int]:
@@ -627,7 +658,8 @@ def _audit_one(
         sig = _main_signature(text)
         # split on "%argN: " boundaries so each segment carries one
         # argument's full attr dict — attr values nest braces
-        # (mhlo.sharding = "{replicated}"), which defeats a flat regex
+        # (sdy.sharding = #sdy.sharding<@mesh, [{}, {}]>), which defeats a
+        # flat regex
         sig_args = {}
         for part in _ARG_SPLIT_RE.split(sig):
             m = _ARG_NUM_RE.match(part)
@@ -708,19 +740,18 @@ def _audit_one(
         # a per-line scan would attribute a small replicated arg's
         # annotation to every big SHARDED tensor sharing the (single-
         # line) @main signature and false-fire on real models
-        repl_sizes = [
+        repl_sizes = []
+        for content, attrs in _annotated_tensors(text):
+            m = _SDY_SHARDING_RE.search(attrs)
+            if m and _sdy_replicated(m.group(1)):
+                repl_sizes.append(_tensor_bytes(content))
+        # sharding-constraint intermediates carry the sharding before
+        # the type
+        repl_sizes += [
             _tensor_bytes(content)
-            for content, attrs in _TENSOR_ATTRS_RE.findall(text)
-            if '"{replicated}"' in attrs
+            for dims, content in _SDY_CONSTRAINT_RE.findall(text)
+            if _sdy_replicated(dims)
         ]
-        # sharding-constraint intermediates: the attr dict precedes the
-        # type there (custom_call @Sharding(... ) {mhlo.sharding = ...}
-        # : (tensor<...>) -> tensor<...>)
-        for line in text.splitlines():
-            if "@Sharding" in line and '"{replicated}"' in line:
-                m = _SHARDING_RESULT_RE.search(line)
-                if m:
-                    repl_sizes.append(_tensor_bytes(m.group(1)))
         # one finding per distinct size: the same value annotated at its
         # arg AND result position is one replication, not two
         flagged = 0

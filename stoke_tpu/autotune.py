@@ -12,9 +12,8 @@ already exposes —
 - ``flash_block_q`` / ``flash_block_k``: the Pallas flash-attention
   blocking (``ops/flash_attention.py``),
 - ``comm_dtype``: the gradient-transport wire format (ISSUE 2),
-- ``decode_pages_per_block`` / ``decode_block_h``: the Pallas
-  paged-decode kernel's blocking (ISSUE 13 serve fast path;
-  ``--workload serve_decode``),
+- ``decode_pages_per_block``: the Pallas paged-decode kernel's blocking
+  (ISSUE 13 serve fast path; ``--workload serve_decode``),
 
 — scoring each trial on the attribution vertical's own metrics (per-window
 MFU x goodput fraction, throughput as the fallback) and **pruning the
@@ -54,18 +53,16 @@ KNOB_KIND: Dict[str, str] = {
     "flash_block_k": "memory",
     "comm_dtype": "comm",
     # ISSUE 13 serve fast path: the Pallas paged-decode kernel's block
-    # knobs (KV pages streamed HBM→VMEM per kernel step / heads per grid
-    # cell) — decode attention is HBM-bandwidth-bound, so both are
-    # memory-kind; swept by `scripts/autotune.py --workload serve_decode`
+    # knob (KV pages fetched HBM→VMEM per kernel step) — decode attention
+    # is HBM-bandwidth-bound, so it is memory-kind; swept by
+    # `scripts/autotune.py --workload serve_decode`
     "decode_pages_per_block": "memory",
-    "decode_block_h": "memory",
     # ISSUE 17 speculative decode: the Pallas k-token verify kernel's
-    # block knobs (same HBM→VMEM streaming loop as the decode kernel,
-    # S=k+1 query rows per sequence) — memory-kind for the same reason;
-    # swept by `scripts/autotune.py --workload serve_decode` when the
-    # sweep runs its speculative variant
+    # block knob (same page walk as the decode kernel, S=k+1 query rows
+    # per sequence) — memory-kind for the same reason; swept by
+    # `scripts/autotune.py --workload serve_decode` when the sweep runs
+    # its speculative variant
     "verify_pages_per_block": "memory",
-    "verify_block_h": "memory",
 }
 
 #: bound classification -> knob kinds worth sweeping, in priority order.
@@ -87,7 +84,7 @@ BOUND_KNOB_KINDS: Dict[Optional[str], Tuple[str, ...]] = {
 
 #: TPU-side XLA flag candidates for the compute sweep (each a full
 #: XLA_FLAGS fragment; "" = baseline).  Curated from the profile_capture
-#: A/B arms BENCH_NOTES queued behind the round-4 evidence.
+#: A/B arms queued behind the round-4 evidence.
 TPU_XLA_FLAG_CANDIDATES: Tuple[str, ...] = (
     "",
     "--xla_tpu_enable_experimental_fusion_cost_model=true",
@@ -109,9 +106,7 @@ class TrialSpec:
     flash_block_k: Optional[int] = None
     comm_dtype: Optional[str] = None
     decode_pages_per_block: Optional[int] = None
-    decode_block_h: Optional[int] = None
     verify_pages_per_block: Optional[int] = None
-    verify_block_h: Optional[int] = None
 
     def config_key(self) -> str:
         """Canonical, process-stable identity of this configuration (the

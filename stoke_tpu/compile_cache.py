@@ -13,7 +13,9 @@ the no-cache path:
    executable cache then serves every call with ZERO recompilation.
    Works on every backend.
 2. **XLA persistent cache** — :func:`install_persistent_xla_cache`
-   points the process-global jax compilation cache at a directory, so
+   turns on the process-global jax compilation cache at
+   :func:`persistent_cache_dir` (``JAX_COMPILATION_CACHE_DIR`` when the
+   environment sets it, else one fixed directory in the checkout), so
    backend compiles are disk-memoized across processes and a warm
    process's compiles load in milliseconds.  NON-CPU backends only: this
    jaxlib's CPU persistent cache round-trips executables through a
@@ -73,9 +75,17 @@ from typing import Any, Dict, Optional
 #: optional ``.bin`` serialized-executable artifact)
 ENTRY_PREFIX = "exe-"
 
-#: module-global: the persistent-XLA-cache directory already installed
-#: (the jax knob is process-global; first caller wins)
-_xla_cache_installed: set = set()
+#: where JAX's persistent cache goes when the environment does not place
+#: it: one fixed directory at the root of the checkout, resolved from this
+#: file — never from the cwd, a temporary name, a pid or the time, because
+#: the path must be the same for every process that should share entries
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
+)
+
+#: module-global: the persistent-XLA-cache directory this process
+#: installed (the jax knob is process-global), None before
+_xla_cache_installed: Optional[str] = None
 _xla_cache_lock = threading.Lock()
 
 #: process-level program cache: HLO cache key -> the first already-built
@@ -179,15 +189,26 @@ def hlo_cache_key(hlo_text: str, fingerprint: str) -> str:
     return ENTRY_PREFIX + h.hexdigest()[:40]
 
 
+def persistent_cache_dir() -> str:
+    """THE rule for where JAX's persistent compilation cache lives:
+    ``JAX_COMPILATION_CACHE_DIR`` when the environment sets it (jax reads
+    it itself, and this package then sets no other), else
+    :data:`DEFAULT_CACHE_DIR`.  ``chip_smoke.py``, ``bench.py`` and
+    ``CompileConfig``'s default all resolve through here."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_CACHE_DIR
+
+
 def install_persistent_xla_cache(
-    cache_dir: str, min_compile_time_s: float = 0.0
-) -> bool:
-    """Point jax's process-global persistent compilation cache at
-    ``cache_dir``.  Idempotent; FIRST caller wins — re-pointing the
-    global knob mid-process would strand the earlier run's entries, and
-    the cache is content-addressed so sharing one directory is always
-    safe.  Returns True when this directory owns the knob, False when
-    another does or the runtime lacks the facility.
+    min_compile_time_s: float = 0.0,
+) -> Optional[str]:
+    """Turn on jax's process-global persistent compilation cache at
+    :func:`persistent_cache_dir`.  Idempotent.  Returns the directory, or
+    None when the cache is refused (CPU backend).
+
+    With ``JAX_COMPILATION_CACHE_DIR`` set jax already holds that path:
+    nothing is re-pointed and the cache is not reset.  Without it the knob
+    is set to the fixed in-checkout directory, and the cache is reset so a
+    compile that ran before this call cannot have latched it off.
 
     REFUSED on the CPU backend: this jaxlib's CPU persistent cache
     round-trips executables through a serialization path that corrupts
@@ -197,82 +218,55 @@ def install_persistent_xla_cache(
     bookkeeping loss that makes ``deserialize_and_load`` dispatch unsafe.
     CPU warm starts come from the process-level program cache instead.
     """
+    global _xla_cache_installed, _cpu_refusal_warned
+    import jax
+
     with _xla_cache_lock:
-        if cache_dir in _xla_cache_installed:
-            return True
-        if _xla_cache_installed:
-            return False
-        try:
-            import jax
-
-            if jax.default_backend() == "cpu":
-                global _cpu_refusal_warned
-                if not _cpu_refusal_warned:
-                    _cpu_refusal_warned = True
-                    warnings.warn(
-                        "Stoke -- persistent XLA compilation cache "
-                        "disabled on the CPU backend (its executable "
-                        "serialization corrupts the heap for sharded/"
-                        "donated programs on this jaxlib); same-process "
-                        "warm starts still hit the in-process program "
-                        "cache"
-                    )
-                return False
-            os.makedirs(cache_dir, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            try:
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs",
-                    float(min_compile_time_s),
+        if _xla_cache_installed is not None:
+            return _xla_cache_installed
+        if jax.default_backend() == "cpu":
+            if not _cpu_refusal_warned:
+                _cpu_refusal_warned = True
+                warnings.warn(
+                    "Stoke -- persistent XLA compilation cache disabled on "
+                    "the CPU backend (its executable serialization corrupts "
+                    "the heap for sharded/donated programs on this jaxlib); "
+                    "same-process warm starts still hit the in-process "
+                    "program cache"
                 )
-            except Exception:
-                pass  # knob renamed/absent: threshold stays default
-            try:
-                # cache small test/CPU programs too (default floor skips
-                # tiny entries, which would defeat the CPU-mesh tests)
-                jax.config.update(
-                    "jax_persistent_cache_min_entry_size_bytes", -1
-                )
-            except Exception:
-                pass
-            try:
-                # jax latches its cache-enabled decision at the FIRST
-                # backend compile — which already happened during mesh
-                # build / placement before this config existed.  Reset so
-                # the next compile re-initializes against the new dir
-                # (without this the dir is silently never written).
-                from jax.experimental.compilation_cache import (
-                    compilation_cache as _cc,
-                )
-
-                _cc.reset_cache()
-            except Exception:
-                pass
-            _xla_cache_installed.add(cache_dir)
-            return True
-        except Exception as e:
-            warnings.warn(
-                f"Stoke -- persistent XLA compilation cache unavailable "
-                f"({e!r}); compile warm-starts disabled"
+            return None
+        cache_dir = persistent_cache_dir()
+        os.makedirs(cache_dir, exist_ok=True)
+        # cache every program, small ones too: a second run of the same
+        # programs must find all of them, whatever each took to compile
+        jax.config.update(
+            "jax_persistent_cache_min_compile_time_secs",
+            float(min_compile_time_s),
+        )
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            from jax.experimental.compilation_cache import (
+                compilation_cache as _cc,
             )
-            return False
 
-
-def xla_cache_active() -> bool:
-    """True when SOME persistent XLA cache directory owns the process
-    knob (first-caller-wins; serving works for every run in the process
-    regardless of which run installed it)."""
-    return bool(_xla_cache_installed)
+            jax.config.update("jax_compilation_cache_dir", cache_dir)
+            _cc.reset_cache()
+        _xla_cache_installed = cache_dir
+        return cache_dir
 
 
 def active_xla_cache_dir() -> Optional[str]:
-    """The directory owning the process-global persistent-cache knob
-    (None when none installed).  Markers record it so a hit is only
-    claimed when the cache that would serve the compile is the one the
-    marker's entry was persisted into."""
-    for d in _xla_cache_installed:
-        return d
-    return None
+    """The directory this process's persistent cache was installed at
+    (None when none).  Markers record it so a hit is only claimed when the
+    cache that would serve the compile is the one the marker's entry was
+    persisted into."""
+    return _xla_cache_installed
+
+
+def ledger_dir(cfg) -> str:
+    """Directory of a ``CompileConfig``'s AOT marker ledger: its explicit
+    ``cache_dir``, else ``aot/`` under :func:`persistent_cache_dir`."""
+    return cfg.cache_dir or os.path.join(persistent_cache_dir(), "aot")
 
 
 class CompileCache:
@@ -310,16 +304,15 @@ class CompileCache:
         self._memo: Dict[Any, Any] = {}
         self._lock = threading.Lock()
         self._warned = False
-        os.makedirs(cfg.cache_dir, exist_ok=True)
-        installed = False
+        #: the AOT marker ledger's directory
+        self.dir = ledger_dir(cfg)
+        os.makedirs(self.dir, exist_ok=True)
         if cfg.xla_cache:
-            installed = install_persistent_xla_cache(
-                os.path.join(cfg.cache_dir, "xla"), cfg.min_compile_time_s
-            )
+            install_persistent_xla_cache(cfg.min_compile_time_s)
         # hits require a LIVE persistent cache (ours or another run's in
         # this process — the knob is global): a marker alone reclaims
         # nothing, and counting it as a hit would be a lie
-        self.xla_available = installed or xla_cache_active()
+        self.xla_available = active_xla_cache_dir() is not None
         if registry is not None:
             registry.counter(
                 "compile_cache/hits_total",
@@ -391,7 +384,7 @@ class CompileCache:
         t0 = time.perf_counter()
         lowered = fn.lower(*args)
         key = hlo_cache_key(lowered.as_text(), self.fingerprint)
-        base = os.path.join(self.cfg.cache_dir, key)
+        base = os.path.join(self.dir, key)
         # hit accounting starts AFTER lowering: tracing/lowering happens
         # on the cold path too and is counted in neither path's compile
         # bucket — the hit seconds measure only the ledger's own
@@ -534,7 +527,7 @@ class CompileCache:
             deserialize_and_load,
         )
 
-        with open(os.path.join(self.cfg.cache_dir, key + ".bin"), "rb") as f:
+        with open(os.path.join(self.dir, key + ".bin"), "rb") as f:
             payload, in_tree, out_tree = pickle.load(f)
         return deserialize_and_load(payload, in_tree, out_tree)
 
@@ -565,7 +558,7 @@ class CompileCache:
             "misses": self.misses,
             "saved_compile_s": round(self.saved_compile_s, 6),
             "serialize_errors": self.serialize_errors,
-            "cache_dir": self.cfg.cache_dir,
+            "cache_dir": self.dir,
             "xla_cache_active": self.xla_available,
             "entries": len(self._memo),
         }

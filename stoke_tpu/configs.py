@@ -782,7 +782,7 @@ class HealthConfig:
        post-mortems" for the bundle layout).
     4. **Watchdog** (``watchdog=True``): a daemon thread armed per
        dispatch that fires when no step completes within
-       ``watchdog_timeout_s`` (the wedged-collective / dead-tunnel case),
+       ``watchdog_timeout_s`` (the wedged-collective case),
        dumping all-thread stacks + the bundle and — with
        ``watchdog_kill=True`` — exiting with a distinct code the
        ``scripts/_supervise.py`` runner recognizes.
@@ -1294,9 +1294,13 @@ class CompileConfig:
        lower to identical HLO dispatches through the first facade's
        already-compiled jit fns — zero recompilation, every backend.
     2. **XLA persistent cache** (``xla_cache=True``, non-CPU backends):
-       the process-global jax compilation cache is pointed at
-       ``<cache_dir>/xla`` so a warm PROCESS's backend compiles load
-       from disk in milliseconds instead of re-running XLA codegen.
+       the process-global jax compilation cache is turned on at
+       ``JAX_COMPILATION_CACHE_DIR`` when the environment sets it, else
+       at the fixed ``<checkout>/.jax_cache``
+       (``stoke_tpu.compile_cache.persistent_cache_dir`` — the path is
+       never taken from this config), so a warm PROCESS's backend
+       compiles load from disk in milliseconds instead of re-running
+       XLA codegen.
        Refused on CPU — this jaxlib's CPU cache serialization corrupts
        the heap for sharded/donated programs (the compile_cache module
        docstring pins the evidence).
@@ -1325,16 +1329,17 @@ class CompileConfig:
     key — a warm start can never be served different math.
 
     Attributes:
-        cache_dir: cache directory (created if missing; status-validated
-            writable).  Shareable across runs/processes — entries are
-            content-addressed and written atomically.
+        cache_dir: directory of the AOT marker ledger (created if
+            missing; status-validated writable).  ``None`` = ``aot/``
+            under the persistent cache directory of layer 2.  Shareable
+            across runs/processes — entries are content-addressed and
+            written atomically.
         aot: enable the AOT program ledger + process program cache
             (layers 1 and 3 above — warm-start serving, hit/miss
             accounting, serialized artifacts).
-        xla_cache: point the process-global jax persistent compilation
-            cache at ``<cache_dir>/xla`` (layer 2 above; non-CPU
-            backends).  Process-global by nature; the FIRST run to
-            install wins, and every later run in the process shares it
+        xla_cache: turn on the process-global jax persistent
+            compilation cache (layer 2 above; non-CPU backends).
+            Process-global by nature: every run in the process shares it
             (content-addressed, so sharing is always safe).
         serialize_executables: also write the ``exe-<key>.bin``
             serialized-executable artifact on each ledger miss (for
@@ -1346,7 +1351,7 @@ class CompileConfig:
             everything — right for tests and the CPU mesh).
     """
 
-    cache_dir: str = "compile_cache"
+    cache_dir: Optional[str] = None
     aot: bool = True
     xla_cache: bool = True
     serialize_executables: bool = True
@@ -1438,11 +1443,10 @@ class ServeConfig:
             the pallas INTERPRETER (the CPU parity mode tests pin against
             the reference); a real serve config declaring ``device='cpu'``
             is a status error instead.
-        decode_pages_per_block / decode_block_h: the pallas decode
-            kernel's block knobs (KV pages streamed per kernel step;
-            heads per grid cell).  ``None`` = kernel defaults; both live
-            in the autotune catalog (``decode_pages_per_block`` /
-            ``decode_block_h``) for the ``--workload serve_decode``
+        decode_pages_per_block: the pallas decode kernel's block knob
+            (KV pages fetched per kernel step).  ``None`` = kernel
+            default; it lives in the autotune catalog
+            (``decode_pages_per_block``) for the ``--workload serve_decode``
             sweep.
         prefill_chunk_tokens: chunked prefill (ISSUE 13) — prompts longer
             than this prefill in fixed chunks of this many tokens,
@@ -1506,11 +1510,10 @@ class ServeConfig:
             ``serving/speculative.py``).  Only read when
             ``speculative_k`` is set — non-default values without it are
             a status error, never silently ignored.
-        verify_pages_per_block / verify_block_h: the pallas verify
-            kernel's block knobs (autotune catalog entries
-            ``verify_pages_per_block`` / ``verify_block_h`` under the
+        verify_pages_per_block: the pallas verify kernel's block knob
+            (autotune catalog entry ``verify_pages_per_block`` under the
             ``serve_decode`` sweep).  Only read when ``speculative_k``
-            is set AND ``decode_kernel="pallas"``; setting them outside
+            is set AND ``decode_kernel="pallas"``; setting it outside
             that is a status error.
         cost_cards: serve roofline observatory (ISSUE 18) — attach one
             XLA cost analysis (FLOPs, bytes accessed, peak-HBM where
@@ -1535,7 +1538,6 @@ class ServeConfig:
     attention: str = "dense"
     decode_kernel: str = "reference"
     decode_pages_per_block: Optional[int] = None
-    decode_block_h: Optional[int] = None
     prefill_chunk_tokens: Optional[int] = None
     sampling: bool = False
     temperature: float = 0.0
@@ -1555,7 +1557,6 @@ class ServeConfig:
     speculative_ngram_max: int = 3
     speculative_ngram_min: int = 1
     verify_pages_per_block: Optional[int] = None
-    verify_block_h: Optional[int] = None
     cost_cards: bool = False
 
 

@@ -32,6 +32,7 @@ distributed.py:648-669 — no ``no_sync`` needed here: nothing eagerly syncs).
 
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Callable, Dict, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -48,6 +49,7 @@ from stoke_tpu.configs import (
     PrecisionOptions,
     StokeOptimizer,
 )
+from stoke_tpu.ops.flash_attention import partition_kernels_over
 from stoke_tpu.parallel.zero import make_transport
 from stoke_tpu.parallel.sharding import ShardingRules, place_global_tree
 from stoke_tpu.telemetry.tracing import trace_span
@@ -696,6 +698,15 @@ class StepEngine:
         policy = getattr(jax.checkpoint_policies, self.remat.policy)
         return jax.checkpoint(fn, policy=policy, prevent_cse=self.remat.prevent_cse)
 
+    def _kernel_scope(self):
+        """Trace-time scope around every model forward: under a mesh the
+        Pallas kernels inside the model must ``shard_map`` themselves over
+        it, batch rows split over the data axis (Mosaic kernels are never
+        partitioned automatically)."""
+        if self.rules is None:
+            return contextlib.nullcontext()
+        return partition_kernels_over(self.rules.mesh, (self.rules.axis_name,))
+
     def _run_forward_train(self, variables, rng, margs, mkwargs):
         cvars = {
             "params": self.precision.cast_compute(variables["params"]),
@@ -703,7 +714,10 @@ class StepEngine:
         }
         cargs = self.precision.cast_compute(margs)
         ckwargs = self.precision.cast_compute(mkwargs)
-        out, updated = self.adapter.apply_train(cvars, rng, cargs, ckwargs)
+        with self._kernel_scope():
+            out, updated = self.adapter.apply_train(
+                cvars, rng, cargs, ckwargs
+            )
         return self.precision.cast_output(out), updated
 
     def train_fwd(self, variables, rng, margs: tuple, mkwargs: dict):
@@ -737,7 +751,8 @@ class StepEngine:
                 }
                 cargs = self.precision.cast_compute(margs)
                 ckwargs = self.precision.cast_compute(mkwargs)
-                out = self.adapter.apply_eval(cvars, cargs, ckwargs)
+                with self._kernel_scope():
+                    out = self.adapter.apply_eval(cvars, cargs, ckwargs)
                 return self.precision.cast_output(out)
 
             self._fwd_cache[key] = _efwd

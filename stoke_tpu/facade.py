@@ -51,7 +51,11 @@ from stoke_tpu.engine import (
     init_scaler_state,
     is_deferred,
 )
-from stoke_tpu.parallel.mesh import build_mesh, initialize_distributed
+from stoke_tpu.parallel.mesh import (
+    backend_devices,
+    build_mesh,
+    initialize_distributed,
+)
 from stoke_tpu.parallel.sharding import make_sharding_rules, place_global_tree
 from stoke_tpu.status import StokeStatus
 from stoke_tpu.telemetry import Telemetry
@@ -286,8 +290,7 @@ class Stoke:
             ),
         )
         if self._mesh is None:
-            backend = "cpu" if st.device is DeviceOptions.cpu else None
-            self._device = jax.devices(backend)[0] if backend else jax.devices()[0]
+            self._device = backend_devices(st.device, local=True)[0]
         else:
             self._device = None
 
@@ -395,12 +398,11 @@ class Stoke:
         # analytic per-step bytes-on-wire of the gradient exchange
         # (telemetry counters; None without a CommConfig)
         self._comm_bytes = self._engine.comm_bytes_per_step(self._variables)
-        # create the key host-side: PRNGKey dispatches on the DEFAULT
-        # backend, which may be a (possibly unreachable) accelerator even
-        # when this run targets cpu.  LOCAL device: in multi-process runs
-        # jax.devices() lists other processes' (non-addressable) devices
-        # first.
-        with jax.default_device(jax.local_devices(backend="cpu")[0]):
+        # PRNGKey dispatches on the default backend, which need not be
+        # this run's; build it on a device the run owns.  LOCAL device: in
+        # multi-process runs the mesh leads with other processes'
+        # (non-addressable) devices.
+        with jax.default_device(self._device or self._mesh.local_devices[0]):
             key = jax.random.PRNGKey(seed)
         self._rng = self._place_scalar_tree(key)
 

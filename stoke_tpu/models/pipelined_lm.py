@@ -115,32 +115,28 @@ class PipelinedLM(ModelAdapter):
     # ------------------------------------------------------------------ #
 
     def init(self, rng) -> dict:
-        """Host-side initialization of embed + S stage trees + head."""
-        # local_devices, not devices: in a multi-process run the global
-        # device list leads with process 0's devices, which other processes
-        # cannot address (same fix as utils/init.py)
-        cpu = jax.local_devices(backend="cpu")[0]
-        with jax.default_device(cpu):
-            k_embed, k_pos, k_head, *k_stages = jax.random.split(
-                rng, 3 + self.num_stages
-            )
-            H = self.size.hidden
-            embed = {
-                "tok": jax.random.normal(k_embed, (self.vocab_size, H)) * 0.02,
-                "pos": jax.random.normal(k_pos, (self.max_len, H)) * 0.02,
+        """Initialization of embed + S stage trees + head on the default
+        device (the facade then places the tree onto the mesh)."""
+        k_embed, k_pos, k_head, *k_stages = jax.random.split(
+            rng, 3 + self.num_stages
+        )
+        H = self.size.hidden
+        embed = {
+            "tok": jax.random.normal(k_embed, (self.vocab_size, H)) * 0.02,
+            "pos": jax.random.normal(k_pos, (self.max_len, H)) * 0.02,
+        }
+        dummy = jnp.zeros((1, 8, H), jnp.float32)
+        stage_trees = [
+            self._stage_module.init(k, dummy)["params"] for k in k_stages
+        ]
+        head = jax.random.normal(k_head, (H, self.vocab_size)) * 0.02
+        return {
+            "params": {
+                "embed": embed,
+                "stages": stack_stage_params(stage_trees),
+                "head": head,
             }
-            dummy = jnp.zeros((1, 8, H), jnp.float32)
-            stage_trees = [
-                self._stage_module.init(k, dummy)["params"] for k in k_stages
-            ]
-            head = jax.random.normal(k_head, (H, self.vocab_size)) * 0.02
-            return {
-                "params": {
-                    "embed": embed,
-                    "stages": stack_stage_params(stage_trees),
-                    "head": head,
-                }
-            }
+        }
 
     def _forward(self, params, input_ids):
         B, L = input_ids.shape

@@ -48,22 +48,15 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from .flash_attention import DEFAULT_BLOCK_Q, _pick_block, flash_attention
 
-try:  # jax >= 0.4.35 exposes shard_map at top level
-    from jax import shard_map as _shard_map_fn
 
-    def shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-        return _shard_map_fn(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_old
+def shard_map(f, mesh, in_specs, out_specs, check_vma=False):
+    """``jax.shard_map`` with this repo's default: no varying-manual-axes
+    check (the per-shard bodies here mix replicated and sharded values)."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check_vma,
+    )
 
-    def shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-        return _shard_map_old(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma,
-        )
 
 _NEG_INF = -1e30
 
@@ -72,7 +65,7 @@ def _resolve_inner(inner: str, L: int) -> str:
     """Resolve the inner-kernel choice.  ``"auto"`` (the default) uses flash
     when the flash block picker supports the local length L and falls back
     to the dense einsum otherwise (flash needs L ≤ 512 or L divisible by a
-    block candidate); explicit ``"flash"``/``"dense"`` are honored verbatim
+    128-multiple block candidate); explicit ``"flash"``/``"dense"`` are honored verbatim
     (flash will raise its actionable block error for unsupported L)."""
     if inner not in ("auto", "flash", "dense"):
         raise ValueError(
@@ -81,7 +74,9 @@ def _resolve_inner(inner: str, L: int) -> str:
     if inner != "auto":
         return inner
     try:
-        _pick_block(None, L, DEFAULT_BLOCK_Q)
+        # the ring hops and key-padded calls carry a key mask, whose block
+        # must be lane-aligned
+        _pick_block(None, L, DEFAULT_BLOCK_Q, lane_aligned=True)
         return "flash"
     except ValueError:
         return "dense"

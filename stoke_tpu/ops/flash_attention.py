@@ -26,39 +26,117 @@ cross-device sequence sharding, flash for the on-chip block math).
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 #: block-size candidates, best first — on v5e the 512x512 blocking is ~3.5x
 #: faster than 128x128 (K/V HBM refetch traffic scales as L^2·D/block_q;
-#: measured sweep in scripts/flash_tpu_check.py / BENCH_NOTES.md)
+#: measured 2026-07-29 by scripts/flash_tpu_check.py under an older JAX;
+#: not re-measured since — ROADMAP S6)
 _BLOCK_CANDIDATES = (512, 256, 128, 64)
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 _NEG_INF = -1e30
 
 
-def _pick_block(requested: Optional[int], L: int, default: int) -> int:
+def _pick_block(requested: Optional[int], L: int, default: int,
+                lane_aligned: bool = False) -> int:
     """Resolve a block size: explicit request wins (clamped to L); when
     L ≤ default a single full-length block is used (always legal, one grid
-    step); otherwise the largest candidate ≤ default dividing L."""
+    step); otherwise the largest candidate ≤ default dividing L.
+
+    ``lane_aligned`` restricts the candidates to multiples of 128: a key
+    block that also blocks the [BH, 1, L] key mask sits on the mask's lane
+    axis, where Mosaic takes only 128-multiples (or the whole axis)."""
     if requested is not None:
         return min(requested, L)
     if L <= default:
         return L
-    for c in _BLOCK_CANDIDATES:
+    candidates = tuple(
+        c for c in _BLOCK_CANDIDATES if not lane_aligned or c % 128 == 0
+    )
+    for c in candidates:
         if c <= default and L % c == 0:
             return c
+    step = candidates[-1]
     raise ValueError(
         f"flash attention auto block selection: no candidate in "
-        f"{_BLOCK_CANDIDATES} divides sequence length {L}. Pad the sequence "
-        f"to a multiple of one of the candidates (e.g. {64 * -(-L // 64)}), "
-        f"or pass an explicit block size that divides L."
+        f"{candidates} divides sequence length {L}"
+        f"{' (key-masked call)' if lane_aligned else ''}. Pad the sequence "
+        f"to a multiple of one of the candidates (e.g. "
+        f"{step * -(-L // step)}), or pass an explicit block size that "
+        f"divides L."
+    )
+
+
+# --------------------------------------------------------------------------- #
+# partitioning under a sharded jit
+# --------------------------------------------------------------------------- #
+
+
+#: (mesh, row axes) of the multi-device program being traced, set by
+#: :func:`partition_kernels_over`
+_KERNEL_PARTITION: contextvars.ContextVar = contextvars.ContextVar(
+    "stoke_kernel_partition", default=None
+)
+
+
+@contextlib.contextmanager
+def partition_kernels_over(mesh, axis_names):
+    """Tell the Pallas kernels traced inside this scope that they are part
+    of a program partitioned over ``mesh``, with the batch sharded over
+    ``axis_names``.
+
+    Mosaic kernels cannot be partitioned automatically: lowering a bare
+    ``pallas_call`` inside a multi-device ``jit`` (``distributed="dp"``,
+    fsdp, ...) raises on the chip ("Mosaic kernels cannot be automatically
+    partitioned. Please wrap the call in a shard_map").  Inside this scope
+    :func:`flash_attention` wraps its kernels in a ``shard_map`` over the
+    mesh, each device taking its share of the batch×heads rows.  The step
+    and serving engines enter it around every model forward; enter it
+    yourself when you call the kernels under your own sharded ``jit``."""
+    token = _KERNEL_PARTITION.set((mesh, tuple(axis_names)))
+    try:
+        yield
+    finally:
+        _KERNEL_PARTITION.reset(token)
+
+
+def _current_partition():
+    """The (mesh, row axes) a kernel traced NOW must shard_map itself over;
+    None on one device and inside a ``shard_map`` body (the ring/Ulysses
+    transforms), where the kernel already sees per-device arrays."""
+    part = _KERNEL_PARTITION.get()
+    if part is None or part[0].size == 1:
+        return None
+    if jax.sharding.get_abstract_mesh().manual_axes:
+        return None
+    return part
+
+
+def _partition_rows(call, part, n_rows: int):
+    """``call`` (arrays in, arrays out, every one leading with the same
+    axis of independent batch×heads rows) as it must appear in the program:
+    bare without a partition, else ``shard_map``-ped over the mesh with the
+    rows split over the partition's axes (kept whole, every device
+    computing all of them, when they do not divide)."""
+    if part is None:
+        return call
+    mesh, axes = part
+    axes = tuple(a for a in axes if a in mesh.axis_names)
+    ways = math.prod(mesh.shape[a] for a in axes)
+    spec = P(axes) if axes and n_rows % ways == 0 else P()
+    return jax.shard_map(
+        call, mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False
     )
 
 
@@ -130,8 +208,13 @@ def _fwd_kernel(mask_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0] = jnp.where(l > 0, lse, _NEG_INF)
 
 
-def _flash_forward(q, k, v, mask, heads, scale, causal, block_q, block_k,
-                   interpret):
+def _flash_forward(q, k, v, mask, scale, causal, block_q, block_k, interpret,
+                   part):
+    """q/k/v ``[BH, L, D]``; ``mask`` ``None`` or ``[BH, 1, L]`` (one row per
+    batch×head row, so every operand shares the row axis
+    :func:`_partition_rows` shards; the unit middle axis keeps the
+    ``(1, 1, block_k)`` mask block legal under the Mosaic tiling rule — see
+    the lse layout note in ``_fwd_kernel``)."""
     BH, L, D = q.shape
     nq, nk = pl.cdiv(L, block_q), pl.cdiv(L, block_k)
     kernel = functools.partial(
@@ -140,39 +223,40 @@ def _flash_forward(q, k, v, mask, heads, scale, causal, block_q, block_k,
         scale=scale, causal=causal, block_q=block_q, block_k=block_k, L=L,
     )
     in_specs = []
-    args = []
     if mask is not None:
-        # mask is [B, 1, L]: the length-1 middle axis makes the (1, 1, block_k)
-        # block legal under the Mosaic tiling rule (see lse layout note)
         in_specs.append(
-            pl.BlockSpec((1, 1, block_k), lambda bh, qi, ki: (bh // heads, 0, ki))
+            pl.BlockSpec((1, 1, block_k), lambda bh, qi, ki: (bh, 0, ki))
         )
-        args.append(mask)
     in_specs += [
         pl.BlockSpec((1, block_q, D), lambda bh, qi, ki: (bh, qi, 0)),
         pl.BlockSpec((1, block_k, D), lambda bh, qi, ki: (bh, ki, 0)),
         pl.BlockSpec((1, block_k, D), lambda bh, qi, ki: (bh, ki, 0)),
     ]
-    args += [q, k, v]
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(BH, nq, nk),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh, qi, ki: (bh, qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, L, D), q.dtype),
-            jax.ShapeDtypeStruct((BH, L, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*args)
+
+    def call(*args):  # ([mask], q, k, v), all with this device's rows
+        rows = args[-1].shape[0]
+        return pl.pallas_call(
+            kernel,
+            grid=(rows, nq, nk),
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((1, block_q, D), lambda bh, qi, ki: (bh, qi, 0)),
+                pl.BlockSpec((1, block_q, 1), lambda bh, qi, ki: (bh, qi, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((rows, L, D), q.dtype),
+                jax.ShapeDtypeStruct((rows, L, 1), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, D), jnp.float32),
+                pltpu.VMEM((block_q, 128), jnp.float32),
+                pltpu.VMEM((block_q, 128), jnp.float32),
+            ],
+            interpret=interpret,
+        )(*args)
+
+    args = ([mask] if mask is not None else []) + [q, k, v]
+    out, lse = _partition_rows(call, part, BH)(*args)
     return out, lse
 
 
@@ -286,7 +370,7 @@ def _dkv_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _flash_backward(res, g, heads, scale, causal, block_q, block_k, interpret,
+def _flash_backward(res, g, scale, causal, block_q, block_k, interpret, part,
                     dlse=None):
     q, k, v, mask, out, lse = res
     do = g
@@ -302,7 +386,7 @@ def _flash_backward(res, g, heads, scale, causal, block_q, block_k, interpret,
         # ds = p*(dp - delta + dlse) = p*(dp - (delta - dlse))
         delta = delta - dlse.astype(jnp.float32)
 
-    def specs(maskless_first, grid_inner_is_k):
+    def specs(grid_inner_is_k):
         idx_q = (lambda bh, a, b: (bh, a, 0)) if grid_inner_is_k else (
             lambda bh, a, b: (bh, b, 0))
         idx_k = (lambda bh, a, b: (bh, b, 0)) if grid_inner_is_k else (
@@ -310,7 +394,7 @@ def _flash_backward(res, g, heads, scale, causal, block_q, block_k, interpret,
         sp = []
         if mask is not None:
             sp.append(pl.BlockSpec((1, 1, block_k), lambda bh, a, b: (
-                bh // heads, 0, b if grid_inner_is_k else a)))
+                bh, 0, b if grid_inner_is_k else a)))
         sp += [
             pl.BlockSpec((1, block_q, D), idx_q),   # q
             pl.BlockSpec((1, block_k, D), idx_k),   # k
@@ -327,38 +411,50 @@ def _flash_backward(res, g, heads, scale, causal, block_q, block_k, interpret,
         _dq_kernel if mask is not None else functools.partial(_dq_kernel, None),
         scale=scale, causal=causal, block_q=block_q, block_k=block_k,
     )
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid=(BH, nq, nk),
-        in_specs=specs(mask is None, grid_inner_is_k=True),
-        out_specs=pl.BlockSpec((1, block_q, D), lambda bh, qi, ki: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, L, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        interpret=interpret,
-    )(*args)
+
+    def dq_call(*args):
+        rows = args[-1].shape[0]
+        return pl.pallas_call(
+            dq_kernel,
+            grid=(rows, nq, nk),
+            in_specs=specs(grid_inner_is_k=True),
+            out_specs=pl.BlockSpec(
+                (1, block_q, D), lambda bh, qi, ki: (bh, qi, 0)
+            ),
+            out_shape=jax.ShapeDtypeStruct((rows, L, D), q.dtype),
+            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+            interpret=interpret,
+        )(*args)
+
+    dq = _partition_rows(dq_call, part, BH)(*args)
 
     dkv_kernel = functools.partial(
         _dkv_kernel if mask is not None else functools.partial(_dkv_kernel, None),
         scale=scale, causal=causal, block_q=block_q, block_k=block_k,
     )
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
-        grid=(BH, nk, nq),
-        in_specs=specs(mask is None, grid_inner_is_k=False),
-        out_specs=[
-            pl.BlockSpec((1, block_k, D), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, ki, qi: (bh, ki, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, L, D), k.dtype),
-            jax.ShapeDtypeStruct((BH, L, D), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*args)
+
+    def dkv_call(*args):
+        rows = args[-1].shape[0]
+        return pl.pallas_call(
+            dkv_kernel,
+            grid=(rows, nk, nq),
+            in_specs=specs(grid_inner_is_k=False),
+            out_specs=[
+                pl.BlockSpec((1, block_k, D), lambda bh, ki, qi: (bh, ki, 0)),
+                pl.BlockSpec((1, block_k, D), lambda bh, ki, qi: (bh, ki, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((rows, L, D), k.dtype),
+                jax.ShapeDtypeStruct((rows, L, D), v.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, D), jnp.float32),
+                pltpu.VMEM((block_k, D), jnp.float32),
+            ],
+            interpret=interpret,
+        )(*args)
+
+    dk, dv = _partition_rows(dkv_call, part, BH)(*args)
     return dq, dk, dv, None
 
 
@@ -367,59 +463,55 @@ def _flash_backward(res, g, heads, scale, causal, block_q, block_k, interpret,
 # --------------------------------------------------------------------------- #
 
 
-@functools.partial(
-    jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9)
-)
-def _flash(q, k, v, mask, heads, scale, causal, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, mask, scale, causal, block_q, block_k, interpret, part):
     out, _ = _flash_forward(
-        q, k, v, mask, heads, scale, causal, block_q, block_k, interpret
+        q, k, v, mask, scale, causal, block_q, block_k, interpret, part
     )
     return out
 
 
-def _flash_fwd_rule(q, k, v, mask, heads, scale, causal, block_q, block_k,
-                    interpret):
+def _flash_fwd_rule(q, k, v, mask, scale, causal, block_q, block_k, interpret,
+                    part):
     out, lse = _flash_forward(
-        q, k, v, mask, heads, scale, causal, block_q, block_k, interpret
+        q, k, v, mask, scale, causal, block_q, block_k, interpret, part
     )
     return out, (q, k, v, mask, out, lse)
 
 
-def _flash_bwd_rule(heads, scale, causal, block_q, block_k, interpret, res, g):
+def _flash_bwd_rule(scale, causal, block_q, block_k, interpret, part, res, g):
     return _flash_backward(
-        res, g, heads, scale, causal, block_q, block_k, interpret
+        res, g, scale, causal, block_q, block_k, interpret, part
     )
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-@functools.partial(
-    jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9)
-)
-def _flash_with_lse(q, k, v, mask, heads, scale, causal, block_q, block_k,
-                    interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash_with_lse(q, k, v, mask, scale, causal, block_q, block_k,
+                    interpret, part):
     """Like ``_flash`` but also returns the [BH, L, 1] logsumexp rows —
     the composition hook for ring attention (hop outputs are re-weighted by
     their lse, so lse needs a real gradient path)."""
     return _flash_forward(
-        q, k, v, mask, heads, scale, causal, block_q, block_k, interpret
+        q, k, v, mask, scale, causal, block_q, block_k, interpret, part
     )
 
 
-def _flash_lse_fwd_rule(q, k, v, mask, heads, scale, causal, block_q, block_k,
-                        interpret):
+def _flash_lse_fwd_rule(q, k, v, mask, scale, causal, block_q, block_k,
+                        interpret, part):
     out, lse = _flash_forward(
-        q, k, v, mask, heads, scale, causal, block_q, block_k, interpret
+        q, k, v, mask, scale, causal, block_q, block_k, interpret, part
     )
     return (out, lse), (q, k, v, mask, out, lse)
 
 
-def _flash_lse_bwd_rule(heads, scale, causal, block_q, block_k, interpret,
-                        res, g):
+def _flash_lse_bwd_rule(scale, causal, block_q, block_k, interpret, part, res,
+                        g):
     do, dlse = g
     return _flash_backward(
-        res, do, heads, scale, causal, block_q, block_k, interpret, dlse=dlse
+        res, do, scale, causal, block_q, block_k, interpret, part, dlse=dlse
     )
 
 
@@ -456,26 +548,31 @@ def flash_attention(
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     block_q = _pick_block(block_q, L, DEFAULT_BLOCK_Q)
-    block_k = _pick_block(block_k, L, DEFAULT_BLOCK_K)
+    block_k = _pick_block(
+        block_k, L, DEFAULT_BLOCK_K, lane_aligned=mask is not None
+    )
     if L % block_q or L % block_k:
         raise ValueError(
             f"sequence length {L} must be divisible by block sizes "
             f"({block_q}, {block_k})"
         )
     flat = lambda t: t.reshape(B * H, L, D)
-    # [B, 1, L]: the unit middle axis keeps every mask block legal under the
-    # Mosaic (8, 128)-or-full tiling rule (see the lse layout note in
-    # _fwd_kernel)
-    mask3 = None if mask is None else mask.reshape(B, 1, L)
+    # one mask row per batch×head row ([BH, 1, L], see _flash_forward)
+    mask3 = None if mask is None else jnp.repeat(mask, H, axis=0).reshape(
+        B * H, 1, L
+    )
+    # read at FORWARD trace time and handed down as a static argument: the
+    # backward rule is traced later, outside any scope
+    part = _current_partition()
     if return_lse:
         out, lse = _flash_with_lse(
-            flat(q), flat(k), flat(v), mask3, H, 1.0 / (D**0.5), causal,
-            block_q, block_k, interpret,
+            flat(q), flat(k), flat(v), mask3, 1.0 / (D**0.5), causal,
+            block_q, block_k, interpret, part,
         )
         return out.reshape(B, H, L, D), lse.reshape(B, H, L)
     out = _flash(
-        flat(q), flat(k), flat(v), mask3, H, 1.0 / (D**0.5), causal,
-        block_q, block_k, interpret,
+        flat(q), flat(k), flat(v), mask3, 1.0 / (D**0.5), causal,
+        block_q, block_k, interpret, part,
     )
     return out.reshape(B, H, L, D)
 
@@ -517,9 +614,9 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, context_lens):
     reference uses — the flash recurrence degenerates at q-length 1 (one
     online-softmax row), so the gather IS the whole memory schedule and
     XLA lowers it to per-block dynamic slices out of HBM
-    (pallas_guide.md: KV caches live in HBM; a dedicated Pallas decode
-    kernel streaming blocks through VMEM is the TPU follow-up, the math
-    below is its reference semantics).
+    (pallas_guide.md: KV caches live in HBM;
+    :func:`paged_decode_attention_pallas` streams the blocks through VMEM
+    itself, with the math below as its reference semantics).
 
     Args:
         q: ``[B, H, 1, D]`` current-token queries (one per decode slot).
@@ -555,21 +652,17 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, context_lens):
     return out.astype(q.dtype)
 
 
-#: default number of KV pages streamed HBM→VMEM per kernel step (ISSUE 13)
-#: — bigger groups amortize DMA issue overhead and enlarge the per-step
-#: matmul; both decode knobs live in the autotune catalog
-#: (``stoke_tpu.autotune.KNOB_KIND``) so ``scripts/autotune.py --workload
-#: serve_decode`` can sweep them on-chip
+#: default number of KV pages fetched HBM→VMEM per kernel step (ISSUE 13)
+#: — bigger groups amortize per-step overhead; the knob lives in the
+#: autotune catalog (``stoke_tpu.autotune.KNOB_KIND``) so
+#: ``scripts/autotune.py --workload serve_decode`` can sweep it on-chip
 DEFAULT_DECODE_PAGES_PER_BLOCK = 8
-#: default heads fetched per kernel step (each head owns its own K/V slice,
-#: so blocking heads widens the DMA transfers rather than sharing them)
-DEFAULT_DECODE_BLOCK_H = 1
 
 
 def _pick_divisor(requested: Optional[int], total: int, default: int) -> int:
     """Largest divisor of ``total`` that is <= the requested (or default)
-    value — decode block knobs must tile their dimension exactly, and a
-    sweep-supplied candidate that does not divide degrades to the nearest
+    value — the pages-per-step knob must tile the block table exactly, and
+    a sweep-supplied candidate that does not divide degrades to the nearest
     legal size instead of failing the trial."""
     want = default if requested is None else int(requested)
     want = max(1, min(want, total))
@@ -578,129 +671,81 @@ def _pick_divisor(requested: Optional[int], total: int, default: int) -> int:
     return want
 
 
-def _paged_decode_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
-                         k_vmem, v_vmem, sem_k, sem_v, *, block_size,
-                         pages_per_block, n_steps, block_h, scale):
-    """Streaming paged-decode attention body (one (batch, head-group) grid
-    cell).  K/V pages stay in HBM (``pltpu.ANY``); each step DMAs
-    ``pages_per_block`` pages of the request's block table into a
-    double-buffered VMEM landing zone (the fetch for step j+1 is issued
-    before step j's compute — pallas_guide.md double-buffering pattern) and
-    folds them into the fp32 online-softmax accumulators.  Inactive table
-    entries point at the reserved scratch block 0, so every DMA is legal;
-    their positions are masked by ``context_lens``, so they contribute
-    nothing (the same dead-block traffic the jnp reference gather pays)."""
-    b = pl.program_id(0)
-    hg = pl.program_id(1)
-    ctx = lens_ref[b, 0]
-    group = pages_per_block * block_size
+def _paged_attention_kernel(tables_ref, qpos_ref, q_ref, *refs, heads, n_q,
+                            block_size, pages_per_block, scale):
+    """Streaming paged-attention body shared by decode (``n_q == 1``) and
+    speculative verify (``n_q == k+1``): one ``(request, page group)`` grid
+    cell.
 
-    def copies(j, slot):
-        # one descriptor per (page, plane): start() issues them, wait()
-        # rebuilds the SAME descriptors so the semaphore byte accounting
-        # matches exactly
-        out = []
-        for p in range(pages_per_block):
-            blk = tables_ref[b, j * pages_per_block + p]
-            for src, dst, sem in (
-                (k_hbm, k_vmem, sem_k), (v_hbm, v_vmem, sem_v)
-            ):
-                out.append(
-                    pltpu.make_async_copy(
-                        src.at[blk, :, pl.ds(hg * block_h, block_h), :],
-                        dst.at[slot, pl.ds(p * block_size, block_size)],
-                        sem.at[slot],
-                    )
-                )
-        return out
+    The page pool is viewed ``[NB, BS*H, D]`` (row ``t*H + h`` = token ``t``
+    of the page, head ``h``) and the block table rides as a scalar-prefetch
+    argument, so each of the ``pages_per_block`` K and V inputs is an
+    ordinary BlockSpec whose index map reads the table — the Pallas
+    pipeline double-buffers the page DMAs itself, whole pages at a time
+    (Mosaic cannot slice one head out of the ``(H, D)`` tile a page is
+    stored in).  Queries are ``[H*n_q, D]`` (row ``h*n_q + s``).  Per page:
+    one ``[rows, D] x [D, BS*H]`` score matmul against ALL heads' keys,
+    masked down to each row's own head and to window positions
+    ``<= qpos`` before the online softmax, then ``P @ V`` — masked
+    probabilities are exact zeros, so the other heads' values contribute
+    nothing.  The fp32 running max / normalizer / accumulator live in VMEM
+    scratch across the page-group sweep (init at the first group, finalize
+    at the last), exactly like ``_fwd_kernel``.  Inactive table entries
+    point at the reserved scratch block 0 (a legal fetch); their positions
+    are masked, so they contribute nothing (the same dead-block traffic the
+    jnp reference gather pays)."""
+    k_refs = refs[:pages_per_block]
+    v_refs = refs[pages_per_block:2 * pages_per_block]
+    o_ref, acc, m_sc, l_sc = refs[2 * pages_per_block:]
+    j = pl.program_id(1)
+    rows = heads * n_q
+    cols = block_size * heads
 
-    D = q_ref.shape[-1]
-    q = q_ref[0, :, 0, :].astype(jnp.float32) * scale  # [block_h, D]
-    m = [jnp.full((1, 1), _NEG_INF, jnp.float32) for _ in range(block_h)]
-    l = [jnp.zeros((1, 1), jnp.float32) for _ in range(block_h)]
-    acc = [jnp.zeros((1, D), jnp.float32) for _ in range(block_h)]
+    @pl.when(j == 0)
+    def _init():
+        acc[:] = jnp.zeros_like(acc)
+        m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
+        l_sc[:] = jnp.zeros_like(l_sc)
 
-    for c in copies(0, 0):
-        c.start()
-    for j in range(n_steps):
-        slot = j % 2
-        if j + 1 < n_steps:
-            for c in copies(j + 1, (j + 1) % 2):
-                c.start()
-        for c in copies(j, slot):
-            c.wait()
-        kb = k_vmem[slot].astype(jnp.float32)  # [group, block_h, D]
-        vb = v_vmem[slot].astype(jnp.float32)
-        pos = j * group + jax.lax.broadcasted_iota(
-            jnp.int32, (1, group), 1
+    q = q_ref[0].astype(jnp.float32) * scale  # [rows, D]
+    qpos = qpos_ref[0]  # [rows, 1] last window position each row may see
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+    own_head = (col % heads) == (row // n_q)
+    tok = col // heads  # token offset of the column inside its page
+    for p in range(pages_per_block):
+        s = jax.lax.dot_general(
+            q, k_refs[p][...].astype(jnp.float32), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [rows, BS*H]
+        base = (j * pages_per_block + p) * block_size
+        valid = own_head & (base + tok <= qpos)
+        s = jnp.where(valid, s, _NEG_INF)
+        m_prev = m_sc[:, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        prob = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_sc[:, 0:1] = l_sc[:, 0:1] * corr + jnp.sum(
+            prob, axis=-1, keepdims=True
         )
-        valid = pos < ctx  # [1, group]
-        for hh in range(block_h):
-            s = jax.lax.dot_general(
-                q[hh : hh + 1], kb[:, hh, :], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [1, group]
-            s = jnp.where(valid, s, _NEG_INF)
-            m_new = jnp.maximum(m[hh], jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            p = jnp.where(s > _NEG_INF * 0.5, p, 0.0)
-            corr = jnp.exp(m[hh] - m_new)
-            l[hh] = l[hh] * corr + jnp.sum(p, axis=-1, keepdims=True)
-            m[hh] = m_new
-            pv = jax.lax.dot_general(
-                p, vb[:, hh, :], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            acc[hh] = acc[hh] * corr + pv
-
-    for hh in range(block_h):
-        safe_l = jnp.where(l[hh] > 0, l[hh], 1.0)
-        o_ref[0, hh] = (acc[hh] / safe_l).astype(o_ref.dtype)
-
-
-def paged_decode_attention_pallas(
-    q, k_pages, v_pages, block_tables, context_lens, *,
-    pages_per_block: Optional[int] = None, block_h: Optional[int] = None,
-    interpret: Optional[bool] = None,
-):
-    """Pallas paged-decode attention: the dedicated streaming kernel for
-    the serve fast path (ISSUE 13), with
-    :func:`paged_decode_attention` as its pinned reference semantics.
-
-    Decode attention is HBM-bandwidth-bound: the whole job is moving each
-    request's cached K/V past the VPU once.  The jnp reference leaves the
-    memory schedule to XLA's gather lowering; this kernel owns it — grid
-    over ``(batch, heads/block_h)``, the per-request block table in SMEM,
-    the page pool left in HBM (``pltpu.ANY``), and each grid cell walking
-    its table ``pages_per_block`` pages at a time through a
-    double-buffered VMEM landing buffer (``make_async_copy`` issue for
-    step j+1 before step j's compute) into the fp32 online-softmax
-    accumulation.  Same contract as the reference: positions >=
-    ``context_lens[b]`` are masked, unused table entries point at the
-    reserved scratch block 0 (their DMA is legal, their contribution
-    masked), output in the query dtype.
-
-    Args mirror :func:`paged_decode_attention`; the extra knobs:
-
-    Args:
-        pages_per_block: KV pages fetched per kernel step (clamped to the
-            largest divisor of the table width; default
-            ``DEFAULT_DECODE_PAGES_PER_BLOCK``).  The autotune catalog
-            knob ``decode_pages_per_block``.
-        block_h: heads per grid cell (clamped to a divisor of H; default
-            ``DEFAULT_DECODE_BLOCK_H``) — widens each DMA by fetching
-            several heads' slices per page.  Catalog knob
-            ``decode_block_h``.
-        interpret: run through the pallas interpreter (``None`` =
-            auto-select off-TPU, like :func:`flash_attention` — the CPU
-            parity mode the tests pin against the reference).
-    """
-    B, H, one, D = q.shape
-    if one != 1:
-        raise ValueError(
-            f"paged_decode_attention_pallas is single-token decode; got "
-            f"q-length {one}"
+        m_sc[:, 0:1] = m_new
+        acc[:] = acc[:] * corr + jax.lax.dot_general(
+            prob, v_refs[p][...].astype(jnp.float32),
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
         )
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finalize():
+        l = l_sc[:, 0:1]
+        o_ref[0] = (acc[:] / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
+
+
+def _paged_attention_pallas(q, k_pages, v_pages, block_tables, positions,
+                            pages_per_block, default_pages, interpret):
+    """Shared driver of the two Pallas paged-attention entry points: query
+    row ``s`` of request ``b`` attends window positions
+    ``<= positions[b, s]``."""
+    B, H, S, D = q.shape
     if k_pages.shape != v_pages.shape or k_pages.ndim != 4:
         raise ValueError(
             f"k_pages/v_pages must be identical [NB, BS, H, D] pools, got "
@@ -718,43 +763,102 @@ def paged_decode_attention_pallas(
         )
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    BS = int(k_pages.shape[1])
+    NB, BS = int(k_pages.shape[0]), int(k_pages.shape[1])
     MB = int(block_tables.shape[1])
-    ppb = _pick_divisor(pages_per_block, MB, DEFAULT_DECODE_PAGES_PER_BLOCK)
-    bh = _pick_divisor(block_h, H, DEFAULT_DECODE_BLOCK_H)
-    n_steps = MB // ppb
+    ppb = _pick_divisor(pages_per_block, MB, default_pages)
+    rows = H * S
     kernel = functools.partial(
-        _paged_decode_kernel,
-        block_size=BS, pages_per_block=ppb, n_steps=n_steps, block_h=bh,
+        _paged_attention_kernel,
+        heads=H, n_q=S, block_size=BS, pages_per_block=ppb,
         scale=1.0 / (D**0.5),
     )
-    out = pl.pallas_call(
+    # row h*S + s of the flattened queries carries positions[b, s]
+    qpos = jnp.tile(positions.astype(jnp.int32), (1, H)).reshape(B, rows, 1)
+    # merging (BS, H) keeps the pool's tiled layout whenever H fills whole
+    # sublane tiles (8 rows of f32, 16 of bf16), so XLA lowers these
+    # reshapes to bitcasts, not copies of the pool
+    k_flat = k_pages.reshape(NB, BS * H, D)
+    v_flat = v_pages.reshape(NB, BS * H, D)
+
+    def page_spec(p):
+        return pl.BlockSpec(
+            (None, BS * H, D), lambda b, j, tbl: (tbl[b, j * ppb + p], 0, 0)
+        )
+
+    call = pl.pallas_call(
         kernel,
-        grid=(B, H // bh),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # block tables [B, MB]
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # context lens [B, 1]
-            pl.BlockSpec((1, bh, 1, D), lambda b, h: (b, h, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),   # K pool stays in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),   # V pool stays in HBM
-        ],
-        out_specs=pl.BlockSpec((1, bh, 1, D), lambda b, h: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, 1, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((2, ppb * BS, bh, D), k_pages.dtype),
-            pltpu.VMEM((2, ppb * BS, bh, D), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,  # the block table
+            grid=(B, MB // ppb),
+            in_specs=[
+                pl.BlockSpec((1, rows, 1), lambda b, j, tbl: (b, 0, 0)),
+                pl.BlockSpec((1, rows, D), lambda b, j, tbl: (b, 0, 0)),
+            ] + [page_spec(p) for p in range(ppb)] * 2,
+            out_specs=pl.BlockSpec((1, rows, D), lambda b, j, tbl: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((rows, D), jnp.float32),
+                pltpu.VMEM((rows, 128), jnp.float32),
+                pltpu.VMEM((rows, 128), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, rows, D), q.dtype),
         interpret=interpret,
-    )(
-        block_tables.astype(jnp.int32),
-        context_lens.reshape(B, 1).astype(jnp.int32),
-        q,
-        k_pages,
-        v_pages,
     )
-    return out
+    part = _current_partition()
+    if part is not None:
+        # nothing here is split: every device walks the whole slot batch
+        # over its own replica of the pool
+        part = (part[0], ())
+    out = _partition_rows(call, part, B)(
+        block_tables.astype(jnp.int32), qpos, q.reshape(B, rows, D),
+        *([k_flat] * ppb), *([v_flat] * ppb),
+    )
+    return out.reshape(B, H, S, D)
+
+
+def paged_decode_attention_pallas(
+    q, k_pages, v_pages, block_tables, context_lens, *,
+    pages_per_block: Optional[int] = None,
+    interpret: Optional[bool] = None,
+):
+    """Pallas paged-decode attention (ISSUE 13), with
+    :func:`paged_decode_attention` as its pinned reference semantics.
+
+    Decode attention is HBM-bandwidth-bound: the whole job is moving each
+    request's cached K/V past the compute units once.  The jnp reference
+    leaves the memory schedule to XLA's gather lowering; this kernel owns
+    it — grid ``(batch, table_width / pages_per_block)``, the block table
+    scalar-prefetched, the page pool left in HBM and fetched whole pages at
+    a time by table-indexed BlockSpecs into the fp32 online-softmax
+    accumulation (:func:`_paged_attention_kernel`).  Same contract as the
+    reference: positions >= ``context_lens[b]`` are masked, unused table
+    entries point at the reserved scratch block 0 (a legal fetch, a masked
+    contribution), output in the query dtype.  A slot with
+    ``context_lens[b] == 0`` returns zeros (the reference returns a
+    meaningless mean; callers discard inactive slots either way).
+
+    Args mirror :func:`paged_decode_attention`; the extra knobs:
+
+    Args:
+        pages_per_block: KV pages fetched per kernel step (clamped to the
+            largest divisor of the table width; default
+            ``DEFAULT_DECODE_PAGES_PER_BLOCK``).  The autotune catalog
+            knob ``decode_pages_per_block``.
+        interpret: run through the pallas interpreter (``None`` =
+            auto-select off-TPU, like :func:`flash_attention` — the CPU
+            parity mode the tests pin against the reference).
+    """
+    if q.ndim != 4 or q.shape[2] != 1:
+        raise ValueError(
+            f"paged_decode_attention_pallas is single-token decode "
+            f"([B, H, 1, D]); got q shape {q.shape}"
+        )
+    # decode is verify with one query row at the last cached position
+    positions = context_lens.astype(jnp.int32).reshape(-1, 1) - 1
+    return _paged_attention_pallas(
+        q, k_pages, v_pages, block_tables, positions, pages_per_block,
+        DEFAULT_DECODE_PAGES_PER_BLOCK, interpret,
+    )
 
 
 def paged_prefill_chunk_attention(q, k_pages, v_pages, block_tables,
@@ -796,13 +900,11 @@ def paged_prefill_chunk_attention(q, k_pages, v_pages, block_tables,
     return out.astype(q.dtype)
 
 
-#: default KV pages streamed per step by the speculative verify kernel
+#: default KV pages fetched per step by the speculative verify kernel
 #: (ISSUE 17) — its own autotune catalog knob (``verify_pages_per_block``)
-#: because the verify grid amortizes each fetched page over k+1 query rows,
-#: shifting the DMA/compute balance away from the decode kernel's optimum
+#: because verify amortizes each fetched page over k+1 query rows,
+#: shifting the fetch/compute balance away from the decode kernel's optimum
 DEFAULT_VERIFY_PAGES_PER_BLOCK = 8
-#: default heads per verify grid cell (catalog knob ``verify_block_h``)
-DEFAULT_VERIFY_BLOCK_H = 1
 
 
 def paged_verify_attention(q, k_pages, v_pages, block_tables, positions):
@@ -833,99 +935,21 @@ def paged_verify_attention(q, k_pages, v_pages, block_tables, positions):
     )
 
 
-def _paged_verify_kernel(tables_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
-                         k_vmem, v_vmem, sem_k, sem_v, *, block_size,
-                         pages_per_block, n_steps, block_h, n_q, scale):
-    """Streaming verify-attention body: the :func:`_paged_decode_kernel`
-    schedule (double-buffered HBM→VMEM page DMA, fp32 online softmax)
-    generalized to ``n_q`` query rows per request.  Each fetched page is
-    folded into ALL n_q rows' accumulators — the per-byte compute that
-    makes speculative decode pay: one table walk now scores k+1
-    candidate positions instead of one."""
-    b = pl.program_id(0)
-    hg = pl.program_id(1)
-    group = pages_per_block * block_size
-
-    def copies(j, slot):
-        out = []
-        for p in range(pages_per_block):
-            blk = tables_ref[b, j * pages_per_block + p]
-            for src, dst, sem in (
-                (k_hbm, k_vmem, sem_k), (v_hbm, v_vmem, sem_v)
-            ):
-                out.append(
-                    pltpu.make_async_copy(
-                        src.at[blk, :, pl.ds(hg * block_h, block_h), :],
-                        dst.at[slot, pl.ds(p * block_size, block_size)],
-                        sem.at[slot],
-                    )
-                )
-        return out
-
-    D = q_ref.shape[-1]
-    q = q_ref[0].astype(jnp.float32) * scale  # [block_h, n_q, D]
-    qpos = jnp.stack(
-        [pos_ref[b, s] for s in range(n_q)]
-    ).reshape(n_q, 1)  # [n_q, 1] global positions out of SMEM
-    m = [jnp.full((n_q, 1), _NEG_INF, jnp.float32) for _ in range(block_h)]
-    l = [jnp.zeros((n_q, 1), jnp.float32) for _ in range(block_h)]
-    acc = [jnp.zeros((n_q, D), jnp.float32) for _ in range(block_h)]
-
-    for c in copies(0, 0):
-        c.start()
-    for j in range(n_steps):
-        slot = j % 2
-        if j + 1 < n_steps:
-            for c in copies(j + 1, (j + 1) % 2):
-                c.start()
-        for c in copies(j, slot):
-            c.wait()
-        kb = k_vmem[slot].astype(jnp.float32)  # [group, block_h, D]
-        vb = v_vmem[slot].astype(jnp.float32)
-        pos = j * group + jax.lax.broadcasted_iota(
-            jnp.int32, (1, group), 1
-        )
-        valid = pos <= qpos  # [n_q, group] positional causality
-        for hh in range(block_h):
-            s = jax.lax.dot_general(
-                q[hh], kb[:, hh, :], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [n_q, group]
-            s = jnp.where(valid, s, _NEG_INF)
-            m_new = jnp.maximum(m[hh], jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            p = jnp.where(s > _NEG_INF * 0.5, p, 0.0)
-            corr = jnp.exp(m[hh] - m_new)
-            l[hh] = l[hh] * corr + jnp.sum(p, axis=-1, keepdims=True)
-            m[hh] = m_new
-            pv = jax.lax.dot_general(
-                p, vb[:, hh, :], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            acc[hh] = acc[hh] * corr + pv
-
-    for hh in range(block_h):
-        safe_l = jnp.where(l[hh] > 0, l[hh], 1.0)
-        o_ref[0, hh] = (acc[hh] / safe_l).astype(o_ref.dtype)
-
-
 def paged_verify_attention_pallas(
     q, k_pages, v_pages, block_tables, positions, *,
-    pages_per_block: Optional[int] = None, block_h: Optional[int] = None,
+    pages_per_block: Optional[int] = None,
     interpret: Optional[bool] = None,
 ):
     """Pallas verify attention: the k-token speculative-decode kernel
     (ISSUE 17), with :func:`paged_verify_attention` as its pinned
     reference semantics.
 
-    Identical memory schedule to :func:`paged_decode_attention_pallas`
-    — grid ``(batch, heads/block_h)``, block table in SMEM, page pools
-    in HBM (``pltpu.ANY``), double-buffered VMEM landing zone — but each
-    grid cell scores S = k+1 query rows against every streamed page, so
-    the per-dispatch HBM traffic (the decode bottleneck) is amortized
-    over up to k+1 emitted tokens.  Masking is positional per query row
-    (``w_pos <= positions[b, s]``), matching the chunk-attention
-    predicate rather than decode's ``< context_lens``.
+    The memory schedule of :func:`paged_decode_attention_pallas` (the two
+    share :func:`_paged_attention_kernel`), but each grid cell scores
+    S = k+1 query rows against every fetched page, so the per-dispatch HBM
+    traffic (the decode bottleneck) is amortized over up to k+1 emitted
+    tokens.  Masking is positional per query row
+    (``w_pos <= positions[b, s]``), the chunk-attention predicate.
 
     Args:
         q: ``[B, H, S, D]`` verify queries.
@@ -933,70 +957,21 @@ def paged_verify_attention_pallas(
         block_tables: ``[B, MAX_BLOCKS] int32`` per-request block ids
             (unused entries at the reserved scratch block 0).
         positions: ``[B, S] int32`` per-query global positions.
-        pages_per_block / block_h: catalog knobs
-            ``verify_pages_per_block`` / ``verify_block_h`` (clamped to
-            divisors like the decode kernel's).
+        pages_per_block: catalog knob ``verify_pages_per_block`` (clamped
+            to a divisor like the decode kernel's).
         interpret: pallas interpreter toggle (``None`` = auto off-TPU).
     """
-    B, H, S, D = q.shape
-    if k_pages.shape != v_pages.shape or k_pages.ndim != 4:
+    if q.ndim != 4:
+        raise ValueError(f"expected [B, H, S, D] queries, got {q.shape}")
+    if positions.shape != (q.shape[0], q.shape[2]):
         raise ValueError(
-            f"k_pages/v_pages must be identical [NB, BS, H, D] pools, got "
-            f"{k_pages.shape}/{v_pages.shape}"
+            f"positions must be [B={q.shape[0]}, S={q.shape[2]}], got "
+            f"{positions.shape}"
         )
-    if k_pages.shape[2] != H or k_pages.shape[3] != D:
-        raise ValueError(
-            f"page pool heads/dim {k_pages.shape[2:]} do not match the "
-            f"query's {(H, D)}"
-        )
-    if block_tables.ndim != 2 or block_tables.shape[0] != B:
-        raise ValueError(
-            f"block_tables must be [B={B}, MAX_BLOCKS], got "
-            f"{block_tables.shape}"
-        )
-    if positions.shape != (B, S):
-        raise ValueError(
-            f"positions must be [B={B}, S={S}], got {positions.shape}"
-        )
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    BS = int(k_pages.shape[1])
-    MB = int(block_tables.shape[1])
-    ppb = _pick_divisor(pages_per_block, MB, DEFAULT_VERIFY_PAGES_PER_BLOCK)
-    bh = _pick_divisor(block_h, H, DEFAULT_VERIFY_BLOCK_H)
-    n_steps = MB // ppb
-    kernel = functools.partial(
-        _paged_verify_kernel,
-        block_size=BS, pages_per_block=ppb, n_steps=n_steps, block_h=bh,
-        n_q=S, scale=1.0 / (D**0.5),
+    return _paged_attention_pallas(
+        q, k_pages, v_pages, block_tables, positions, pages_per_block,
+        DEFAULT_VERIFY_PAGES_PER_BLOCK, interpret,
     )
-    out = pl.pallas_call(
-        kernel,
-        grid=(B, H // bh),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # block tables [B, MB]
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # positions [B, S]
-            pl.BlockSpec((1, bh, S, D), lambda b, h: (b, h, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),   # K pool stays in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),   # V pool stays in HBM
-        ],
-        out_specs=pl.BlockSpec((1, bh, S, D), lambda b, h: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((2, ppb * BS, bh, D), k_pages.dtype),
-            pltpu.VMEM((2, ppb * BS, bh, D), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-        interpret=interpret,
-    )(
-        block_tables.astype(jnp.int32),
-        positions.astype(jnp.int32),
-        q,
-        k_pages,
-        v_pages,
-    )
-    return out
 
 
 def make_flash_attention(

@@ -58,6 +58,7 @@ the PR-13 programs verbatim.
 
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Any, Dict, List, Optional, Sequence
 
@@ -68,6 +69,7 @@ import numpy as np
 from stoke_tpu.configs import ServeConfig
 from stoke_tpu.models.bert import BERT_SIZES
 from stoke_tpu.models.gpt import GPT
+from stoke_tpu.ops.flash_attention import partition_kernels_over
 from stoke_tpu.serving.kv_cache import (
     BlockAllocator,
     PagedAttentionHook,
@@ -161,6 +163,7 @@ class ServingEngine:
         attribution=None,
         memory=None,
     ):
+        self._kv_sharding = kv_sharding
         if not isinstance(model, GPT):
             raise TypeError(
                 f"ServingEngine serves GPT models; got {type(model).__name__} "
@@ -469,14 +472,24 @@ class ServingEngine:
     # ------------------------------------------------------------------ #
 
     def _apply(self, params, tokens, positions, hook, decode: bool):
-        return self.model.apply(
-            {"params": params},
-            tokens,
-            train=False,
-            positions=positions,
-            decode=decode,
-            kv_cache=hook,
+        # with the pool placed on a mesh the serve programs are multi-device
+        # programs, where the Pallas kernels must shard_map themselves; every
+        # replica serves the whole slot batch, so no axis splits the rows
+        mesh = getattr(self._kv_sharding, "mesh", None)
+        scope = (
+            partition_kernels_over(mesh, ())
+            if mesh is not None
+            else contextlib.nullcontext()
         )
+        with scope:
+            return self.model.apply(
+                {"params": params},
+                tokens,
+                train=False,
+                positions=positions,
+                decode=decode,
+                kv_cache=hook,
+            )
 
     def _make_hook(self, k_pages, v_pages, tables, positions, mode, lengths):
         """The per-trace cache hook with this engine's kernel selection —
@@ -488,10 +501,8 @@ class ServingEngine:
             attention_impl=self.cfg.attention,
             decode_impl=self.cfg.decode_kernel,
             decode_pages_per_block=self.cfg.decode_pages_per_block,
-            decode_block_h=self.cfg.decode_block_h,
             decode_interpret=self._decode_interpret,
             verify_pages_per_block=self.cfg.verify_pages_per_block,
-            verify_block_h=self.cfg.verify_block_h,
         )
 
     def _prefill_fn(self, qparams, k_pages, v_pages, tokens, block_row,

@@ -193,14 +193,13 @@ class PagedAttentionHook:
             gathered-block :func:`paged_decode_attention`) or
             ``"pallas"`` (the ISSUE 13 streaming kernel
             :func:`paged_decode_attention_pallas`).
-        decode_pages_per_block / decode_block_h: the pallas kernel's
-            block knobs (``None`` = its defaults; autotune catalog
-            entries).
+        decode_pages_per_block: the pallas kernel's block knob
+            (``None`` = its default; autotune catalog entry).
         decode_interpret: run the pallas kernel through the interpreter
             (``None`` = auto off-TPU — the CPU parity mode).
-        verify_pages_per_block / verify_block_h: the verify kernel's
-            block knobs (``None`` = its defaults; autotune catalog
-            entries ``verify_pages_per_block`` / ``verify_block_h``).
+        verify_pages_per_block: the verify kernel's block knob
+            (``None`` = its default; autotune catalog entry
+            ``verify_pages_per_block``).
             ``decode_impl`` selects reference vs pallas for verify too —
             both kernels share the streaming memory schedule.
     """
@@ -217,10 +216,8 @@ class PagedAttentionHook:
         attention_impl: str = "dense",
         decode_impl: str = "reference",
         decode_pages_per_block: Optional[int] = None,
-        decode_block_h: Optional[int] = None,
         decode_interpret: Optional[bool] = None,
         verify_pages_per_block: Optional[int] = None,
-        verify_block_h: Optional[int] = None,
     ):
         if mode not in ("prefill", "chunk", "decode", "verify"):
             raise ValueError(f"unknown PagedAttentionHook mode {mode!r}")
@@ -238,10 +235,8 @@ class PagedAttentionHook:
         self.attention_impl = attention_impl
         self.decode_impl = decode_impl
         self.decode_pages_per_block = decode_pages_per_block
-        self.decode_block_h = decode_block_h
         self.decode_interpret = decode_interpret
         self.verify_pages_per_block = verify_pages_per_block
-        self.verify_block_h = verify_block_h
         self.block_size = int(k_pages.shape[2])
         # verify mode: per-layer (blocks, offs, old_k, old_v) snapshots
         # taken before each write, consumed by rollback()
@@ -344,7 +339,6 @@ class PagedAttentionHook:
                         self.block_tables,
                         self.lengths,
                         pages_per_block=self.decode_pages_per_block,
-                        block_h=self.decode_block_h,
                         interpret=self.decode_interpret,
                     )
                 return paged_decode_attention(
@@ -367,7 +361,6 @@ class PagedAttentionHook:
                         self.block_tables,
                         self.positions,
                         pages_per_block=self.verify_pages_per_block,
-                        block_h=self.verify_block_h,
                         interpret=self.decode_interpret,
                     )
                 return paged_verify_attention(

@@ -1002,10 +1002,13 @@ class StokeStatus:
                     "CompileConfig with aot=False and xla_cache=False "
                     "caches nothing — enable a layer or drop the config"
                 )
-            err = _probe_writable(cfg.cache_dir)
+            from stoke_tpu.compile_cache import ledger_dir
+
+            target = cfg.cache_dir or ledger_dir(cfg)
+            err = _probe_writable(target)
             if err is not None:
                 return (
-                    f"CompileConfig.cache_dir {cfg.cache_dir!r} is not "
+                    f"CompileConfig.cache_dir {target!r} is not "
                     f"writable: {err}"
                 )
             return False
@@ -1065,6 +1068,37 @@ class StokeStatus:
                     f"ServeConfig.decode_kernel {cfg.decode_kernel!r} "
                     f"unknown; valid: {list(SERVE_DECODE_KERNELS)}"
                 )
+            if cfg.attention == "flash":
+                # every prefill bucket is one flash call over the whole
+                # padded prompt: reject a bucket ladder the kernel's block
+                # picker would refuse at trace time, mid-deployment
+                from stoke_tpu.ops.flash_attention import (
+                    DEFAULT_BLOCK_K,
+                    _pick_block,
+                )
+
+                pad = cfg.prefill_pad_multiple
+                longest = min(
+                    cfg.max_seq_len, cfg.prefill_chunk_tokens or cfg.max_seq_len
+                )
+                for bucket in range(pad, longest + pad, pad):
+                    try:
+                        # prefill masks the prompt padding: key-masked
+                        _pick_block(
+                            None, bucket, DEFAULT_BLOCK_K, lane_aligned=True
+                        )
+                    except ValueError:
+                        return (
+                            f"ServeConfig.attention='flash' with "
+                            f"prefill_pad_multiple={pad}: the {bucket}-token "
+                            f"prefill bucket has no legal flash block "
+                            f"(buckets above {DEFAULT_BLOCK_K} tokens must "
+                            f"be multiples of 128). Use a "
+                            f"prefill_pad_multiple that is a multiple of "
+                            f"128, or cap the unchunked prompt at "
+                            f"{DEFAULT_BLOCK_K} tokens (max_seq_len / "
+                            f"prefill_chunk_tokens)"
+                        )
             if (
                 cfg.decode_kernel == "pallas"
                 and s["device"] is DeviceOptions.cpu
@@ -1081,7 +1115,7 @@ class StokeStatus:
                     "interpreter parity mode is for tests, via a "
                     "standalone ServingEngine)"
                 )
-            for field in ("decode_pages_per_block", "decode_block_h"):
+            for field in ("decode_pages_per_block",):
                 v = getattr(cfg, field)
                 if v is not None and v < 1:
                     return (
@@ -1259,7 +1293,7 @@ class StokeStatus:
                         "engine would silently ignore them; set "
                         "speculative_k or drop the knobs"
                     )
-            for field in ("verify_pages_per_block", "verify_block_h"):
+            for field in ("verify_pages_per_block",):
                 v = getattr(cfg, field)
                 if v is None:
                     continue

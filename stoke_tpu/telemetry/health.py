@@ -18,8 +18,8 @@ flight recorder, lives in :mod:`stoke_tpu.telemetry.recorder`):
   starvation streaks, error-feedback residual runaway), each firing one of
   four actions: ``record`` / ``warn`` / ``dump`` / ``halt``.
 - **Watchdog** — :class:`HangWatchdog`, a daemon thread armed per dispatch
-  that fires when no step completes within the timeout (wedged collective
-  / dead tunnel), dumping all-thread stacks + a post-mortem bundle and
+  that fires when no step completes within the timeout (a wedged
+  collective), dumping all-thread stacks + a post-mortem bundle and
   optionally hard-exiting with :data:`WATCHDOG_EXIT_CODE`.
 
 Everything is default-OFF; with no ``HealthConfig`` the compiled step
@@ -502,7 +502,7 @@ def build_detectors(cfg) -> List[Detector]:
 
 class HangWatchdog:
     """Daemon thread firing when an armed dispatch does not complete in
-    time (the wedged-collective / dead-tunnel case: the training thread is
+    time (the wedged-collective case: the training thread is
     stuck inside a device call and can never report the hang itself).
 
     ``arm()`` before a dispatch, ``disarm()`` once the step (and its

@@ -1,6 +1,6 @@
 """Utility helpers (reference: stoke/utils.py:1-151, TPU-native re-design)."""
 
-from stoke_tpu.utils.init import force_cpu, init_module
+from stoke_tpu.utils.init import init_module
 from stoke_tpu.utils.yaml_config import stoke_from_config, stoke_kwargs_from_config
 from stoke_tpu.utils.printing import unrolled_print, make_folder
 from stoke_tpu.utils.trees import (
@@ -15,7 +15,6 @@ from stoke_tpu.utils.trees import (
 )
 
 __all__ = [
-    "force_cpu",
     "init_module",
     "stoke_from_config",
     "stoke_kwargs_from_config",

@@ -1,11 +1,9 @@
 """Model initialization helpers.
 
-``flax.linen.Module.init`` run eagerly executes hundreds of small ops on the
-default backend; on a remote/tunneled TPU each op pays a round trip and init
-takes minutes.  :func:`init_module` runs the whole init as ONE compiled
-program on the host CPU — the facade then places the result onto the mesh
-according to the sharding rules, so no device ever holds more than its shard
-(plus the host copy)."""
+``flax.linen.Module.init`` run eagerly executes hundreds of small ops, each
+its own dispatch.  :func:`init_module` runs the whole init as ONE compiled
+program on the process's default device; the facade then places the result
+onto its device or mesh according to the sharding rules."""
 
 from __future__ import annotations
 
@@ -14,42 +12,14 @@ from typing import Any
 import jax
 
 
-def force_cpu() -> None:
-    """Restrict THIS process to the JAX CPU backend.
-
-    Call before building a ``Stoke`` (and before ANY jax computation) when
-    you want a pure-CPU run on a machine whose accelerator backend is broken
-    or unreachable (a wedged remote-TPU tunnel hangs any code that lets JAX
-    enumerate backends).  Works even when jax was already imported
-    (config-level, not env) — but NOT once a backend has initialized: the
-    platform restriction would silently be a no-op, so that case raises.
-    """
-    try:
-        from jax._src import xla_bridge as _xb
-
-        initialized = bool(getattr(_xb, "_backends", {}))
-    except Exception:
-        initialized = False
-    if initialized:
-        raise RuntimeError(
-            "stoke_tpu.force_cpu() must run before any JAX computation: a "
-            "backend is already initialized and the platform restriction "
-            "would silently have no effect"
-        )
-    jax.config.update("jax_platforms", "cpu")
-
-
 def init_module(module, rng, *args, **kwargs) -> Any:
-    """Initialize a flax module's variables host-side in one compiled call.
+    """Initialize a flax module's variables in one compiled call.
+
+    The full tree lands on the default backend's first local device (the
+    chip when there is one), so the model must fit there unsharded.
 
     Usage:
         variables = init_module(model, jax.random.PRNGKey(0), dummy_batch,
                                 train=False)
     """
-    # local_devices, not devices: in a multi-process run the global device
-    # list leads with process 0's devices, which other processes cannot
-    # address (device_put would raise "non-addressable device")
-    cpu = jax.local_devices(backend="cpu")[0]
-    rng = jax.device_put(rng, cpu)
-    with jax.default_device(cpu):
-        return jax.jit(lambda r: module.init(r, *args, **kwargs))(rng)
+    return jax.jit(lambda r: module.init(r, *args, **kwargs))(rng)

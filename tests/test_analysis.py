@@ -523,7 +523,7 @@ def test_audit_replicated_bytes(devices):
 
 
 def test_audit_comm_bytes_cross_check(devices):
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.array(devices).reshape(8), ("data",))

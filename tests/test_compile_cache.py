@@ -67,11 +67,8 @@ def _make_stoke(tmp_path, *, cache=True, telemetry=False, tag="run",
         ))
         configs.append(AttributionConfig(peak_tflops=1e-3))
     if cache:
-        # the persistent-XLA-cache knob is process-global and
-        # first-caller-wins: the first CompileConfig test claims it for
-        # its tmp dir and every later run in the pytest process shares
-        # it (content-addressed, so sharing is safe — and exactly the
-        # multi-run topology the cache is for)
+        # the marker ledger lands under tmp_path; the persistent XLA
+        # cache itself is refused on the CPU backend
         configs.append(CompileConfig(
             cache_dir=cache_dir or str(tmp_path / "compile_cache"),
         ))
@@ -202,6 +199,69 @@ def test_cache_key_stable_across_processes():
         keys.append(out.stdout.strip().splitlines()[-1])
     assert keys[0] == keys[1]
     assert keys[0].startswith("exe-")
+
+
+# --------------------------------------------------------------------------- #
+# where the persistent cache lives (ISSUE 21): one rule
+# --------------------------------------------------------------------------- #
+
+#: the rule refuses the CPU backend, so the probe process says "tpu" for
+#: the one query the rule makes; nothing is compiled, nothing is written
+_RULE_SNIPPET = r"""
+import json, jax
+jax.default_backend = lambda: "tpu"
+from stoke_tpu import compile_cache as cc
+seen = []
+update = jax.config.update
+jax.config.update = lambda k, v: (seen.append((k, v)), update(k, v))[1]
+returned = cc.install_persistent_xla_cache()
+print(json.dumps({
+    "returned": returned,
+    "knob": jax.config.jax_compilation_cache_dir,
+    "set_in_code": [v for k, v in seen if k == "jax_compilation_cache_dir"],
+}))
+"""
+
+
+def _run_rule(cwd, **env):
+    base = {k: v for k, v in os.environ.items()
+            if k != "JAX_COMPILATION_CACHE_DIR"}
+    out = subprocess.run(
+        [sys.executable, "-c", _RULE_SNIPPET],
+        capture_output=True, text=True, timeout=120, cwd=str(cwd),
+        env={**base, "PYTHONPATH": REPO, "JAX_PLATFORMS": "cpu", **env},
+    )
+    assert out.returncode == 0, out.stderr[-500:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_cache_rule_env_var_wins_and_no_other_path_is_set(tmp_path):
+    placed = str(tmp_path / "placed")
+    got = _run_rule(tmp_path, JAX_COMPILATION_CACHE_DIR=placed)
+    assert got["returned"] == got["knob"] == placed
+    assert got["set_in_code"] == []  # jax read the variable itself
+
+
+def test_cache_rule_unset_is_one_fixed_path_in_the_checkout(tmp_path):
+    from stoke_tpu.compile_cache import DEFAULT_CACHE_DIR
+
+    assert DEFAULT_CACHE_DIR == os.path.join(REPO, ".jax_cache")
+    (tmp_path / "a").mkdir()
+    (tmp_path / "b").mkdir()
+    got = [_run_rule(tmp_path / d) for d in ("a", "b")]  # two cwds
+    for g in got:
+        assert g["returned"] == g["knob"] == DEFAULT_CACHE_DIR
+        assert g["set_in_code"] == [DEFAULT_CACHE_DIR]
+
+
+def test_compile_config_default_ledger_sits_on_the_rule(monkeypatch, tmp_path):
+    from stoke_tpu.compile_cache import DEFAULT_CACHE_DIR, ledger_dir
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert ledger_dir(CompileConfig()) == os.path.join(DEFAULT_CACHE_DIR, "aot")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert ledger_dir(CompileConfig()) == str(tmp_path / "aot")
+    assert ledger_dir(CompileConfig(cache_dir="/x")) == "/x"
 
 
 # --------------------------------------------------------------------------- #

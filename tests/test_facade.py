@@ -715,3 +715,19 @@ def test_estimate_step_flops(rng):
     # must at least cover the forward matmul FLOPs
     if flops is not None:
         assert flops >= 2 * 8 * 4 * 2
+
+
+@pytest.mark.parametrize("distributed", [None, "dp"])
+def test_device_tpu_means_a_tpu(distributed):
+    """On a process where JAX exposes no TPU, device="tpu" raises — it never
+    trains on the CPU under the name of the chip — and the error names the
+    backend it found."""
+    from stoke_tpu import StokeValidationError
+
+    with pytest.raises(StokeValidationError) as e:
+        make_stoke(device="tpu", distributed=distributed)
+    msg = str(e.value)
+    assert "device='tpu'" in msg
+    assert "jax.default_backend()='cpu'" in msg
+    assert "JAX_PLATFORMS" in msg
+

@@ -1,17 +1,21 @@
-"""Real-TPU validation of the Pallas flash-attention kernel.
+"""Real-TPU validation of the Pallas kernels.
 
 The CPU suite exercises the same kernels through the pallas interpreter
-(tests/test_attention.py); these tests compile the real Mosaic kernels and
-therefore ONLY run when a TPU backend is present (conftest.py forces the cpu
-platform for the rest of the suite, so this module must be run explicitly:
+(tests/test_attention.py, tests/test_serving.py); these tests compile the
+real Mosaic kernels and therefore ONLY run when a TPU backend is present
+(conftest.py forces the cpu platform for the rest of the suite, so this
+module must be run explicitly, as the one chip-owning process:
 
     STOKE_TEST_TPU=1 python -m pytest tests/test_flash_tpu.py -q
 
-The standalone runner `scripts/flash_tpu_check.py` performs the same checks
-plus a flash-vs-dense microbenchmark; results are recorded in BENCH_NOTES.md.
-Both validate against the same `dense_reference` and tolerances
-(stoke_tpu/ops/flash_attention.py) so the gate and the check cannot diverge.
+The standalone runner `scripts/flash_tpu_check.py` performs the flash checks
+plus a flash-vs-dense microbenchmark.  Both validate against the same
+`dense_reference` and tolerances (stoke_tpu/ops/flash_attention.py) so the
+gate and the check cannot diverge; the GPT-large-geometry cases at the
+bottom are `chip_smoke.py`'s own kernel-leg checks.
 """
+
+import sys
 
 import numpy as np
 import pytest
@@ -176,3 +180,83 @@ def test_chunked_ce_matches_full_logits_on_tpu():
     b = jax.jit(full)(hidden, emb)
     np.testing.assert_allclose(float(a), float(b), rtol=1e-5)
     _grad_close(chunked, full, (hidden, emb), 1e-4)
+
+
+# ---- chip_smoke.py's kernel leg, case by case (PR 21) --------------------- #
+# GPT-large head geometry: 16 heads of 64, 16-token pages.
+
+sys.path.insert(0, __file__.rsplit("/tests/", 1)[0])
+import chip_smoke  # noqa: E402
+
+
+@pytest.mark.parametrize("seq_len", [1024, 96])
+def test_flash_at_serve_and_train_lengths_on_tpu(seq_len):
+    """The training length, and one short serve bucket that is not a
+    multiple of 128 (a single whole-bucket block)."""
+    chip_smoke.check_flash_parity(
+        heads=16, head_dim=64, seq_len=seq_len, interpret=False
+    )
+
+
+def test_flash_key_masked_bucket_above_512_on_tpu():
+    """A 640-token prefill bucket: blocked path, key mask on the lane axis
+    (the case a 64-wide key block made illegal for Mosaic)."""
+    from stoke_tpu.ops.flash_attention import (
+        FWD_ATOL_BF16,
+        dense_reference,
+        flash_attention,
+    )
+
+    r = np.random.default_rng(4)
+    q, k, v = _qkv(r, B=2, H=16, L=640, D=64)
+    lengths = np.array([520, 640])
+    mask = jnp.asarray(
+        (np.arange(640)[None, :] < lengths[:, None]).astype(np.int32)
+    )
+    out = flash_attention(q, k, v, mask, causal=True, interpret=False)
+    ref = dense_reference(q, k, v, mask, causal=True)
+    assert float(jnp.max(jnp.abs(out.astype(jnp.float32) - ref))) < FWD_ATOL_BF16
+
+
+@pytest.mark.parametrize("pool_dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("n_q", [1, 5], ids=["decode", "verify"])
+def test_paged_kernels_compile_and_match_on_tpu(pool_dtype, n_q):
+    chip_smoke.check_paged_parity(
+        heads=16, head_dim=64, block_size=16,
+        pool_dtype=jnp.dtype(pool_dtype), n_q=n_q, interpret=False,
+    )
+
+
+@pytest.mark.skipif(
+    jax.device_count() < 2, reason="needs several chips"
+)
+def test_flash_partitions_itself_under_a_mesh_on_tpu():
+    """A Mosaic kernel inside a multi-device jit must shard_map itself:
+    batch-sharded inputs, no gather, same values as one device."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from stoke_tpu.ops.flash_attention import (
+        flash_attention,
+        partition_kernels_over,
+    )
+
+    n = jax.device_count()
+    mesh = Mesh(np.asarray(jax.devices()), ("data",))
+    sharded = NamedSharding(mesh, P("data"))
+    q, k, v = _qkv(np.random.default_rng(5), B=2 * n, H=4, L=256, D=64)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False)
+
+    @jax.jit
+    def under_mesh(q, k, v):
+        with partition_kernels_over(mesh, ("data",)):
+            return flash(q, k, v)
+
+    out = under_mesh(*(jax.device_put(t, sharded) for t in (q, k, v)))
+    assert out.sharding.spec == P("data")
+    np.testing.assert_array_equal(
+        np.asarray(out.astype(jnp.float32)),
+        np.asarray(flash(q, k, v).astype(jnp.float32)),
+    )
+

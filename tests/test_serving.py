@@ -680,8 +680,7 @@ def _paged_pool(rng, B=3, H=4, D=16, BS=8, NB=17, MB=4):
 
 
 @pytest.mark.parametrize("pages_per_block", [1, 2, 4])
-@pytest.mark.parametrize("block_h", [1, 2, 4])
-def test_pallas_decode_matches_reference(pages_per_block, block_h, rng):
+def test_pallas_decode_matches_reference(pages_per_block, rng):
     """Acceptance: the streaming kernel matches the pinned jnp reference
     within fp32 tolerance across ragged context_lens, multi-block tables,
     and the scratch-block-0 inactive-slot convention — at every block
@@ -696,7 +695,7 @@ def test_pallas_decode_matches_reference(pages_per_block, block_h, rng):
     out = paged_decode_attention_pallas(
         jnp.asarray(q), jnp.asarray(k_pages), jnp.asarray(v_pages),
         jnp.asarray(tables), jnp.asarray(ctx),
-        pages_per_block=pages_per_block, block_h=block_h,
+        pages_per_block=pages_per_block,
     )
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), atol=1e-5, rtol=1e-5
@@ -782,10 +781,8 @@ def test_autotune_catalog_has_decode_knobs():
     from stoke_tpu.autotune import KNOB_KIND, TrialSpec, knobs_for_bound
 
     assert KNOB_KIND["decode_pages_per_block"] == "memory"
-    assert KNOB_KIND["decode_block_h"] == "memory"
-    spec = TrialSpec(decode_pages_per_block=4, decode_block_h=2)
+    spec = TrialSpec(decode_pages_per_block=4)
     assert "decode_pages_per_block=4" in spec.config_key()
-    assert "decode_block_h=2" in spec.config_key()
     # a memory-bound baseline sweeps them (decode IS memory-bound)
     knobs = knobs_for_bound(
         "memory", {"decode_pages_per_block": [1, 2], "xla_flags": [""]}
@@ -1223,7 +1220,6 @@ def test_serve_event_fields_match_schema():
     [
         {"decode_kernel": "triton"},
         {"decode_pages_per_block": 0},
-        {"decode_block_h": 0},
         {"prefill_chunk_tokens": 0},
         {"prefill_chunk_tokens": 24},   # not a multiple of pad 16
         {"prefill_chunk_tokens": 128},  # exceeds max_seq_len 64
@@ -1237,7 +1233,7 @@ def test_serve_event_fields_match_schema():
         {"top_p": 0.9},
         # decode block knobs only the pallas kernel reads: same rule
         {"decode_pages_per_block": 4},
-        {"decode_block_h": 2, "decode_kernel": "reference"},
+        {"decode_pages_per_block": 2, "decode_kernel": "reference"},
     ],
 )
 def test_serve_fastpath_config_validation_rejects(bad):
@@ -1254,7 +1250,7 @@ def test_serve_fastpath_config_validation_accepts():
         prefill_pad_multiple=16, prefill_chunk_tokens=32,
         sampling=True, temperature=0.8, top_k=40, top_p=0.9,
         decode_kernel="pallas",
-        decode_pages_per_block=4, decode_block_h=2,
+        decode_pages_per_block=4,
     )
     # pallas + block knobs need the TPU device (the cpu rule above)
     st = StokeStatus(batch_size_per_device=1, device="tpu", configs=[cfg])

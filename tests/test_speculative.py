@@ -189,10 +189,10 @@ def test_verify_attention_pallas_matches_reference():
     )
     q = jnp.asarray(r.normal(size=(B, H, S, D)).astype(np.float32))
     ref = paged_verify_attention(q, k_pages, v_pages, tables, positions)
-    for ppb, bh in ((None, None), (2, 2)):
+    for ppb in (None, 2):
         out = paged_verify_attention_pallas(
             q, k_pages, v_pages, tables, positions,
-            pages_per_block=ppb, block_h=bh, interpret=True,
+            pages_per_block=ppb, interpret=True,
         )
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), atol=2e-5
@@ -403,7 +403,8 @@ def test_status_rejects_bad_speculative_configs(gpt):
     # knobs a disabled feature would silently ignore are rejected
     _reject("drafter knobs set", speculative_ngram_max=5)
     _reject("speculative_k=None", verify_pages_per_block=4)
-    _reject("pallas", sampling=True, speculative_k=3, verify_block_h=1)
+    _reject("pallas", sampling=True, speculative_k=3,
+            verify_pages_per_block=1)
     # engine construction enforces the sampling rule too
     model, params = gpt
     with pytest.raises(ValueError, match="sampling"):

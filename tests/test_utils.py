@@ -104,35 +104,6 @@ def test_loss_reduction_sum(rng):
         s.detach_and_sync_loss(l, user_reduction="nope")
 
 
-@pytest.mark.slow
-def test_force_cpu_contract():
-    """force_cpu works before backend init and raises after (subprocesses:
-    this test process has backends initialized already)."""
-    import subprocess
-    import sys
-
-    pre = subprocess.run(
-        [sys.executable, "-c",
-         "import stoke_tpu; stoke_tpu.force_cpu(); import jax; "
-         "print(jax.devices()[0].platform)"],
-        capture_output=True, text=True, timeout=120,
-        env={**__import__('os').environ, "JAX_PLATFORMS": ""},
-    )
-    assert pre.stdout.strip().splitlines()[-1] == "cpu", pre.stderr[-300:]
-    post = subprocess.run(
-        [sys.executable, "-c",
-         "import jax; jax.config.update('jax_platforms', 'cpu'); "
-         "import jax.numpy as jnp; jnp.zeros(1) + 1; "
-         "import stoke_tpu\n"
-         "try:\n"
-         "    stoke_tpu.force_cpu(); print('NORAISE')\n"
-         "except RuntimeError:\n"
-         "    print('RAISED')"],
-        capture_output=True, text=True, timeout=120,
-    )
-    assert post.stdout.strip().splitlines()[-1] == "RAISED", post.stderr[-300:]
-
-
 def test_multihost_env_detection(monkeypatch):
     from stoke_tpu.parallel.mesh import _multihost_env_present
 
