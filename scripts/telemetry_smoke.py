@@ -843,7 +843,7 @@ def main() -> int:
     step_span_names = {e["name"] for e in train_trace}
     spans_by_rid = sv_result["spans_by_rid"]
     tracing_ok = (
-        bool(step_span_names & {"stoke/dispatch", "stoke/accum", "stoke/step"})
+        bool(step_span_names & {"stoke/dispatch", "stoke/accum", "stoke/apply"})
         and "stoke/place" in step_span_names
         and sum(
             1

@@ -683,8 +683,6 @@ class TelemetryConfig:
             ``jax.monitoring`` listeners.
         track_hbm: refresh HBM high-watermark gauges from
             ``device.memory_stats()`` at each record.
-        xprof_annotations: label engine phases in xprof timelines via
-            ``jax.profiler.TraceAnnotation`` (nearly free outside traces).
     """
 
     output_dir: str = "telemetry"
@@ -699,7 +697,6 @@ class TelemetryConfig:
     grad_norm: bool = False
     track_compiles: bool = True
     track_hbm: bool = True
-    xprof_annotations: bool = True
 
 
 @dataclass
@@ -712,7 +709,7 @@ class TraceConfig:
     the prior art here is ``xprof_span`` — a ``jax.profiler
     .TraceAnnotation`` visible only inside an active xprof capture.  With
     this config, every annotated section (engine ``stoke/accum`` /
-    ``stoke/dispatch`` / ``stoke/step``, facade ``stoke/place`` /
+    ``stoke/dispatch`` / ``stoke/apply``, facade ``stoke/place`` /
     ``stoke/io`` and the ``facade/*`` phase timers, loader waits,
     checkpoint save/wait, and the serving path's per-request
     admission → prefill → decode → evict spans) ALSO lands in a host-side

@@ -856,8 +856,11 @@ class StepEngine:
         """``jax.jit`` a step program AND record its declared donations
         under the program's audit name — stated once, here, so the
         ISSUE 15 donation-integrity check can never drift from what the
-        jit actually received."""
+        jit actually received.  The program's name becomes the module's
+        (``jit_accum``, ``jit_apply``, ``jit_fused``, ...): that is what a
+        profiler trace's ``XLA Modules`` line calls each execution."""
         self._program_donations[program] = tuple(donate)
+        fn.__name__ = fn.__qualname__ = program
         if out_shardings is not None:
             return jax.jit(fn, out_shardings=out_shardings,
                            donate_argnums=donate)
@@ -1205,7 +1208,8 @@ class StepEngine:
              margs_stacked, mkwargs_stacked, loss_args_flat_stacked),
         )
         self.dispatch_count += 1
-        with trace_span("stoke/dispatch", track="step"):
+        with trace_span("stoke/dispatch", track="step",
+                        attrs={"program": "window"}):
             return call(
                 variables, opt_state, grad_buf, scaler_state, comm_state,
                 rng, margs_stacked, mkwargs_stacked, loss_args_flat_stacked,
@@ -1353,7 +1357,8 @@ class StepEngine:
              margs_stacked, mkwargs_stacked, loss_args_flat_stacked),
         )
         self.dispatch_count += 1
-        with trace_span("stoke/dispatch", track="step"):
+        with trace_span("stoke/dispatch", track="step",
+                        attrs={"program": "multi"}):
             return call(
                 variables, opt_state, grad_buf, scaler_state, comm_state,
                 rng, margs_stacked, mkwargs_stacked, loss_args_flat_stacked,
@@ -1440,7 +1445,7 @@ class StepEngine:
              loss_val),
         )
         self.dispatch_count += 1
-        with trace_span("stoke/step", track="step"):
+        with trace_span("stoke/apply", track="step"):
             return call(
                 variables, opt_state, grad_buf, scaler_state, comm_state,
                 loss_val,
@@ -1630,7 +1635,8 @@ class StepEngine:
                 (variables, opt_state, grad_buf, scaler_state, comm_state,
                  rng, margs, mkwargs, loss_args_flat),
             )
-            with trace_span("stoke/dispatch", track="step"):
+            with trace_span("stoke/dispatch", track="step",
+                            attrs={"program": "fused"}):
                 return call(
                     variables, opt_state, grad_buf, scaler_state, comm_state,
                     rng, margs, mkwargs, loss_args_flat,
@@ -1650,7 +1656,8 @@ class StepEngine:
             (variables, grad_buf, scaler_state, rng, margs, mkwargs,
              loss_args_flat),
         )
-        with trace_span("stoke/dispatch", track="step"):
+        with trace_span("stoke/dispatch", track="step",
+                        attrs={"program": "fused_nb"}):
             (report, updated, new_vars, new_buf, new_scaler, new_rng,
              finite) = call(
                 variables, grad_buf, scaler_state, rng, margs, mkwargs,

@@ -119,8 +119,8 @@ def _check_segment_memory(seg_bytes: int, stats: Optional[dict]) -> None:
 
 
 def _timed(phase: str):
-    """Method decorator feeding the wall-clock breakdown (no-op overhead of
-    one null-context when disabled)."""
+    """Method decorator: the call runs under its ``stoke/<phase>`` span,
+    which also feeds the wall-clock breakdown when that is enabled."""
     import functools
 
     def deco(fn):
@@ -2750,19 +2750,23 @@ class Stoke:
         return total
 
     def _update_loss_tracking(self, report) -> None:
-        # losses arrive divided by grad_accum; track the undivided micro loss
-        micro = self._loss_total(report) * self._status_obj.grad_accum
-        self._last_step_loss = micro
-        self._agg_loss = self._agg_loss + micro
-        self._agg_count += 1
-        w = self._ema_weight
-        if not self._ema_initialized:
-            self._rolling_mean_loss = micro
-            self._ema_initialized = True
-        else:
-            self._rolling_mean_loss = (
-                1.0 - w
-            ) * self._rolling_mean_loss + w * micro
+        # a handful of eager scalar dispatches per micro-step: its own
+        # span, so a trace tells them from the step programs' dispatches
+        with trace_span("stoke/track", track="facade"):
+            # losses arrive divided by grad_accum; track the undivided
+            # micro loss
+            micro = self._loss_total(report) * self._status_obj.grad_accum
+            self._last_step_loss = micro
+            self._agg_loss = self._agg_loss + micro
+            self._agg_count += 1
+            w = self._ema_weight
+            if not self._ema_initialized:
+                self._rolling_mean_loss = micro
+                self._ema_initialized = True
+            else:
+                self._rolling_mean_loss = (
+                    1.0 - w
+                ) * self._rolling_mean_loss + w * micro
 
     def _reset_tracking_window(self) -> None:
         self._agg_loss = self._zero_scalar()
@@ -2901,13 +2905,12 @@ class Stoke:
     # ------------------------------------------------------------------ #
 
     def _clock(self, phase: str):
-        """Accumulating host-side timer for the wall-clock breakdown —
-        a thin alias onto the telemetry registry (``facade/<phase>_s``
-        counters) plus a labeled xprof span."""
-        import contextlib
-
+        """The ``stoke/<phase>`` span of a user-facing call, always in a
+        profiler trace; with the wall-clock breakdown on it also
+        accumulates the telemetry registry's ``facade/<phase>_s``
+        counter."""
         if not self._wall_clock_enabled:
-            return contextlib.nullcontext()
+            return trace_span(f"stoke/{phase}", track="facade")
         return self._telemetry.phase(phase)
 
     @property

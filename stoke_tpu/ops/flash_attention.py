@@ -253,6 +253,7 @@ def _flash_forward(q, k, v, mask, scale, causal, block_q, block_k, interpret,
                 pltpu.VMEM((block_q, 128), jnp.float32),
             ],
             interpret=interpret,
+            name="flash_fwd",
         )(*args)
 
     args = ([mask] if mask is not None else []) + [q, k, v]
@@ -424,6 +425,7 @@ def _flash_backward(res, g, scale, causal, block_q, block_k, interpret, part,
             out_shape=jax.ShapeDtypeStruct((rows, L, D), q.dtype),
             scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
             interpret=interpret,
+            name="flash_bwd_dq",
         )(*args)
 
     dq = _partition_rows(dq_call, part, BH)(*args)
@@ -452,6 +454,7 @@ def _flash_backward(res, g, scale, causal, block_q, block_k, interpret, part,
                 pltpu.VMEM((block_k, D), jnp.float32),
             ],
             interpret=interpret,
+            name="flash_bwd_dkv",
         )(*args)
 
     dk, dv = _partition_rows(dkv_call, part, BH)(*args)
@@ -803,6 +806,7 @@ def _paged_attention_pallas(q, k_pages, v_pages, block_tables, positions,
         ),
         out_shape=jax.ShapeDtypeStruct((B, rows, D), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )
     part = _current_partition()
     if part is not None:

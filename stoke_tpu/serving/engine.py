@@ -362,23 +362,31 @@ class ServingEngine:
         # donation keeps the page pool in-place in HBM; the CPU backend
         # has no donation (jax warns and copies), so only donate off-CPU
         donate = (1, 2) if jax.default_backend() != "cpu" else ()
+
+        def program(name, method):
+            # the module carries the program's name (``jit_serve_decode``):
+            # what a profiler trace's ``XLA Modules`` line calls it
+            def fn(*args):
+                return method(*args)
+
+            fn.__name__ = fn.__qualname__ = name
+            return jax.jit(fn, donate_argnums=donate)
+
         if self._sampling:
-            self._prefill_jit = jax.jit(
-                self._prefill_sampling_fn, donate_argnums=donate
+            self._prefill_jit = program(
+                "serve_prefill", self._prefill_sampling_fn
             )
-            self._decode_jit = jax.jit(
-                self._decode_sampling_fn, donate_argnums=donate
+            self._decode_jit = program(
+                "serve_decode", self._decode_sampling_fn
             )
         else:
             # greedy programs are the PRE-ISSUE-13 ones verbatim: with
             # decode_kernel="reference" their HLO and token streams are
             # bit-identical to the pre-fast-path engine
-            self._prefill_jit = jax.jit(
-                self._prefill_fn, donate_argnums=donate
-            )
-            self._decode_jit = jax.jit(self._decode_fn, donate_argnums=donate)
+            self._prefill_jit = program("serve_prefill", self._prefill_fn)
+            self._decode_jit = program("serve_decode", self._decode_fn)
         self._chunk_jit = (
-            jax.jit(self._chunk_fn, donate_argnums=donate)
+            program("serve_prefill_chunk", self._chunk_fn)
             if cfg.prefill_chunk_tokens is not None
             else None
         )
@@ -391,12 +399,12 @@ class ServingEngine:
         # audit_specs lowering asserts).
         self._speculative_k = cfg.speculative_k
         self._verify_jit = (
-            jax.jit(self._verify_fn, donate_argnums=donate)
+            program("serve_verify", self._verify_fn)
             if cfg.speculative_k is not None
             else None
         )
         self._packed_chunk_jit = (
-            jax.jit(self._packed_chunk_fn, donate_argnums=donate)
+            program("serve_prefill_chunk_packed", self._packed_chunk_fn)
             if (
                 cfg.speculative_k is not None
                 and cfg.prefill_chunk_tokens is not None
@@ -744,6 +752,35 @@ class ServingEngine:
             fn = cc.executable(program, (program, self._sig(args)), fn, args)
         return fn(*args)
 
+    def _upload(self, host_args: tuple) -> tuple:
+        """A serve program's arguments: the weights, the page pool, and
+        the numpy ``host_args`` put on the device."""
+        return (
+            self.qparams,
+            self.cache.k_pages,
+            self.cache.v_pages,
+            *map(jnp.asarray, host_args),
+        )
+
+    def _run(self, program: str, fn, args: tuple) -> list:
+        """Dispatch one serve program; the page pool it returns last
+        replaces the cache's.  Returns its other outputs, still on the
+        device."""
+        *out, k_pages, v_pages = self._dispatch(program, fn, args)
+        self.cache.k_pages, self.cache.v_pages = k_pages, v_pages
+        return out
+
+    def _launch(self, span: str, program: str, fn, host_args: tuple) -> list:
+        """:meth:`_upload` and :meth:`_run`, each under its own child of
+        ``span`` (``<span>/upload``, ``<span>/dispatch``).  The caller
+        fetches what comes back under ``<span>/read``, so a profiler trace
+        tells the blocking read from the upload and from the enqueue.  For
+        the two sites the benchmark's serve cell runs (prefill, decode)."""
+        with trace_span(f"{span}/upload", track="serve"):
+            args = self._upload(host_args)
+        with trace_span(f"{span}/dispatch", track="serve"):
+            return self._run(program, fn, args)
+
     # ------------------------------------------------------------------ #
     # request intake
     # ------------------------------------------------------------------ #
@@ -813,10 +850,10 @@ class ServingEngine:
         (key_data [1, ...], temperature [1], top_k [1], top_p [1])."""
         t, k, p = params.as_arrays()
         return (
-            jnp.asarray(self._key_data[slot : slot + 1]),
-            jnp.array([t], jnp.float32),
-            jnp.array([k], jnp.int32),
-            jnp.array([p], jnp.float32),
+            self._key_data[slot : slot + 1],
+            np.array([t], np.float32),
+            np.array([k], np.int32),
+            np.array([p], np.float32),
         )
 
     def _emit_first_token(self, slot, req, tok_host, now):
@@ -836,33 +873,32 @@ class ServingEngine:
         (the pre-ISSUE-13 path, sampling-aware when enabled)."""
         sched, m = self.scheduler, self.metrics
         t0 = time.perf_counter()
-        with trace_span("serve/prefill", track="serve",
-                        request_id=req.rid,
-                        attrs={"padded_len": int(padded.shape[1])}):
-            args = (
-                self.qparams,
-                self.cache.k_pages,
-                self.cache.v_pages,
-                jnp.asarray(padded),
-                jnp.asarray(sched.block_tables[slot : slot + 1]),
-                jnp.array([plen], jnp.int32),
+        with trace_span(
+            "serve/prefill", track="serve", request_id=req.rid,
+            attrs={
+                "padded_len": int(padded.shape[1]),
+                "prompt_len": int(plen),
+                "queue_wait_us": 1e6 * (req.admit_ts - req.arrival_ts),
+            },
+        ):
+            host_args = (
+                padded,
+                sched.block_tables[slot : slot + 1],
+                np.array([plen], np.int32),
             )
             if self._sampling:
-                args += self._sampling_scalar_args(req.params, slot)
-                tok, key_out, row, k_pages, v_pages = self._dispatch(
-                    "serve_prefill", self._prefill_jit, args
-                )
-                self._key_data[slot] = np.asarray(key_out)[0]
-                if self.capture_logits:
-                    self.captured_logits.setdefault(req.rid, []).append(
-                        np.asarray(row)[0].copy()
-                    )
-            else:
-                tok, k_pages, v_pages = self._dispatch(
-                    "serve_prefill", self._prefill_jit, args
-                )
-            self.cache.k_pages, self.cache.v_pages = k_pages, v_pages
-            tok_host = int(np.asarray(tok)[0])  # sync: the TTFT point
+                host_args += self._sampling_scalar_args(req.params, slot)
+            out = self._launch(
+                "serve/prefill", "serve_prefill", self._prefill_jit, host_args
+            )
+            with trace_span("serve/prefill/read", track="serve"):
+                if self._sampling:
+                    self._key_data[slot] = np.asarray(out[1])[0]
+                    if self.capture_logits:
+                        self.captured_logits.setdefault(req.rid, []).append(
+                            np.asarray(out[2])[0].copy()
+                        )
+                tok_host = int(np.asarray(out[0])[0])  # sync: the TTFT point
         now = time.perf_counter()
         m.prefills.inc()
         m.prefill_s.inc(now - t0)
@@ -885,20 +921,16 @@ class ServingEngine:
                 "final": bool(is_final),
             },
         ):
-            args = (
-                self.qparams,
-                self.cache.k_pages,
-                self.cache.v_pages,
-                jnp.asarray(toks[None, :]),
-                jnp.asarray(positions[None, :]),
-                jnp.asarray(sched.block_tables[slot : slot + 1]),
-                jnp.array([int(req.prompt.size)], jnp.int32),
-                jnp.array([logit_idx], jnp.int32),
-            ) + self._sampling_scalar_args(req.params, slot)
-            tok, key_out, row, k_pages, v_pages = self._dispatch(
-                "serve_prefill_chunk", self._chunk_jit, args
+            tok, key_out, row = self._run(
+                "serve_prefill_chunk", self._chunk_jit,
+                self._upload((
+                    toks[None, :],
+                    positions[None, :],
+                    sched.block_tables[slot : slot + 1],
+                    np.array([int(req.prompt.size)], np.int32),
+                    np.array([logit_idx], np.int32),
+                ) + self._sampling_scalar_args(req.params, slot)),
             )
-            self.cache.k_pages, self.cache.v_pages = k_pages, v_pages
             # EVERY chunk syncs (one [1] token fetch): dispatch is async,
             # and without the sync the chunk's compute would be charged to
             # the NEXT decode step's fetch — the serve/prefill_chunk spans
@@ -934,24 +966,11 @@ class ServingEngine:
             "serve/prefill_chunk_packed", track="serve",
             attrs={"packed": len(rows), "chunk": int(tokens.shape[1])},
         ):
-            args = (
-                self.qparams,
-                self.cache.k_pages,
-                self.cache.v_pages,
-                jnp.asarray(tokens),
-                jnp.asarray(positions),
-                jnp.asarray(tables),
-                jnp.asarray(lengths),
-                jnp.asarray(logit_idx),
-                jnp.asarray(self._key_data),
-                jnp.asarray(temps),
-                jnp.asarray(ks),
-                jnp.asarray(ps),
+            tok, key_out, logit_rows = self._run(
+                "serve_prefill_chunk_packed", self._packed_chunk_jit,
+                self._upload((tokens, positions, tables, lengths, logit_idx,
+                              self._key_data, temps, ks, ps)),
             )
-            tok, key_out, logit_rows, k_pages, v_pages = self._dispatch(
-                "serve_prefill_chunk_packed", self._packed_chunk_jit, args
-            )
-            self.cache.k_pages, self.cache.v_pages = k_pages, v_pages
             # sync for the same reason the single-chunk path does: the
             # chunk compute must be charged to the prefill bucket, not
             # the next dispatch's fetch
@@ -1001,32 +1020,18 @@ class ServingEngine:
         t0 = time.perf_counter()
         with trace_span("serve/verify_step", track="serve",
                         attrs={"active": sched.decoding, "k": k}):
-            tokens, positions, tables, lengths, draft_lens = (
-                sched.verify_batch(
-                    k,
-                    ngram_max=self.cfg.speculative_ngram_max,
-                    ngram_min=self.cfg.speculative_ngram_min,
-                )
+            batch = sched.verify_batch(
+                k,
+                ngram_max=self.cfg.speculative_ngram_max,
+                ngram_min=self.cfg.speculative_ngram_min,
             )
-            temps, tks, tps = sched.sampling_batch()
-            args = (
-                self.qparams,
-                self.cache.k_pages,
-                self.cache.v_pages,
-                jnp.asarray(tokens),
-                jnp.asarray(positions),
-                jnp.asarray(tables),
-                jnp.asarray(lengths),
-                jnp.asarray(draft_lens),
-                jnp.asarray(self._key_data),
-                jnp.asarray(temps),
-                jnp.asarray(tks),
-                jnp.asarray(tps),
+            draft_lens = batch[-1]
+            targets, n_emit, key_out, logits = self._run(
+                "serve_verify", self._verify_jit,
+                self._upload(
+                    batch + (self._key_data,) + sched.sampling_batch()
+                ),
             )
-            targets, n_emit, key_out, logits, k_pages, v_pages = (
-                self._dispatch("serve_verify", self._verify_jit, args)
-            )
-            self.cache.k_pages, self.cache.v_pages = k_pages, v_pages
             targets_host = np.asarray(targets)  # sync: tokens stream out
             n_emit_host = np.asarray(n_emit)
             kd = np.asarray(key_out)
@@ -1075,113 +1080,123 @@ class ServingEngine:
         prefill chunk, then one decode step over the fully-prefilled slot
         batch.  Bounding per-iteration prefill work by the chunk size is
         what keeps in-flight TPOT flat while a long prompt admits.
-        Returns True while work remains."""
+        Returns True while work remains.
+
+        One span tree per iteration (docs/observability.md): ``serve/step``
+        holds ``serve/admit``, each ``serve/prefill``, the decode step with
+        its batch / upload / dispatch / read children, ``serve/commit`` and
+        ``serve/gauges``, so a profiler trace splits the device's idle time
+        between the blocking read, the launch path and the bookkeeping."""
         sched = self.scheduler
-        m = self.metrics
-
-        for slot, req, padded, plen in sched.admit():
-            if tracing_active():
-                # the request timeline's first span: arrival → admission
-                # (the queue wait) on the request's own track row
-                # count_self=False: the queue wait overlaps other
-                # requests' prefill/decode spans, which own that wall
-                trace_add(
-                    "serve/admission", req.arrival_ts, req.admit_ts,
-                    track="serve", request_id=req.rid,
-                    attrs={"prompt_len": plen}, count_self=False,
-                )
-            if req.slo is not None:
-                self.slo.on_admit(req)
-            if self._sampling or self._chunk_jit is not None:
-                self._key_data[slot] = initial_key_data(req.seed)
-            if padded is None:
-                continue  # chunked admission: chunks run below
-            self._prefill_one(slot, req, padded, plen)
-
-        if self._packed_chunk_jit is not None:
-            nxt = sched.next_chunks()
-            if nxt is not None:
-                self._run_packed_chunks(*nxt)
-        else:
-            nxt = sched.next_chunk()
-            if nxt is not None:
-                self._run_chunk(*nxt)
-
-        if sched.decoding > 0 and self._verify_jit is not None:
-            self._step_verify()
-        elif sched.decoding > 0:
-            # rows in the decode batch (fully-prefilled slots) BEFORE the
-            # commit evicts any — each gets a per-request decode-slice
-            # span below, and sampling key writebacks target exactly them
-            decode_rows = [
-                i
-                for i, s in enumerate(sched.slots)
-                if s.request is not None and s.prefill_pos is None
-            ]
-            live_rids = (
-                [sched.slots[i].request.rid for i in decode_rows]
-                if tracing_active()
-                else None
-            )
-            t0 = time.perf_counter()
-            with trace_span("serve/decode_step", track="serve",
-                            attrs={"active": sched.decoding}):
-                tokens, positions, tables, context = sched.decode_batch()
-                args = (
-                    self.qparams,
-                    self.cache.k_pages,
-                    self.cache.v_pages,
-                    jnp.asarray(tokens),
-                    jnp.asarray(positions),
-                    jnp.asarray(tables),
-                    jnp.asarray(context),
-                )
-                if self._sampling:
-                    temps, ks, ps = sched.sampling_batch()
-                    args += (
-                        jnp.asarray(self._key_data),
-                        jnp.asarray(temps),
-                        jnp.asarray(ks),
-                        jnp.asarray(ps),
+        with trace_span(
+            "serve/step", track="serve",
+            attrs={"it": self._iterations, "queued": sched.queued,
+                   "active": sched.active},
+        ):
+            with trace_span("serve/admit", track="serve"):
+                admitted = sched.admit()
+            for slot, req, padded, plen in admitted:
+                if tracing_active():
+                    # the request timeline's first span: arrival → admission
+                    # (the queue wait) on the request's own track row
+                    # count_self=False: the queue wait overlaps other
+                    # requests' prefill/decode spans, which own that wall
+                    trace_add(
+                        "serve/admission", req.arrival_ts, req.admit_ts,
+                        track="serve", request_id=req.rid,
+                        attrs={"prompt_len": plen}, count_self=False,
                     )
-                    next_tok, key_out, logits, k_pages, v_pages = (
-                        self._dispatch(
-                            "serve_decode", self._decode_jit, args
-                        )
-                    )
+                if req.slo is not None:
+                    self.slo.on_admit(req)
+                if self._sampling or self._chunk_jit is not None:
+                    self._key_data[slot] = initial_key_data(req.seed)
+                if padded is None:
+                    continue  # chunked admission: chunks run below
+                self._prefill_one(slot, req, padded, plen)
+
+            if self._packed_chunk_jit is not None:
+                nxt = sched.next_chunks()
+                if nxt is not None:
+                    self._run_packed_chunks(*nxt)
+            else:
+                nxt = sched.next_chunk()
+                if nxt is not None:
+                    self._run_chunk(*nxt)
+
+            if sched.decoding > 0:
+                if self._verify_jit is not None:
+                    self._step_verify()
                 else:
-                    next_tok, k_pages, v_pages = self._dispatch(
-                        "serve_decode", self._decode_jit, args
-                    )
-                self.cache.k_pages, self.cache.v_pages = k_pages, v_pages
-                next_host = np.asarray(next_tok)  # sync: tokens stream out
+                    self._step_decode()
+
+            with trace_span("serve/gauges", track="serve"):
+                self._iterations += 1
+                self._refresh_gauges()
+                if (
+                    self._iterations - self._last_emit_iter
+                    >= self.cfg.log_every_n_steps
+                ):
+                    self.emit_record()
+        return sched.has_work
+
+    def _step_decode(self) -> None:
+        """One decode step over the fully-prefilled slot batch: build the
+        batch, upload, dispatch, read the tokens back, commit them."""
+        sched, m = self.scheduler, self.metrics
+        # rows in the decode batch (fully-prefilled slots) BEFORE the
+        # commit evicts any — each gets a per-request decode-slice
+        # span below, and sampling key writebacks target exactly them
+        decode_rows = [
+            i
+            for i, s in enumerate(sched.slots)
+            if s.request is not None and s.prefill_pos is None
+        ]
+        live_rids = (
+            [sched.slots[i].request.rid for i in decode_rows]
+            if tracing_active()
+            else None
+        )
+        t0 = time.perf_counter()
+        with trace_span("serve/decode_step", track="serve",
+                        attrs={"active": sched.decoding}):
+            with trace_span("serve/decode_step/batch", track="serve"):
+                host_args = sched.decode_batch()
+                if self._sampling:
+                    host_args += (self._key_data,) + sched.sampling_batch()
+            out = self._launch(
+                "serve/decode_step", "serve_decode", self._decode_jit,
+                host_args,
+            )
+            with trace_span("serve/decode_step/read", track="serve"):
+                next_host = np.asarray(out[0])  # sync: tokens stream out
                 if self._sampling:
                     # advance ONLY the decoding slots' key streams: a
                     # request's draw sequence depends on its own seed and
                     # token count, never on who else rode the batch
-                    kd = np.asarray(key_out)
+                    kd = np.asarray(out[1])
                     for i in decode_rows:
                         self._key_data[i] = kd[i]
                     if self.capture_logits:
-                        larr = np.asarray(logits)
+                        larr = np.asarray(out[2])
                         for i in decode_rows:
                             rid = sched.slots[i].request.rid
                             self.captured_logits.setdefault(rid, []).append(
                                 larr[i].copy()
                             )
-            now = time.perf_counter()
-            if live_rids:
-                # per-request decode slices: every live request's timeline
-                # row shows the batch decode interval it rode (the TPOT
-                # structure the histograms only summarize).
-                # count_self=False: all slices share ONE interval the
-                # serve/decode_step span above already owns — charging
-                # each would multiply-count the window by batch depth
-                for rid in live_rids:
-                    trace_add("serve/decode", t0, now, track="serve",
-                              request_id=rid, count_self=False)
-            m.decode_steps.inc()
-            m.decode_s.inc(now - t0)
+        now = time.perf_counter()
+        if live_rids:
+            # per-request decode slices: every live request's timeline
+            # row shows the batch decode interval it rode (the TPOT
+            # structure the histograms only summarize).
+            # count_self=False: all slices share ONE interval the
+            # serve/decode_step span above already owns — charging
+            # each would multiply-count the window by batch depth
+            for rid in live_rids:
+                trace_add("serve/decode", t0, now, track="serve",
+                          request_id=rid, count_self=False)
+        m.decode_steps.inc()
+        m.decode_s.inc(now - t0)
+        with trace_span("serve/commit", track="serve"):
             n_sampled = sum(
                 1
                 for i in decode_rows
@@ -1194,15 +1209,6 @@ class ServingEngine:
                 m.sampled_tokens.inc(n_sampled)
             for rid in set(sched.finished) - was_finished:
                 self._finish(sched.finished[rid])
-
-        self._iterations += 1
-        self._refresh_gauges()
-        if (
-            self._iterations - self._last_emit_iter
-            >= self.cfg.log_every_n_steps
-        ):
-            self.emit_record()
-        return sched.has_work
 
     def run(self, max_steps: Optional[int] = None) -> int:
         """Drive :meth:`step` until drained (or ``max_steps``); emits a

@@ -33,7 +33,6 @@ from typing import Any, Dict, List, Optional
 from stoke_tpu.telemetry.collectors import (
     CompileTracker,
     hbm_stats,
-    set_xprof_enabled,
     update_hbm_gauges,
     xprof_span,
 )
@@ -143,7 +142,6 @@ __all__ = [
     "hbm_stats",
     "update_hbm_gauges",
     "xprof_span",
-    "set_xprof_enabled",
     "STEP_EVENT_SCHEMA",
     "build_step_event",
     "validate_step_event",
@@ -274,12 +272,6 @@ class Telemetry:
             return
         import os
 
-        # xprof annotation gating is process-global; only ever *disable*
-        # from a config (never re-enable) so a later default-config
-        # instance cannot clobber an earlier instance's explicit opt-out.
-        # Re-enable explicitly via set_xprof_enabled(True) if needed.
-        if not config.xprof_annotations:
-            set_xprof_enabled(False)
         if config.track_compiles:
             self.compile_tracker = CompileTracker(self.registry)
         is_rank0 = self.rank == 0

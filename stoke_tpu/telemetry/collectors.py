@@ -11,10 +11,10 @@ labeled xprof spans.
   ``device.memory_stats()`` (None-tolerant: the CPU simulator reports
   nothing) into high-watermark gauges.
 - :func:`xprof_span` wraps ``jax.profiler.TraceAnnotation`` so engine phases
-  (place/dispatch/accum/step/io) show up *named* in xprof/TensorBoard-profile
-  timelines instead of as anonymous python frames.  Spans are process-global
-  (annotations are free when no trace is active) but can be disabled via
-  :func:`set_xprof_enabled` for pathological host-bound microbenchmarks.
+  (place/dispatch/accum/step/io) show up *named*, with their attributes, in
+  the profiler's trace — on the same clock as the device events — instead
+  of as anonymous python frames.  Always emitted: an annotation outside an
+  active trace costs about a microsecond.
 
 ``jax.monitoring`` listeners are process-global and cannot be individually
 removed, so ONE module-level dispatcher is installed lazily and fans out to
@@ -24,7 +24,6 @@ not leak its tracker forever).
 
 from __future__ import annotations
 
-import contextlib
 import threading
 import weakref
 from typing import Dict, Optional
@@ -152,25 +151,10 @@ def update_hbm_gauges(registry, device=None) -> Optional[Dict[str, int]]:
 # labeled xprof spans
 # --------------------------------------------------------------------------- #
 
-_xprof_enabled = True
+def xprof_span(name: str, **attrs):
+    """Context manager labeling the enclosed host section in the profiler's
+    trace (``jax.profiler.TraceAnnotation``).  ``attrs`` (ints, floats,
+    bools, short strings) arrive there as the event's stats."""
+    import jax.profiler
 
-
-def set_xprof_enabled(enabled: bool) -> None:
-    """Process-wide toggle for phase annotations (on by default — a
-    TraceAnnotation outside an active trace is nearly free)."""
-    global _xprof_enabled
-    _xprof_enabled = bool(enabled)
-
-
-def xprof_span(name: str):
-    """Context manager labeling the enclosed host dispatch in xprof traces
-    (``jax.profiler.TraceAnnotation``); no-op when disabled or when the
-    profiler module is unavailable."""
-    if not _xprof_enabled:
-        return contextlib.nullcontext()
-    try:
-        import jax.profiler
-
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # pragma: no cover - profiler-free builds
-        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(name, **attrs)

@@ -344,9 +344,8 @@ class TraceRecorder:
         """
         spans = self.spans()
         # aggregate by (name, track): the same name can appear on several
-        # tracks ("stoke/step" is both the facade phase and the engine
-        # apply dispatch; "stoke/io" both loader fetch and checkpoint
-        # IO) and merging them would mislabel the critical path
+        # tracks ("stoke/io" is both loader fetch and checkpoint IO) and
+        # merging them would mislabel the critical path
         agg_by_key: Dict[tuple, Dict[str, Any]] = {}
         for s in spans:
             agg = agg_by_key.setdefault(
@@ -511,11 +510,19 @@ def trace_span(
     registry ``_Timer``) — one context manager instead of three
     hand-rolled pairings.  With no recorder registered and no timer it
     returns the bare annotation: exactly the pre-tracing call sites'
-    behavior and cost."""
+    behavior and cost.
+
+    ``attrs`` (ints, floats, bools, short strings) and ``request_id`` (as
+    ``rid``) go to the annotation and to the ring alike: the profiler's
+    trace, where host spans and device events share a clock, then says
+    which bucket, which request, how many slots."""
     recs = list(_RECORDERS) if _RECORDERS else ()
     cms: List[Any] = []
     if annotate:
-        cms.append(xprof_span(name))
+        stats = dict(attrs) if attrs else {}
+        if request_id is not None:
+            stats["rid"] = request_id
+        cms.append(xprof_span(name, **stats))
     for rec in recs:
         cms.append(rec.span(name, track=track, request_id=request_id,
                             attrs=attrs))

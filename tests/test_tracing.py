@@ -138,17 +138,19 @@ def test_overlapping_slices_do_not_multiply_count_self_time():
 
 
 def test_summary_disambiguates_same_name_across_tracks():
-    """'stoke/step' is both a facade phase and the engine apply dispatch;
-    the summary must keep the two apart instead of mislabeling one."""
+    """'stoke/io' is both the loader's fetch (track ``data``) and a
+    checkpoint's IO (track ``io``); the summary must keep the two apart
+    instead of mislabeling one.  (The engine's apply dispatch no longer
+    shares the facade phase's name: it is ``stoke/apply``.)"""
     rec = TraceRecorder(ring_size=64)
-    rec.add("stoke/step", 0.0, 2.0, track="facade")
-    rec.add("stoke/step", 0.5, 1.5, track="step")
+    rec.add("stoke/io", 0.0, 2.0, track="data")
+    rec.add("stoke/io", 0.5, 1.5, track="io")
     rec.add("stoke/place", 2.0, 2.5, track="facade")
     s = rec.summary()
-    assert "stoke/step [facade]" in s["by_name"]
-    assert "stoke/step [step]" in s["by_name"]
-    assert s["by_name"]["stoke/step [facade]"]["track"] == "facade"
-    assert s["by_name"]["stoke/step [step]"]["self_s"] == pytest.approx(1.0)
+    assert "stoke/io [data]" in s["by_name"]
+    assert "stoke/io [io]" in s["by_name"]
+    assert s["by_name"]["stoke/io [data]"]["track"] == "data"
+    assert s["by_name"]["stoke/io [io]"]["self_s"] == pytest.approx(1.0)
     # track-unique names keep their bare label
     assert "stoke/place" in s["by_name"]
 
@@ -188,6 +190,81 @@ def test_trace_span_without_recorder_is_annotation_only():
     with cm:
         pass
     trace_point("stoke/nothing")  # no-op, must not raise
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    """Every ``(name, stats)`` handed to the profiler annotation, in place
+    of the annotation itself (whose content only a profiler session
+    shows; tests/benchmark/test_benchmark_spans.py reads one)."""
+    import contextlib
+
+    from stoke_tpu.telemetry import tracing
+
+    seen = []
+
+    def fake_xprof_span(name, **stats):
+        seen.append((name, stats))
+        return contextlib.nullcontext()
+
+    monkeypatch.setattr(tracing, "xprof_span", fake_xprof_span)
+    return seen
+
+
+@pytest.mark.parametrize("with_ring", [False, True])
+@pytest.mark.parametrize("attrs,request_id,stats", [
+    (None, None, {}),
+    ({"padded_len": 128, "queue_wait_us": 2.5}, None,
+     {"padded_len": 128, "queue_wait_us": 2.5}),
+    ({"program": "fused"}, 7, {"program": "fused", "rid": 7}),
+    (None, 0, {"rid": 0}),
+])
+def test_trace_span_forwards_attrs_to_annotation_and_ring(
+        annotations, tmp_path, with_ring, attrs, request_id, stats):
+    rec = None
+    if with_ring:
+        rec = TraceRecorder(ring_size=8, output_dir=str(tmp_path))
+        register_recorder(rec)
+    try:
+        with trace_span("serve/prefill", track="serve",
+                        request_id=request_id, attrs=attrs):
+            pass
+    finally:
+        if rec is not None:
+            unregister_recorder(rec)
+    assert annotations == [("serve/prefill", stats)]
+    if rec is not None:
+        (span,) = rec.spans()
+        assert (span.attrs or {}) == (attrs or {})
+        assert span.request_id == request_id
+
+
+@pytest.mark.parametrize("api,expected", [
+    ("4call", ["stoke/model", "stoke/loss", "stoke/accum", "stoke/track",
+               "stoke/backward", "stoke/step", "stoke/apply"]),
+    ("train_step", ["stoke/train_step", "stoke/dispatch", "stoke/track"]),
+])
+def test_facade_calls_annotate_with_wall_clock_breakdown_off(
+        annotations, tmp_path, api, expected):
+    """No TelemetryConfig, ProfilerConfig or TraceConfig: the four calls
+    (and the fused one) are still in a profiler trace, and the engine's
+    apply dispatch is ``stoke/apply``, not a second ``stoke/step``."""
+    s = _linear_stoke(tmp_path, with_trace=False)
+    assert not s._wall_clock_enabled
+    x = np.ones((4, 8), np.float32)
+    y = np.zeros((4, 4), np.float32)
+    if api == "4call":
+        loss = s.loss(s.model(x), y)
+        s.backward(loss)
+        s.step()
+    else:
+        s.train_step(x, y)
+    names = [n for n, _ in annotations if n != "stoke/place"]
+    assert names == expected
+    programs = [st["program"] for n, st in annotations
+                if n == "stoke/dispatch"]
+    assert programs == (["fused"] if api == "train_step" else [])
+    assert s.wall_clock_breakdown == {}
 
 
 def test_telemetry_phase_records_span(recorder):
