@@ -605,6 +605,91 @@ def dense_reference(q, k, v, mask=None, causal=False):
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32))
 
 
+def _one_layer_pool(pages):
+    """``[NB, BS, H, D]`` pages of one layer as the ``[1, NB, BS, H*D]`` pool
+    the serving cache stores (:func:`paged_pool_attention`'s layout)."""
+    NB, BS, H, D = pages.shape
+    return pages.reshape(1, NB, BS, H * D)
+
+
+def paged_pool_attention(q, k_pool, v_pool, layer, block_tables, positions):
+    """Attention of ``q`` over one layer of the serving cache's page pool,
+    in the layout the pool is stored in (the implementation behind
+    :func:`paged_decode_attention`, :func:`paged_prefill_chunk_attention`
+    and :func:`paged_verify_attention`).
+
+    The pool is ``[n_layers, NB, BS, H*D]``: a cached token is one row of
+    ``H*D`` lanes, heads side by side (``serving/kv_cache.py``).  Each
+    slot's window is ONE gather at ``(layer, block_tables)`` out of the
+    whole pool — no layer plane is sliced out first, and the table's
+    entries are promised in bounds (the allocator hands out nothing else;
+    unused entries point at the scratch block 0), so no select runs over
+    the window.  The arithmetic stays on those flat rows: the queries are
+    laid out block-diagonally, row ``(s, h)`` holding head ``h``'s query in
+    head ``h``'s lanes and zeros elsewhere, so one ``[S*H, H*D] x [H*D, W]``
+    matmul per slot gives every head's scores and one ``[S*H, W] x [W,
+    H*D]`` matmul every head's values (of whose ``H*D`` output lanes row
+    ``(s, h)`` keeps head ``h``'s).  The zeros cost ``H`` times the MXU
+    work and save reshaping the window to ``[.., H, D]``, which on the
+    device is a copy of the window into a padded tiling; the window is the
+    larger by far.  The softmax is fp32 and masked by position.
+
+    A single query row (decode) multiplies at ``Precision.HIGHEST``, so the
+    float32 cache is read with float32 products (the one-row case is a
+    multiply-reduce in exact arithmetic; a default-precision matmul would
+    round K, V and the probabilities to bf16).  Several rows (chunk,
+    verify) are matmuls proper and run at the default precision.
+
+    Args:
+        q: ``[B, H, S, D]`` queries.
+        k_pool / v_pool: ``[n_layers, NB, BS, H*D]`` page pools.
+        layer: static layer index into the pools.
+        block_tables: ``[B, MAX_BLOCKS] int32`` per-slot block ids.
+        positions: ``[B, S] int32`` — query row ``s`` of slot ``b`` attends
+            window positions ``<= positions[b, s]``.
+
+    Returns ``[B, H, S, D]`` attention outputs in the query dtype.
+    """
+    B, H, S, D = q.shape
+    HD = H * D
+    if k_pool.shape != v_pool.shape or k_pool.ndim != 4:
+        raise ValueError(
+            f"k_pool/v_pool must be identical [n_layers, NB, BS, H*D] "
+            f"pools, got {k_pool.shape}/{v_pool.shape}"
+        )
+    if k_pool.shape[3] != HD:
+        raise ValueError(
+            f"the pool's rows are {k_pool.shape[3]} wide; the query's "
+            f"heads x head_dim is {H} x {D}"
+        )
+    MB = block_tables.shape[1]
+    W = MB * k_pool.shape[2]
+
+    def window(pool):  # [B, MB, BS, HD] -> [B, W, HD]: merges whole tiles
+        rows = pool.at[layer, block_tables].get(mode="promise_in_bounds")
+        return rows.reshape(B, W, HD).astype(jnp.float32)
+
+    precision = jax.lax.Precision.HIGHEST if S == 1 else None
+    # lane j belongs to head j // D
+    own = (
+        jnp.arange(HD, dtype=jnp.int32)[None, :] // D
+        == jnp.arange(H, dtype=jnp.int32)[:, None]
+    ).astype(jnp.float32)  # [H, HD]
+    q_rows = jnp.swapaxes(q, 1, 2).reshape(B, S, 1, HD).astype(jnp.float32)
+    q_diag = (q_rows * own).reshape(B, S * H, HD)
+    s = jnp.einsum(
+        "brj,bwj->brw", q_diag, window(k_pool), precision=precision
+    ) / (D**0.5)
+    w_pos = jnp.arange(W, dtype=jnp.int32)
+    valid = w_pos[None, None, :] <= positions[:, :, None]  # [B, S, W]
+    s = jnp.where(jnp.repeat(valid, H, axis=1), s, _NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("brw,bwj->brj", p, window(v_pool), precision=precision)
+    # row (s, h) keeps head h's lanes; the rows of one s then sum to [HD]
+    out = (out.reshape(B, S, H, HD) * own).sum(axis=2).reshape(B, S, H, D)
+    return jnp.swapaxes(out, 1, 2).astype(q.dtype)
+
+
 def paged_decode_attention(q, k_pages, v_pages, block_tables, context_lens):
     """Decode-mode attention over a paged KV-cache (ISSUE 9 serving path).
 
@@ -612,47 +697,39 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, context_lens):
     attends over its own sequence's cached K/V, which lives scattered
     across a block pool addressed by a per-request block table (the
     vLLM-style layout, sized so freed blocks refill mid-flight —
-    ``stoke_tpu.serving.kv_cache``).  The kernel gathers each request's
-    blocks from the pool and runs the same fp32 masked softmax the dense
-    reference uses — the flash recurrence degenerates at q-length 1 (one
-    online-softmax row), so the gather IS the whole memory schedule and
-    XLA lowers it to per-block dynamic slices out of HBM
-    (pallas_guide.md: KV caches live in HBM;
-    :func:`paged_decode_attention_pallas` streams the blocks through VMEM
-    itself, with the math below as its reference semantics).
+    ``stoke_tpu.serving.kv_cache``).  Each request's blocks are gathered
+    from the pool and scored with an fp32 masked softmax — the flash
+    recurrence degenerates at q-length 1 (one online-softmax row), so the
+    gather IS the whole memory schedule
+    (:func:`paged_decode_attention_pallas` streams the blocks through VMEM
+    itself, with this function as its reference semantics).  A thin
+    wrapper: the pages are viewed as a one-layer pool and
+    :func:`paged_pool_attention`, which the serving engine calls on its
+    whole pool, does the work.
 
     Args:
         q: ``[B, H, 1, D]`` current-token queries (one per decode slot).
         k_pages / v_pages: ``[NB, BS, H, D]`` block pool for ONE layer
             (NB blocks of BS tokens).
         block_tables: ``[B, MAX_BLOCKS] int32`` — each slot's block ids
-            into the pool, in sequence order; unused entries may point
-            anywhere (the reserved scratch block 0 by convention) — they
-            are masked by ``context_lens``.
+            into the pool, in sequence order; unused entries point at a
+            legal block (the reserved scratch block 0 by convention) —
+            they are masked by ``context_lens``.
         context_lens: ``[B] int32`` — valid tokens per slot INCLUDING the
             current one (positions ``>= context_lens[b]`` are masked).
 
     Returns ``[B, H, 1, D]`` attention outputs in the query dtype.
     """
-    B, H, one, D = q.shape
-    if one != 1:
+    if q.shape[2] != 1:
         raise ValueError(
             f"paged_decode_attention is single-token decode; got q-length "
-            f"{one} (prefill goes through flash_attention/dense_attention)"
+            f"{q.shape[2]} (prefill goes through flash_attention/"
+            f"dense_attention)"
         )
-    NB, BS = k_pages.shape[0], k_pages.shape[1]
-    # gather each slot's window: [B, MAX_BLOCKS, BS, H, D] -> [B, W, H, D]
-    k = jnp.take(k_pages, block_tables, axis=0).reshape(B, -1, H, D)
-    v = jnp.take(v_pages, block_tables, axis=0).reshape(B, -1, H, D)
-    s = jnp.einsum(
-        "bhqd,bwhd->bhqw", q.astype(jnp.float32), k.astype(jnp.float32)
-    ) / (D**0.5)
-    w_pos = jnp.arange(k.shape[1], dtype=jnp.int32)
-    valid = w_pos[None, :] < context_lens[:, None]  # [B, W]
-    s = jnp.where(valid[:, None, None, :], s, _NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqw,bwhd->bhqd", p, v.astype(jnp.float32))
-    return out.astype(q.dtype)
+    return paged_pool_attention(
+        q, _one_layer_pool(k_pages), _one_layer_pool(v_pages), 0,
+        block_tables, context_lens.astype(jnp.int32)[:, None] - 1,
+    )
 
 
 #: default number of KV pages fetched HBM→VMEM per kernel step (ISSUE 13)
@@ -777,9 +854,9 @@ def _paged_attention_pallas(q, k_pages, v_pages, block_tables, positions,
     )
     # row h*S + s of the flattened queries carries positions[b, s]
     qpos = jnp.tile(positions.astype(jnp.int32), (1, H)).reshape(B, rows, 1)
-    # merging (BS, H) keeps the pool's tiled layout whenever H fills whole
-    # sublane tiles (8 rows of f32, 16 of bf16), so XLA lowers these
-    # reshapes to bitcasts, not copies of the pool
+    # the kernel's view of a page: one row per (token, head).  The serving
+    # cache stores a token as one H*D-wide row, so from its pool these
+    # reshapes are copies of the layer's pages into a D-minor tiling
     k_flat = k_pages.reshape(NB, BS * H, D)
     v_flat = v_pages.reshape(NB, BS * H, D)
 
@@ -890,18 +967,10 @@ def paged_prefill_chunk_attention(q, k_pages, v_pages, block_tables,
 
     Returns ``[B, H, C, D]`` attention outputs in the query dtype.
     """
-    B, H, C, D = q.shape
-    k = jnp.take(k_pages, block_tables, axis=0).reshape(B, -1, H, D)
-    v = jnp.take(v_pages, block_tables, axis=0).reshape(B, -1, H, D)
-    s = jnp.einsum(
-        "bhqd,bwhd->bhqw", q.astype(jnp.float32), k.astype(jnp.float32)
-    ) / (D**0.5)
-    w_pos = jnp.arange(k.shape[1], dtype=jnp.int32)
-    valid = w_pos[None, None, :] <= positions[:, :, None]  # [B, C, W]
-    s = jnp.where(valid[:, None, :, :], s, _NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqw,bwhd->bhqd", p, v.astype(jnp.float32))
-    return out.astype(q.dtype)
+    return paged_pool_attention(
+        q, _one_layer_pool(k_pages), _one_layer_pool(v_pages), 0,
+        block_tables, positions,
+    )
 
 
 #: default KV pages fetched per step by the speculative verify kernel

@@ -15,7 +15,7 @@ Three pieces:
   K/V there, so the compiled decode program always runs the full fixed-shape
   slot batch with no active-mask branching.
 - :class:`PagedKVCache` — the device arrays: ``[n_layers, n_blocks,
-  block_size, heads, head_dim]`` K and V page planes, created zeroed on the
+  block_size, heads * head_dim]`` K and V page pools, created zeroed on the
   target device/mesh.  The serving engine threads them functionally through
   its compiled programs (donated, so updates are in-place in HBM).
 - :class:`PagedAttentionHook` — the per-trace bridge into ``models/gpt.py``:
@@ -24,7 +24,7 @@ Three pieces:
   runs ordinary causal attention (dense or the flash kernel) over the
   prompt; in decode mode it writes the single fresh token's K/V and attends
   over the gathered cached blocks
-  (:func:`stoke_tpu.ops.flash_attention.paged_decode_attention`).  The hook
+  (:func:`stoke_tpu.ops.flash_attention.paged_pool_attention`).  The hook
   carries the updated page arrays across layers within one trace; the
   caller reads them back after ``apply`` and returns them from the jitted
   program.
@@ -40,10 +40,8 @@ import jax.numpy as jnp
 from stoke_tpu.models.bert import dense_attention
 from stoke_tpu.ops.flash_attention import (
     flash_attention,
-    paged_decode_attention,
     paged_decode_attention_pallas,
-    paged_prefill_chunk_attention,
-    paged_verify_attention,
+    paged_pool_attention,
     paged_verify_attention_pallas,
 )
 
@@ -116,11 +114,23 @@ class BlockAllocator:
 
 
 class PagedKVCache:
-    """The device-side block pool: K and V page planes per layer.
+    """The device-side block pool: K and V pages of every layer.
 
-    Layout ``[n_layers, n_blocks, block_size, heads, head_dim]`` — layer
-    outermost so each layer's hook update is one static-index plane, block
-    next so a request's window gathers as per-block slices out of HBM.
+    Stored ``[n_layers, n_blocks, block_size, heads * head_dim]``: a cached
+    token is ONE row, its heads side by side.  The minor dimension is then
+    whole 128-lane tiles at every width in use (1024, 768, the tests' 128)
+    and ``block_size`` whole sublanes, so row-major is the layout the
+    device keeps the pool in at rest AND the one every program computes
+    on: nothing is padded, no program converts the pool on its way in or
+    out, and the donated pool is updated in place.  (With a trailing
+    ``[heads, head_dim]`` the 64-wide minor dimension makes the device
+    keep the block index in the lanes at rest, and every program copies K
+    and V to a ``head_dim``-padded tiling and back: four copies of the
+    pool a dispatch.)  A width that is no multiple of 128 is still
+    correct, and pads.  Layer
+    outermost, block next: a request's window is one gather of whole
+    blocks at ``(layer, block_table)``, a write one scatter of rows at
+    ``(layer, block, offset)``.
 
     ``sharding`` (optional ``jax.sharding.Sharding``) places the pool on
     the serving mesh — replicated by default (data-parallel serving
@@ -142,7 +152,7 @@ class PagedKVCache:
         self.block_size = int(block_size)
         self.num_blocks = int(num_blocks)
         self.dtype = jnp.dtype(dtype)
-        shape = (n_layers, num_blocks, block_size, heads, head_dim)
+        shape = (n_layers, num_blocks, block_size, heads * head_dim)
         k = jnp.zeros(shape, dtype)
         v = jnp.zeros(shape, dtype)
         if sharding is not None:
@@ -157,10 +167,11 @@ class PagedKVCache:
         return int(self.k_pages.size + self.v_pages.size) * self.dtype.itemsize
 
 
-def _flatten_heads(t):
-    """[B, H, L, D] attention layout -> [B*L, H, D] page-write layout."""
+def _token_rows(t):
+    """[B, H, L, D] attention layout -> [B*L, H*D] rows as the pool stores
+    them."""
     B, H, L, D = t.shape
-    return jnp.swapaxes(t, 1, 2).reshape(B * L, H, D)
+    return jnp.swapaxes(t, 1, 2).reshape(B * L, H * D)
 
 
 class PagedAttentionHook:
@@ -173,7 +184,11 @@ class PagedAttentionHook:
     ``self.v_pages`` so the program returns the updated pool.
 
     Args:
-        k_pages / v_pages: ``[n_layers, NB, BS, H, D]`` pool planes.
+        k_pages / v_pages: ``[n_layers, NB, BS, H*D]`` page pools
+            (:class:`PagedKVCache`'s layout).  Every access addresses the
+            WHOLE pool at ``(layer, block, ...)``: no layer's pages are
+            sliced out, and every index is promised in bounds (block ids
+            come from the allocator or are the scratch block).
         block_tables: ``[B, MAX_BLOCKS] int32`` per-slot block ids.
         positions: ``[B, L] int32`` token positions being written this
             call (prefill: ``arange`` rows; decode: each slot's current
@@ -190,9 +205,10 @@ class PagedAttentionHook:
             padding query rows past it steer to scratch).
         attention_impl: prefill kernel, ``"dense"`` or ``"flash"``.
         decode_impl: decode kernel — ``"reference"`` (the jnp
-            gathered-block :func:`paged_decode_attention`) or
+            gathered-block :func:`paged_pool_attention`) or
             ``"pallas"`` (the ISSUE 13 streaming kernel
-            :func:`paged_decode_attention_pallas`).
+            :func:`paged_decode_attention_pallas`, which takes one layer's
+            pages reshaped to ``[NB, BS, H, D]``).
         decode_pages_per_block: the pallas kernel's block knob
             (``None`` = its default; autotune catalog entry).
         decode_interpret: run the pallas kernel through the interpreter
@@ -279,13 +295,22 @@ class PagedAttentionHook:
             # rejected tail exactly — acceptance is only known after the
             # forward, but the chunk-attention semantics need the draft
             # K/V resident DURING it
-            old_k = self.k_pages[layer, blocks, offs]
-            old_v = self.v_pages[layer, blocks, offs]
+            old_k, old_v = (
+                pool.at[layer, blocks, offs].get(mode="promise_in_bounds")
+                for pool in (self.k_pages, self.v_pages)
+            )
             self._saved.append((blocks, offs, old_k, old_v))
-        kw = _flatten_heads(k).astype(self.k_pages.dtype)
-        vw = _flatten_heads(v).astype(self.v_pages.dtype)
-        self.k_pages = self.k_pages.at[layer, blocks, offs].set(kw)
-        self.v_pages = self.v_pages.at[layer, blocks, offs].set(vw)
+        self._set_rows(layer, blocks, offs, _token_rows(k), _token_rows(v))
+
+    def _set_rows(self, layer: int, blocks, offs, k_rows, v_rows) -> None:
+        """Scatter ``[N, H*D]`` rows into both pools at ``(layer,
+        blocks[n], offs[n])``."""
+        self.k_pages, self.v_pages = (
+            pool.at[layer, blocks, offs].set(
+                rows.astype(pool.dtype), mode="promise_in_bounds"
+            )
+            for pool, rows in ((self.k_pages, k_rows), (self.v_pages, v_rows))
+        )
 
     def rollback(self, n_keep) -> None:
         """Restore every verify write PAST the accepted window (ISSUE 17).
@@ -314,8 +339,7 @@ class PagedAttentionHook:
         keep = within < n_keep.astype(jnp.int32)[slot]
         for layer, (blocks, offs, old_k, old_v) in enumerate(self._saved):
             blocks_r = jnp.where(keep, SCRATCH_BLOCK, blocks)
-            self.k_pages = self.k_pages.at[layer, blocks_r, offs].set(old_k)
-            self.v_pages = self.v_pages.at[layer, blocks_r, offs].set(old_v)
+            self._set_rows(layer, blocks_r, offs, old_k, old_v)
 
     # ----------------------------- attention --------------------------- #
 
@@ -330,57 +354,41 @@ class PagedAttentionHook:
                     "dropout is not supported"
                 )
             self._write_layer(layer, k, v)
-            if self.mode == "decode":
-                if self.decode_impl == "pallas":
-                    return paged_decode_attention_pallas(
-                        q,
-                        self.k_pages[layer],
-                        self.v_pages[layer],
-                        self.block_tables,
-                        self.lengths,
-                        pages_per_block=self.decode_pages_per_block,
-                        interpret=self.decode_interpret,
-                    )
-                return paged_decode_attention(
-                    q,
-                    self.k_pages[layer],
-                    self.v_pages[layer],
-                    self.block_tables,
-                    self.lengths,
+            if self.mode != "prefill":
+                # decode: one query row at the last cached position.
+                # chunk: the chunk's K/V were just written, so attention is
+                # one paged gather masked causally by GLOBAL position —
+                # earlier chunks' prefix and the intra-chunk causal mask
+                # fall out of the same predicate.  verify: S = k+1 query
+                # rows over the paged prefix (draft K/V just written)
+                # under that same predicate.
+                positions = (
+                    self.lengths.astype(jnp.int32)[:, None] - 1
+                    if self.mode == "decode"
+                    else self.positions
                 )
-            if self.mode == "verify":
-                # speculative verify: S = k+1 query rows attend the paged
-                # prefix (draft K/V just written) under the chunk-style
-                # positional predicate; reference delegates to the chunk
-                # attention, pallas streams pages once for all S rows
-                if self.decode_impl == "pallas":
+                if self.decode_impl == "pallas" and self.mode != "chunk":
+                    # the kernel streams one layer's [NB, BS, H, D] pages
+                    # once for all query rows
+                    NB, BS = self.k_pages.shape[1:3]
+                    k_l, v_l = (
+                        pool[layer].reshape(NB, BS, q.shape[1], q.shape[3])
+                        for pool in (self.k_pages, self.v_pages)
+                    )
+                    if self.mode == "decode":
+                        return paged_decode_attention_pallas(
+                            q, k_l, v_l, self.block_tables, self.lengths,
+                            pages_per_block=self.decode_pages_per_block,
+                            interpret=self.decode_interpret,
+                        )
                     return paged_verify_attention_pallas(
-                        q,
-                        self.k_pages[layer],
-                        self.v_pages[layer],
-                        self.block_tables,
-                        self.positions,
+                        q, k_l, v_l, self.block_tables, positions,
                         pages_per_block=self.verify_pages_per_block,
                         interpret=self.decode_interpret,
                     )
-                return paged_verify_attention(
-                    q,
-                    self.k_pages[layer],
-                    self.v_pages[layer],
-                    self.block_tables,
-                    self.positions,
-                )
-            if self.mode == "chunk":
-                # chunked prefill: the chunk's K/V were just written, so
-                # attention is one paged gather masked causally by GLOBAL
-                # position — earlier chunks' prefix and the intra-chunk
-                # causal mask fall out of the same predicate
-                return paged_prefill_chunk_attention(
-                    q,
-                    self.k_pages[layer],
-                    self.v_pages[layer],
-                    self.block_tables,
-                    self.positions,
+                return paged_pool_attention(
+                    q, self.k_pages, self.v_pages, layer,
+                    self.block_tables, positions,
                 )
             # prefill: ordinary causal attention over the (padded) prompt
             # — the pages were just written for DECODE's benefit; the

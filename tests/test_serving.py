@@ -30,6 +30,8 @@ from stoke_tpu.serving import (
 from stoke_tpu.status import StokeStatus, StokeValidationError
 from stoke_tpu.utils import init_module
 
+from _paged_reference import flat_pool_case, old_paged_attention
+
 pytestmark = pytest.mark.serving
 
 VOCAB = 257
@@ -1361,3 +1363,192 @@ def test_sample_tokens_top_p_disabled_keeps_full_support(rng):
         if len(seen) == V:
             break
     assert seen == set(range(V)), seen
+
+
+# --------------------------------------------------------------------------- #
+# ISSUE 27: the page pool stored [n_layers, NB, BS, H*D], addressed whole
+# --------------------------------------------------------------------------- #
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("H,D", [(2, 64), (12, 64), (16, 64)])
+@pytest.mark.parametrize("mode", ["decode", "chunk"])
+def test_flat_pool_attention_matches_old_pages_formulation(mode, H, D, dtype):
+    """The flat-pool attention (whole pool + layer index, one gather, the
+    arithmetic on H*D-wide rows) against the per-layer ``[NB, BS, H, D]``
+    formulation it replaced, at the widths in use, on both pool dtypes."""
+    from stoke_tpu.ops.flash_attention import paged_pool_attention
+
+    S = 1 if mode == "decode" else 5
+    q, k_pool, v_pool, tables, positions = flat_pool_case(H, D, dtype, S)
+    NB, BS = k_pool.shape[1:3]
+    for layer in (0, 1):
+        out = paged_pool_attention(
+            q, k_pool, v_pool, layer, tables, positions
+        )
+        ref = old_paged_attention(
+            q,
+            k_pool[layer].reshape(NB, BS, H, D),
+            v_pool[layer].reshape(NB, BS, H, D),
+            tables, positions,
+        )
+        assert out.shape == q.shape and out.dtype == q.dtype
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ref), atol=2e-5
+        )
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_pages_signature_wrappers_match_old_formulation(dtype, rng):
+    """The module-level ``[NB, BS, H, D]`` entries are thin wrappers over
+    the flat implementation and keep their semantics."""
+    from stoke_tpu.ops.flash_attention import (
+        paged_prefill_chunk_attention,
+        paged_verify_attention,
+    )
+
+    q, k_pages, v_pages, tables, ctx = _paged_pool(rng)
+    k_pages = jnp.asarray(k_pages).astype(dtype)
+    v_pages = jnp.asarray(v_pages).astype(dtype)
+    tables, ctx = jnp.asarray(tables), jnp.asarray(ctx)
+    np.testing.assert_allclose(
+        np.asarray(paged_decode_attention(
+            jnp.asarray(q), k_pages, v_pages, tables, ctx
+        )),
+        np.asarray(old_paged_attention(
+            jnp.asarray(q), k_pages, v_pages, tables, ctx[:, None] - 1
+        )),
+        atol=2e-5,
+    )
+    S = 3
+    qs = jnp.asarray(rng.normal(size=(3, 4, S, 16)).astype(np.float32))
+    positions = jnp.maximum(ctx[:, None] - S + jnp.arange(S)[None, :], 0)
+    ref = old_paged_attention(qs, k_pages, v_pages, tables, positions)
+    for fn in (paged_prefill_chunk_attention, paged_verify_attention):
+        np.testing.assert_allclose(
+            np.asarray(fn(qs, k_pages, v_pages, tables, positions)),
+            np.asarray(ref), atol=2e-5,
+        )
+
+
+def test_flat_pool_attention_rejects_a_pool_of_another_width():
+    from stoke_tpu.ops.flash_attention import paged_pool_attention
+
+    q = jnp.zeros((1, 2, 1, 64))
+    with pytest.raises(ValueError, match="rows are 64 wide"):
+        paged_pool_attention(
+            q, jnp.zeros((1, 2, 8, 64)), jnp.zeros((1, 2, 8, 64)), 0,
+            jnp.zeros((1, 1), jnp.int32), jnp.zeros((1, 1), jnp.int32),
+        )
+    with pytest.raises(ValueError, match="identical"):
+        paged_pool_attention(
+            q, jnp.zeros((2, 8, 2, 64)), jnp.zeros((1, 2, 8, 128)), 0,
+            jnp.zeros((1, 1), jnp.int32), jnp.zeros((1, 1), jnp.int32),
+        )
+
+
+def test_cache_pool_is_stored_one_row_per_token():
+    from stoke_tpu.serving import PagedKVCache
+
+    cache = PagedKVCache(3, 5, 8, 12, 64, dtype=jnp.bfloat16)
+    assert cache.k_pages.shape == cache.v_pages.shape == (3, 5, 8, 768)
+    assert cache.nbytes == 2 * 3 * 5 * 8 * 768 * 2
+    model, params = _gpt("dense")
+    eng = ServingEngine(model, params, _cfg())
+    # tiny: 2 layers, 2 heads of 64; 4 slots x 8 blocks + scratch
+    assert eng.cache.k_pages.shape == (2, 33, 8, 128)
+
+
+@pytest.mark.parametrize("attn", ["dense", "flash"])
+def test_streams_match_cache_free_forward_at_an_unaligned_width(attn, rng):
+    """Prefill-then-decode token streams equal the cache-free forward's at
+    3 heads of 64: rows 192 wide, no multiple of the 128 lanes (the layout
+    pads there and stays correct), with requests staggered over slots."""
+    from stoke_tpu.models.bert import BERT_SIZES, BertSize
+
+    BERT_SIZES["p27-3x64"] = BertSize(2, 192, 3, 384)
+    kwargs = {}
+    if attn == "flash":
+        kwargs = dict(
+            attention_fn=make_flash_attention(causal=True),
+            attention_is_causal=True,
+        )
+    model = GPT(
+        vocab_size=VOCAB, size_name="p27-3x64", max_len=128,
+        dropout_rate=0.0, **kwargs
+    )
+    params = init_module(
+        model, jax.random.PRNGKey(1), np.zeros((1, 8), np.int32), train=False
+    )["params"]
+    eng = ServingEngine(
+        model, params, _cfg(attention=attn, max_seqs=2, max_new_tokens=5)
+    )
+    assert eng.cache.k_pages.shape[-1] == 192
+    prompts = [
+        rng.integers(1, VOCAB, size=n).astype(np.int32) for n in (13, 5, 9)
+    ]
+    outs = eng.generate(prompts, max_new_tokens=5)
+    for prompt, out in zip(prompts, outs):
+        assert out == _ref_greedy(model, params, prompt, 5)
+    assert eng.allocator.occupancy == 0.0
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, sub-jaxprs (pjit, custom calls) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+@pytest.mark.parametrize("program", ["serve_decode", "serve_prefill"])
+def test_serve_programs_address_the_whole_pool(program, rng):
+    """Structure of the two programs the serve cell runs: the pool argument
+    is ``[layers, blocks, block, heads * head_dim]``; nothing cuts a whole
+    layer's pages out of it; the window's gather reads the pool itself and
+    is not in fill mode (no select over the window)."""
+    from jax.lax import GatherScatterMode
+
+    model, params = _gpt("flash")
+    eng = ServingEngine(model, params, _cfg(attention="flash"))
+    pool_shape = eng.cache.k_pages.shape
+    n_layers, NB, BS, HD = pool_shape
+    assert HD == eng._heads * eng._head_dim == 128
+    if program == "serve_decode":
+        tokens, positions, tables, context = eng.scheduler.decode_batch()
+        fn, args = eng._decode_fn, (tokens, positions, tables, context)
+    else:
+        fn = eng._prefill_fn
+        args = (
+            np.zeros((1, 16), np.int32),
+            np.zeros((1, eng._max_blocks_per_seq), np.int32),
+            np.array([11], np.int32),
+        )
+    args = (eng.qparams, eng.cache.k_pages, eng.cache.v_pages) + tuple(
+        jnp.asarray(a) for a in args
+    )
+    text = jax.jit(fn).lower(*args).as_text()
+    assert text.count(f"tensor<{n_layers}x{NB}x{BS}x{HD}xf32>") >= 4
+    assert f"x{BS}x{eng._heads}x{eng._head_dim}xf32>" not in text
+    plane = NB * BS * HD
+    pool_gathers = 0
+    for eqn in _eqns(jax.make_jaxpr(fn)(*args).jaxpr):
+        name = eqn.primitive.name
+        if name in ("slice", "dynamic_slice", "squeeze", "index_in_dim"):
+            assert all(
+                int(np.prod(v.aval.shape)) < plane for v in eqn.outvars
+            ), f"{name} cuts a layer's pages out of the pool: {eqn}"
+        if name == "gather" and eqn.invars[0].aval.shape == pool_shape:
+            pool_gathers += 1
+            assert eqn.params["mode"] in (
+                GatherScatterMode.PROMISE_IN_BOUNDS, GatherScatterMode.CLIP
+            ), eqn.params["mode"]
+        if name == "scatter" and eqn.invars[0].aval.shape == pool_shape:
+            # a write is rows at (layer, block, offset) of the whole pool
+            assert eqn.invars[2].aval.shape[-1] == HD
+    # decode reads K and V of each layer's window; prefill only writes
+    expected = 2 * n_layers if program == "serve_decode" else 0
+    assert pool_gathers == expected

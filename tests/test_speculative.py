@@ -207,8 +207,9 @@ def test_verify_rollback_never_dirties_cache():
     L_, NB, BS, H, D = 1, 5, 4, 2, 3
     B, S = 2, 3
     r = np.random.default_rng(0)
-    k0 = jnp.asarray(r.normal(size=(L_, NB, BS, H, D)).astype(np.float32))
-    v0 = jnp.asarray(r.normal(size=(L_, NB, BS, H, D)).astype(np.float32))
+    # the pool as PagedKVCache stores it: a token is one H*D-wide row
+    k0 = jnp.asarray(r.normal(size=(L_, NB, BS, H * D)).astype(np.float32))
+    v0 = jnp.asarray(r.normal(size=(L_, NB, BS, H * D)).astype(np.float32))
     tables = jnp.asarray([[1, 2], [3, 4]], np.int32)
     # slot 0 verifies positions 2..4 (crossing its block boundary at 4),
     # slot 1 positions 0..2
@@ -245,6 +246,87 @@ def test_verify_rollback_never_dirties_cache():
     assert set(diff[:, 1]) <= {SCRATCH_BLOCK} | {
         int(tables[s, p // BS]) for s, p in rejected
     }
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("H,D", [(2, 64), (12, 64), (16, 64)])
+def test_flat_pool_verify_matches_old_pages_formulation(H, D, dtype):
+    """Verify's S = k+1 query rows over the flat pool (whole pool + layer
+    index) against the per-layer ``[NB, BS, H, D]`` formulation, ragged
+    contexts and scratch-pointing table entries included; the Pallas
+    kernel on one layer's reshaped pages agrees too."""
+    from _paged_reference import flat_pool_case, old_paged_attention
+
+    from stoke_tpu.ops.flash_attention import (
+        paged_pool_attention,
+        paged_verify_attention_pallas,
+    )
+
+    S = 4
+    q, k_pool, v_pool, tables, positions = flat_pool_case(H, D, dtype, S)
+    NB, BS = k_pool.shape[1:3]
+    layer = 1
+    out = paged_pool_attention(q, k_pool, v_pool, layer, tables, positions)
+    pages = (
+        k_pool[layer].reshape(NB, BS, H, D),
+        v_pool[layer].reshape(NB, BS, H, D),
+    )
+    ref = old_paged_attention(q, *pages, tables, positions)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    if (H, D) == (2, 64):  # the interpreter is slow at the wide shapes
+        pal = paged_verify_attention_pallas(
+            q, *pages, tables, positions, interpret=True
+        )
+        np.testing.assert_allclose(
+            np.asarray(pal), np.asarray(ref), atol=2e-5
+        )
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_verify_rollback_restores_flat_pool_exactly(dtype):
+    """Every layer of a flat pool: rows written by verify and rolled back
+    in full are bit-identical to the pool before the dispatch, everywhere
+    but the scratch block; rows kept hold what was written."""
+    n_layers, NB, BS, H, D = 3, 5, 4, 2, 64
+    B, S = 2, 3
+    r = np.random.default_rng(1)
+    k0, v0 = (
+        jnp.asarray(
+            r.normal(size=(n_layers, NB, BS, H * D)).astype(np.float32)
+        ).astype(dtype)
+        for _ in range(2)
+    )
+    tables = jnp.asarray([[1, 2], [3, 4]], np.int32)
+    positions = jnp.asarray([[2, 3, 4], [0, 1, 2]], np.int32)
+    lengths = jnp.asarray([5, 3], np.int32)
+    written = []
+    hook = PagedAttentionHook(
+        k0, v0, tables, positions, mode="verify", lengths=lengths
+    )
+    for layer in range(n_layers):
+        kw, vw = (
+            jnp.asarray(r.normal(size=(B, H, S, D)).astype(np.float32))
+            for _ in range(2)
+        )
+        hook._write_layer(layer, kw, vw)
+        written.append(kw)
+    assert hook.k_pages.shape == k0.shape and hook.k_pages.dtype == dtype
+    # slot 0's row 0 (position 2 -> block 1, offset 2) holds head-major
+    # [H*D] of what was written, in the pool's dtype
+    for layer in range(n_layers):
+        np.testing.assert_array_equal(
+            np.asarray(hook.k_pages[layer, 1, 2], np.float32),
+            np.asarray(
+                written[layer][0, :, 0, :].reshape(H * D).astype(dtype),
+                np.float32,
+            ),
+        )
+    hook.rollback(jnp.asarray([0, 0], np.int32))
+    for before, after in ((k0, hook.k_pages), (v0, hook.v_pages)):
+        np.testing.assert_array_equal(
+            np.asarray(after[:, 1:], np.float32),
+            np.asarray(before[:, 1:], np.float32),
+        )
 
 
 # --------------------------------------------------------------------------- #
