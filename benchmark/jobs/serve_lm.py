@@ -25,8 +25,8 @@ from types import SimpleNamespace
 
 import numpy as np
 
-from benchmark.lib import compiles, reference, stats
-from benchmark.lib.model import build_model, init_params
+from benchmark.lib import compiles, stats
+from benchmark.lib.model import family
 
 
 def _quantile_lengths(spec: dict, n: int) -> np.ndarray:
@@ -138,10 +138,11 @@ def setup(config: dict, traffic: dict, seed: int, chips: int):
     longest = max(len(p) + o for p, o in pool)
     if longest > cfg.max_seq_len:
         raise ValueError(f"a request needs {longest} positions")
-    model = build_model(config)
+    fam = family(config)
+    model = fam.build_model(config)
     pad = cfg.prefill_pad_multiple
     buckets = sorted({-(-len(p) // pad) * pad for p, _ in pool})
-    params = init_params(model, seed, pad)["params"]
+    params = fam.init_params(model, seed, pad)["params"]
     engine = ServingEngine(model, params, cfg)
     t_built = time.perf_counter()
     print(f"bench: serve_lm ServeConfig in effect {dataclasses.asdict(cfg)}",
@@ -166,6 +167,7 @@ def setup(config: dict, traffic: dict, seed: int, chips: int):
         f"{time.perf_counter() - t_warm:.1f} s", flush=True)
     return SimpleNamespace(
         engine=engine, loop=loop, params=params, cfg=cfg,
+        logits_at=fam.logits_at,
         trace_seconds=float(traffic["trace_seconds"]),
         checked_requests=int(traffic["checked_requests"]),
         tolerance=float(traffic["logit_tolerance_frac"]),
@@ -222,8 +224,11 @@ def measure(state, seconds: float, tracer) -> dict:
     }
 
 
-def check(state) -> bool:
-    """Requests finished in the window, spread over the prompt lengths:
+def check(state):
+    """Returns ``(ok, compared)``: ``compared`` names each number held
+    against a limit, ``{name: {"value": .., "limit": ..}}``.
+
+    Requests finished in the window, spread over the prompt lengths:
     every served token's logit in the float32 reference forward of
     ``prompt + tokens so far`` against that forward's maximum, as a share of
     the forward's logit range.
@@ -245,7 +250,8 @@ def check(state) -> bool:
     if len(done) < n:
         print(f"bench: check serve_lm only {len(done)} finished requests",
               flush=True)
-        return False
+        return False, {"finished_requests_short_of":
+                       {"value": n - len(done), "limit": 0}}
     picked = [done[round(i * (len(done) - 1) / (n - 1))] for i in range(n)]
     L, T = state.cfg.max_seq_len, state.max_out
     ids = np.zeros((n, L), np.int32)
@@ -261,8 +267,8 @@ def check(state) -> bool:
         served[i, :k], valid[i, :k] = tokens, True
     with jax.default_matmul_precision("highest"):
         rows = np.asarray(
-            jax.jit(reference.logits_at)(state.params, jnp.asarray(ids),
-                                         jnp.asarray(at))
+            jax.jit(state.logits_at)(state.params, jnp.asarray(ids),
+                                     jnp.asarray(at))
         )
     top, low = rows.max(-1), rows.min(-1)
     got = np.take_along_axis(rows, served[:, :, None], axis=2)[..., 0]
@@ -276,7 +282,8 @@ def check(state) -> bool:
         f"{state.tolerance}) -> {'ok' if ok else 'FAILED'}",
         flush=True,
     )
-    return ok
+    return ok, {"worst_logit_gap_frac":
+                {"value": float(gap.max()), "limit": state.tolerance}}
 
 
 def end_to_end(observations: dict) -> dict:

@@ -20,8 +20,8 @@ from types import SimpleNamespace
 
 import numpy as np
 
-from benchmark.lib import compiles, flops, reference
-from benchmark.lib.model import build_model, init_params
+from benchmark.lib import compiles
+from benchmark.lib.model import family
 
 
 def _batches(loader):
@@ -73,8 +73,9 @@ def setup(config: dict, traffic: dict, seed: int, chips: int):
     world = chips if traffic.get("distributed") else 1
     rows_micro = int(traffic["rows_per_device"]) * world
     grad_accum = int(traffic["grad_accum"])
-    model = build_model(config)
-    variables = init_params(model, seed, seq_len)
+    fam = family(config)
+    model = fam.build_model(config)
+    variables = fam.init_params(model, seed, seq_len)
     n_params = sum(
         int(leaf.size) for leaf in jax.tree_util.tree_leaves(variables)
     )
@@ -86,7 +87,7 @@ def setup(config: dict, traffic: dict, seed: int, chips: int):
     # made, before the trainer takes (and donates) them
     with jax.default_matmul_precision("highest"):
         ref_loss = float(
-            jax.jit(reference.causal_lm_loss)(
+            jax.jit(fam.causal_lm_loss)(
                 variables["params"], tokens[:rows_micro]
             )
         )
@@ -119,7 +120,7 @@ def setup(config: dict, traffic: dict, seed: int, chips: int):
     state = SimpleNamespace(
         stoke=stoke, batches=_batches(loader), api=traffic["api"],
         grad_accum=grad_accum, tokens_per_step=rows_micro * seq_len * grad_accum,
-        flops_per_token=flops.train_flops_per_token(config, seq_len),
+        flops_per_token=fam.train_flops_per_token(config, seq_len),
         ref_loss=ref_loss, loss_tolerance=float(traffic["loss_tolerance"]),
         trace_seconds=float(traffic["trace_seconds"]),
     )
@@ -140,8 +141,11 @@ def setup(config: dict, traffic: dict, seed: int, chips: int):
     return state
 
 
-def check(state) -> bool:
-    """The program's loss on the first micro-batch, before any update,
+def check(state):
+    """Returns ``(ok, compared)``: ``compared`` names each number held
+    against a limit, ``{name: {"value": .., "limit": ..}}``.
+
+    The program's loss on the first micro-batch, before any update,
     against the float32 reference's on the same rows and weights.
 
     Tolerance (``loss_tolerance`` in the traffic file, absolute, on a loss
@@ -159,7 +163,8 @@ def check(state) -> bool:
         f"(tolerance {state.loss_tolerance}) -> {'ok' if ok else 'FAILED'}",
         flush=True,
     )
-    return ok
+    return ok, {"first_loss_gap":
+                {"value": float(gap), "limit": state.loss_tolerance}}
 
 
 def measure(state, seconds: float, tracer) -> dict:
@@ -172,15 +177,19 @@ def measure(state, seconds: float, tracer) -> dict:
     pending, fetched = deque(), []
     compiles0 = compiles.count()
     t0 = time.perf_counter()
+    done_at = [t0]  # the host's clock each time a step's losses arrived
     while True:
         pending.append(_optimizer_step(state, clock))
         if len(pending) > 1:
             fetched.append(_fetch(pending.popleft()))
+            done_at.append(time.perf_counter())
         if time.perf_counter() - t0 >= seconds:
             break
     last = pending.popleft()
     jax.block_until_ready((last, state.stoke.params))
     window_s = time.perf_counter() - t0
+    done_at.append(t0 + window_s)
+    step_s = np.diff(done_at)
     if tracer is not None:
         tracer.stop()
     fetched.append(_fetch(last))
@@ -198,6 +207,13 @@ def measure(state, seconds: float, tracer) -> dict:
         "train.host_dispatch_s": clock["dispatch"],
         "train.loader_wait_s": clock["loader"],
         "train.last_loss": fetched[-1][-1] * state.grad_accum,
+        # where a slow run lost its time: one long step is a stall, every
+        # step longer is a slow device (read from the observations line)
+        "train.step_ms_p50": 1e3 * float(np.median(step_s)),
+        "train.step_ms_max": 1e3 * float(step_s.max()),
+        "train.stall_s": float(
+            np.clip(step_s - 1.5 * np.median(step_s), 0.0, None).sum()
+        ),
     }
 
 

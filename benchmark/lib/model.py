@@ -1,50 +1,57 @@
-"""The one place that knows how this program builds a model from a
-configuration file.
+"""The one place that knows how a configuration becomes code: the lookup from
+a configuration file's ``"family"`` to its module under
+``benchmark/lib/families/``.
 
-The program's decoder takes its widths from a table keyed by a size name
-(``stoke_tpu.models.bert.BERT_SIZES``, a public dict read at call time by
-``GPT`` and ``ServingEngine``), so a configuration is registered there under
-its own name.  When the program grows a config-driven decoder (ROADMAP D5)
-this function changes, in a benchmark PR, and nothing else here does.
+A family module holds everything the benchmark knows about one kind of
+model, under these names, and the jobs ask for nothing else:
+
+- ``build_model(config)``: the program's model at the configuration's sizes.
+- ``init_params(model, seed, seq_len)``: the model's variables, made on the
+  device in one jitted call from the seed; ``["params"]`` of them is the tree
+  the reference reads.
+- ``logits_at(params, ids, positions)`` and ``causal_lm_loss(params, ids)``:
+  the plain float32 reference, which imports nothing of the program.  A
+  family whose weights do not fit in float32 computes them in blocks behind
+  the same names.
+- ``train_flops_per_token(config, seq_len)``: operations the algorithm needs,
+  from the configuration's shapes; a kernel's operation and byte counts sit
+  beside it.
+
+A configuration's key names are its family's own business.  The one key every
+family's configuration carries is ``vocab_size``: both jobs draw tokens
+from it.
 """
 
 from __future__ import annotations
 
-from .flops import ffn_width
+import importlib
+
+NAMES = ("build_model", "init_params", "logits_at", "causal_lm_loss",
+         "train_flops_per_token")
 
 
-def build_model(config: dict):
-    """``GPT`` at the configuration's widths, causal flash attention on the
-    training and prefill path, no dropout."""
-    from stoke_tpu.models import GPT
-    from stoke_tpu.models.bert import BERT_SIZES, BertSize
-    from stoke_tpu.ops import make_flash_attention
-
-    if config["n_embd"] % config["n_head"]:
-        raise ValueError(f"{config['name']}: n_embd not divisible by n_head")
-    BERT_SIZES[config["name"]] = BertSize(
-        int(config["n_layer"]), int(config["n_embd"]),
-        int(config["n_head"]), ffn_width(config),
-    )
-    return GPT(
-        vocab_size=int(config["vocab_size"]),
-        size_name=config["name"],
-        max_len=int(config["n_positions"]),
-        dropout_rate=0.0,
-        attention_fn=make_flash_attention(causal=True),
-        attention_is_causal=True,
-    )
-
-
-def init_params(model, seed: int, seq_len: int):
-    """The model's variables, made on the device in one jitted call from the
-    seed (no file is loaded)."""
-    import jax
-    import numpy as np
-
-    from stoke_tpu import init_module
-
-    return init_module(
-        model, jax.random.PRNGKey(seed % (2**31)),
-        np.zeros((1, seq_len), np.int32), train=False,
-    )
+def family(config: dict, where: str | None = None):
+    """The module of ``config["family"]``.  No default: a configuration
+    without the key, or naming a module that does not exist or lacks one of
+    ``NAMES``, is an error that names the file (``where``, else the
+    configuration's ``name``)."""
+    where = where or f"configuration {config.get('name')!r}"
+    name = config.get("family")
+    if not name:
+        raise ValueError(
+            f"{where}: no \"family\" key; it names the module "
+            f"benchmark/lib/families/<family>.py")
+    if "vocab_size" not in config:
+        raise ValueError(f"{where}: no \"vocab_size\" key")
+    try:
+        module = importlib.import_module(f"benchmark.lib.families.{name}")
+    except ModuleNotFoundError as e:
+        if e.name != f"benchmark.lib.families.{name}":
+            raise
+        raise ValueError(
+            f"{where}: unknown family {name!r}: no "
+            f"benchmark/lib/families/{name}.py") from None
+    missing = [n for n in NAMES if not hasattr(module, n)]
+    if missing:
+        raise ValueError(f"{where}: family {name!r} lacks {missing}")
+    return module

@@ -6,7 +6,9 @@
 One process: finds the cell in ``BENCHMARK.json``, loads its configuration
 file and its traffic file, imports the job the traffic file names, and calls
 that job's ``setup`` (build, warm), ``measure`` (one window), ``check``
-(correctness, outside the window) and ``end_to_end``.  With ``--trace 1`` the
+(correctness, outside the window) and ``end_to_end``.  The job takes the
+model's builder, reference and counts from the module the configuration's
+``"family"`` names (``benchmark/lib/model.py``).  With ``--trace 1`` the
 window runs under ``jax.profiler`` and the line carries the cell's per-layer
 metrics, each read from the observations by the reader its
 ``metrics/<name>.json`` names.  Nothing about a cell, a model or a traffic
@@ -94,6 +96,16 @@ def _memory_peak(after_window: dict, at_end: dict) -> int:
     )
 
 
+def _compared_lines(correct: bool, compared: dict) -> list:
+    """Each number held against a limit, beside it: the run's last words on
+    standard error."""
+    lines = [
+        f"benchmark: compared {name}: {c['value']} (limit {c['limit']})"
+        for name, c in compared.items()
+    ]
+    return lines + [f"benchmark: correct={str(correct).lower()}"]
+
+
 def _cell(bench: dict, name: str):
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -165,6 +177,7 @@ def main(argv=None) -> int:
         return 1
 
     from benchmark.lib import compiles
+    from benchmark.lib.model import family
     from benchmark.lib.trace_reduce import Tracer
 
     cache_dir = _install_compile_cache(platform)
@@ -172,6 +185,7 @@ def main(argv=None) -> int:
     compiles.install()
 
     config = _load(args.root, config_entry["file"])
+    family(config, config_entry["file"])  # a missing one stops the run here
     traffic = _load(args.root,
                     os.path.join(TRAFFIC_DIR, cell["traffic"] + ".json"))
     job = importlib.import_module(f"benchmark.jobs.{traffic['job']}")
@@ -192,7 +206,17 @@ def main(argv=None) -> int:
         tracer = Tracer(trace_dir)
     obs = job.measure(state, seconds, tracer)
     resident = _memory_stats(devices, cell["chips"])
-    correct = bool(job.check(state)) and obs["failed"] == 0
+    checked, compared = job.check(state)
+    compared = {
+        **compared,
+        "failed": {"value": int(obs["failed"]), "limit": 0},
+        "compiles_in_window": {"value": int(obs["compiles_in_window"]),
+                               "limit": 0},
+    }
+    for c in compared.values():  # the line stays JSON whatever was read
+        if not math.isfinite(c["value"]):
+            c["value"] = None
+    correct = bool(checked) and obs["failed"] == 0
     _say(f"compiles: {compiles_setup} in set-up, "
          f"{obs['compiles_in_window']} in the window; compile cache "
          f"{cache_dir}: {cache_before} entries before, "
@@ -247,7 +271,10 @@ def main(argv=None) -> int:
         result["metrics"] = _per_layer(bench, args.root, cell["name"], obs)
     _say("observations " + json.dumps(obs, sort_keys=True))
     result["device"] = device
+    result["compared"] = compared
     print(json.dumps(result), flush=True)
+    print("\n".join(_compared_lines(correct, compared)), file=sys.stderr,
+          flush=True)
     return 0
 
 

@@ -12,7 +12,8 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from benchmark.jobs import serve_lm  # noqa: E402
-from benchmark.lib import flops, stats  # noqa: E402
+from benchmark.lib import stats  # noqa: E402
+from benchmark.lib.model import family  # noqa: E402
 from benchmark.lib.trace_reduce import (  # noqa: E402
     host_owner,
     op_label,
@@ -125,10 +126,11 @@ def test_percentile_and_tail_note():
 
 
 def test_flops_per_token_gpt2_medium():
-    config = {"n_layer": 24, "n_embd": 1024, "n_head": 16, "n_inner": None,
-              "vocab_size": 50257}
-    assert flops.matmul_params(config) == 353_453_056
-    assert flops.train_flops_per_token(config, 1024) == (
+    config = {"family": "gpt2", "n_layer": 24, "n_embd": 1024, "n_head": 16,
+              "n_inner": None, "vocab_size": 50257}
+    counts = family(config)
+    assert counts.matmul_params(config) == 353_453_056
+    assert counts.train_flops_per_token(config, 1024) == (
         6 * 353_453_056 + 6 * 24 * 1024 * 1024)
 
 
@@ -152,22 +154,22 @@ def test_pool_is_the_same_multiset_for_every_seed():
 
 
 def test_reference_matches_the_program_at_tiny_width():
-    """`lib/reference.py` against `model.apply` with dense attention on the
-    CPU, so the reference is known good before it judges a chip run.
-    Tolerance: both sides are float32 on the CPU; they differ in the order
+    """The `gpt2` family's reference against `model.apply` with dense
+    attention on the CPU, so the reference is known good before it judges a
+    chip run.  Tolerance: both sides are float32 on the CPU; they differ in the order
     of sums (fused qkv einsum, LayerNorm's variance formula), a few ulps
     through two layers on logits of order 1."""
     import jax
     import jax.numpy as jnp
 
-    from benchmark.lib import reference
-    from benchmark.lib.model import build_model
     from stoke_tpu.models import causal_lm_loss
     from stoke_tpu.models.bert import dense_attention
 
-    config = {"name": "ref-tiny", "n_layer": 2, "n_embd": 128, "n_head": 2,
-              "n_inner": None, "vocab_size": 300, "n_positions": 64}
-    model = build_model(config).clone(
+    config = {"name": "ref-tiny", "family": "gpt2", "n_layer": 2,
+              "n_embd": 128, "n_head": 2, "n_inner": None,
+              "vocab_size": 300, "n_positions": 64}
+    reference = family(config)
+    model = reference.build_model(config).clone(
         attention_fn=dense_attention, attention_is_causal=False)
     ids = jnp.asarray(
         np.random.default_rng(0).integers(0, 300, (3, 48), dtype=np.int32))

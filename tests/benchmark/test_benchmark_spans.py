@@ -214,21 +214,24 @@ def test_newest_xplane_skips_what_an_earlier_process_left(tmp_path, monkeypatch)
 # one real trace
 # --------------------------------------------------------------------------- #
 
-TINY = {"name": "spans-tiny", "n_layer": 2, "n_embd": 128, "n_head": 2,
-        "n_inner": None, "vocab_size": 300, "n_positions": 64}
+TINY = {"name": "spans-tiny", "family": "gpt2", "n_layer": 2,
+        "n_embd": 128, "n_head": 2, "n_inner": None, "vocab_size": 300,
+        "n_positions": 64}
 
 
 @pytest.fixture(scope="module")
 def trace(tmp_path_factory):
     import optax
 
-    from benchmark.lib.model import build_model, init_params
+    from benchmark.lib.model import family
     from benchmark.lib.trace_reduce import Tracer, find_xplane
     from stoke_tpu import ServeConfig, Stoke, StokeOptimizer
     from stoke_tpu.models import causal_lm_loss
     from stoke_tpu.serving.engine import ServingEngine
 
-    model = build_model(TINY)
+    fam = family(TINY)
+    init_params = fam.init_params
+    model = fam.build_model(TINY)
     engine = ServingEngine(
         model, init_params(model, 0, 16)["params"],
         ServeConfig(max_seqs=2, kv_block_size=16, max_seq_len=64,
