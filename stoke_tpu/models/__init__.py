@@ -23,7 +23,9 @@ from stoke_tpu.models.gpt import GPT, GPTBase, GPTTiny, causal_lm_loss
 # explicit at call sites.
 gpt_tensor_parallel_rules = bert_tensor_parallel_rules
 vit_tensor_parallel_rules = bert_tensor_parallel_rules
+from stoke_tpu.models.decoder import Decoder, DecoderConfig
 from stoke_tpu.models.moe import (
+    ExpertShareFFN,
     MoEFFN,
     MoETransformerBlock,
     moe_expert_parallel_rules,
@@ -54,6 +56,9 @@ __all__ = [
     "GPTBase",
     "GPTTiny",
     "causal_lm_loss",
+    "Decoder",
+    "DecoderConfig",
+    "ExpertShareFFN",
     "MoEFFN",
     "MoETransformerBlock",
     "moe_expert_parallel_rules",

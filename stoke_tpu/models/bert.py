@@ -33,6 +33,39 @@ class BertSize:
     ff: int
 
 
+@dataclass(frozen=True)
+class CacheSpec:
+    """What a model tells ``ServingEngine`` about the cache it serves
+    through (the serving contract beside ``__call__(input_ids, train,
+    positions, decode, kv_cache)``): the engine builds the page pool from
+    it and reads no width table.
+
+    ``planes`` are the pool's arrays, ``(name, row width)`` each, one
+    ``[layers, blocks, block_size, width]`` array a plane; ``values`` is how
+    many of a token's row, over all planes, are cached values (None: all of
+    it; a model may pad a row to whole 128-lane tiles, which is the form the
+    device keeps row-major at rest).  ``kind`` says
+    which hook method the model's layers call: ``"mha"`` (``layer_attention``:
+    per-head K and V rows, two planes of ``heads * head_dim``) or
+    ``"latent"`` (``latent_attention``: one row a token, the normed latent
+    and the roped shared key side by side)."""
+
+    layers: int
+    planes: tuple
+    kind: str
+    heads: int
+    head_dim: int
+    max_len: int
+    values: Optional[int] = None
+
+    @property
+    def values_per_token(self) -> int:
+        """Cached values a token a layer, all planes together."""
+        if self.values is not None:
+            return self.values
+        return sum(width for _, width in self.planes)
+
+
 BERT_SIZES = {
     "tiny": BertSize(2, 128, 2, 512),
     "mini": BertSize(4, 256, 4, 1024),

@@ -1,0 +1,455 @@
+"""A pre-norm decoder assembled from a ``config.json``-shaped description.
+
+The DeepSeek-V3 family's block, keyed by the source's own names
+(``hidden_size``, ``q_lora_rank``, ``kv_lora_rank``, ``qk_nope_head_dim``,
+``n_routed_experts``, ``first_k_dense_replace``, ``rope_scaling`` ...), so a
+published configuration (A.X-K1, ``model_type: "axk1"``) builds the model as
+it stands:
+
+- per layer ``h += Attn(RMSNorm(h))``, ``h += FFN(RMSNorm(h))``; a final
+  RMSNorm; an untied ``lm_head``; no biases;
+- attention is multi-head latent attention (MLA): queries through a low-rank
+  pair ``W_qb RMSNorm(W_qa x)``, split per head into a ``nope`` and a
+  ``rope`` part; keys and values from one latent a token, ``[c, k_r] =
+  W_kva x`` with ``c`` RMS-normed and ``k_r`` roped once for all heads,
+  ``[k_nope, v] = W_kvb c``.  Rotary positions with YaRN frequencies, pairs
+  ``(2i, 2i+1)``;
+- the feed-forward is a dense SwiGLU in the first ``first_k_dense_replace``
+  layers and an expert layer after
+  (:class:`stoke_tpu.models.moe.ExpertShareFFN`: the router's published
+  width, the experts this chip holds, the shared expert).
+
+The same ``__call__(input_ids, train, positions, decode, kv_cache)``
+contract as :class:`stoke_tpu.models.gpt.GPT`, and :meth:`Decoder.cache_spec`
+for ``ServingEngine``: one latent row a token a layer (``kv_lora_rank +
+qk_rope_head_dim`` values, stored padded to whole 128-lane tiles).  With a
+cache hook each layer's attention is the hook's
+(``kv_cache.latent_attention(i)``: the row written once, then the expanded
+form in prefill and the absorbed form in decode, both in this file);
+without one it is the expanded form over the whole sequence.
+
+Parameters are ``param_dtype``, products take ``dtype`` inputs and
+accumulate in float32; router, softmax and RMSNorm statistics are float32.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from functools import partial
+from typing import Any, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from stoke_tpu.models.bert import CacheSpec
+from stoke_tpu.models.moe import ExpertShareFFN, SwiGLU
+
+_NEG_INF = -1e30
+
+
+@dataclasses.dataclass(frozen=True)
+class DecoderConfig:
+    """The keys of the source's ``config.json`` the decoder reads, under
+    the source's names.  ``n_routed_experts`` is the router's width."""
+
+    vocab_size: int
+    hidden_size: int
+    num_hidden_layers: int
+    num_attention_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    intermediate_size: int
+    moe_intermediate_size: int
+    n_routed_experts: int
+    num_experts_per_tok: int
+    n_group: int
+    topk_group: int
+    first_k_dense_replace: int = 1
+    n_shared_experts: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
+    max_position_embeddings: int = 4096
+    # YaRN, from ``rope_scaling`` (factor 1: plain rotary frequencies)
+    rope_factor: float = 1.0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    rope_original_max_position_embeddings: int = 4096
+
+    @classmethod
+    def from_dict(cls, config: dict) -> "DecoderConfig":
+        """From a dict with the source's key names; keys the decoder does
+        not read (``model_type``, ``seq_aux``, ``ep_size`` ...) are left.
+        What the decoder cannot build is an error, not a default."""
+        if config.get("hidden_act", "silu") != "silu":
+            raise ValueError(f"hidden_act {config['hidden_act']!r}: the "
+                             f"feed-forward here is SwiGLU")
+        if config.get("scoring_func", "sigmoid") != "sigmoid":
+            raise ValueError("the router here scores with a sigmoid")
+        if config.get("moe_layer_freq", 1) != 1:
+            raise ValueError("every layer after the dense ones is an "
+                             "expert layer here (moe_layer_freq 1)")
+        if config.get("tie_word_embeddings", False):
+            raise ValueError("the head here is untied")
+        fields = {f.name for f in dataclasses.fields(cls)}
+        kwargs = {k: v for k, v in config.items() if k in fields}
+        scaling = config.get("rope_scaling")
+        if scaling:
+            if scaling.get("type", scaling.get("rope_type")) != "yarn":
+                raise ValueError(f"rope_scaling {scaling!r}: only yarn")
+            for key in ("factor", "beta_fast", "beta_slow", "mscale",
+                        "mscale_all_dim", "original_max_position_embeddings"):
+                if key in scaling:
+                    kwargs["rope_" + key] = scaling[key]
+        return cls(**kwargs)
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_width(self) -> int:
+        """Values cached a token a layer: the normed latent and the roped
+        shared key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_row_width(self) -> int:
+        """The cached row as stored: ``latent_width`` values, then zeros up
+        to whole 128-lane tiles (576 -> 640).  A 576-wide plane the device
+        keeps with the block index in the lanes at rest and copies whole,
+        twice a dispatch; a 640-wide one it keeps row-major, as computed
+        on."""
+        return -(-self.latent_width // 128) * 128
+
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def softmax_scale(cfg: DecoderConfig) -> float:
+    """``qk_head_dim ** -0.5``, times the square of YaRN's attention factor
+    over all dimensions (the family's convention)."""
+    m = _yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
+    return cfg.qk_head_dim ** -0.5 * m * m
+
+
+def rope_inv_freq(cfg: DecoderConfig):
+    """``(float32[qk_rope_head_dim / 2] rotary frequencies, the factor on
+    cos and sin)``: YaRN's blend of the plain and the ``factor``-times-
+    interpolated frequencies, by a linear ramp between the dimensions that
+    turn ``beta_fast`` and ``beta_slow`` times over the original context;
+    the factor is ``mscale(factor, mscale) / mscale(factor,
+    mscale_all_dim)``, 1 where the two are equal."""
+    dim, base = cfg.qk_rope_head_dim, float(cfg.rope_theta)
+    plain = 1.0 / base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    if cfg.rope_factor <= 1.0:
+        return plain, 1.0
+
+    def correction_dim(rotations):
+        return (dim * math.log(cfg.rope_original_max_position_embeddings
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(correction_dim(cfg.rope_beta_fast)), 0)
+    high = min(math.ceil(correction_dim(cfg.rope_beta_slow)), dim - 1)
+    span = (high - low) or 0.001
+    ramp = jnp.clip(
+        (jnp.arange(dim // 2, dtype=jnp.float32) - low) / span, 0.0, 1.0
+    )
+    inv_freq = plain / cfg.rope_factor * ramp + plain * (1.0 - ramp)
+    cos_sin_scale = _yarn_mscale(cfg.rope_factor, cfg.rope_mscale) / (
+        _yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim))
+    return inv_freq, cos_sin_scale
+
+
+def apply_rope(x, positions, cfg: DecoderConfig):
+    """Rotate the pairs ``(2i, 2i+1)`` of ``x [..., qk_rope_head_dim]`` by
+    ``positions * inv_freq[i]``; ``positions`` broadcasts against ``x``'s
+    leading dimensions.  Angles and the rotation are float32."""
+    inv_freq, m = rope_inv_freq(cfg)
+    angle = positions[..., None].astype(jnp.float32) * inv_freq
+    cos, sin = jnp.cos(angle) * m, jnp.sin(angle) * m
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (-1, 2))
+    a, b = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+class RMSNorm(nn.Module):
+    eps: float = 1e-6
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           self.param_dtype)
+        y = x.astype(jnp.float32)
+        y = y * jax.lax.rsqrt((y * y).mean(-1, keepdims=True) + self.eps)
+        return (y * scale.astype(jnp.float32)).astype(self.dtype)
+
+
+# --------------------------------------------------------------------------- #
+# the two forms of one attention
+# --------------------------------------------------------------------------- #
+
+
+def _einsum(spec, a, b):
+    return jnp.einsum(spec, a, b, preferred_element_type=jnp.float32)
+
+
+def expanded_attention(q_nope, q_rope, c, k_rope, w_kvb, scale, key_valid,
+                       impl: str = "dense"):
+    """MLA with keys and values expanded per head, causal over one padded
+    sequence: the training and prefill form.
+
+    ``q_nope [B, L, H, dn]``, ``q_rope [B, L, H, dr]`` (roped), ``c [B, L,
+    C]`` (normed latent), ``k_rope [B, L, dr]`` (roped, shared by the
+    heads), ``w_kvb [C, H, dn + dv]``, ``key_valid [B, L]`` bool.  Returns
+    ``[B, L, H, dv]``.  ``impl="flash"`` runs the repo's flash kernel, which
+    wants one head size for q, k and v and scales by its inverse root: the
+    values are zero-padded from ``dv`` to ``dn + dr`` (a third more
+    ``P V`` work than the algorithm needs) and YaRN's factor goes on q."""
+    dn = q_nope.shape[-1]
+    dtype = q_nope.dtype
+    kv = _einsum("blc,chd->blhd", c, w_kvb.astype(dtype)).astype(dtype)
+    k_nope, v = kv[..., :dn], kv[..., dn:]
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
+                                  k_nope.shape[:-1] + k_rope.shape[-1:])],
+        axis=-1,
+    )
+    D, dv = q.shape[-1], v.shape[-1]
+    if impl == "flash":
+        from stoke_tpu.ops.flash_attention import flash_attention
+
+        q = (q * (scale * D ** 0.5)).astype(dtype)
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, D - dv)))
+        heads_first = partial(jnp.swapaxes, axis1=1, axis2=2)
+        out = flash_attention(
+            heads_first(q), heads_first(k), heads_first(v),
+            key_valid.astype(jnp.int32), causal=True,
+        )
+        return heads_first(out)[..., :dv]
+    if impl != "dense":
+        raise ValueError(f"unknown attention impl {impl!r}")
+    L = q.shape[1]
+    s = _einsum("bqhd,bkhd->bhqk", q, k) * scale
+    allow = jnp.tril(jnp.ones((L, L), bool))[None, None] & (
+        key_valid[:, None, None, :])
+    p = jax.nn.softmax(jnp.where(allow, s, _NEG_INF), axis=-1)
+    return _einsum("bhqk,bkhd->bqhd", p.astype(dtype), v).astype(dtype)
+
+
+def absorbed_attention(q_nope, q_rope, window, positions, w_kvb, scale):
+    """MLA over cached latent rows with ``W_kvb`` absorbed into the query
+    and the output: the decode form.  ``W_UK`` and ``W_UV`` are the two
+    halves of ``w_kvb``, not new parameters.
+
+    ``q_nope [B, S, H, dn]``, ``q_rope [B, S, H, dr]`` (roped), ``window
+    [B, W, >= C + dr]`` (a slot's cached rows in position order: normed
+    latent, roped shared key, then the row's zero padding, which the query
+    is padded to meet), ``positions [B, S]``: query row ``s`` attends
+    window positions ``<= positions[b, s]``.  ``q_lat = q_nope W_UK``;
+    scores are ``q_lat . c + q_r . k_r``, one product over the whole row;
+    ``o = (softmax . c) W_UV``.  Returns ``[B, S, H, dv]``."""
+    dn = q_nope.shape[-1]
+    C = w_kvb.shape[0]
+    dtype = q_nope.dtype
+    w_kvb = w_kvb.astype(dtype)
+    q_lat = _einsum("bshd,chd->bshc", q_nope, w_kvb[..., :dn]).astype(dtype)
+    q_row = jnp.concatenate([q_lat, q_rope], axis=-1)  # [B, S, H, C + dr]
+    q_row = jnp.pad(
+        q_row, ((0, 0),) * 3 + ((0, window.shape[-1] - q_row.shape[-1]),))
+    window = window.astype(dtype)
+    s = _einsum("bshj,bwj->bshw", q_row, window) * scale
+    W = window.shape[1]
+    valid = (jnp.arange(W, dtype=jnp.int32)[None, None, None, :]
+             <= positions[:, :, None, None])
+    p = jax.nn.softmax(jnp.where(valid, s, _NEG_INF), axis=-1)
+    # over the whole row: the key and padding lanes ride along and are
+    # dropped; slicing them off the window first would copy the window
+    o_lat = _einsum("bshw,bwj->bshj", p.astype(dtype), window)[..., :C]
+    return _einsum(
+        "bshc,chd->bshd", o_lat.astype(dtype), w_kvb[..., dn:]
+    ).astype(dtype)
+
+
+# --------------------------------------------------------------------------- #
+# modules
+# --------------------------------------------------------------------------- #
+
+
+class LatentAttention(nn.Module):
+    cfg: DecoderConfig
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+    attention: str = "dense"
+
+    @nn.compact
+    def __call__(self, x, positions, attend=None):
+        cfg = self.cfg
+        B, L, _ = x.shape
+        H, dn, dr = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                     cfg.qk_rope_head_dim)
+        dense = partial(nn.Dense, use_bias=False, dtype=self.dtype,
+                        param_dtype=self.param_dtype)
+        norm = partial(RMSNorm, cfg.rms_norm_eps, self.dtype,
+                       self.param_dtype)
+        q = dense(cfg.q_lora_rank, name="q_a")(x)
+        q = dense(H * cfg.qk_head_dim, name="q_b")(norm(name="q_a_norm")(q))
+        q = q.reshape(B, L, H, cfg.qk_head_dim)
+        q_nope = q[..., :dn]
+        q_rope = apply_rope(q[..., dn:], positions[:, :, None], cfg)
+        kv = dense(cfg.latent_width, name="kv_a")(x)
+        c = norm(name="kv_a_norm")(kv[..., : cfg.kv_lora_rank])
+        k_rope = apply_rope(kv[..., cfg.kv_lora_rank:], positions, cfg)
+        w_kvb = self.param(
+            "kv_b", nn.initializers.lecun_normal(),
+            (cfg.kv_lora_rank, H * (dn + cfg.v_head_dim)), self.param_dtype,
+        ).reshape(cfg.kv_lora_rank, H, dn + cfg.v_head_dim)
+        scale = softmax_scale(cfg)
+        if attend is None:
+            out = expanded_attention(
+                q_nope, q_rope, c, k_rope, w_kvb, scale,
+                jnp.ones((B, L), bool), self.attention,
+            )
+        else:
+            out = attend(q_nope, q_rope, c, k_rope, w_kvb, scale)
+        return dense(cfg.hidden_size, name="o")(
+            out.reshape(B, L, H * cfg.v_head_dim))
+
+
+class DecoderLayer(nn.Module):
+    cfg: DecoderConfig
+    index: int
+    held_experts: Optional[Tuple[int, int]] = None
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+    attention: str = "dense"
+
+    @nn.compact
+    def __call__(self, h, positions, attend=None):
+        cfg = self.cfg
+        norm = partial(RMSNorm, cfg.rms_norm_eps, self.dtype,
+                       self.param_dtype)
+        with jax.named_scope("mla"):
+            h = h + LatentAttention(
+                cfg, self.dtype, self.param_dtype, self.attention,
+                name="attn",
+            )(norm(name="attn_norm")(h), positions, attend)
+        x = norm(name="ffn_norm")(h)
+        if self.index < cfg.first_k_dense_replace:
+            return h + SwiGLU(cfg.intermediate_size, self.dtype,
+                              self.param_dtype, name="ffn")(x)
+        return h + ExpertShareFFN(
+            cfg.hidden_size, cfg.moe_intermediate_size,
+            cfg.n_routed_experts,
+            self.held_experts or (0, cfg.n_routed_experts),
+            cfg.num_experts_per_tok, cfg.n_group, cfg.topk_group,
+            cfg.routed_scaling_factor, cfg.norm_topk_prob,
+            cfg.n_shared_experts, self.dtype, self.param_dtype, name="ffn",
+        )(x)
+
+
+class Decoder(nn.Module):
+    """A pre-norm decoder-only language model assembled from ``cfg``
+    (:meth:`DecoderConfig.from_dict`): latent attention, SwiGLU, a dense
+    or an expert feed-forward per layer, an untied head.
+
+    Args:
+        held_experts: ``(first, count)``: the routed experts of every
+            expert layer that live here (None: all of them).
+        dtype / param_dtype: compute and storage dtype (bfloat16 to serve
+            at the published widths; float32 in the tests).
+        attention: the cacheless forward's kernel, ``"dense"`` or
+            ``"flash"`` (a cache hook brings its own choice).
+    """
+
+    cfg: DecoderConfig
+    held_experts: Optional[Tuple[int, int]] = None
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+    attention: str = "dense"
+
+    @property
+    def max_len(self) -> int:
+        return self.cfg.max_position_embeddings
+
+    @property
+    def experts_held(self) -> int:
+        """Routed experts each expert layer computes here (0: no expert
+        layer).  A model with some sows their assignment counts, and
+        ``ServingEngine`` hands them back from its decode program."""
+        cfg = self.cfg
+        if cfg.num_hidden_layers <= cfg.first_k_dense_replace:
+            return 0
+        return (self.held_experts or (0, cfg.n_routed_experts))[1]
+
+    def cache_spec(self) -> CacheSpec:
+        cfg = self.cfg
+        return CacheSpec(
+            layers=cfg.num_hidden_layers,
+            planes=(("latent", cfg.latent_row_width),),
+            values=cfg.latent_width,
+            kind="latent",
+            heads=cfg.num_attention_heads,
+            head_dim=cfg.qk_head_dim,
+            max_len=cfg.max_position_embeddings,
+        )
+
+    @nn.compact
+    def __call__(self, input_ids, train: bool = True, positions=None,
+                 decode: bool = False, kv_cache=None):
+        """Logits ``[B, L, vocab]`` (float32).  ``train`` changes nothing
+        (no dropout); ``positions`` (``[L]`` or ``[B, L]``) default to
+        ``arange``; ``decode=True`` is the single-token incremental forward
+        and needs ``kv_cache`` and ``positions``, as in ``GPT``."""
+        cfg = self.cfg
+        B, L = input_ids.shape
+        if decode and (kv_cache is None or positions is None or L != 1):
+            raise ValueError(
+                f"Decoder: decode=True is single-token incremental decode "
+                f"through a kv_cache hook at explicit positions; got "
+                f"sequence length {L}, kv_cache {kv_cache is not None}, "
+                f"positions {positions is not None}"
+            )
+        if L > cfg.max_position_embeddings:
+            raise ValueError(
+                f"Decoder: sequence length {L} exceeds "
+                f"max_position_embeddings={cfg.max_position_embeddings}"
+            )
+        if positions is None:
+            positions = jnp.arange(L, dtype=jnp.int32)
+        positions = jnp.broadcast_to(
+            jnp.asarray(positions, jnp.int32).reshape(-1, L), (B, L))
+        h = nn.Embed(
+            cfg.vocab_size, cfg.hidden_size, dtype=self.dtype,
+            param_dtype=self.param_dtype, name="embed_tokens",
+        )(input_ids)
+        for i in range(cfg.num_hidden_layers):
+            attend = (None if kv_cache is None
+                      else kv_cache.latent_attention(i))
+            h = DecoderLayer(
+                cfg, i, self.held_experts, self.dtype, self.param_dtype,
+                self.attention, name=f"layer_{i}",
+            )(h, positions, attend)
+        with jax.named_scope("head"):
+            h = RMSNorm(cfg.rms_norm_eps, self.dtype, self.param_dtype,
+                        name="norm")(h)
+            return nn.Dense(
+                cfg.vocab_size, use_bias=False, dtype=self.dtype,
+                param_dtype=self.param_dtype, name="lm_head",
+                dot_general=partial(jax.lax.dot_general,
+                                    preferred_element_type=jnp.float32),
+            )(h)

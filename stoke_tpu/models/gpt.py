@@ -22,6 +22,7 @@ import numpy as np
 from stoke_tpu.models.bert import (
     BERT_SIZES,
     BertSize,
+    CacheSpec,
     TransformerBlock,
     dense_attention,
 )
@@ -61,6 +62,29 @@ class GPT(nn.Module):
     # chunked over the sequence (ops/chunked_ce.py) — the [B, L, V] logits
     # tensor is never materialized; requires tie_embeddings
     chunked_head: bool = False
+
+    def cache_spec(self) -> CacheSpec:
+        """The serving contract's cache description: two planes (K, V) of
+        ``heads * head_dim`` a layer.  Raises for the options the paged
+        serving path has no forward for."""
+        if self.chunked_head:
+            raise ValueError(
+                "ServingEngine needs logits from the forward; construct the "
+                "serving GPT with chunked_head=False (params are identical)"
+            )
+        if self.moe_num_experts > 0:
+            raise NotImplementedError(
+                "ServingEngine supports dense-FFN GPT only (no MoE)"
+            )
+        size: BertSize = BERT_SIZES[self.size_name]
+        return CacheSpec(
+            layers=size.num_layers,
+            planes=(("k", size.hidden), ("v", size.hidden)),
+            kind="mha",
+            heads=size.heads,
+            head_dim=size.hidden // size.heads,
+            max_len=self.max_len,
+        )
 
     @nn.compact
     def __call__(self, input_ids, train: bool = True, positions=None,

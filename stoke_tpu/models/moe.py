@@ -26,7 +26,7 @@ top-1 routing collapses onto a few experts in real training.
 
 from __future__ import annotations
 
-from typing import Callable, Optional, Tuple
+from typing import Any, Callable, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -141,6 +141,154 @@ class MoEFFN(nn.Module):
         return jnp.einsum(
             "gsec,egch->gsh", combine.astype(x.dtype), expert_out
         )
+
+
+def group_limited_topk(scores, n_group: int, topk_group: int, top_k: int,
+                       scale: float = 1.0, norm: bool = True):
+    """Group-limited top-k routing (the DeepSeek-V3 family's, without a
+    score-correction bias): ``scores [N, E]`` float32, one column an expert,
+    the experts in ``n_group`` contiguous groups.  A group's score is the sum
+    of its two highest scores; the ``topk_group`` best groups are kept; the
+    ``top_k`` highest scores within them are the token's experts.  Returns
+    ``(experts [N, top_k] int32, weights [N, top_k] float32)``: the chosen
+    scores, renormalised over the ``top_k`` when ``norm``, times ``scale``.
+    Ties go to the lower index, at both levels."""
+    N, E = scores.shape
+    if E % n_group:
+        raise ValueError(f"{E} experts do not split into {n_group} groups")
+    grouped = scores.reshape(N, n_group, E // n_group)
+    group_score = jax.lax.top_k(grouped, min(2, E // n_group))[0].sum(-1)
+    _, kept = jax.lax.top_k(group_score, topk_group)  # [N, topk_group]
+    in_kept = (
+        kept[:, :, None] == jnp.arange(n_group, dtype=kept.dtype)
+    ).any(axis=1)  # [N, n_group]
+    # scores are sigmoids, above 0: -1 ranks every masked expert last
+    masked = jnp.where(in_kept[:, :, None], grouped, -1.0).reshape(N, E)
+    weights, experts = jax.lax.top_k(masked, top_k)
+    if norm:
+        weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), weights * scale
+
+
+class SwiGLU(nn.Module):
+    """``W_d(silu(W_g x) * W_u x)``, no biases; products accumulate in
+    float32 and come back in ``dtype``."""
+
+    width: int
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        def proj(name, features, y):
+            return nn.Dense(
+                features, use_bias=False, dtype=self.dtype,
+                param_dtype=self.param_dtype, name=name,
+            )(y)
+
+        gated = jax.nn.silu(proj("gate", self.width, x)) * proj(
+            "up", self.width, x
+        )
+        return proj("down", x.shape[-1], gated)
+
+
+class ExpertShareFFN(nn.Module):
+    """One chip's share of a sparse expert layer: a router of the model's
+    full width, the ``held = (first, count)`` routed experts that live here,
+    and the shared expert.
+
+    Every token is routed over all ``num_experts`` (sigmoid scores,
+    :func:`group_limited_topk`).  Assignments to experts outside ``held``
+    are left out: their part of the sum is what the chip that holds them
+    would add, and nothing here stands in for it.  Assignments to held
+    experts are all computed, none dropped: the ``N * top_k`` assignments
+    are sorted by expert (absent ones last), the held experts' SwiGLU runs
+    as three grouped products over the sorted rows
+    (``jax.lax.ragged_dot``; rows past the last group belong to no expert
+    and are never read back), and each token sums its own rows by the
+    inverse permutation, weighted.  The shared expert is computed for every
+    token.  Sows the per-held-expert assignment counts (``int32[count]``)
+    into the ``intermediates`` collection as ``expert_counts``.
+
+    Router logits, sigmoid, top-k and weights are float32 (the logits at
+    ``Precision.HIGHEST``); expert products take ``dtype`` inputs and
+    accumulate in float32."""
+
+    hidden: int
+    ff: int
+    num_experts: int
+    held: Tuple[int, int]
+    top_k: int
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    n_shared_experts: int = 1
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        B, L, H = x.shape
+        N, k = B * L, self.top_k
+        first, count = self.held
+        if not (0 <= first and count >= 1
+                and first + count <= self.num_experts):
+            raise ValueError(
+                f"ExpertShareFFN: held={self.held} is no range of the "
+                f"{self.num_experts} routed experts"
+            )
+        xf = x.reshape(N, H)
+        with jax.named_scope("router"):
+            w_r = self.param(
+                "router", nn.initializers.lecun_normal(),
+                (H, self.num_experts), jnp.float32,
+            )
+            scores = jax.nn.sigmoid(jnp.dot(
+                xf.astype(jnp.float32), w_r,
+                precision=jax.lax.Precision.HIGHEST,
+            ))
+            experts, weights = group_limited_topk(
+                scores, self.n_group, self.topk_group, k,
+                self.routed_scaling_factor, self.norm_topk_prob,
+            )
+        with jax.named_scope("moe"):
+            local = experts - first
+            is_held = (local >= 0) & (local < count)  # [N, k]
+            key = jnp.where(is_held, local, count).reshape(N * k)
+            counts = (
+                key[:, None] == jnp.arange(count, dtype=key.dtype)
+            ).sum(axis=0, dtype=jnp.int32)
+            order = jnp.argsort(key, stable=True)  # held first, by expert
+            rows = xf.astype(self.dtype)[order // k]  # [N*k, H]
+            init = nn.initializers.lecun_normal(batch_axis=(0,))
+            w_gate, w_up = (
+                self.param(name, init, (count, H, self.ff), self.param_dtype)
+                for name in ("w_gate", "w_up")
+            )
+            w_down = self.param(
+                "w_down", init, (count, self.ff, H), self.param_dtype
+            )
+
+            def grouped(lhs, rhs):
+                return jax.lax.ragged_dot(
+                    lhs, rhs.astype(self.dtype), counts,
+                    preferred_element_type=jnp.float32,
+                )
+
+            mid = jax.nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up)
+            y = grouped(mid.astype(self.dtype), w_down)  # [N*k, H] float32
+            y = y[jnp.argsort(order)].reshape(N, k, H)
+            # a row of no group holds whatever the grouped product left
+            y = jnp.where(is_held[:, :, None], y, 0.0)
+            routed = (y * weights[:, :, None]).sum(axis=1)
+            shared = SwiGLU(
+                self.ff * self.n_shared_experts, self.dtype,
+                self.param_dtype, name="shared",
+            )(x)
+            out = shared + routed.reshape(B, L, H).astype(self.dtype)
+        self.sow("intermediates", "expert_counts", counts)
+        return out
 
 
 def moe_expert_parallel_rules(expert_axis: str = "expert") -> Tuple:

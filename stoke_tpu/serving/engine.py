@@ -67,11 +67,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from stoke_tpu.configs import ServeConfig
-from stoke_tpu.models.bert import BERT_SIZES
-from stoke_tpu.models.gpt import GPT
 from stoke_tpu.ops.flash_attention import partition_kernels_over
 from stoke_tpu.serving.kv_cache import (
     BlockAllocator,
+    LatentAttentionHook,
     PagedAttentionHook,
     PagedKVCache,
 )
@@ -115,15 +114,25 @@ def _round_up(n: int, m: int) -> int:
 
 
 class ServingEngine:
-    """Continuous-batching inference engine over one GPT model.
+    """Continuous-batching inference engine over one decoder-only model.
 
     Built by :meth:`stoke_tpu.facade.Stoke.serve` (which supplies the
     trained params, telemetry pipeline, and compile cache) or standalone
     in tests/scripts.
 
     Args:
-        model: a :class:`~stoke_tpu.models.gpt.GPT` module (dense FFN,
-            ``chunked_head=False``).
+        model: a module that carries the serving contract:
+            ``__call__(input_ids, train, positions, decode, kv_cache)`` and
+            ``cache_spec()``, the description of its cache
+            (:class:`~stoke_tpu.models.bert.CacheSpec`: layers, planes and
+            their widths) the pool is built from; a model with expert
+            layers also says how many routed experts it holds
+            (``experts_held``).
+            :class:`~stoke_tpu.models.gpt.GPT` (dense FFN,
+            ``chunked_head=False``; two planes of ``heads * head_dim``) and
+            :class:`~stoke_tpu.models.decoder.Decoder` (one latent plane;
+            its weights are served as given, in its own ``param_dtype``,
+            and live on the device once) do.
         params: the model's ``params`` pytree (NOT the variables dict).
         cfg: :class:`~stoke_tpu.configs.ServeConfig`.
         registry: metrics registry for the ``serve/*`` instruments
@@ -152,7 +161,7 @@ class ServingEngine:
 
     def __init__(
         self,
-        model: GPT,
+        model: Any,
         params: Any,
         cfg: ServeConfig,
         *,
@@ -164,20 +173,46 @@ class ServingEngine:
         memory=None,
     ):
         self._kv_sharding = kv_sharding
-        if not isinstance(model, GPT):
+        if not callable(getattr(model, "cache_spec", None)):
             raise TypeError(
-                f"ServingEngine serves GPT models; got {type(model).__name__} "
-                f"(the paged-cache decode forward lives in models/gpt.py)"
+                f"ServingEngine serves models that carry the serving "
+                f"contract (GPT, Decoder: a paged-cache decode forward and "
+                f"cache_spec()); got {type(model).__name__}"
             )
-        if model.chunked_head:
-            raise ValueError(
-                "ServingEngine needs logits from the forward; construct the "
-                "serving GPT with chunked_head=False (params are identical)"
-            )
-        if model.moe_num_experts > 0:
-            raise NotImplementedError(
-                "ServingEngine supports dense-FFN GPT only (no MoE)"
-            )
+        spec = model.cache_spec()
+        #: routed experts the model's expert layers compute here (the
+        #: model's ``experts_held``; 0 for a model with none): the decode
+        #: program then hands their assignment counts back
+        self._experts_held = int(getattr(model, "experts_held", 0))
+        # a latent cache or an expert layer runs through the greedy
+        # serve_prefill and serve_decode programs only, and states its own
+        # compute dtype: its weights are served as given
+        self._native = spec.kind != "mha" or self._experts_held > 0
+        if self._native:
+            missing = {
+                "sampling": (cfg.sampling, "sampling serve_prefill / "
+                             "serve_decode"),
+                "prefill_chunk_tokens": (
+                    cfg.prefill_chunk_tokens is not None,
+                    "serve_prefill_chunk"),
+                "speculative_k": (cfg.speculative_k is not None,
+                                  "serve_verify"),
+                "decode_kernel='pallas'": (
+                    cfg.decode_kernel == "pallas",
+                    "Pallas paged-decode kernel over latent rows"),
+                f"quant={cfg.quant!r}": (
+                    cfg.quant != "none",
+                    "quantized weight store for a model served in its "
+                    "own param_dtype"),
+            }
+            for option, (asked, program) in missing.items():
+                if asked:
+                    raise NotImplementedError(
+                        f"ServeConfig {option}: a {spec.kind}-cache model"
+                        f"{' with experts' if self._experts_held else ''} "
+                        f"has no {program} program yet (greedy "
+                        f"serve_prefill and serve_decode only)"
+                    )
         if cfg.max_seq_len > model.max_len:
             raise ValueError(
                 f"ServeConfig.max_seq_len={cfg.max_seq_len} exceeds the "
@@ -235,9 +270,9 @@ class ServingEngine:
         # fields) until the first SLO-tagged request arrives
         self.slo = SLOTracker(self.metrics.registry)
 
-        size = BERT_SIZES[model.size_name]
-        self._heads = size.heads
-        self._head_dim = size.hidden // size.heads
+        self._spec = spec
+        self._heads = spec.heads
+        self._head_dim = spec.head_dim
 
         # --- weight store (pillar 4): quantize once at load time ---
         self.qparams = quantize_params(
@@ -297,14 +332,16 @@ class ServingEngine:
             else cfg.max_seqs * max_blocks_per_seq + 1  # +1 scratch
         )
         self.cache = PagedKVCache(
-            size.num_layers,
+            spec.layers,
             num_blocks,
             cfg.kv_block_size,
-            self._heads,
-            self._head_dim,
             dtype=_KV_DTYPES[cfg.kv_dtype],
             sharding=kv_sharding,
+            planes=spec.planes,
         )
+        self.metrics.cache_bytes_per_token.set(self.cache.bytes_per_token)
+        if self._experts_held:
+            self.metrics.enable_experts()
         self.allocator = BlockAllocator(num_blocks, cfg.kv_block_size)
 
         # --- continuous-batching scheduler (pillar 2) ---
@@ -361,7 +398,11 @@ class ServingEngine:
         # --- compiled programs (pillar 3) ---
         # donation keeps the page pool in-place in HBM; the CPU backend
         # has no donation (jax warns and copies), so only donate off-CPU
-        donate = (1, 2) if jax.default_backend() != "cpu" else ()
+        donate = (
+            tuple(range(1, 1 + len(spec.planes)))
+            if jax.default_backend() != "cpu"
+            else ()
+        )
 
         def program(name, method):
             # the module carries the program's name (``jit_serve_decode``):
@@ -479,7 +520,8 @@ class ServingEngine:
     # compiled program bodies
     # ------------------------------------------------------------------ #
 
-    def _apply(self, params, tokens, positions, hook, decode: bool):
+    def _apply(self, params, tokens, positions, hook, decode: bool,
+               expert_counts: bool = False):
         # with the pool placed on a mesh the serve programs are multi-device
         # programs, where the Pallas kernels must shard_map themselves; every
         # replica serves the whole slot batch, so no axis splits the rows
@@ -490,21 +532,44 @@ class ServingEngine:
             else contextlib.nullcontext()
         )
         with scope:
-            return self.model.apply(
+            out = self.model.apply(
                 {"params": params},
                 tokens,
                 train=False,
                 positions=positions,
                 decode=decode,
                 kv_cache=hook,
+                mutable=["intermediates"] if expert_counts else False,
             )
+        if not expert_counts:
+            return out
+        # what the expert layers sowed: int32[held] a layer, summed
+        logits, sown = out
+        counts = jax.tree_util.tree_leaves(sown["intermediates"])
+        return logits, sum(counts[1:], counts[0])
 
-    def _make_hook(self, k_pages, v_pages, tables, positions, mode, lengths):
+    def _split(self, args: tuple):
+        """A serve program's arguments after the weights: the pool's planes
+        as the model describes them, then the rest."""
+        n = len(self._spec.planes)
+        return args[:n], args[n:]
+
+    def _weights(self, qparams):
+        """The dense tree the forward reads: dequantized from the store, or
+        as given for a model that states its own compute dtype."""
+        return qparams if self._native else dequantize_params(qparams)
+
+    def _make_hook(self, pages, tables, positions, mode, lengths):
         """The per-trace cache hook with this engine's kernel selection —
         with the default ``decode_kernel="reference"`` the constructed
         graph is op-for-op the pre-ISSUE-13 one."""
+        if self._spec.kind == "latent":
+            return LatentAttentionHook(
+                *pages, tables, positions, mode=mode, lengths=lengths,
+                attention_impl=self.cfg.attention,
+            )
         return PagedAttentionHook(
-            k_pages, v_pages, tables, positions,
+            *pages, tables, positions,
             mode=mode, lengths=lengths,
             attention_impl=self.cfg.attention,
             decode_impl=self.cfg.decode_kernel,
@@ -513,40 +578,50 @@ class ServingEngine:
             verify_pages_per_block=self.cfg.verify_pages_per_block,
         )
 
-    def _prefill_fn(self, qparams, k_pages, v_pages, tokens, block_row,
-                    prompt_len):
-        """tokens [1, P] padded prompt; block_row [1, MB]; prompt_len [1].
+    def _prefill_fn(self, qparams, *args):
+        """After the weights and the pool's planes: tokens [1, P] padded
+        prompt; block_row [1, MB]; prompt_len [1].
         Returns (first generated token [1], updated pages)."""
-        params = dequantize_params(qparams)
+        pages, (tokens, block_row, prompt_len) = self._split(args)
+        params = self._weights(qparams)
         P = tokens.shape[1]
         positions = jnp.arange(P, dtype=jnp.int32)[None, :]
         hook = self._make_hook(
-            k_pages, v_pages, block_row, positions, "prefill", prompt_len
+            pages, block_row, positions, "prefill", prompt_len
         )
         logits = self._apply(params, tokens, positions, hook, decode=False)
         last = logits[0, prompt_len[0] - 1]
         return (
             jnp.argmax(last, axis=-1).astype(jnp.int32)[None],
-            hook.k_pages,
-            hook.v_pages,
+            *hook.pages,
         )
 
-    def _decode_fn(self, qparams, k_pages, v_pages, tokens, positions,
-                   block_tables, context_lens):
-        """tokens/positions [B]; block_tables [B, MB]; context_lens [B].
-        Returns (next tokens [B], updated pages)."""
-        params = dequantize_params(qparams)
+    def _decode_fn(self, qparams, *args):
+        """After the weights and the pool's planes: tokens/positions [B];
+        block_tables [B, MB]; context_lens [B].
+        Returns (next tokens [B], updated pages); a model with expert
+        layers hands back their assignment counts (int32[held], summed
+        over the layers) beside the tokens."""
+        pages, (tokens, positions, block_tables, context_lens) = (
+            self._split(args)
+        )
+        params = self._weights(qparams)
         hook = self._make_hook(
-            k_pages, v_pages, block_tables, positions[:, None], "decode",
-            context_lens,
+            pages, block_tables, positions[:, None], "decode", context_lens
         )
+        counting = self._experts_held > 0
         logits = self._apply(
-            params, tokens[:, None], positions[:, None], hook, decode=True
+            params, tokens[:, None], positions[:, None], hook, decode=True,
+            expert_counts=counting,
         )
+        counts = ()
+        if counting:
+            logits, held_counts = logits
+            counts = (held_counts,)
         return (
             jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32),
-            hook.k_pages,
-            hook.v_pages,
+            *counts,
+            *hook.pages,
         )
 
     # --- sampling-mode programs (ISSUE 13): same forward, the draw added
@@ -564,7 +639,7 @@ class ServingEngine:
         P = tokens.shape[1]
         positions = jnp.arange(P, dtype=jnp.int32)[None, :]
         hook = self._make_hook(
-            k_pages, v_pages, block_row, positions, "prefill", prompt_len
+            (k_pages, v_pages), block_row, positions, "prefill", prompt_len
         )
         logits = self._apply(params, tokens, positions, hook, decode=False)
         row = logits[0, prompt_len[0] - 1][None, :]
@@ -579,7 +654,7 @@ class ServingEngine:
         pre-sampling logits [B, V], updated pages)."""
         params = dequantize_params(qparams)
         hook = self._make_hook(
-            k_pages, v_pages, block_tables, positions[:, None], "decode",
+            (k_pages, v_pages), block_tables, positions[:, None], "decode",
             context_lens,
         )
         logits = self._apply(
@@ -602,7 +677,7 @@ class ServingEngine:
         registers it once)."""
         params = dequantize_params(qparams)
         hook = self._make_hook(
-            k_pages, v_pages, block_row, positions, "chunk", prompt_len
+            (k_pages, v_pages), block_row, positions, "chunk", prompt_len
         )
         logits = self._apply(params, tokens, positions, hook, decode=False)
         row = logits[0, logit_idx[0]][None, :]
@@ -629,7 +704,7 @@ class ServingEngine:
         [B, S, V], updated pages)``."""
         params = dequantize_params(qparams)
         hook = self._make_hook(
-            k_pages, v_pages, block_tables, positions, "verify", lengths
+            (k_pages, v_pages), block_tables, positions, "verify", lengths
         )
         logits = self._apply(params, tokens, positions, hook, decode=False)
         targets, key_stack = speculative_sample_tokens(
@@ -654,7 +729,7 @@ class ServingEngine:
         pre-sampling logit rows [B, V], updated pages)``."""
         params = dequantize_params(qparams)
         hook = self._make_hook(
-            k_pages, v_pages, block_tables, positions, "chunk", lengths
+            (k_pages, v_pages), block_tables, positions, "chunk", lengths
         )
         logits = self._apply(params, tokens, positions, hook, decode=False)
         rows = jnp.take_along_axis(
@@ -720,8 +795,7 @@ class ServingEngine:
         i32 = jnp.int32
         args = (
             jax.tree_util.tree_map(abstract, self.qparams),
-            abstract(self.cache.k_pages),
-            abstract(self.cache.v_pages),
+            *map(abstract, self.cache.pages),
             jax.ShapeDtypeStruct((B,), i32),  # tokens
             jax.ShapeDtypeStruct((B,), i32),  # positions
             jax.ShapeDtypeStruct((B, self._max_blocks_per_seq), i32),
@@ -757,8 +831,7 @@ class ServingEngine:
         the numpy ``host_args`` put on the device."""
         return (
             self.qparams,
-            self.cache.k_pages,
-            self.cache.v_pages,
+            *self.cache.pages,
             *map(jnp.asarray, host_args),
         )
 
@@ -766,9 +839,10 @@ class ServingEngine:
         """Dispatch one serve program; the page pool it returns last
         replaces the cache's.  Returns its other outputs, still on the
         device."""
-        *out, k_pages, v_pages = self._dispatch(program, fn, args)
-        self.cache.k_pages, self.cache.v_pages = k_pages, v_pages
-        return out
+        out = self._dispatch(program, fn, args)
+        n = len(self.cache.pages)
+        self.cache.pages = tuple(out[-n:])
+        return list(out[:-n])
 
     def _launch(self, span: str, program: str, fn, host_args: tuple) -> list:
         """:meth:`_upload` and :meth:`_run`, each under its own child of
@@ -1169,6 +1243,9 @@ class ServingEngine:
             )
             with trace_span("serve/decode_step/read", track="serve"):
                 next_host = np.asarray(out[0])  # sync: tokens stream out
+                if self._experts_held:
+                    # the held experts' assignment counts ride beside
+                    held_counts = np.asarray(out[1])
                 if self._sampling:
                     # advance ONLY the decoding slots' key streams: a
                     # request's draw sequence depends on its own seed and
@@ -1196,7 +1273,23 @@ class ServingEngine:
                           request_id=rid, count_self=False)
         m.decode_steps.inc()
         m.decode_s.inc(now - t0)
-        with trace_span("serve/commit", track="serve"):
+        # what the step just read, on the span that closes after the read:
+        # the live rows' context lengths (fresh token included) and, of an
+        # expert model, its held experts' load
+        step_attrs = {"context_tokens": int(host_args[3][decode_rows].sum())}
+        if self._experts_held:
+            total = int(held_counts.sum())
+            imbalance = (
+                float(held_counts.max()) * held_counts.size / total
+                if total else 0.0
+            )
+            m.expert_assignments.inc(total)
+            m.expert_load_max_over_mean.set(imbalance)
+            step_attrs.update(
+                expert_assignments=total,
+                expert_load_max_over_mean=imbalance,
+            )
+        with trace_span("serve/commit", track="serve", attrs=step_attrs):
             n_sampled = sum(
                 1
                 for i in decode_rows

@@ -114,12 +114,16 @@ class BlockAllocator:
 
 
 class PagedKVCache:
-    """The device-side block pool: K and V pages of every layer.
+    """The device-side block pool: the cache planes of every layer.
 
-    Stored ``[n_layers, n_blocks, block_size, heads * head_dim]``: a cached
-    token is ONE row, its heads side by side.  The minor dimension is then
-    whole 128-lane tiles at every width in use (1024, 768, the tests' 128)
-    and ``block_size`` whole sublanes, so row-major is the layout the
+    A plane is ``[n_layers, n_blocks, block_size, width]``: a cached token
+    is ONE row.  Which planes there are is the model's to say
+    (``CacheSpec.planes``): multi-head attention keeps two, K and V, of
+    ``heads * head_dim`` values, a token's heads side by side; latent
+    attention keeps one, the normed latent and the roped shared key side
+    by side.  The minor dimension is then
+    whole 128-lane tiles at every MHA width in use (1024, 768, the tests'
+    128) and ``block_size`` whole sublanes, so row-major is the layout the
     device keeps the pool in at rest AND the one every program computes
     on: nothing is padded, no program converts the pool on its way in or
     out, and the donated pool is updated in place.  (With a trailing
@@ -127,10 +131,21 @@ class PagedKVCache:
     keep the block index in the lanes at rest, and every program copies K
     and V to a ``head_dim``-padded tiling and back: four copies of the
     pool a dispatch.)  A width that is no multiple of 128 is still
-    correct, and pads.  Layer
+    correct, and is copied the same way: read from the entry layouts of
+    programs compiled for the v5e (a row scatter and a block gather on a
+    bfloat16 plane), widths 576 and 64 are kept ``{1,3,2,0:T(8,128)(2,1)}``
+    at rest with two whole-plane copies a dispatch, widths 640, 512 and 128
+    ``{3,2,1,0:T(8,128)(2,1)}`` with none.  So the 576-value latent row of
+    the DeepSeek-V3 family is ONE plane of 640, 64 zero lanes at its end
+    (what the tiled layout pads it to anyway), and not 512 + 64 as two,
+    whose second plane would be copied, nor 512 + 128, which costs a second
+    gather and scatter a layer for the same bytes.  Layer
     outermost, block next: a request's window is one gather of whole
     blocks at ``(layer, block_table)``, a write one scatter of rows at
     ``(layer, block, offset)``.
+
+    ``planes`` is the model's ``CacheSpec.planes``: ``(("k", 768), ("v",
+    768))`` for 12 heads of 64, ``(("latent", 640),)`` for the latent row.
 
     ``sharding`` (optional ``jax.sharding.Sharding``) places the pool on
     the serving mesh — replicated by default (data-parallel serving
@@ -143,8 +158,7 @@ class PagedKVCache:
         n_layers: int,
         num_blocks: int,
         block_size: int,
-        heads: int,
-        head_dim: int,
+        planes: Sequence,
         dtype=jnp.float32,
         sharding=None,
     ):
@@ -152,19 +166,63 @@ class PagedKVCache:
         self.block_size = int(block_size)
         self.num_blocks = int(num_blocks)
         self.dtype = jnp.dtype(dtype)
-        shape = (n_layers, num_blocks, block_size, heads * head_dim)
-        k = jnp.zeros(shape, dtype)
-        v = jnp.zeros(shape, dtype)
-        if sharding is not None:
-            k = jax.device_put(k, sharding)
-            v = jax.device_put(v, sharding)
-        self.k_pages = k
-        self.v_pages = v
+        self.plane_names = tuple(name for name, _ in planes)
+        pages = []
+        for _, width in planes:
+            plane = jnp.zeros((n_layers, num_blocks, block_size, width), dtype)
+            if sharding is not None:
+                plane = jax.device_put(plane, sharding)
+            pages.append(plane)
+        #: the planes, in the model's order; the serve programs take them
+        #: (donated) and hand them back
+        self.pages = tuple(pages)
+
+    @property
+    def k_pages(self):
+        return self.pages[self.plane_names.index("k")]
+
+    @property
+    def v_pages(self):
+        return self.pages[self.plane_names.index("v")]
 
     @property
     def nbytes(self) -> int:
-        """HBM footprint of the pool (both planes)."""
-        return int(self.k_pages.size + self.v_pages.size) * self.dtype.itemsize
+        """HBM footprint of the pool (all planes, unpadded)."""
+        return sum(int(p.size) for p in self.pages) * self.dtype.itemsize
+
+    @property
+    def bytes_per_token(self) -> int:
+        """Cached bytes a token, over all layers and planes."""
+        return self.n_layers * self.dtype.itemsize * sum(
+            int(p.shape[-1]) for p in self.pages)
+
+
+def _write_targets(block_tables, positions, block_size: int, lengths=None):
+    """``(blocks, offs)``, each ``[B*L]``: where this call's tokens at
+    ``positions [B, L]`` land in the pool.  A token at a position below its
+    slot's ``lengths`` (or any token, without ``lengths``: decode) lands at
+    ``(block_table[b, pos // BS], pos % BS)``; the others land in the
+    scratch block."""
+    B, L = positions.shape
+    pos = positions.reshape(-1)  # [B*L]
+    slot = jnp.repeat(jnp.arange(B, dtype=jnp.int32), L)
+    blk_idx = pos // block_size
+    if lengths is not None:
+        # chunk rows past the prompt end (the last chunk's padding)
+        # carry clamped positions >= the prompt length, so the same
+        # predicate steers them to scratch; verify's lengths bound
+        # the real write window (context + draft + 1) the same way
+        valid = (
+            positions < lengths[:, None].astype(positions.dtype)
+        ).reshape(-1)
+    else:
+        valid = jnp.ones_like(pos, dtype=bool)
+    # clamp the table column so padding positions past the allocated
+    # window index legally, then steer invalid writes to scratch
+    blk_idx = jnp.minimum(blk_idx, block_tables.shape[1] - 1)
+    blocks = block_tables[slot, blk_idx]
+    blocks = jnp.where(valid, blocks, SCRATCH_BLOCK)
+    return blocks, pos % block_size
 
 
 def _token_rows(t):
@@ -258,6 +316,11 @@ class PagedAttentionHook:
         # taken before each write, consumed by rollback()
         self._saved: List[tuple] = []
 
+    @property
+    def pages(self) -> tuple:
+        """The planes as the serve programs thread them."""
+        return (self.k_pages, self.v_pages)
+
     # ------------------------------ writes ----------------------------- #
 
     def _write_layer(self, layer: int, k, v) -> None:
@@ -269,27 +332,11 @@ class PagedAttentionHook:
         in the scratch block, which nothing reads.  Distinct live slots
         own distinct blocks, so in-batch writes never collide.
         """
-        B, L = self.positions.shape
-        pos = self.positions.reshape(-1)  # [B*L]
-        slot = jnp.repeat(jnp.arange(B, dtype=jnp.int32), L)
-        blk_idx = pos // self.block_size
-        if self.mode in ("prefill", "chunk", "verify"):
-            # chunk rows past the prompt end (the last chunk's padding)
-            # carry clamped positions >= the prompt length, so the same
-            # predicate steers them to scratch; verify's lengths bound
-            # the real write window (context + draft + 1) the same way
-            valid = (
-                self.positions
-                < self.lengths[:, None].astype(self.positions.dtype)
-            ).reshape(-1)
-        else:
-            valid = jnp.ones_like(pos, dtype=bool)
-        # clamp the table column so padding positions past the allocated
-        # window index legally, then steer invalid writes to scratch
-        blk_idx = jnp.minimum(blk_idx, self.block_tables.shape[1] - 1)
-        blocks = self.block_tables[slot, blk_idx]
-        blocks = jnp.where(valid, blocks, SCRATCH_BLOCK)
-        offs = pos % self.block_size
+        blocks, offs = _write_targets(
+            self.block_tables, self.positions, self.block_size,
+            self.lengths if self.mode in ("prefill", "chunk", "verify")
+            else None,
+        )
         if self.mode == "verify":
             # snapshot what the write clobbers so rollback() can undo the
             # rejected tail exactly — acceptance is only known after the
@@ -409,3 +456,85 @@ class PagedAttentionHook:
             return dense_attention(q, k, v, pbias)
 
         return attention_fn
+
+
+class LatentAttentionHook:
+    """Per-trace cache bridge for a latent-attention model
+    (``Decoder(..., kv_cache=hook)``): one plane, one row a token a layer,
+    the normed latent and the roped shared key side by side.
+
+    ``latent_attention(i)`` returns layer ``i``'s ``attend(q_nope, q_rope,
+    c, k_rope, w_kvb, scale)``.  It writes the call's rows into the slot's
+    blocks (the same steering as :class:`PagedAttentionHook`: padding and
+    idle slots land in the scratch block), then attends: in ``"prefill"``
+    mode the expanded form, causal over the padded prompt, through
+    ``attention_impl`` (``"flash"`` or ``"dense"``); in ``"decode"`` mode
+    the absorbed form over the slot's gathered window
+    (``models/decoder.py`` holds both).  The window is ONE gather of whole
+    blocks at ``(layer, block_tables)`` out of the whole plane, as the MHA
+    pool's is.  The chunk and verify modes have no latent program yet.
+
+    Args as :class:`PagedAttentionHook`'s, with ``pages`` the pool's one
+    plane ``[n_layers, NB, BS, >= C + dr]`` (the row's values, then zeros up
+    to whole 128-lane tiles: ``DecoderConfig.latent_row_width``).
+    """
+
+    def __init__(self, pages, block_tables, positions, *, mode: str,
+                 lengths, attention_impl: str = "dense"):
+        if mode not in ("prefill", "decode"):
+            raise NotImplementedError(
+                f"LatentAttentionHook has no {mode!r} mode: the latent "
+                f"cache is written and read by the serve_prefill and "
+                f"serve_decode programs only"
+            )
+        self.latent_pages = pages
+        self.block_tables = block_tables
+        self.positions = positions
+        self.mode = mode
+        self.lengths = lengths
+        self.attention_impl = attention_impl
+        self.block_size = int(pages.shape[2])
+
+    @property
+    def pages(self) -> tuple:
+        return (self.latent_pages,)
+
+    def latent_attention(self, layer: int):
+        from stoke_tpu.models.decoder import (
+            absorbed_attention,
+            expanded_attention,
+        )
+
+        def attend(q_nope, q_rope, c, k_rope, w_kvb, scale):
+            B, L = self.positions.shape
+            rows = jnp.concatenate([c, k_rope], axis=-1).reshape(B * L, -1)
+            pool = self.latent_pages
+            # the row as stored: its values, then zeros up to whole tiles
+            rows = jnp.pad(
+                rows, ((0, 0), (0, pool.shape[-1] - rows.shape[-1])))
+            blocks, offs = _write_targets(
+                self.block_tables, self.positions, self.block_size,
+                self.lengths if self.mode == "prefill" else None,
+            )
+            self.latent_pages = pool.at[layer, blocks, offs].set(
+                rows.astype(pool.dtype), mode="promise_in_bounds"
+            )
+            if self.mode == "prefill":
+                key_valid = (
+                    jnp.arange(L, dtype=jnp.int32)[None, :]
+                    < self.lengths[:, None].astype(jnp.int32)
+                )
+                return expanded_attention(
+                    q_nope, q_rope, c, k_rope, w_kvb, scale, key_valid,
+                    self.attention_impl,
+                )
+            window = self.latent_pages.at[layer, self.block_tables].get(
+                mode="promise_in_bounds"
+            )  # [B, MB, BS, row]: whole blocks, merged below
+            window = window.reshape(B, -1, window.shape[-1])
+            return absorbed_attention(
+                q_nope, q_rope, window,
+                self.lengths.astype(jnp.int32)[:, None] - 1, w_kvb, scale,
+            )
+
+        return attend

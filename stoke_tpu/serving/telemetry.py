@@ -145,6 +145,11 @@ class ServeMetrics:
             "serve/quant_compression",
             help="param bytes fp / param bytes as-served",
         )
+        self.cache_bytes_per_token = registry.gauge(
+            "serve/cache_bytes_per_token",
+            help="cached bytes a token over all layers and planes, as the "
+            "model describes its cache",
+        )
         self._p = {
             "ttft_p50": registry.gauge("serve/ttft_p50_s"),
             "ttft_p99": registry.gauge("serve/ttft_p99_s"),
@@ -163,6 +168,29 @@ class ServeMetrics:
         self.cost_active = False
         self.cost_flops = None
         self.cost_bytes = None
+        # expert-layer load: created by enable_experts() for a model that
+        # holds routed experts, so no other engine registers the series
+        self.expert_assignments = None
+        self.expert_load_max_over_mean = None
+
+    def enable_experts(self) -> None:
+        """Arm the expert-layer instruments, called at engine construction
+        for a model whose ``experts_held`` is above 0:
+        assignments the held experts computed, over all expert layers and
+        decode steps, and the last decode step's busiest held expert over
+        the mean (1.0: even)."""
+        if self.expert_assignments is not None:
+            return
+        self.expert_assignments = self.registry.counter(
+            "serve/expert_assignments_total",
+            help="token-to-expert assignments computed by the held experts "
+            "in decode steps (all expert layers)",
+        )
+        self.expert_load_max_over_mean = self.registry.gauge(
+            "serve/expert_load_max_over_mean",
+            help="last decode step: busiest held expert's assignments over "
+            "the mean of the held experts",
+        )
 
     def enable_speculative(self) -> None:
         """Arm the speculative-decoding instruments (ISSUE 17) — called at

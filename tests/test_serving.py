@@ -819,11 +819,12 @@ def test_chunked_prefill_streams_identical(rng):
 
 def test_chunked_prefill_interleaves_decode_and_bounds_stall(rng):
     """Acceptance: with one long prompt admitted mid-flight, the in-flight
-    request keeps receiving tokens BETWEEN chunks, and its worst
-    inter-token gap (from the span timeline) is smaller than a full
-    unchunked prefill step of the same prompt."""
-    import time as _time
-
+    request keeps receiving tokens BETWEEN chunks, and its stall is bounded
+    by ONE chunk: on the span timeline no two chunks of the long prompt run
+    between two consecutive decode steps of the in-flight request (an
+    unchunked prefill would put all eight there).  Asserted on the order of
+    the spans, not on their durations: a wall-clock comparison of one gap
+    against one prefill fails on a loaded machine."""
     from stoke_tpu.telemetry.tracing import (
         TraceRecorder,
         register_recorder,
@@ -836,28 +837,14 @@ def test_chunked_prefill_interleaves_decode_and_bounds_stall(rng):
     long_prompt = rng.integers(1, VOCAB, size=460).astype(np.int32)
     short = rng.integers(1, VOCAB, size=8).astype(np.int32)
 
-    # reference leg: the wall time of ONE full unchunked prefill step
-    # (warm), via the serve/prefill span
+    # reference leg: the unchunked engine's stream of the long prompt
     ref = ServingEngine(model, params, ServeConfig(**cfg))
-    # warm the 512 bucket; the stream doubles as the unchunked reference
     ref_stream = ref.generate([long_prompt], max_new_tokens=2)[0]
-    rec = TraceRecorder(ring_size=512)
-    register_recorder(rec)
-    try:
-        ref.submit(long_prompt, 2)
-        ref.step()
-    finally:
-        unregister_recorder(rec)
-    full_prefill_s = max(
-        s.dur_s for s in rec.spans() if s.name == "serve/prefill"
-    )
 
     # chunked leg: short request decoding, long prompt admitted mid-flight
     eng = ServingEngine(
         model, params, ServeConfig(**cfg, prefill_chunk_tokens=64)
     )
-    eng.generate([long_prompt], max_new_tokens=2)  # warm chunk program
-    eng.generate([short], max_new_tokens=2)        # warm decode + bucket
     rec2 = TraceRecorder(ring_size=4096)
     register_recorder(rec2)
     try:
@@ -880,19 +867,20 @@ def test_chunked_prefill_interleaves_decode_and_bounds_stall(rng):
         if s.name == "serve/decode_step" and t_first < s.t_start < t_last
     ]
     assert len(decode_between) >= len(chunk_spans) - 2
-    # the in-flight request's measured TPOT stall: worst gap between its
-    # consecutive decode slices on the span timeline
+    # the in-flight request's stall, in steps: the chunks that start
+    # between two consecutive decode slices of its timeline
     short_decodes = sorted(
-        s.t_start + s.dur_s
+        s.t_start
         for s in spans
         if s.name == "serve/decode" and s.request_id == rid_short
     )
-    assert len(short_decodes) >= 2
-    worst_gap = max(
-        b - a for a, b in zip(short_decodes, short_decodes[1:])
-    )
-    # acceptance: TPOT degrades by LESS than a full unchunked prefill
-    assert worst_gap < full_prefill_s, (worst_gap, full_prefill_s)
+    assert len(short_decodes) >= len(chunk_spans)
+    chunks_in_gap = [
+        sum(a < c.t_start < b for c in chunk_spans)
+        for a, b in zip(short_decodes, short_decodes[1:])
+    ]
+    assert max(chunks_in_gap) == 1, chunks_in_gap
+    assert sum(chunks_in_gap) >= len(chunk_spans) - 1
     # streams unaffected by the interleaving
     assert eng.scheduler.finished[rid_long].tokens == ref_stream
     assert eng.allocator.occupancy == 0.0
@@ -1450,7 +1438,8 @@ def test_flat_pool_attention_rejects_a_pool_of_another_width():
 def test_cache_pool_is_stored_one_row_per_token():
     from stoke_tpu.serving import PagedKVCache
 
-    cache = PagedKVCache(3, 5, 8, 12, 64, dtype=jnp.bfloat16)
+    cache = PagedKVCache(3, 5, 8, (("k", 768), ("v", 768)),
+                         dtype=jnp.bfloat16)
     assert cache.k_pages.shape == cache.v_pages.shape == (3, 5, 8, 768)
     assert cache.nbytes == 2 * 3 * 5 * 8 * 768 * 2
     model, params = _gpt("dense")
