@@ -1431,12 +1431,15 @@ class ServeConfig:
         attention: prefill kernel — "dense" (causal bias in fp32 softmax)
             or "flash" (the Pallas kernel, ``causal=True``; interpreted
             off-TPU).  Decode always reads the paged cache.
-        decode_kernel: decode attention kernel (ISSUE 13) — "reference"
+        decode_kernel: decode attention kernel of the multi-head (K and V)
+            cache (ISSUE 13) — "reference"
             (the jnp gathered-block math, XLA-lowered; bit-identical to
             the pre-fast-path engine) or "pallas"
             (``ops.flash_attention.paged_decode_attention_pallas``: the
             dedicated streaming kernel walking each request's block table
-            HBM→VMEM).  Off-TPU a standalone engine auto-falls-back to
+            HBM→VMEM).  A latent cache has one decode path
+            (``latent_paged_attention``) and does not consult this field.
+            Off-TPU a standalone engine auto-falls-back to
             the pallas INTERPRETER (the CPU parity mode tests pin against
             the reference); a real serve config declaring ``device='cpu'``
             is a status error instead.

@@ -251,9 +251,35 @@ def expanded_attention(q_nope, q_rope, c, k_rope, w_kvb, scale, key_valid,
     return _einsum("bhqk,bkhd->bqhd", p.astype(dtype), v).astype(dtype)
 
 
+def _absorbed_query(q_nope, q_rope, w_kvb, width):
+    """``[B, S, H, width]`` rows the cached rows are scored against:
+    ``q_nope W_UK``, the roped part, zeros up to the cached row's width."""
+    dn = q_nope.shape[-1]
+    dtype = q_nope.dtype
+    q_lat = _einsum(
+        "bshd,chd->bshc", q_nope, w_kvb[..., :dn].astype(dtype)
+    ).astype(dtype)
+    q_row = jnp.concatenate([q_lat, q_rope], axis=-1)  # [B, S, H, C + dr]
+    return jnp.pad(q_row, ((0, 0),) * 3 + ((0, width - q_row.shape[-1]),))
+
+
+def _absorbed_output(o_row, w_kvb, dn):
+    """``(softmax . rows) W_UV``: of the attended rows ``[B, S, H, >= C]``
+    the latent lanes, through the value half of ``w_kvb``.  The key and
+    padding lanes rode along and are dropped here; slicing them off the
+    cached rows first would copy those."""
+    dtype = o_row.dtype
+    return _einsum(
+        "bshc,chd->bshd", o_row[..., : w_kvb.shape[0]],
+        w_kvb[..., dn:].astype(dtype),
+    ).astype(dtype)
+
+
 def absorbed_attention(q_nope, q_rope, window, positions, w_kvb, scale):
     """MLA over cached latent rows with ``W_kvb`` absorbed into the query
-    and the output: the decode form.  ``W_UK`` and ``W_UV`` are the two
+    and the output: the decode form, over a window gathered out of the
+    cache.  It defines what :func:`absorbed_paged_attention` computes and
+    is what the tests compare that with.  ``W_UK`` and ``W_UV`` are the two
     halves of ``w_kvb``, not new parameters.
 
     ``q_nope [B, S, H, dn]``, ``q_rope [B, S, H, dr]`` (roped), ``window
@@ -263,26 +289,33 @@ def absorbed_attention(q_nope, q_rope, window, positions, w_kvb, scale):
     window positions ``<= positions[b, s]``.  ``q_lat = q_nope W_UK``;
     scores are ``q_lat . c + q_r . k_r``, one product over the whole row;
     ``o = (softmax . c) W_UV``.  Returns ``[B, S, H, dv]``."""
-    dn = q_nope.shape[-1]
-    C = w_kvb.shape[0]
     dtype = q_nope.dtype
-    w_kvb = w_kvb.astype(dtype)
-    q_lat = _einsum("bshd,chd->bshc", q_nope, w_kvb[..., :dn]).astype(dtype)
-    q_row = jnp.concatenate([q_lat, q_rope], axis=-1)  # [B, S, H, C + dr]
-    q_row = jnp.pad(
-        q_row, ((0, 0),) * 3 + ((0, window.shape[-1] - q_row.shape[-1]),))
+    q_row = _absorbed_query(q_nope, q_rope, w_kvb, window.shape[-1])
     window = window.astype(dtype)
     s = _einsum("bshj,bwj->bshw", q_row, window) * scale
     W = window.shape[1]
     valid = (jnp.arange(W, dtype=jnp.int32)[None, None, None, :]
              <= positions[:, :, None, None])
     p = jax.nn.softmax(jnp.where(valid, s, _NEG_INF), axis=-1)
-    # over the whole row: the key and padding lanes ride along and are
-    # dropped; slicing them off the window first would copy the window
-    o_lat = _einsum("bshw,bwj->bshj", p.astype(dtype), window)[..., :C]
-    return _einsum(
-        "bshc,chd->bshd", o_lat.astype(dtype), w_kvb[..., dn:]
-    ).astype(dtype)
+    o_row = _einsum("bshw,bwj->bshj", p.astype(dtype), window).astype(dtype)
+    return _absorbed_output(o_row, w_kvb, q_nope.shape[-1])
+
+
+def absorbed_paged_attention(q_nope, q_rope, plane, layer, block_tables,
+                             context_lens, w_kvb, scale):
+    """:func:`absorbed_attention` of one query row a slot (``S == 1``) at
+    its last cached position, over the latent plane ``[n_layers, NB, BS,
+    row]`` read in place: the Pallas kernel
+    ``ops/flash_attention.py`` ``latent_paged_attention`` streams each
+    slot's ``ceil(context_lens[b] / BS)`` live blocks of
+    ``block_tables[b]`` and gathers no window.  Same products in the same
+    precisions.  Returns ``[B, 1, H, dv]``."""
+    from stoke_tpu.ops.flash_attention import latent_paged_attention
+
+    q_row = _absorbed_query(q_nope, q_rope, w_kvb, plane.shape[-1])
+    o_row = latent_paged_attention(
+        q_row[:, 0], plane, layer, block_tables, context_lens, scale)
+    return _absorbed_output(o_row[:, None], w_kvb, q_nope.shape[-1])
 
 
 # --------------------------------------------------------------------------- #

@@ -1047,6 +1047,187 @@ def paged_verify_attention_pallas(
     )
 
 
+#: bytes of latent pages :func:`latent_paged_attention` holds in each of its
+#: two VMEM buffers: 32 pages of [16, 640] bfloat16, so a step's two
+#: products run over 512 positions.  Swept on the v5e (PERF.md section 6,
+#: PR 30): a step's cost is the products' fixed part, not the page DMAs, and
+#: 512 positions is where long and short contexts both stop gaining.
+_LATENT_GROUP_BYTES = 640 * 1024
+
+
+def _latent_paged_kernel(tables_ref, lens_ref, q_ref, plane_ref, o_ref,
+                         buf, sems, acc, m_sc, l_sc, *, layer, block_size,
+                         group, max_blocks, scale):
+    """One slot of :func:`latent_paged_attention`: walk the slot's live
+    blocks, ``group`` pages a step, fetching step ``g + 1``'s pages from
+    the plane in HBM into one half of ``buf`` while step ``g`` is scored
+    out of the other.  A page is fetched once and serves both products:
+    every head's scores against its rows, then the probabilities against
+    the same rows (whose leading lanes are the values).  The fp32 running
+    max / normalizer / accumulator live in VMEM scratch, as in
+    ``_paged_attention_kernel``.  Blocks past the slot's length cost
+    neither a DMA nor a step."""
+    b = pl.program_id(0)
+    n_ctx = lens_ref[b]
+    n_blocks = jnp.minimum(pl.cdiv(n_ctx, block_size), max_blocks)
+    n_groups = pl.cdiv(n_blocks, group)
+    tokens = group * block_size
+
+    def live_pages(g, half, act):
+        # a loop and not ``group`` unrolled copies: the kernel is traced
+        # once a layer, and 3 x 32 guarded DMAs a trace cost the set-up 20 s
+        first = g * group
+
+        def page(p, carry):
+            at = pl.multiple_of(p * block_size, block_size)
+            act(pltpu.make_async_copy(
+                plane_ref.at[layer, tables_ref[b * max_blocks + first + p]],
+                buf.at[half, pl.ds(at, block_size)],
+                sems.at[half],
+            ))
+            return carry
+
+        jax.lax.fori_loop(0, jnp.minimum(group, n_blocks - first), page, None)
+
+    @pl.when(b == 0)
+    def _():
+        # the last step of a slot scores its unfetched pages too (masked to
+        # exact zeros): what lies there must be finite, so start from zeros
+        # and from then on it is rows fetched earlier
+        buf[...] = jnp.zeros_like(buf)
+
+    acc[...] = jnp.zeros_like(acc)
+    m_sc[...] = jnp.full_like(m_sc, _NEG_INF)
+    l_sc[...] = jnp.zeros_like(l_sc)
+
+    @pl.when(n_groups > 0)
+    def _():
+        live_pages(0, 0, lambda dma: dma.start())
+
+    q = q_ref[...]  # [H, row]
+
+    def step(g, carry):
+        half = g % 2
+
+        @pl.when(g + 1 < n_groups)
+        def _():
+            live_pages(g + 1, 1 - half, lambda dma: dma.start())
+
+        live_pages(g, half, lambda dma: dma.wait())
+        page = buf[half].astype(q.dtype)  # [tokens, row]
+        s = jax.lax.dot_general(
+            q, page, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [H, tokens]
+        pos = g * tokens + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        valid = pos < n_ctx
+        s = jnp.where(valid, s, _NEG_INF)
+        m_prev = m_sc[:, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        prob = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_sc[:, 0:1] = l_sc[:, 0:1] * corr + jnp.sum(
+            prob, axis=-1, keepdims=True
+        )
+        m_sc[:, 0:1] = m_new
+        acc[...] = acc[...] * corr + jax.lax.dot_general(
+            prob.astype(q.dtype), page, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        return carry
+
+    jax.lax.fori_loop(0, n_groups, step, None)
+    l = l_sc[:, 0:1]
+    o_ref[...] = (acc[...] / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
+
+
+def latent_paged_attention(q_row, plane, layer, block_tables, context_lens,
+                           scale, *, interpret: Optional[bool] = None):
+    """Decode attention of absorbed query rows over the latent cache's
+    plane, read in place: each slot's LIVE blocks stream through VMEM
+    once, with an online softmax, and no window is ever materialised.
+    ``models/decoder.py`` ``absorbed_attention`` over the slot's gathered
+    window is its pinned reference semantics.
+
+    Latent attention keeps one row a token that every head shares, so a
+    page is one ``[BS, row]`` tile-aligned block and the score product is
+    ``[H, row] x [row, tokens]``.  The block table and the lengths ride as
+    scalar-prefetch arguments; the plane stays in HBM (``pl.ANY``), whole:
+    no layer is sliced out and nothing is reshaped, either of which would
+    copy it.  Grid over the slots; inside, a loop to the slot's own block
+    count with double-buffered page DMAs (:func:`_latent_paged_kernel`).
+    How many pages a step takes follows from the page's bytes
+    (``_LATENT_GROUP_BYTES``).
+
+    Args:
+        q_row: ``[B, H, row]`` absorbed queries (``q_nope W_UK``, the roped
+            part, zeros up to the row's width), in the compute dtype.
+        plane: ``[n_layers, NB, BS, row]`` latent plane of the pool.
+        layer: static layer index into the plane.
+        block_tables: ``[B, MAX_BLOCKS] int32`` per-slot block ids; only
+            the first ``ceil(context_lens[b] / BS)`` of a slot are read.
+        context_lens: ``[B] int32`` valid positions a slot INCLUDING the
+            current one; at most ``MAX_BLOCKS * BS``.
+        scale: softmax scale, applied to the fp32 scores.
+        interpret: run through the pallas interpreter (``None`` = auto
+            off-TPU, as :func:`flash_attention`).
+
+    Returns ``[B, H, row]`` in the query dtype: the probabilities times the
+    whole rows (the caller keeps the value lanes).  A slot with
+    ``context_lens[b] == 0`` returns zeros.
+    """
+    B, H, row = q_row.shape
+    if plane.ndim != 4 or plane.shape[3] != row:
+        raise ValueError(
+            f"the plane must be [n_layers, NB, BS, row={row}], got "
+            f"{plane.shape}"
+        )
+    if block_tables.ndim != 2 or block_tables.shape[0] != B:
+        raise ValueError(
+            f"block_tables must be [B={B}, MAX_BLOCKS], got "
+            f"{block_tables.shape}"
+        )
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    BS, MB = int(plane.shape[2]), int(block_tables.shape[1])
+    group = max(1, min(
+        MB, _LATENT_GROUP_BYTES // (BS * row * plane.dtype.itemsize)))
+    kernel = functools.partial(
+        _latent_paged_kernel, layer=int(layer), block_size=BS, group=group,
+        max_blocks=MB, scale=float(scale),
+    )
+
+    slot_rows = pl.BlockSpec((None, H, row), lambda b, tbl, lens: (b, 0, 0))
+    call = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,  # the flat block table, the lengths
+            grid=(B,),
+            in_specs=[slot_rows, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=slot_rows,
+            scratch_shapes=[
+                pltpu.VMEM((2, group * BS, row), plane.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((H, row), jnp.float32),
+                pltpu.VMEM((H, 128), jnp.float32),
+                pltpu.VMEM((H, 128), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, H, row), q_row.dtype),
+        interpret=interpret,
+        name="latent_paged_attention",
+    )
+    part = _current_partition()
+    if part is not None:
+        # as the MHA kernel: every device walks the whole slot batch over
+        # its own replica of the pool
+        part = (part[0], ())
+    return _partition_rows(call, part, B)(
+        block_tables.astype(jnp.int32).reshape(-1),
+        context_lens.astype(jnp.int32), q_row, plane,
+    )
+
+
 def make_flash_attention(
     causal: bool = False, block_q: Optional[int] = None,
     block_k: Optional[int] = None, interpret: Optional[bool] = None,

@@ -197,9 +197,6 @@ class ServingEngine:
                     "serve_prefill_chunk"),
                 "speculative_k": (cfg.speculative_k is not None,
                                   "serve_verify"),
-                "decode_kernel='pallas'": (
-                    cfg.decode_kernel == "pallas",
-                    "Pallas paged-decode kernel over latent rows"),
                 f"quant={cfg.quant!r}": (
                     cfg.quant != "none",
                     "quantized weight store for a model served in its "
@@ -1274,9 +1271,19 @@ class ServingEngine:
         m.decode_steps.inc()
         m.decode_s.inc(now - t0)
         # what the step just read, on the span that closes after the read:
-        # the live rows' context lengths (fresh token included) and, of an
-        # expert model, its held experts' load
-        step_attrs = {"context_tokens": int(host_args[3][decode_rows].sum())}
+        # the live rows' context lengths (fresh token included), the blocks
+        # of the pool one layer's attention read for them (a latent cache
+        # is read to each slot's own length, the MHA gather takes every
+        # slot's whole table) and, of an expert model, its held experts'
+        # load
+        tables, context = host_args[2], host_args[3][decode_rows]
+        step_attrs = {
+            "context_tokens": int(context.sum()),
+            "window_blocks": (
+                int((-(-context // self.cfg.kv_block_size)).sum())
+                if self._spec.kind == "latent" else tables.size
+            ),
+        }
         if self._experts_held:
             total = int(held_counts.sum())
             imbalance = (

@@ -140,9 +140,11 @@ class PagedKVCache:
     (what the tiled layout pads it to anyway), and not 512 + 64 as two,
     whose second plane would be copied, nor 512 + 128, which costs a second
     gather and scatter a layer for the same bytes.  Layer
-    outermost, block next: a request's window is one gather of whole
-    blocks at ``(layer, block_table)``, a write one scatter of rows at
-    ``(layer, block, offset)``.
+    outermost, block next: a write is one scatter of rows at ``(layer,
+    block, offset)``; the MHA window is one gather of whole blocks at
+    ``(layer, block_table)``, and the latent plane's decode kernel fetches
+    a slot's live blocks at ``(layer, block)``, one ``[block_size,
+    width]`` tile-aligned page each.
 
     ``planes`` is the model's ``CacheSpec.planes``: ``(("k", 768), ("v",
     768))`` for 12 heads of 64, ``(("latent", 640),)`` for the latent row.
@@ -469,10 +471,12 @@ class LatentAttentionHook:
     idle slots land in the scratch block), then attends: in ``"prefill"``
     mode the expanded form, causal over the padded prompt, through
     ``attention_impl`` (``"flash"`` or ``"dense"``); in ``"decode"`` mode
-    the absorbed form over the slot's gathered window
-    (``models/decoder.py`` holds both).  The window is ONE gather of whole
-    blocks at ``(layer, block_tables)`` out of the whole plane, as the MHA
-    pool's is.  The chunk and verify modes have no latent program yet.
+    the absorbed form (``models/decoder.py`` holds both) through the
+    latent cache's one decode path, the Pallas kernel
+    ``latent_paged_attention``: it reads each slot's live blocks of the
+    whole plane in place, to the slot's own length, and gathers no window
+    (interpreted off the TPU).  The chunk and verify modes have no latent
+    program yet.
 
     Args as :class:`PagedAttentionHook`'s, with ``pages`` the pool's one
     plane ``[n_layers, NB, BS, >= C + dr]`` (the row's values, then zeros up
@@ -501,7 +505,7 @@ class LatentAttentionHook:
 
     def latent_attention(self, layer: int):
         from stoke_tpu.models.decoder import (
-            absorbed_attention,
+            absorbed_paged_attention,
             expanded_attention,
         )
 
@@ -528,13 +532,9 @@ class LatentAttentionHook:
                     q_nope, q_rope, c, k_rope, w_kvb, scale, key_valid,
                     self.attention_impl,
                 )
-            window = self.latent_pages.at[layer, self.block_tables].get(
-                mode="promise_in_bounds"
-            )  # [B, MB, BS, row]: whole blocks, merged below
-            window = window.reshape(B, -1, window.shape[-1])
-            return absorbed_attention(
-                q_nope, q_rope, window,
-                self.lengths.astype(jnp.int32)[:, None] - 1, w_kvb, scale,
+            return absorbed_paged_attention(
+                q_nope, q_rope, self.latent_pages, layer, self.block_tables,
+                self.lengths, w_kvb, scale,
             )
 
         return attend

@@ -5,11 +5,13 @@ keeps for the family (``benchmark/lib/families/axk1.py``: ``jax.numpy`` at
 
 (a) the decoder's full forward against the reference; (b) prefill then decode
 through the latent paged cache and ``ServingEngine`` against the reference's
-full forward; (c) absorbed decode equals expanded attention; (d) the routed
-parts of all shares plus the shared expert once add up to the uncut layer;
-(e) the router against a ``numpy`` top-k with groups and a tie; (f) the pool's
-bytes a token and the allocator's accounting; (g) the options without a
-latent program raise.  (h), the cell's rehearsal, is
+full forward, and what ``serve/commit`` says the step read; (c) absorbed
+decode equals expanded attention, and the paged kernel over the plane equals
+absorbed decode over the gathered window; (d) the routed parts of all shares
+plus the shared expert once add up to the uncut layer; (e) the router against
+a ``numpy`` top-k with groups and a tie; (f) the pool's bytes a token and the
+allocator's accounting; (g) the options without a latent program raise.  (h),
+the cell's rehearsal, is
 ``tests/benchmark/test_benchmark_harness.py::test_cell_rehearsal``.
 """
 
@@ -33,6 +35,7 @@ from stoke_tpu.models.decoder import (  # noqa: E402
     Decoder,
     DecoderConfig,
     absorbed_attention,
+    absorbed_paged_attention,
     expanded_attention,
 )
 from stoke_tpu.models.moe import ExpertShareFFN, group_limited_topk  # noqa: E402
@@ -190,6 +193,47 @@ def test_decode_program_hands_back_the_held_experts_counts(tiny):
     assert 0 <= counts.sum() <= 8
 
 
+@pytest.mark.parametrize("family", ["latent", "mha"])
+def test_commit_span_says_what_the_decode_step_read(tiny, monkeypatch, family):
+    """``serve/commit`` carries the decode rows' ``context_tokens`` and the
+    ``window_blocks`` one layer's attention read for them: a latent cache is
+    read to each slot's own length, the MHA gather takes every slot's whole
+    table."""
+    import contextlib
+
+    from stoke_tpu.telemetry import tracing
+
+    seen = []
+
+    def fake_xprof_span(name, **stats):
+        seen.append((name, stats))
+        return contextlib.nullcontext()
+
+    monkeypatch.setattr(tracing, "xprof_span", fake_xprof_span)
+    if family == "latent":
+        model, params = tiny
+    else:
+        model = GPT(vocab_size=64, size_name="tiny", max_len=32)
+        params = model.init(jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))["params"]
+    eng = ServingEngine(model, params, ServeConfig(
+        max_seqs=3, kv_block_size=BLOCK, max_seq_len=32,
+        prefill_pad_multiple=BUCKET))
+    eng.submit(np.arange(9, dtype=np.int32), 4)
+    eng.submit(np.arange(17, dtype=np.int32), 4)
+    while not any(name == "serve/commit" and stats["context_tokens"] == 30
+                  for name, stats in seen):
+        assert eng.scheduler.has_work
+        eng.step()
+    commits = [stats for name, stats in seen if name == "serve/commit"]
+    # both decoding, one token each behind them: 9 + 1 + 1 and 17 + 1 + 1
+    # cached positions, in 2 and 3 blocks of 8; three slots of four blocks
+    assert commits[-1]["context_tokens"] == 11 + 19
+    assert commits[-1]["window_blocks"] == (2 + 3 if family == "latent"
+                                            else 3 * 4)
+    assert all(c["window_blocks"] > 0 for c in commits)
+
+
 # ------------------------------- (c) -------------------------------------- #
 
 
@@ -214,6 +258,99 @@ def test_absorbed_decode_equals_expanded_attention():
                                jnp.ones((B, L), bool), "flash")
     np.testing.assert_allclose(np.asarray(flash), np.asarray(want),
                                atol=2e-5, rtol=0)
+
+
+# the cell's page: 16 rows of 640 lanes (512 latent + 64 key + 64 zeros).  A
+# float32 page is 40 KB and a bfloat16 one 20 KB, so the kernel takes 16 and
+# 32 pages a step and a table of 40 blocks is walked in 3 and 2 steps
+PAGED = dict(H=4, dn=16, dr=64, dv=16, C=512, row=640, BS=16, MB=40, layers=3)
+FULL = PAGED["MB"] * PAGED["BS"]
+
+
+RAGGED = [5, FULL, 37, 264, 1, 530]
+
+
+@pytest.mark.parametrize("lens,plane_dtype,q_dtype,layer,shuffled", [
+    pytest.param([1], "float32", "float32", 0, True, id="length-1"),
+    pytest.param([15], "float32", "float32", 0, True, id="length-BS-1"),
+    pytest.param([16], "float32", "float32", 0, True, id="length-BS"),
+    pytest.param([17], "float32", "float32", 0, True, id="length-BS+1"),
+    pytest.param([FULL], "float32", "float32", 0, True, id="full-table"),
+    pytest.param(RAGGED, "float32", "float32", 0, True, id="ragged"),
+    pytest.param([0, 40, 0], "float32", "float32", 0, True, id="idle-slots"),
+    pytest.param([33, 300, 7], "float32", "float32", 0, False,
+                 id="pool-in-order"),
+    pytest.param([33, 300, 7], "float32", "float32", 2, True, id="layer-2"),
+    pytest.param(RAGGED, "bfloat16", "bfloat16", 1, True,
+                 id="ragged-bfloat16"),
+    pytest.param([16, 529, 0], "bfloat16", "bfloat16", 0, True,
+                 id="idle-bfloat16"),
+    pytest.param([600, 17], "bfloat16", "float32", 0, True,
+                 id="bfloat16-plane-float32-queries"),
+])
+def test_paged_kernel_equals_absorbed_attention_over_the_window(
+        lens, plane_dtype, q_dtype, layer, shuffled):
+    """``latent_paged_attention`` (interpreted) under
+    ``absorbed_paged_attention`` against ``absorbed_attention`` over each
+    slot's gathered window.  A slot owns the blocks its budget needs (its
+    length and 40 positions more, as the scheduler allocates ahead), holding
+    rows a step must not read past the length; the table's other entries
+    point at the scratch block."""
+    P = PAGED
+    rng = np.random.default_rng(len(lens) * 1000 + sum(lens))
+    q_dtype = jnp.dtype(q_dtype)
+    B = len(lens)
+    NB = 1 + B * P["MB"]
+    plane = jnp.asarray(
+        rng.standard_normal((P["layers"], NB, P["BS"], P["row"])) * 0.5,
+        plane_dtype).at[..., P["C"] + P["dr"]:].set(0)
+    ids = rng.permutation(np.arange(1, NB)) if shuffled else np.arange(1, NB)
+    tables = np.zeros((B, P["MB"]), np.int32)
+    for b, n in enumerate(lens):
+        owned = min(P["MB"], -(-(n + 40) // P["BS"])) if n else 0
+        tables[b, :owned] = ids[b * P["MB"]:b * P["MB"] + owned]
+    f = lambda *shape: jnp.asarray(rng.standard_normal(shape), q_dtype)  # noqa: E731
+    q_nope, q_rope = f(B, 1, P["H"], P["dn"]), f(B, 1, P["H"], P["dr"])
+    w_kvb = f(P["C"], P["H"], P["dn"] + P["dv"]) / 20
+    scale = 0.13
+    lens = jnp.asarray(lens, jnp.int32)
+    got = absorbed_paged_attention(
+        q_nope, q_rope, plane, layer, jnp.asarray(tables), lens, w_kvb, scale)
+    window = plane[layer][tables].reshape(B, FULL, P["row"])
+    want = absorbed_attention(
+        q_nope, q_rope, window, lens[:, None] - 1, w_kvb, scale)
+    assert got.shape == want.shape == (B, 1, P["H"], P["dv"])
+    assert got.dtype == q_dtype
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    idle = np.asarray(lens) == 0
+    # an idle slot reads nothing and returns zeros (the reference's mean
+    # over a window with every position masked is what callers discard)
+    assert not got[idle].any()
+    # bfloat16: the probabilities are rounded before and the outputs after
+    # the product, once each as in the reference, at other partial sums
+    atol = 2e-5 if q_dtype == jnp.float32 else 2.0 ** -7 * np.abs(want).max()
+    np.testing.assert_allclose(got[~idle], want[~idle], atol=atol, rtol=0)
+
+
+def test_latent_decode_program_holds_no_window(tiny):
+    """The latent engine's decode program, as lowered: no array of the
+    shape of the full-table window (every slot's whole table gathered out
+    of the plane), merged or not."""
+    model, params = tiny
+    eng = ServingEngine(model, params, ServeConfig(
+        max_seqs=3, kv_block_size=BLOCK, max_seq_len=40,
+        prefill_pad_multiple=BUCKET))
+    (plane,) = eng.cache.pages
+    row = plane.shape[-1]
+    B, MB = eng.scheduler.decode_batch()[2].shape
+    assert (B, MB) == (3, 5)
+    text = eng._decode_jit.lower(
+        params, plane, *eng.scheduler.decode_batch()).as_text()
+    for shape in ((B, MB * BLOCK, row), (B * MB, BLOCK, row),
+                  (B, MB, BLOCK, row)):
+        assert "tensor<" + "x".join(map(str, shape)) + "x" not in text, shape
+    # the same text does hold the plane, so the pattern is the text's own
+    assert "tensor<" + "x".join(map(str, plane.shape)) + "x" in text
 
 
 # ------------------------------- (d) -------------------------------------- #
@@ -345,7 +482,6 @@ def test_gpt_describes_its_cache_as_two_planes():
     ({"sampling": True}, "sampling"),
     ({"prefill_chunk_tokens": 16}, "serve_prefill_chunk"),
     ({"sampling": True, "speculative_k": 2}, "sampling"),
-    ({"decode_kernel": "pallas"}, "Pallas"),
     ({"quant": "int8"}, "quantized"),
     ({"quant": "bf16"}, "quantized"),
 ])
