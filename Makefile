@@ -7,7 +7,7 @@
 CPU_ENV = env JAX_PLATFORMS=cpu
 MESH_ENV = $(CPU_ENV) XLA_FLAGS=--xla_force_host_platform_device_count=8
 
-.PHONY: test test-full test-fast test-telemetry test-collectives test-health test-attribution test-fleet test-autotune test-resilience test-zero test-serving test-serve-cost test-tracing test-numerics test-elastic test-analysis test-memory test-opsplane lint autotune-smoke dryrun bench-smoke telemetry-smoke serve-smoke chip-smoke
+.PHONY: test test-full test-fast test-telemetry test-collectives test-health test-attribution test-fleet test-resilience test-zero test-serving test-serve-cost test-tracing test-numerics test-elastic test-analysis test-memory test-opsplane lint dryrun telemetry-smoke serve-smoke chip-smoke
 
 lint:            ## static analysis (ISSUE 15): invariant linter (jax-free), program auditor over the lowered step/serve programs, + generated-api drift check; CI runs this before pytest
 	python scripts/stoke_lint.py
@@ -41,9 +41,6 @@ test-attribution: ## step-time attribution tests only (CostCards/MFU/goodput/aut
 test-fleet:      ## fleet-observability tests only (skew aggregation/stragglers/barrier attribution)
 	$(MESH_ENV) python -m pytest tests/ -x -q -m fleet
 
-test-autotune:   ## autotuner + compile-cache tests only (search/pruning/ledger/warm starts)
-	$(MESH_ENV) python -m pytest tests/ -x -q -m autotune
-
 test-resilience: ## pod-scale resilience tests only (preemption save/resume/quarantine/chaos/supervisor)
 	$(MESH_ENV) python -m pytest tests/ -x -q -m resilience
 
@@ -74,15 +71,8 @@ test-memory:     ## HBM-capacity-observatory tests only (ledger recombination/OO
 test-opsplane:   ## live-ops-plane tests only (default-OFF contract/endpoint schemas/healthz flip/capture budget)
 	$(MESH_ENV) python -m pytest tests/ -x -q -m opsplane
 
-serve-smoke:     ## CPU-safe serve smoke: traced chunked-prefill + top-p request end-to-end, then the Poisson trace arm
+serve-smoke:     ## CPU-safe serve smoke: traced chunked-prefill + top-p request end-to-end
 	$(MESH_ENV) python scripts/telemetry_smoke.py --serve-only
-	$(CPU_ENV) python bench.py --preset tiny --serve
-
-autotune-smoke:  ## CPU-safe autotune sweep smoke (>= 4 subprocess trials)
-	$(CPU_ENV) python scripts/autotune.py --smoke --no-persist
-
-bench-smoke:     ## CPU-safe bench smoke
-	$(CPU_ENV) python bench.py --preset tiny
 
 telemetry-smoke: ## one JSONL-emitting CPU train step through the full telemetry pipeline
 	$(MESH_ENV) python scripts/telemetry_smoke.py

@@ -14,8 +14,8 @@ Hermetic by default — simulated 8-device CPU mesh, tiny shapes:
         python examples/pipeline_lm/train.py
 
 On a TPU slice, drop the env overrides and scale --batch/--seq-len/--size.
-Schedule characterization numbers (bubble fraction vs microbatches/rounds):
-docs/sharding.md, measured by scripts/bench_pipeline.py.
+Schedule characterization (bubble fraction vs microbatches/rounds):
+docs/sharding.md.
 """
 
 from __future__ import annotations

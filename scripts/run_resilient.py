@@ -29,8 +29,7 @@ The worker is expected to call ``Stoke.resume()`` at startup (or
 ``maybe_resume``) so a restart continues from the emergency checkpoint
 instead of step 0 — see docs/multihost.md "Surviving preemption".
 
-Like ``scripts/_supervise.py`` and ``scripts/autotune.py``, this process
-NEVER imports jax (the chip belongs to one process — the worker):
+This process NEVER imports jax (the chip belongs to one process — the worker):
 the jax-free resilience primitives are loaded from
 ``stoke_tpu/resilience.py`` by FILE, bypassing the package ``__init__``
 whose facade import would pull jax in.
@@ -54,11 +53,20 @@ _RESILIENCE_PY = os.path.join(
     os.path.dirname(_HERE), "stoke_tpu", "resilience.py"
 )
 
-# the recorder handshake (BUNDLE_FILE_ENV + bundle-file reader) lives in the
-# sibling jax-free supervisor module — one definition, not three
-if _HERE not in sys.path:
-    sys.path.insert(0, _HERE)
-from _supervise import BUNDLE_FILE_ENV, _read_bundles  # noqa: E402
+#: env var the flight recorder appends bundle paths to (kept in sync with
+#: stoke_tpu/telemetry/recorder.py BUNDLE_FILE_ENV, which this jax-free
+#: process cannot import)
+BUNDLE_FILE_ENV = "STOKE_HEALTH_BUNDLE_FILE"
+
+
+def _read_bundles(path: str) -> list[str]:
+    """Bundle paths the worker's flight recorder reported (empty when no
+    bundle was written or the handshake file is unreadable)."""
+    try:
+        with open(path) as f:
+            return [ln.strip() for ln in f if ln.strip()]
+    except OSError:
+        return []
 
 
 def load_resilience():

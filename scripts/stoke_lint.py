@@ -22,7 +22,7 @@ Usage (CI runs the default mode via ``make lint``):
 
 Exit codes: 0 clean, 1 findings, 2 usage/internal error.
 
-Like ``scripts/autotune.py`` / ``scripts/run_resilient.py``, this
+Like ``scripts/run_resilient.py``, this
 process NEVER imports jax (a parent that touches JAX holds the chip its
 worker needs — and CI lint must not depend on a backend at all): the
 linter module is loaded from ``stoke_tpu/analysis/invariants.py`` by
@@ -59,7 +59,7 @@ def _load_invariants(repo_root: str):
     )
     mod = importlib.util.module_from_spec(spec)
     # dataclass field-type resolution looks the module up in sys.modules
-    # — register before exec (the scripts/autotune.py discipline)
+    # — register before exec
     sys.modules[spec.name] = mod
     spec.loader.exec_module(mod)
     return mod

@@ -31,7 +31,7 @@ checks over the source tree:
   not know are exactly how a dashboard breaks at 3am).
 - **Banned APIs** (``banned-jax-import`` / ``banned-device-get``):
   module-scope ``jax``/``jaxlib`` imports in the jax-free modules (the
-  supervisor/autotune/lint drivers: the chip belongs to one process, so
+  supervisor/lint drivers: the chip belongs to one process, so
   a parent that starts chip-owning children stays off JAX — including
   this linter's own CLI), and
   ``device_get`` anywhere in the engine/serving hot paths (the
@@ -42,8 +42,8 @@ checks over the source tree:
 Deliberately **jax-free and AST-based** (stdlib only: ``ast``, ``json``,
 ``os``, ``dataclasses``) so ``scripts/stoke_lint.py`` can load this file
 directly (by FILE, bypassing the package ``__init__`` whose facade
-import would pull jax in — the ``scripts/autotune.py`` discipline) and
-run in CI before any backend exists.  The jax-dependent half — the
+import would pull jax in — as ``scripts/run_resilient.py`` loads
+``resilience.py``) and run in CI before any backend exists.  The jax-dependent half — the
 program auditor over lowered jaxpr/HLO step programs — lives in
 :mod:`stoke_tpu.analysis.program` and shares this module's
 :class:`Finding` type.
@@ -75,11 +75,9 @@ EVENTS_SCHEMA_PATH = "stoke_tpu/telemetry/events.py"
 #: touched JAX would hold the chip its worker needs (function-local
 #: imports are fine — resilience.py's contract)
 JAX_FREE_MODULES: Tuple[str, ...] = (
-    "stoke_tpu/autotune.py",
     "stoke_tpu/resilience.py",
     "stoke_tpu/analysis/invariants.py",  # the CLI loads THIS in-process
     "scripts/run_resilient.py",
-    "scripts/_supervise.py",
     "scripts/stoke_lint.py",
 )
 
@@ -699,8 +697,7 @@ def check_banned_apis(
                             remedy=(
                                 "move the import inside the function "
                                 "that needs it, or run the jax-"
-                                "dependent work in a subprocess "
-                                "(the scripts/autotune.py discipline)"
+                                "dependent work in a subprocess"
                             ),
                         )
                     )

@@ -193,8 +193,8 @@ def persistent_cache_dir() -> str:
     """THE rule for where JAX's persistent compilation cache lives:
     ``JAX_COMPILATION_CACHE_DIR`` when the environment sets it (jax reads
     it itself, and this package then sets no other), else
-    :data:`DEFAULT_CACHE_DIR`.  ``chip_smoke.py``, ``bench.py`` and
-    ``CompileConfig``'s default all resolve through here."""
+    :data:`DEFAULT_CACHE_DIR`.  ``chip_smoke.py`` and
+    ``CompileConfig``'s default both resolve through here."""
     return os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_CACHE_DIR
 
 
@@ -552,7 +552,7 @@ class CompileCache:
 
     def stats(self) -> Dict[str, Any]:
         """Run-level cache accounting (also the ``Stoke.compile_cache``
-        surface the bench ``--tuned`` arm records)."""
+        surface)."""
         return {
             "hits": self.hits,
             "misses": self.misses,

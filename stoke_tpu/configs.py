@@ -782,7 +782,7 @@ class HealthConfig:
        ``watchdog_timeout_s`` (the wedged-collective case),
        dumping all-thread stacks + the bundle and — with
        ``watchdog_kill=True`` — exiting with a distinct code the
-       ``scripts/_supervise.py`` runner recognizes.
+       ``scripts/run_resilient.py`` supervisor restarts.
 
     Attributes:
         sentinels: compile the on-device diagnostics vector into every
@@ -885,8 +885,7 @@ class AttributionConfig:
     Attributes:
         peak_tflops: the chip's peak TFLOP/s for the active compute
             dtype — MFU's denominator.  Must be > 0 (status-validated);
-            measure it with ``scripts/flops_probe.py``'s matmul-peak
-            probe or use the datasheet number (v5e bf16 dense: 197).
+            the datasheet number (v5e bf16 dense: 197).
         peak_hbm_gbps: HBM bandwidth peak (GB/s) for the
             memory-roofline bound and the ``hbm_bw_util`` gauge; 0
             disables the memory leg (compute-only roofline).
@@ -1163,7 +1162,7 @@ class OpsPlaneConfig:
     Attributes:
         port: base TCP port; rank ``r`` binds ``port + r`` so colocated
             multihost ranks never collide.  ``0`` binds an ephemeral
-            port (tests/benches; ``OpsPlane.port`` reports the bound
+            port (tests; ``OpsPlane.port`` reports the bound
             one).  Status-validated to 0..65535.
         host: bind address — loopback by default so enabling the plane
             never exposes a run to the network without an explicit
@@ -1443,11 +1442,6 @@ class ServeConfig:
             the pallas INTERPRETER (the CPU parity mode tests pin against
             the reference); a real serve config declaring ``device='cpu'``
             is a status error instead.
-        decode_pages_per_block: the pallas decode kernel's block knob
-            (KV pages fetched per kernel step).  ``None`` = kernel
-            default; it lives in the autotune catalog
-            (``decode_pages_per_block``) for the ``--workload serve_decode``
-            sweep.
         prefill_chunk_tokens: chunked prefill (ISSUE 13) — prompts longer
             than this prefill in fixed chunks of this many tokens,
             interleaved one chunk per engine iteration with decode steps,
@@ -1510,11 +1504,6 @@ class ServeConfig:
             ``serving/speculative.py``).  Only read when
             ``speculative_k`` is set — non-default values without it are
             a status error, never silently ignored.
-        verify_pages_per_block: the pallas verify kernel's block knob
-            (autotune catalog entry ``verify_pages_per_block`` under the
-            ``serve_decode`` sweep).  Only read when ``speculative_k``
-            is set AND ``decode_kernel="pallas"``; setting it outside
-            that is a status error.
         cost_cards: serve roofline observatory (ISSUE 18) — attach one
             XLA cost analysis (FLOPs, bytes accessed, peak-HBM where
             available) to every serve program at the dispatch funnel,
@@ -1537,7 +1526,6 @@ class ServeConfig:
     prefill_pad_multiple: int = 64
     attention: str = "dense"
     decode_kernel: str = "reference"
-    decode_pages_per_block: Optional[int] = None
     prefill_chunk_tokens: Optional[int] = None
     sampling: bool = False
     temperature: float = 0.0
@@ -1556,7 +1544,6 @@ class ServeConfig:
     speculative_k: Optional[int] = None
     speculative_ngram_max: int = 3
     speculative_ngram_min: int = 1
-    verify_pages_per_block: Optional[int] = None
     cost_cards: bool = False
 
 

@@ -31,7 +31,7 @@ from typing import Any, Callable, Iterator, List, Optional, Sequence
 
 import numpy as np
 
-#: one-time flag for the threaded-fallback semantics warning (ADVICE r4)
+#: one-time flag for the threaded-fallback semantics warning
 _WARNED_THREADED = False
 
 
@@ -463,8 +463,8 @@ class _FallbackLoader:
     Supports batch_size/shuffle/sampler/drop_last/collate_fn, plus a
     thread-pool parallel path for ``num_workers > 0`` (the reference
     inherits torch's C++ multi-worker loader, SURVEY.md §2.6 #24; a
-    torch-free image previously had no parallel path for generic map-style
-    datasets — VERDICT r3 missing #3).
+    torch-free image otherwise has no parallel path for generic map-style
+    datasets).
 
     Threads, not processes: dataset ``__getitem__`` for real workloads is
     IO/decode/numpy-bound (all GIL-releasing), batches need no pickling,
@@ -535,7 +535,7 @@ class _FallbackLoader:
         # on the one shared dataset object (torch would fork per-worker
         # copies).  Surface the semantic change once so a dataset with
         # shared mutable state (e.g. a seeked file handle) isn't silently
-        # raced (ADVICE r4).
+        # raced.
         global _WARNED_THREADED
         if not _WARNED_THREADED:
             _WARNED_THREADED = True

@@ -1003,7 +1003,7 @@ class Stoke:
             self._token += 1
             # stash the CURRENT rng: loss() will consume exactly this key for
             # the fused step, so a later .value read reproduces the same
-            # dropout masks even after self._rng has advanced (ADVICE r1)
+            # dropout masks even after self._rng has advanced
             self._stashed_model_call = (
                 placed_args, placed_kwargs, self._token, self._rng
             )
@@ -1295,7 +1295,7 @@ class Stoke:
             from stoke_tpu.utils.tb_writer import TBEventWriter
 
             # native event writer (utils/tb_writer.py) — same file format,
-            # no torch import on the metrics path (VERDICT r2 weak #7)
+            # no torch import on the metrics path
             self._tb_writer_obj = TBEventWriter(
                 os.path.join(cfg.output_path, cfg.job_name)
             )
@@ -2710,7 +2710,7 @@ class Stoke:
         self._backward_steps += n * k
         # EMA per optimizer step: ONE device reduction ([n, k, ...] ->
         # [n, ...]) and ONE host transfer for the whole segment, then a pure
-        # host loop — not n per-step device dispatches (VERDICT r2 weak #8)
+        # host loop — not n per-step device dispatches
         step_means = jax.device_get(
             jax.tree_util.tree_map(lambda r: r.mean(axis=1), reports)
         )
@@ -2894,7 +2894,7 @@ class Stoke:
                 multihost_utils.sync_global_devices("stoke_barrier")
 
     def block_until_ready(self) -> None:
-        """Wait for all in-flight device work (bench/test helper)."""
+        """Wait for all in-flight device work (a fence for timing and tests)."""
         jax.block_until_ready(
             (self._variables, self._opt_state, self._grad_buf)
         )
@@ -2994,7 +2994,7 @@ class Stoke:
         one fused optimizer step at these batch shapes: FLOPs, bytes
         accessed, and (when an ``AttributionConfig`` supplies peaks) the
         roofline-optimal step time.  The same cost-analysis funnel the
-        live attribution gauges and ``scripts/flops_probe.py`` use.
+        live attribution gauges use.
         Returns None when the backend reports no cost analysis."""
         if not isinstance(model_args, tuple):
             model_args = (model_args,)

@@ -613,7 +613,7 @@ def wait_for_saves() -> None:
 
     Raises on background-save failure (disk full, serialization error, ...)
     rather than silently dropping it — a checkpoint that was never written
-    must not look saved (ADVICE r1: io_ops medium).  EVERY failed tag dir
+    must not look saved.  EVERY failed tag dir
     is named in the message (ISSUE 7 satellite: an operator deciding which
     checkpoints are trustworthy needs the full casualty list, not the first
     failure with "+2 more"); the first underlying exception chains as the

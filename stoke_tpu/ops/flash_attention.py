@@ -40,7 +40,7 @@ from jax.sharding import PartitionSpec as P
 
 #: block-size candidates, best first — on v5e the 512x512 blocking is ~3.5x
 #: faster than 128x128 (K/V HBM refetch traffic scales as L^2·D/block_q;
-#: measured 2026-07-29 by scripts/flash_tpu_check.py under an older JAX;
+#: measured 2026-07-29 under an older JAX, PERF.md "Before the benchmark";
 #: not re-measured since — ROADMAP S6)
 _BLOCK_CANDIDATES = (512, 256, 128, 64)
 DEFAULT_BLOCK_Q = 512
@@ -581,9 +581,8 @@ def flash_attention(
 
 
 #: numerics-contract tolerances for validating the kernel against the dense
-#: reference at bf16 inputs (shared by tests/test_flash_tpu.py and
-#: scripts/flash_tpu_check.py so the pytest gate and the standalone on-TPU
-#: check can never disagree)
+#: reference at bf16 inputs (tests/test_flash_tpu.py holds the compiled
+#: kernel to them on the chip)
 FWD_ATOL_BF16 = 2e-2
 BWD_RTOL_BF16 = 0.05
 
@@ -732,19 +731,18 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, context_lens):
     )
 
 
-#: default number of KV pages fetched HBM→VMEM per kernel step (ISSUE 13)
-#: — bigger groups amortize per-step overhead; the knob lives in the
-#: autotune catalog (``stoke_tpu.autotune.KNOB_KIND``) so
-#: ``scripts/autotune.py --workload serve_decode`` can sweep it on-chip
-DEFAULT_DECODE_PAGES_PER_BLOCK = 8
+#: KV pages the decode and verify kernels fetch HBM→VMEM per kernel step
+#: when the caller names no step (serving never does) — bigger groups
+#: amortize per-step overhead
+_PAGES_PER_STEP = 8
 
 
-def _pick_divisor(requested: Optional[int], total: int, default: int) -> int:
-    """Largest divisor of ``total`` that is <= the requested (or default)
-    value — the pages-per-step knob must tile the block table exactly, and
-    a sweep-supplied candidate that does not divide degrades to the nearest
-    legal size instead of failing the trial."""
-    want = default if requested is None else int(requested)
+def _pick_divisor(requested: Optional[int], total: int) -> int:
+    """Largest divisor of ``total`` that is <= the requested value (default
+    :data:`_PAGES_PER_STEP`) — the pages fetched per step must tile the
+    block table exactly, so a step that does not divide the table width
+    degrades to the nearest legal size."""
+    want = _PAGES_PER_STEP if requested is None else int(requested)
     want = max(1, min(want, total))
     while total % want:
         want -= 1
@@ -821,7 +819,7 @@ def _paged_attention_kernel(tables_ref, qpos_ref, q_ref, *refs, heads, n_q,
 
 
 def _paged_attention_pallas(q, k_pages, v_pages, block_tables, positions,
-                            pages_per_block, default_pages, interpret):
+                            pages_per_block, interpret):
     """Shared driver of the two Pallas paged-attention entry points: query
     row ``s`` of request ``b`` attends window positions
     ``<= positions[b, s]``."""
@@ -845,7 +843,7 @@ def _paged_attention_pallas(q, k_pages, v_pages, block_tables, positions,
         interpret = jax.default_backend() != "tpu"
     NB, BS = int(k_pages.shape[0]), int(k_pages.shape[1])
     MB = int(block_tables.shape[1])
-    ppb = _pick_divisor(pages_per_block, MB, default_pages)
+    ppb = _pick_divisor(pages_per_block, MB)
     rows = H * S
     kernel = functools.partial(
         _paged_attention_kernel,
@@ -922,9 +920,8 @@ def paged_decode_attention_pallas(
 
     Args:
         pages_per_block: KV pages fetched per kernel step (clamped to the
-            largest divisor of the table width; default
-            ``DEFAULT_DECODE_PAGES_PER_BLOCK``).  The autotune catalog
-            knob ``decode_pages_per_block``.
+            largest divisor of the table width; ``None`` = up to
+            ``_PAGES_PER_STEP``, which is what serving runs).
         interpret: run through the pallas interpreter (``None`` =
             auto-select off-TPU, like :func:`flash_attention` — the CPU
             parity mode the tests pin against the reference).
@@ -938,7 +935,7 @@ def paged_decode_attention_pallas(
     positions = context_lens.astype(jnp.int32).reshape(-1, 1) - 1
     return _paged_attention_pallas(
         q, k_pages, v_pages, block_tables, positions, pages_per_block,
-        DEFAULT_DECODE_PAGES_PER_BLOCK, interpret,
+        interpret,
     )
 
 
@@ -971,13 +968,6 @@ def paged_prefill_chunk_attention(q, k_pages, v_pages, block_tables,
         q, _one_layer_pool(k_pages), _one_layer_pool(v_pages), 0,
         block_tables, positions,
     )
-
-
-#: default KV pages fetched per step by the speculative verify kernel
-#: (ISSUE 17) — its own autotune catalog knob (``verify_pages_per_block``)
-#: because verify amortizes each fetched page over k+1 query rows,
-#: shifting the fetch/compute balance away from the decode kernel's optimum
-DEFAULT_VERIFY_PAGES_PER_BLOCK = 8
 
 
 def paged_verify_attention(q, k_pages, v_pages, block_tables, positions):
@@ -1030,8 +1020,8 @@ def paged_verify_attention_pallas(
         block_tables: ``[B, MAX_BLOCKS] int32`` per-request block ids
             (unused entries at the reserved scratch block 0).
         positions: ``[B, S] int32`` per-query global positions.
-        pages_per_block: catalog knob ``verify_pages_per_block`` (clamped
-            to a divisor like the decode kernel's).
+        pages_per_block: KV pages fetched per kernel step (clamped to a
+            divisor like the decode kernel's; ``None`` = the same default).
         interpret: pallas interpreter toggle (``None`` = auto off-TPU).
     """
     if q.ndim != 4:
@@ -1043,7 +1033,7 @@ def paged_verify_attention_pallas(
         )
     return _paged_attention_pallas(
         q, k_pages, v_pages, block_tables, positions, pages_per_block,
-        DEFAULT_VERIFY_PAGES_PER_BLOCK, interpret,
+        interpret,
     )
 
 

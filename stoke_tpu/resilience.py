@@ -39,8 +39,7 @@ manual save; now the detect→save→restart→resume loop closes:
    end-to-end — a run killed at an arbitrary step resumes bit-identically.
 
 This module imports no jax at module scope: the restart supervisor
-(``scripts/run_resilient.py``) loads it by file, exactly like the
-``scripts/autotune.py`` parent loads the search module, so the supervising
+(``scripts/run_resilient.py``) loads it by file, so the supervising
 process never touches JAX (and so never holds the chip its worker needs).
 """
 
@@ -60,8 +59,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 #: exit code of a preempted worker that drained and saved cleanly — kept
 #: distinct from the health watchdog's 113 ("hung and self-terminated") so
-#: supervisors can tell a graceful drain from a wedge.  scripts/_supervise.py
-#: keeps a synced copy (it must never import jax-importing packages).
+#: supervisors can tell a graceful drain from a wedge.
 PREEMPTION_EXIT_CODE = 114
 
 #: the health hang-watchdog's exit code (stoke_tpu/telemetry/health.py
@@ -945,7 +943,7 @@ class ResilienceMonitor:
 
     def summary(self) -> Dict[str, Any]:
         """End-of-run resilience accounting (the ``Stoke.resilience_summary``
-        surface; the bench ``--resilience`` arm's column source)."""
+        surface)."""
         def _int(name):
             inst = self.registry.get(name)
             return 0 if inst is None else int(inst.value)

@@ -284,7 +284,7 @@ class ServingEngine:
         # per-layer dequant-error attribution (ISSUE 12): computed ONCE at
         # quantize time — which module int8 hurt most bounds the serving
         # quality story, so it rides the registry (numerics/* gauges), the
-        # engine surface (bench --serve quant_err columns), and
+        # engine surface, and
         # stats()["quant_errors"]
         self.quant_errors: Dict[str, Dict[str, float]] = {}
         self.quant_errors_by_group: Dict[str, Dict[str, float]] = {}
@@ -570,9 +570,7 @@ class ServingEngine:
             mode=mode, lengths=lengths,
             attention_impl=self.cfg.attention,
             decode_impl=self.cfg.decode_kernel,
-            decode_pages_per_block=self.cfg.decode_pages_per_block,
             decode_interpret=self._decode_interpret,
-            verify_pages_per_block=self.cfg.verify_pages_per_block,
         )
 
     def _prefill_fn(self, qparams, *args):

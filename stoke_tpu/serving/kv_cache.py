@@ -269,15 +269,11 @@ class PagedAttentionHook:
             ``"pallas"`` (the ISSUE 13 streaming kernel
             :func:`paged_decode_attention_pallas`, which takes one layer's
             pages reshaped to ``[NB, BS, H, D]``).
-        decode_pages_per_block: the pallas kernel's block knob
-            (``None`` = its default; autotune catalog entry).
         decode_interpret: run the pallas kernel through the interpreter
             (``None`` = auto off-TPU — the CPU parity mode).
-        verify_pages_per_block: the verify kernel's block knob
-            (``None`` = its default; autotune catalog entry
-            ``verify_pages_per_block``).
             ``decode_impl`` selects reference vs pallas for verify too —
-            both kernels share the streaming memory schedule.
+            both kernels share the streaming memory schedule and take
+            their pages-per-step from the table's width.
     """
 
     def __init__(
@@ -291,9 +287,7 @@ class PagedAttentionHook:
         lengths,
         attention_impl: str = "dense",
         decode_impl: str = "reference",
-        decode_pages_per_block: Optional[int] = None,
         decode_interpret: Optional[bool] = None,
-        verify_pages_per_block: Optional[int] = None,
     ):
         if mode not in ("prefill", "chunk", "decode", "verify"):
             raise ValueError(f"unknown PagedAttentionHook mode {mode!r}")
@@ -310,9 +304,7 @@ class PagedAttentionHook:
         self.lengths = lengths
         self.attention_impl = attention_impl
         self.decode_impl = decode_impl
-        self.decode_pages_per_block = decode_pages_per_block
         self.decode_interpret = decode_interpret
-        self.verify_pages_per_block = verify_pages_per_block
         self.block_size = int(k_pages.shape[2])
         # verify mode: per-layer (blocks, offs, old_k, old_v) snapshots
         # taken before each write, consumed by rollback()
@@ -427,12 +419,10 @@ class PagedAttentionHook:
                     if self.mode == "decode":
                         return paged_decode_attention_pallas(
                             q, k_l, v_l, self.block_tables, self.lengths,
-                            pages_per_block=self.decode_pages_per_block,
                             interpret=self.decode_interpret,
                         )
                     return paged_verify_attention_pallas(
                         q, k_l, v_l, self.block_tables, positions,
-                        pages_per_block=self.verify_pages_per_block,
                         interpret=self.decode_interpret,
                     )
                 return paged_pool_attention(

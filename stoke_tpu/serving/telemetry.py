@@ -196,8 +196,7 @@ class ServeMetrics:
         """Arm the speculative-decoding instruments (ISSUE 17) — called at
         engine construction when ``ServeConfig.speculative_k`` is set.
         ``accepted / drafted`` is the acceptance rate;
-        ``tokens_out / decode_steps`` the accepted-tokens-per-dispatch
-        the bench arm reports."""
+        ``tokens_out / decode_steps`` the accepted tokens per dispatch."""
         if self.spec_active:
             return
         self.spec_active = True
@@ -232,14 +231,6 @@ class ServeMetrics:
 
     # ------------------------------ feeds ------------------------------ #
 
-    def reset_latency_reservoirs(self) -> None:
-        """Drop the exact-percentile sample windows (the cumulative
-        registry histograms are untouched).  For benches that warm the
-        compiled programs first: p50/p99 should describe steady-state
-        latency, not the warm pass's compile-dominated first requests."""
-        self._ttft_samples = _Reservoir()
-        self._tpot_samples = _Reservoir()
-
     def observe_ttft(self, seconds: float) -> None:
         self.ttft.observe(seconds)
         self._ttft_samples.add(seconds)
@@ -250,7 +241,7 @@ class ServeMetrics:
 
     def latency_percentiles(self) -> Dict[str, Optional[float]]:
         """Exact order statistics of the trailing reservoirs — the public
-        accessor the engine summary and the bench arm read (the
+        accessor the engine summary reads (the
         reservoirs themselves are an implementation detail)."""
         return {
             "ttft_p50_s": self._ttft_samples.percentile(0.50),

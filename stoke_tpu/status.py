@@ -592,8 +592,8 @@ class StokeStatus:
             if cfg.peak_tflops <= 0:
                 return (
                     f"AttributionConfig.peak_tflops must be > 0 (MFU's "
-                    f"denominator — measure it with scripts/flops_probe.py "
-                    f"or use the datasheet number), got {cfg.peak_tflops}"
+                    f"denominator — use the datasheet number), got "
+                    f"{cfg.peak_tflops}"
                 )
             if cfg.peak_hbm_gbps < 0 or cfg.ici_gbps < 0:
                 return (
@@ -1115,23 +1115,6 @@ class StokeStatus:
                     "interpreter parity mode is for tests, via a "
                     "standalone ServingEngine)"
                 )
-            for field in ("decode_pages_per_block",):
-                v = getattr(cfg, field)
-                if v is not None and v < 1:
-                    return (
-                        f"ServeConfig.{field} must be >= 1 when set, "
-                        f"got {v}"
-                    )
-                if v is not None and cfg.decode_kernel != "pallas":
-                    # same contract as the sampling-knob rule below: a
-                    # knob the selected kernel never reads is rejected,
-                    # never silently ignored
-                    return (
-                        f"ServeConfig.{field}={v} set but decode_kernel="
-                        f"{cfg.decode_kernel!r} — only the pallas "
-                        f"streaming kernel reads the block knobs; set "
-                        f"decode_kernel='pallas' or drop the knob"
-                    )
             if cfg.prefill_chunk_tokens is not None:
                 c = cfg.prefill_chunk_tokens
                 if c < 1:
@@ -1292,29 +1275,6 @@ class StokeStatus:
                         "but speculative_k=None — the non-speculative "
                         "engine would silently ignore them; set "
                         "speculative_k or drop the knobs"
-                    )
-            for field in ("verify_pages_per_block",):
-                v = getattr(cfg, field)
-                if v is None:
-                    continue
-                if v < 1:
-                    return (
-                        f"ServeConfig.{field} must be >= 1 when set, "
-                        f"got {v}"
-                    )
-                if cfg.speculative_k is None:
-                    return (
-                        f"ServeConfig.{field}={v} set but "
-                        f"speculative_k=None — only the speculative "
-                        f"verify kernel reads the verify block knobs; "
-                        f"set speculative_k or drop the knob"
-                    )
-                if cfg.decode_kernel != "pallas":
-                    return (
-                        f"ServeConfig.{field}={v} set but decode_kernel="
-                        f"{cfg.decode_kernel!r} — the verify block knobs "
-                        f"feed the pallas verify kernel; set "
-                        f"decode_kernel='pallas' or drop the knob"
                     )
             # roofline observatory (ISSUE 18): the cost cards divide by
             # hardware peaks — both roofline legs need a ceiling, so an

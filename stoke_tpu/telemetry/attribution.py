@@ -83,8 +83,7 @@ def _cost_dict(obj) -> Optional[Dict[str, float]]:
 
 def cost_analysis_of(fn, *args, backend: Optional[str] = None):
     """XLA cost analysis of jitted ``fn`` at ``args``: the one shared
-    funnel behind CostCards, ``Stoke.estimate_step_flops`` and
-    ``scripts/flops_probe.py``.
+    funnel behind CostCards and ``Stoke.estimate_step_flops``.
 
     Prefers ``Lowered.cost_analysis()`` (no second compile); falls back
     to compiling when the lowering cannot answer.  Returns the raw cost
@@ -272,9 +271,8 @@ def roofline_summary(
     flops: Optional[float], step_seconds: float, peak_tflops: float
 ) -> Dict[str, Optional[float]]:
     """Achieved TFLOP/s + fraction-of-peak from a per-step FLOPs count
-    and a measured step time — the shared arithmetic behind the live MFU
-    gauge and ``scripts/flops_probe.py`` (which used to re-derive it
-    inline per arm)."""
+    and a measured step time — the arithmetic behind the live MFU
+    gauge."""
     if not flops or step_seconds <= 0:
         return {"achieved_tflops": None, "mfu": None}
     achieved = flops / step_seconds / 1e12

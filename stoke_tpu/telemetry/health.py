@@ -38,8 +38,8 @@ from typing import Any, Callable, Dict, List, Optional
 import numpy as np
 
 #: exit code of a watchdog-killed process — distinct from generic failures
-#: so supervisors (scripts/_supervise.py keeps a synced copy: it must not
-#: import jax) can report "hung and self-terminated" instead of "timed out"
+#: so supervisors (stoke_tpu/resilience.py keeps a synced copy: it must not
+#: import jax) can tell "hung and self-terminated" from a crash
 WATCHDOG_EXIT_CODE = 113
 
 #: sentinel vector layout: field name -> index.  The order is the wire

@@ -368,8 +368,7 @@ def max_quant_error(
     by_group: Dict[str, Dict[str, float]],
 ) -> Tuple[Optional[str], Optional[float]]:
     """``(group_name, rel_rms)`` of the worst-quantized module — the
-    layer that bounds int8 quality (the ``quant_err_layer`` /
-    ``quant_err_max`` bench columns)."""
+    layer that bounds int8 quality."""
     if not by_group:
         return None, None
     name = max(by_group, key=lambda k: by_group[k]["rel_rms"])

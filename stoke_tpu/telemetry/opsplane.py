@@ -39,8 +39,7 @@ Endpoints (all JSON unless noted):
 
 Multi-host: every rank binds ``cfg.port + process_index`` (loopback by
 default), so one host's ranks never collide and a fleet scraper can
-enumerate them; ``port=0`` binds an ephemeral port (tests, colocated
-benches) and :attr:`OpsPlane.port` reports the bound one.
+enumerate them; ``port=0`` binds an ephemeral port (tests) and :attr:`OpsPlane.port` reports the bound one.
 """
 
 from __future__ import annotations

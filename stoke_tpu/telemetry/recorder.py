@@ -6,7 +6,7 @@ A 3-day pod run that dies at step 40k must leave a usable corpse.  The
 anomaly firings in a host-side ring buffer (no IO on the hot path) and, on
 demand — anomaly ``dump`` action, uncaught step-path exception,
 SIGTERM/SIGUSR1, or watchdog trip — writes a **post-mortem bundle**
-directory containing everything a human (or the bench supervisor) needs to
+directory containing everything a human (or the restart supervisor) needs to
 triage without re-running:
 
     <bundle_dir>/postmortem-<utc-ts>-<reason>/
@@ -31,7 +31,7 @@ triage without re-running:
 Bundles are cheap (the ring is small) and atomic enough for crash paths:
 files are written directly into a uniquely named directory, so a partial
 bundle is visibly partial rather than corrupting a previous one.  When the
-``STOKE_HEALTH_BUNDLE_FILE`` env var is set (scripts/_supervise.py sets it
+``STOKE_HEALTH_BUNDLE_FILE`` env var is set (scripts/run_resilient.py sets it
 for supervised workers), every dump also appends the bundle path there so
 the supervisor can attach it to its ledger record.
 """

@@ -1,9 +1,9 @@
 """Native TensorBoard event writer — no torch, no tensorflow.
 
 The reference logs metrics only through DeepSpeed's tensorboard passthrough
-(reference configs.py:392-405); round 2 used ``torch.utils.tensorboard``,
-which drags the whole torch runtime in for what is a ~100-line file format
-(VERDICT r2 weak #7).  This writes the format directly:
+(reference configs.py:392-405); ``torch.utils.tensorboard`` would drag the
+whole torch runtime in for what is a ~100-line file format.  This writes
+the format directly:
 
 - **TFRecord framing**: ``[uint64 len][u32 masked_crc(len)][payload]
   [u32 masked_crc(payload)]`` per record, CRC32C (Castagnoli) with

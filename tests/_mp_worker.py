@@ -332,8 +332,9 @@ def main():
 
     elif SCENARIO == "fleet":
         # fleet observability (ISSUE 5 acceptance): 2 hosts, worker 1's
-        # loader sleeps per item -> its loader_wait skews high, worker 0
-        # waits at the per-step barrier for it.  Rank 0's JSONL must carry
+        # loader sleeps per item -> its loader_wait skews high, and it
+        # reaches the per-step barrier late, so worker 0 waits there for
+        # it.  Rank 0's JSONL must carry
         # the per-host fleet/* fields with the straggler verdict pointing
         # at host 1 (loader-classified), the barrier wait charged to host
         # 1, and the health registry must record EXACTLY ONE
@@ -344,7 +345,10 @@ def main():
         from stoke_tpu import FleetConfig, HealthConfig, TelemetryConfig
         from stoke_tpu.data import BucketedDistributedSampler
 
-        N_ROWS, BATCH_STEPS, SLEEP_S = 256, 8, 0.02
+        # twice the rows the 8 steps read: the loader fetches two batches
+        # ahead, so a dataset that ended with the run would leave the last
+        # two windows with no loader lag and noise would name the straggler
+        N_ROWS, BATCH_STEPS, SLEEP_S, LATE_S = 512, 8, 0.02, 0.1
 
         class _SleepyRows:
             """Per-item sleep models a slow input pipeline on ONE host."""
@@ -400,6 +404,11 @@ def main():
             _warnings.simplefilter("ignore")
             for x, y in loader:
                 s.train_step(x, (y,))
+                if PID == 1:
+                    # the step's collective has just aligned the hosts;
+                    # host work before the sync makes host 1 the last
+                    # arrival by far more than the scheduler's noise
+                    time.sleep(LATE_S)
                 s.barrier()  # per-step host coordination, the wait source
                 steps += 1
                 if steps >= BATCH_STEPS:

@@ -338,7 +338,7 @@ def test_cli_exit0_and_json():
 
 
 def test_cli_never_imports_jax(tmp_path):
-    """The probe from the autotune discipline: a poisoned jax package on
+    """A poisoned jax package on
     PYTHONPATH proves the lint CLI never imports it (the banned-API rule
     enforces the same thing statically; this enforces it dynamically)."""
     poison = tmp_path / "jax"
@@ -359,7 +359,7 @@ def test_cli_never_imports_jax(tmp_path):
 def test_cli_findings_exit1(tmp_path):
     """A doctored mini-tree (jax import in a jax-free module path) exits
     1 with the finding printed file:line + remedy."""
-    driver = tmp_path / "stoke_tpu" / "autotune.py"
+    driver = tmp_path / "stoke_tpu" / "resilience.py"
     driver.parent.mkdir(parents=True)
     driver.write_text("import jax\n")
     # satisfy the manifest-presence checks with empty-but-valid manifests
@@ -380,7 +380,7 @@ def test_cli_findings_exit1(tmp_path):
     )
     assert out.returncode == 1, out.stdout + out.stderr
     assert "banned-jax-import" in out.stdout
-    assert "stoke_tpu/autotune.py:1" in out.stdout
+    assert "stoke_tpu/resilience.py:1" in out.stdout
     assert "remedy" in out.stdout
 
 

@@ -33,8 +33,6 @@ from stoke_tpu.compile_cache import (
 )
 from stoke_tpu.telemetry import read_step_events
 
-pytestmark = pytest.mark.autotune
-
 IN, OUT = 8, 4
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 

@@ -8,10 +8,8 @@ module must be run explicitly, as the one chip-owning process:
 
     STOKE_TEST_TPU=1 python -m pytest tests/test_flash_tpu.py -q
 
-The standalone runner `scripts/flash_tpu_check.py` performs the flash checks
-plus a flash-vs-dense microbenchmark.  Both validate against the same
-`dense_reference` and tolerances (stoke_tpu/ops/flash_attention.py) so the
-gate and the check cannot diverge; the GPT-large-geometry cases at the
+The flash cases validate against `dense_reference` and the tolerances of
+stoke_tpu/ops/flash_attention.py; the GPT-large-geometry cases at the
 bottom are `chip_smoke.py`'s own kernel-leg checks.
 """
 
@@ -68,7 +66,7 @@ def test_flash_matches_dense_on_tpu(causal, masked):
 
 # ---- kernels added since round 2: first on-silicon validation ------------- #
 # (CPU-interpret equivalence is necessary, not sufficient: block-spec/VMEM
-# behavior differs on real Mosaic — VERDICT r4 item 3.)  On one chip the
+# behavior differs on real Mosaic.)  On one chip the
 # ring degenerates to a single hop; the composition under test is the
 # per-hop flash call + lse merge wiring, which is exactly what changed.
 

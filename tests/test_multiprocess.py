@@ -166,11 +166,14 @@ def test_composed_mesh_multiprocess(tmp_path):
 @pytest.mark.fleet
 def test_fleet_multiprocess(tmp_path):
     """Fleet observability across 2 real processes (ISSUE 5 acceptance):
-    worker 1's loader sleeps per item, so rank 0's JSONL must carry
-    per-host ``fleet/*`` fields naming host 1 the loader-classified
-    straggler, the per-step barrier wait must be charged to host 1 (the
-    last arrival), and the health registry must record exactly one
-    ``fleet_straggler`` anomaly."""
+    worker 1's loader sleeps per item and it reaches every barrier late,
+    so rank 0's JSONL must carry per-host ``fleet/*`` fields naming host 1
+    the loader-classified straggler, the per-step barrier wait must be
+    charged to host 1 (the last arrival), and the health registry must
+    record exactly one ``fleet_straggler`` anomaly.  Host 1 lags in every
+    window (0.16 s of loader, 0.1 s at the barrier, against milliseconds
+    of noise); the assertions still ask for a majority of the windows,
+    not all, because the other xdist workers share these cores."""
     run_workers("fleet", str(tmp_path))
     from stoke_tpu.telemetry.events import read_step_events
 
@@ -181,22 +184,18 @@ def test_fleet_multiprocess(tmp_path):
     # every exchanged window saw BOTH hosts' rows
     windows = [r for r in records if r.get("fleet/hosts") is not None]
     assert windows and all(r["fleet/hosts"] == 2 for r in windows)
-    # skip the warm-up window (compile noise); the steady-state windows
+    # skip the warm-up window (compile noise); most steady-state windows
     # must name host 1 the straggler with the lag classified as loader
-    steady = [w for w in windows[1:] if w["fleet/straggler_host"] is not None]
-    assert steady, f"no straggler windows in {len(windows)} windows"
-    assert all(w["fleet/straggler_host"] == 1 for w in steady)
-    assert any(w["fleet/skew_class"] == "loader" for w in steady)
-    assert all((w["fleet/lag_s"] or 0) > 0 for w in steady)
+    steady = windows[1:]
+    named = [w for w in steady if w["fleet/straggler_host"] == 1]
+    assert 2 * len(named) > len(steady), (len(named), len(steady))
+    assert any(w["fleet/skew_class"] == "loader" for w in named)
+    assert all((w["fleet/lag_s"] or 0) > 0 for w in named)
     # barrier-wait attribution: the wait is charged to the late host 1,
-    # not to host 0 who sat waiting
-    charged = [
-        w for w in windows[1:]
-        if w["fleet/barrier_charged_host"] is not None
-    ]
-    assert charged, "no window recorded barrier waits"
-    assert all(w["fleet/barrier_charged_host"] == 1 for w in charged)
-    assert any((w["fleet/barrier_wait_s"] or 0) > 0.005 for w in charged)
+    # not to host 0 who sat waiting for about LATE_S
+    charged = [w for w in steady if w["fleet/barrier_charged_host"] == 1]
+    assert 2 * len(charged) > len(steady), (len(charged), len(steady))
+    assert any((w["fleet/barrier_wait_s"] or 0) > 0.05 for w in charged)
     # exactly one fleet_straggler anomaly on every process's registry
     for pid in range(NPROC):
         with open(tmp_path / f"fleet_result_p{pid}.json") as f:

@@ -207,13 +207,15 @@ def test_run_resilient_budget_exhaustion():
 
 
 def test_supervise_exit_codes_in_sync():
-    """scripts/_supervise.py keeps jax-free copies of the exit codes; they
-    must never drift from the authority in stoke_tpu/resilience.py."""
-    import _supervise
+    """The jax-free supervisor side keeps copies of what the jax-importing
+    side defines (the watchdog's exit code in resilience.py, the recorder's
+    handshake variable in scripts/run_resilient.py); they must never drift.
+    The supervisor keeps no copy of the resumable codes: it takes them from
+    resilience.py, loaded by file."""
+    from stoke_tpu.telemetry import health, recorder
 
-    assert _supervise.PREEMPTION_EXIT_CODE == resilience.PREEMPTION_EXIT_CODE
-    assert (_supervise.HEALTH_WATCHDOG_EXIT_CODE
-            == resilience._WATCHDOG_EXIT_CODE)
+    assert resilience._WATCHDOG_EXIT_CODE == health.WATCHDOG_EXIT_CODE
+    assert run_resilient_mod.BUNDLE_FILE_ENV == recorder.BUNDLE_FILE_ENV
 
 
 def test_tag_regex_in_sync_with_io_ops():

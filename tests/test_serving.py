@@ -666,42 +666,54 @@ def test_gpt_decode_arg_guards():
 # --------------------------------------------------------------------------- #
 
 
-def _paged_pool(rng, B=3, H=4, D=16, BS=8, NB=17, MB=4):
-    """A block pool with ragged per-request tables: request 0 spans 3
-    blocks (ragged tail), 1 spans all 4, 2 holds a single token —
+def _paged_pool(rng, H=4, D=16, BS=8, MB=4):
+    """A block pool with ragged per-request tables ``MB`` blocks wide:
+    request 0 stops a ragged tail short of the last block (at the default
+    width: 3 blocks), 1 spans the whole table, 2 holds a single token —
     unused table entries follow the scratch-block-0 convention."""
+    B, NB = 3, 2 * MB + 9
     k_pages = rng.normal(size=(NB, BS, H, D)).astype(np.float32)
     v_pages = rng.normal(size=(NB, BS, H, D)).astype(np.float32)
+    ctx = np.array(
+        [(MB - 1) * BS - 5 if MB > 1 else BS - 3, MB * BS, 1], np.int32
+    )
     tables = np.full((B, MB), SCRATCH_BLOCK, np.int32)
-    tables[0, :3] = [1, 2, 3]
-    tables[1, :4] = [4, 5, 6, 7]
-    tables[2, :1] = [8]
-    ctx = np.array([19, 32, 1], np.int32)
+    first = 1
+    for b, n in enumerate(-(-ctx // BS)):
+        tables[b, :n] = np.arange(first, first + n)
+        first += n
     q = rng.normal(size=(B, H, 1, D)).astype(np.float32)
     return q, k_pages, v_pages, tables, ctx
 
 
-@pytest.mark.parametrize("pages_per_block", [1, 2, 4])
-def test_pallas_decode_matches_reference(pages_per_block, rng):
+@pytest.mark.parametrize(
+    "pages_per_block,MB,BS",
+    [(1, 4, 8), (2, 4, 8), (4, 4, 8)]
+    # the kernel's own step (what serving runs) at table widths a fixed
+    # step would not divide, would overshoot, or would tile many times
+    + [(None, mb, bs) for bs in (8, 16) for mb in (1, 3, 8, 64)],
+)
+def test_pallas_decode_matches_reference(pages_per_block, MB, BS, rng):
     """Acceptance: the streaming kernel matches the pinned jnp reference
-    within fp32 tolerance across ragged context_lens, multi-block tables,
-    and the scratch-block-0 inactive-slot convention — at every block
-    knob setting."""
+    and the independent ``[NB, BS, H, D]`` formulation within fp32
+    tolerance across ragged context_lens, multi-block tables, and the
+    scratch-block-0 inactive-slot convention — at every explicit step and
+    at the step the kernel picks from the table's width."""
     from stoke_tpu.ops.flash_attention import paged_decode_attention_pallas
 
-    q, k_pages, v_pages, tables, ctx = _paged_pool(rng)
-    ref = paged_decode_attention(
-        jnp.asarray(q), jnp.asarray(k_pages), jnp.asarray(v_pages),
-        jnp.asarray(tables), jnp.asarray(ctx),
+    q, k_pages, v_pages, tables, ctx = (
+        jnp.asarray(a) for a in _paged_pool(rng, MB=MB, BS=BS)
     )
     out = paged_decode_attention_pallas(
-        jnp.asarray(q), jnp.asarray(k_pages), jnp.asarray(v_pages),
-        jnp.asarray(tables), jnp.asarray(ctx),
-        pages_per_block=pages_per_block,
+        q, k_pages, v_pages, tables, ctx, pages_per_block=pages_per_block,
     )
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(ref), atol=1e-5, rtol=1e-5
-    )
+    for ref in (
+        paged_decode_attention(q, k_pages, v_pages, tables, ctx),
+        old_paged_attention(q, k_pages, v_pages, tables, ctx[:, None] - 1),
+    ):
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ref), atol=1e-5, rtol=1e-5
+        )
 
 
 def test_pallas_decode_bf16_pages_and_jit(rng):
@@ -766,31 +778,18 @@ def test_pallas_decode_validates_shapes():
         )
 
 
-def test_pallas_decode_knob_clamping():
-    """Sweep-supplied knobs that do not divide their dimension degrade to
-    the nearest legal divisor instead of failing the trial."""
+def test_pallas_decode_step_clamping():
+    """A step that does not divide the table's width degrades to the
+    nearest legal divisor; with none named the kernel takes up to 8."""
     from stoke_tpu.ops.flash_attention import _pick_divisor
 
-    assert _pick_divisor(None, 8, 8) == 8
-    assert _pick_divisor(3, 4, 8) == 2   # 3 does not divide 4
-    assert _pick_divisor(100, 6, 8) == 6  # clamped to the dimension
-    assert _pick_divisor(1, 7, 8) == 1
-
-
-def test_autotune_catalog_has_decode_knobs():
-    """The kernel's block knobs joined the autotune knob catalog (ISSUE
-    13): KNOB_KIND entries + TrialSpec identity."""
-    from stoke_tpu.autotune import KNOB_KIND, TrialSpec, knobs_for_bound
-
-    assert KNOB_KIND["decode_pages_per_block"] == "memory"
-    spec = TrialSpec(decode_pages_per_block=4)
-    assert "decode_pages_per_block=4" in spec.config_key()
-    # a memory-bound baseline sweeps them (decode IS memory-bound)
-    knobs = knobs_for_bound(
-        "memory", {"decode_pages_per_block": [1, 2], "xla_flags": [""]}
-    )
-    assert "decode_pages_per_block" in knobs
-    assert "xla_flags" not in knobs
+    assert _pick_divisor(None, 8) == 8
+    assert _pick_divisor(None, 64) == 8
+    assert _pick_divisor(None, 12) == 6
+    assert _pick_divisor(None, 3) == 3
+    assert _pick_divisor(3, 4) == 2   # 3 does not divide 4
+    assert _pick_divisor(100, 6) == 6  # clamped to the dimension
+    assert _pick_divisor(1, 7) == 1
 
 
 # --------------------------------------------------------------------------- #
@@ -1209,7 +1208,6 @@ def test_serve_event_fields_match_schema():
     "bad",
     [
         {"decode_kernel": "triton"},
-        {"decode_pages_per_block": 0},
         {"prefill_chunk_tokens": 0},
         {"prefill_chunk_tokens": 24},   # not a multiple of pad 16
         {"prefill_chunk_tokens": 128},  # exceeds max_seq_len 64
@@ -1221,9 +1219,6 @@ def test_serve_event_fields_match_schema():
         # rejected, never ignored
         {"temperature": 0.5},
         {"top_p": 0.9},
-        # decode block knobs only the pallas kernel reads: same rule
-        {"decode_pages_per_block": 4},
-        {"decode_pages_per_block": 2, "decode_kernel": "reference"},
     ],
 )
 def test_serve_fastpath_config_validation_rejects(bad):
@@ -1240,9 +1235,8 @@ def test_serve_fastpath_config_validation_accepts():
         prefill_pad_multiple=16, prefill_chunk_tokens=32,
         sampling=True, temperature=0.8, top_k=40, top_p=0.9,
         decode_kernel="pallas",
-        decode_pages_per_block=4,
     )
-    # pallas + block knobs need the TPU device (the cpu rule above)
+    # the pallas kernel needs the TPU device (the cpu rule above)
     st = StokeStatus(batch_size_per_device=1, device="tpu", configs=[cfg])
     assert st.serve_config.prefill_chunk_tokens == 32
 
