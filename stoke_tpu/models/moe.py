@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from stoke_tpu.ops.grouped_matmul import grouped_matmul, grouped_swiglu
+
 
 class MoEFFN(nn.Module):
     """Switch-routed expert FFN block (drop-in for a dense FFN).
@@ -204,11 +206,13 @@ class ExpertShareFFN(nn.Module):
     experts are all computed, none dropped: the ``N * top_k`` assignments
     are sorted by expert (absent ones last), the held experts' SwiGLU runs
     as three grouped products over the sorted rows
-    (``jax.lax.ragged_dot``; rows past the last group belong to no expert
-    and are never read back), and each token sums its own rows by the
-    inverse permutation, weighted.  The shared expert is computed for every
-    token.  Sows the per-held-expert assignment counts (``int32[count]``)
-    into the ``intermediates`` collection as ``expert_counts``.
+    (:mod:`stoke_tpu.ops.grouped_matmul`: gate and up in one kernel, down
+    in a second, each weight streamed once as stored; rows past the last
+    group belong to no expert, are not computed and are never read back),
+    and each token sums its own rows by the inverse permutation, weighted.
+    The shared expert is computed for every token.  Sows the
+    per-held-expert assignment counts (``int32[count]``) into the
+    ``intermediates`` collection as ``expert_counts``.
 
     Router logits, sigmoid, top-k and weights are float32 (the logits at
     ``Precision.HIGHEST``); expert products take ``dtype`` inputs and
@@ -270,14 +274,8 @@ class ExpertShareFFN(nn.Module):
                 "w_down", init, (count, self.ff, H), self.param_dtype
             )
 
-            def grouped(lhs, rhs):
-                return jax.lax.ragged_dot(
-                    lhs, rhs.astype(self.dtype), counts,
-                    preferred_element_type=jnp.float32,
-                )
-
-            mid = jax.nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up)
-            y = grouped(mid.astype(self.dtype), w_down)  # [N*k, H] float32
+            mid = grouped_swiglu(rows, w_gate, w_up, counts)
+            y = grouped_matmul(mid, w_down, counts)  # [N*k, H] float32
             y = y[jnp.argsort(order)].reshape(N, k, H)
             # a row of no group holds whatever the grouped product left
             y = jnp.where(is_held[:, :, None], y, 0.0)

@@ -68,6 +68,7 @@ import numpy as np
 
 from stoke_tpu.configs import ServeConfig
 from stoke_tpu.ops.flash_attention import partition_kernels_over
+from stoke_tpu.ops.grouped_matmul import expert_weight_passes
 from stoke_tpu.serving.kv_cache import (
     BlockAllocator,
     LatentAttentionHook,
@@ -540,10 +541,11 @@ class ServingEngine:
             )
         if not expert_counts:
             return out
-        # what the expert layers sowed: int32[held] a layer, summed
+        # what the expert layers sowed: int32[held] a layer
         logits, sown = out
-        counts = jax.tree_util.tree_leaves(sown["intermediates"])
-        return logits, sum(counts[1:], counts[0])
+        return logits, jnp.stack(
+            jax.tree_util.tree_leaves(sown["intermediates"])
+        )
 
     def _split(self, args: tuple):
         """A serve program's arguments after the weights: the pool's planes
@@ -595,8 +597,8 @@ class ServingEngine:
         """After the weights and the pool's planes: tokens/positions [B];
         block_tables [B, MB]; context_lens [B].
         Returns (next tokens [B], updated pages); a model with expert
-        layers hands back their assignment counts (int32[held], summed
-        over the layers) beside the tokens."""
+        layers hands back their assignment counts (int32[expert layers,
+        held]) beside the tokens."""
         pages, (tokens, positions, block_tables, context_lens) = (
             self._split(args)
         )
@@ -1273,7 +1275,7 @@ class ServingEngine:
         # of the pool one layer's attention read for them (a latent cache
         # is read to each slot's own length, the MHA gather takes every
         # slot's whole table) and, of an expert model, its held experts'
-        # load
+        # load and how often their products streamed the weights
         tables, context = host_args[2], host_args[3][decode_rows]
         step_attrs = {
             "context_tokens": int(context.sum()),
@@ -1283,16 +1285,20 @@ class ServingEngine:
             ),
         }
         if self._experts_held:
-            total = int(held_counts.sum())
+            per_expert = held_counts.sum(axis=0)  # over the expert layers
+            total = int(per_expert.sum())
             imbalance = (
-                float(held_counts.max()) * held_counts.size / total
+                float(per_expert.max()) * per_expert.size / total
                 if total else 0.0
             )
+            passes = expert_weight_passes(held_counts)
             m.expert_assignments.inc(total)
             m.expert_load_max_over_mean.set(imbalance)
+            m.expert_weight_passes.set(passes)
             step_attrs.update(
                 expert_assignments=total,
                 expert_load_max_over_mean=imbalance,
+                expert_weight_passes=passes,
             )
         with trace_span("serve/commit", track="serve", attrs=step_attrs):
             n_sampled = sum(

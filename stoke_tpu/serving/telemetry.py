@@ -172,13 +172,15 @@ class ServeMetrics:
         # holds routed experts, so no other engine registers the series
         self.expert_assignments = None
         self.expert_load_max_over_mean = None
+        self.expert_weight_passes = None
 
     def enable_experts(self) -> None:
         """Arm the expert-layer instruments, called at engine construction
         for a model whose ``experts_held`` is above 0:
         assignments the held experts computed, over all expert layers and
-        decode steps, and the last decode step's busiest held expert over
-        the mean (1.0: even)."""
+        decode steps, the last decode step's busiest held expert over
+        the mean (1.0: even), and the bytes of held weight its grouped
+        products read over the bytes held (1.0: each streamed once)."""
         if self.expert_assignments is not None:
             return
         self.expert_assignments = self.registry.counter(
@@ -190,6 +192,11 @@ class ServeMetrics:
             "serve/expert_load_max_over_mean",
             help="last decode step: busiest held expert's assignments over "
             "the mean of the held experts",
+        )
+        self.expert_weight_passes = self.registry.gauge(
+            "serve/expert_weight_passes",
+            help="last decode step: bytes of held expert weight the grouped "
+            "products read over the bytes they hold",
         )
 
     def enable_speculative(self) -> None:

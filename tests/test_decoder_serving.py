@@ -17,6 +17,7 @@ the cell's rehearsal, is
 
 import json
 import os
+import re
 import sys
 
 import jax
@@ -188,7 +189,7 @@ def test_decode_program_hands_back_the_held_experts_counts(tiny):
                           tables, context)
     assert len(out) == 3  # tokens, counts, the one plane
     counts = np.asarray(out[1])
-    assert counts.shape == (4,) and counts.dtype == np.int32
+    assert counts.shape == (2, 4) and counts.dtype == np.int32  # a layer
     # 2 rows x 2 experts a token x 2 expert layers, the held ones only
     assert 0 <= counts.sum() <= 8
 
@@ -232,6 +233,15 @@ def test_commit_span_says_what_the_decode_step_read(tiny, monkeypatch, family):
     assert commits[-1]["window_blocks"] == (2 + 3 if family == "latent"
                                             else 3 * 4)
     assert all(c["window_blocks"] > 0 for c in commits)
+    # an expert model says how often its grouped products streamed the held
+    # weights: once each, less the experts that drew no row in a layer
+    if family == "latent":
+        assert all(0.0 < c["expert_weight_passes"] <= 1.0 for c in commits)
+        assert eng.metrics.expert_weight_passes.value == (
+            commits[-1]["expert_weight_passes"])
+    else:
+        assert not any("expert_weight_passes" in c for c in commits)
+        assert eng.metrics.expert_weight_passes is None
 
 
 # ------------------------------- (c) -------------------------------------- #
@@ -351,6 +361,42 @@ def test_latent_decode_program_holds_no_window(tiny):
         assert "tensor<" + "x".join(map(str, shape)) + "x" not in text, shape
     # the same text does hold the plane, so the pattern is the text's own
     assert "tensor<" + "x".join(map(str, plane.shape)) + "x" in text
+
+
+@pytest.mark.parametrize("program", ["serve_decode", "serve_prefill"])
+def test_expert_products_are_the_grouped_kernels_on_the_stored_weights(
+        tiny, monkeypatch, program):
+    """The engine's two programs as they lower for the TPU (the kernels as
+    Mosaic calls, not interpreted): no ``ragged_dot`` is left, the held
+    experts' products are the grouped kernels, and a held weight's shape
+    appears only where the parameter is handed on, never as the result of
+    a copy, convert, transpose or slice."""
+    model, params = tiny
+    eng = ServingEngine(model, params, ServeConfig(
+        max_seqs=3, kv_block_size=BLOCK, max_seq_len=40,
+        prefill_pad_multiple=BUCKET))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if program == "serve_decode":
+        jitted, args = eng._decode_jit, eng.scheduler.decode_batch()
+    else:
+        jitted = eng._prefill_jit
+        args = (np.zeros((1, BUCKET), np.int32),
+                eng.scheduler.decode_batch()[2][:1], np.array([5], np.int32))
+    text = jitted.trace(params, *eng.cache.pages, *args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert "ragged_dot" not in text
+    for kernel in ("grouped_swiglu", "grouped_matmul"):
+        assert kernel in text, kernel
+    ffn = params["layer_1"]["ffn"]
+    for name in ("w_gate", "w_down"):
+        shape = "tensor<" + "x".join(map(str, ffn[name].shape)) + "x"
+        lines = [l.strip() for l in text.splitlines() if shape in l]
+        assert lines, name
+        for line in lines:
+            assert re.match(
+                r"(%\S+ = )?(func\.func |call @_grouped\w*\(|"
+                r"stablehlo\.custom_call @tpu_custom_call\()", line,
+            ), line[:200]
 
 
 # ------------------------------- (d) -------------------------------------- #
