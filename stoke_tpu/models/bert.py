@@ -41,14 +41,24 @@ class CacheSpec:
     it and reads no width table.
 
     ``planes`` are the pool's arrays, ``(name, row width)`` each, one
-    ``[layers, blocks, block_size, width]`` array a plane; ``values`` is how
-    many of a token's row, over all planes, are cached values (None: all of
-    it; a model may pad a row to whole 128-lane tiles, which is the form the
-    device keeps row-major at rest).  ``kind`` says
+    ``[row layers, blocks, block_size, width]`` array a plane; ``values`` is
+    how many of a token's row, over all planes, are cached values (None: all
+    of it; a model may pad a row to whole 128-lane tiles, which is the form
+    the device keeps row-major at rest).  ``kind`` says
     which hook method the model's layers call: ``"mha"`` (``layer_attention``:
-    per-head K and V rows, two planes of ``heads * head_dim``) or
+    per-head K and V rows, two planes of ``heads * head_dim``),
     ``"latent"`` (``latent_attention``: one row a token, the normed latent
-    and the roped shared key side by side)."""
+    and the roped shared key side by side) or ``"hybrid"``
+    (``layer_attention`` for the layers that cache rows, ``layer_state`` for
+    the others).
+
+    ``layer_kinds`` says what each of the ``layers`` keeps: ``"rows"`` (a
+    row a token in the planes, growing with the context) or ``"state"`` (a
+    constant per-slot state, in the ``state`` arrays); None means every
+    layer caches rows.  ``state`` lists the per-slot arrays a state layer
+    keeps, ``(name, shape a slot, dtype name)`` each, held as ``[max_seqs,
+    *shape]``, an array a state layer; a ``cache`` dtype is the pool's
+    (``ServeConfig.kv_dtype``)."""
 
     layers: int
     planes: tuple
@@ -57,13 +67,22 @@ class CacheSpec:
     head_dim: int
     max_len: int
     values: Optional[int] = None
+    layer_kinds: Optional[tuple] = None
+    state: tuple = ()
 
     @property
     def values_per_token(self) -> int:
-        """Cached values a token a layer, all planes together."""
+        """Cached values a token a row layer, all planes together."""
         if self.values is not None:
             return self.values
         return sum(width for _, width in self.planes)
+
+    def layers_of(self, kind: str) -> tuple:
+        """The indices of the layers that keep ``kind`` (``"rows"`` or
+        ``"state"``), in order: layer ``i``'s place in the planes or in the
+        state arrays is its position in this tuple."""
+        kinds = self.layer_kinds or ("rows",) * self.layers
+        return tuple(i for i, k in enumerate(kinds) if k == kind)
 
 
 BERT_SIZES = {

@@ -1,35 +1,48 @@
 """A pre-norm decoder assembled from a ``config.json``-shaped description.
 
-The DeepSeek-V3 family's block, keyed by the source's own names
-(``hidden_size``, ``q_lora_rank``, ``kv_lora_rank``, ``qk_nope_head_dim``,
-``n_routed_experts``, ``first_k_dense_replace``, ``rope_scaling`` ...), so a
-published configuration (A.X-K1, ``model_type: "axk1"``) builds the model as
-it stands:
+Two families' blocks, keyed by their sources' own names, so a published
+configuration builds the model as it stands.  Both: per layer ``h +=
+Attn(RMSNorm(h))``, ``h += FFN(RMSNorm(h))``; a final RMSNorm; an untied
+``lm_head``; no biases; the feed-forward a dense SwiGLU in the first
+``first_k_dense_replace`` layers and an expert layer after
+(:class:`stoke_tpu.models.moe.ExpertShareFFN`: the router's published
+width, the experts this chip holds, the shared expert).  The attention kind
+of layer ``i`` is :meth:`DecoderConfig.layer_kind`:
 
-- per layer ``h += Attn(RMSNorm(h))``, ``h += FFN(RMSNorm(h))``; a final
-  RMSNorm; an untied ``lm_head``; no biases;
-- attention is multi-head latent attention (MLA): queries through a low-rank
-  pair ``W_qb RMSNorm(W_qa x)``, split per head into a ``nope`` and a
-  ``rope`` part; keys and values from one latent a token, ``[c, k_r] =
-  W_kva x`` with ``c`` RMS-normed and ``k_r`` roped once for all heads,
-  ``[k_nope, v] = W_kvb c``.  Rotary positions with YaRN frequencies, pairs
-  ``(2i, 2i+1)``;
-- the feed-forward is a dense SwiGLU in the first ``first_k_dense_replace``
-  layers and an expert layer after
-  (:class:`stoke_tpu.models.moe.ExpertShareFFN`: the router's published
-  width, the experts this chip holds, the shared expert).
+- ``"mla"``, the DeepSeek-V3 family's (``q_lora_rank``, ``kv_lora_rank``,
+  ``qk_nope_head_dim``, ``rope_scaling`` ...; A.X-K1, ``model_type:
+  "axk1"``): multi-head latent attention, queries through a low-rank pair
+  ``W_qb RMSNorm(W_qa x)``, split per head into a ``nope`` and a ``rope``
+  part; keys and values from one latent a token, ``[c, k_r] = W_kva x``
+  with ``c`` RMS-normed and ``k_r`` roped once for all heads, ``[k_nope, v]
+  = W_kvb c``.  Rotary positions with YaRN frequencies, pairs ``(2i,
+  2i+1)``;
+- ``"gqa"`` and ``"kda"``, a hybrid keyed by ``gqa_layers`` and
+  ``linear_attn_config`` (Solar-Open2, ``model_type: "solar_open2"``): the
+  layers in ``gqa_layers`` are softmax attention without positions,
+  ``num_attention_heads`` query heads over ``num_key_value_heads`` key-value
+  heads, the output gated elementwise by ``sigmoid(W_g x)``
+  (:class:`GroupedQueryAttention`); the others are gated delta-rule linear
+  attention with a decay a channel (Kimi Delta Attention, arXiv:2510.26692;
+  :class:`DeltaRuleAttention`): a ``[d_k, d_v]`` float32 state a head in
+  place of cached rows, a depthwise causal convolution on q, k and v.
 
 The same ``__call__(input_ids, train, positions, decode, kv_cache)``
 contract as :class:`stoke_tpu.models.gpt.GPT`, and :meth:`Decoder.cache_spec`
-for ``ServingEngine``: one latent row a token a layer (``kv_lora_rank +
-qk_rope_head_dim`` values, stored padded to whole 128-lane tiles).  With a
-cache hook each layer's attention is the hook's
-(``kv_cache.latent_attention(i)``: the row written once, then the expanded
-form in prefill and the absorbed form in decode, both in this file);
-without one it is the expanded form over the whole sequence.
+for ``ServingEngine``.  A latent model caches one row a token a layer
+(``kv_lora_rank + qk_rope_head_dim`` values, stored padded to whole
+128-lane tiles); a hybrid one a row of keys and values a token in its
+``gqa`` layers only and a constant state a slot in its ``kda`` layers
+(``CacheSpec.layer_kinds``, ``CacheSpec.state``).  With a cache hook each
+layer's attention is the hook's (``kv_cache.latent_attention(i)``: the row
+written once, then the expanded form in prefill and the absorbed form in
+decode; ``kv_cache.layer_attention(i)`` for a ``gqa`` layer;
+``kv_cache.layer_state(i)`` hands a ``kda`` layer its slots' state and
+takes it back); without one it is the whole-sequence form.
 
 Parameters are ``param_dtype``, products take ``dtype`` inputs and
-accumulate in float32; router, softmax and RMSNorm statistics are float32.
+accumulate in float32; router, softmax, RMSNorm statistics and the whole of
+the delta rule's recurrence (decay, state, its products) are float32.
 """
 
 from __future__ import annotations
@@ -45,6 +58,7 @@ import jax.numpy as jnp
 
 from stoke_tpu.models.bert import CacheSpec
 from stoke_tpu.models.moe import ExpertShareFFN, SwiGLU
+from stoke_tpu.ops.flash_attention import grouped_query_attention
 
 _NEG_INF = -1e30
 
@@ -52,23 +66,25 @@ _NEG_INF = -1e30
 @dataclasses.dataclass(frozen=True)
 class DecoderConfig:
     """The keys of the source's ``config.json`` the decoder reads, under
-    the source's names.  ``n_routed_experts`` is the router's width."""
+    the source's names.  ``n_routed_experts`` is the router's width.  The
+    latent-attention keys are needed where a layer is ``"mla"``, the
+    ``gqa_layers`` / ``linear_*`` ones where the model is a hybrid."""
 
     vocab_size: int
     hidden_size: int
     num_hidden_layers: int
     num_attention_heads: int
-    q_lora_rank: int
-    kv_lora_rank: int
-    qk_nope_head_dim: int
-    qk_rope_head_dim: int
-    v_head_dim: int
-    intermediate_size: int
     moe_intermediate_size: int
     n_routed_experts: int
     num_experts_per_tok: int
-    n_group: int
-    topk_group: int
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    intermediate_size: int = 0
+    n_group: int = 1
+    topk_group: int = 1
     first_k_dense_replace: int = 1
     n_shared_experts: int = 1
     norm_topk_prob: bool = True
@@ -83,6 +99,18 @@ class DecoderConfig:
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 0.0
     rope_original_max_position_embeddings: int = 4096
+    # the hybrid: which layers are softmax attention (None: every layer is
+    # latent attention), its key-value heads and head size, its gate; the
+    # delta-rule layers' heads, head size and convolution, from
+    # ``linear_attn_config``
+    gqa_layers: Optional[Tuple[int, ...]] = None
+    num_key_value_heads: int = 0
+    head_dim: int = 0
+    use_gqa_gate: bool = False
+    linear_num_heads: int = 0
+    linear_head_dim: int = 0
+    linear_conv_kernel: int = 4
+    kda_allow_neg_eigval: bool = False
 
     @classmethod
     def from_dict(cls, config: dict) -> "DecoderConfig":
@@ -109,7 +137,76 @@ class DecoderConfig:
                         "mscale_all_dim", "original_max_position_embeddings"):
                 if key in scaling:
                     kwargs["rope_" + key] = scaling[key]
-        return cls(**kwargs)
+        if "gqa_layers" in config:
+            kwargs.update(cls._hybrid_keys(config))
+        else:
+            missing = [k for k in ("q_lora_rank", "kv_lora_rank",
+                                   "qk_nope_head_dim", "qk_rope_head_dim",
+                                   "v_head_dim", "n_group", "topk_group")
+                       if k not in config]
+            if missing:
+                raise ValueError(
+                    f"latent attention needs {missing}: a configuration "
+                    f"without gqa_layers is the DeepSeek-V3 family's")
+        made = cls(**kwargs)
+        if made.first_k_dense_replace and not made.intermediate_size:
+            raise ValueError("the dense layers need intermediate_size")
+        return made
+
+    @staticmethod
+    def _hybrid_keys(config: dict) -> dict:
+        """The hybrid's keys, checked: softmax layers without positions and
+        delta-rule layers with the low-rank decay projection are what is
+        built here."""
+        if config.get("use_rope", False):
+            raise ValueError("use_rope: the softmax layers of a hybrid "
+                             "carry no positions here")
+        if config.get("kda_use_full_proj", False):
+            raise ValueError("kda_use_full_proj: the decay here goes "
+                             "through the low-rank pair")
+        linear = config.get("linear_attn_config")
+        if not linear:
+            raise ValueError("gqa_layers without linear_attn_config: the "
+                             "other layers are delta-rule layers")
+        if linear.get("num_kv_heads") not in (None, linear["num_heads"]):
+            raise ValueError("linear_attn_config.num_kv_heads: the "
+                             "delta-rule layers keep a state a head")
+        for key in ("num_key_value_heads", "head_dim"):
+            if not config.get(key):
+                raise ValueError(f"grouped-query attention needs {key}")
+        if config["num_attention_heads"] % config["num_key_value_heads"]:
+            raise ValueError("the query heads do not divide over the "
+                             "key-value heads")
+        # a configuration cut in depth keeps the source's list
+        layers = tuple(int(i) for i in config["gqa_layers"]
+                       if int(i) < config["num_hidden_layers"])
+        return {
+            "gqa_layers": layers,
+            "linear_num_heads": int(linear["num_heads"]),
+            "linear_head_dim": int(linear["head_dim"]),
+            "linear_conv_kernel": int(linear["short_conv_kernel_size"]),
+        }
+
+    def layer_kind(self, i: int) -> str:
+        """Layer ``i``'s attention: ``"mla"``, ``"gqa"`` or ``"kda"``."""
+        if self.gqa_layers is None:
+            return "mla"
+        return "gqa" if i in self.gqa_layers else "kda"
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        return tuple(map(self.layer_kind, range(self.num_hidden_layers)))
+
+    @property
+    def kv_row_width(self) -> int:
+        """A ``gqa`` layer's cached row: a token's keys, then its values,
+        every key-value head's side by side."""
+        return 2 * self.num_key_value_heads * self.head_dim
+
+    @property
+    def conv_width(self) -> int:
+        """Channels the delta rule's convolution runs over: q, k and v."""
+        return 3 * self.linear_num_heads * self.linear_head_dim
 
     @property
     def qk_head_dim(self) -> int:
@@ -319,6 +416,114 @@ def absorbed_paged_attention(q_nope, q_rope, plane, layer, block_tables,
 
 
 # --------------------------------------------------------------------------- #
+# the delta rule with a decay a channel: one token, and a whole prompt
+# --------------------------------------------------------------------------- #
+
+#: positions a step of :func:`delta_rule_chunked` takes.  The pairwise decay
+#: ``exp(G_t - G_j)`` of a step is ``[heads, C, C, d_k]`` float32 (32 MB at
+#: 64 heads of 128), and its exponentials grow with ``L * C``.
+DELTA_RULE_CHUNK = 32
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def delta_rule_step(state, q, k, v, g, beta):
+    """One position of the gated delta rule, every slot and head at once,
+    in float32 throughout.
+
+    ``state [B, H, dk, dv]``; ``q``, ``k``, ``g [B, H, dk]`` (``g`` the log
+    of the decay, ``<= 0``, a value a key channel); ``v [B, H, dv]``;
+    ``beta [B, H]``.  ``S' = diag(exp g) S``; ``S_new = S' + beta k (v -
+    S'^T k)^T``; ``o = S_new^T q``.  Returns ``(o [B, H, dv], S_new)``.
+
+    ``S'^T k`` and ``S'^T q`` are both taken of the decayed state (``o =
+    S'^T q + (k . q) beta (v - S'^T k)``, the same sum in another order), so
+    that two passes over the state would do: one for the two reductions,
+    one for the update.  As the v5e's compiler fuses it the state is read
+    three times and written once, the two reductions apart (PERF.md
+    section 5)."""
+    decayed = state * jnp.exp(g)[..., None]
+    u = (decayed * k[..., None]).sum(axis=2)
+    p = (decayed * q[..., None]).sum(axis=2)
+    w = beta[..., None] * (v - u)
+    o = p + (k * q).sum(axis=-1, keepdims=True) * w
+    return o, decayed + k[..., None] * w[..., None, :]
+
+
+def delta_rule_chunked(q, k, v, g, beta):
+    """:func:`delta_rule_step` over the ``L`` positions of each sequence
+    from zero state, ``DELTA_RULE_CHUNK`` positions a step of the scan: the
+    whole-prompt form.  Exactly the recurrence, regrouped; float32, its
+    matrix products at ``Precision.HIGHEST``.
+
+    ``q``, ``k``, ``g [B, L, H, dk]``, ``v [B, L, H, dv]``, ``beta [B, L,
+    H]``.  A position with ``beta = 0`` and ``g = 0`` leaves the state as it
+    was (how a caller masks padding).  Returns ``(o [B, L, H, dv], state
+    after the last position)``.
+
+    Within a chunk, with ``G_t`` the running sum of ``g`` from the chunk's
+    start and ``S_0`` the state before it, the rule unrolls to ``S_t =
+    diag(e^{G_t}) S_0 + sum_{j <= t} diag(e^{G_t - G_j}) k_j u_j^T`` where
+    the ``u`` solve the unit lower-triangular system ``u_t + beta_t sum_{j <
+    t} A_tj u_j = beta_t (v_t - S_0^T (e^{G_t} k_t))``, ``A_tj = sum_d k_td
+    k_jd e^{G_td - G_jd}``.  Every exponent taken is ``<= 0``: the pairwise
+    decay is formed explicitly (no division by a running product, which
+    overflows where a channel decays fast).  ``(I + N)^{-1}`` of the
+    nilpotent ``N = diag(beta) A`` is the product of ``I + (-N)^{2^i}``."""
+    B, L, H, dk = q.shape
+    dv = v.shape[-1]
+    C = min(DELTA_RULE_CHUNK, L)
+    pad = -L % C
+    if pad:
+        q, k, v, g = (jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                      for t in (q, k, v, g))
+        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+    n = (L + pad) // C
+
+    def chunks(t):  # [B, n*C, H, ...] -> float32 [n, B, H, C, ...]
+        t = jnp.asarray(t, jnp.float32).reshape((B, n, C) + t.shape[2:])
+        return jnp.moveaxis(jnp.swapaxes(t, 2, 3), 1, 0)
+
+    mm = partial(jnp.einsum, precision=_HIGHEST,
+                 preferred_element_type=jnp.float32)
+    eye = jnp.eye(C, dtype=jnp.float32)
+    strictly_lower = jnp.tril(jnp.ones((C, C), bool), -1)
+    lower = jnp.tril(jnp.ones((C, C), bool))
+
+    def step(S0, xs):
+        qc, kc, vc, gc, bc = xs  # [B, H, C, dk] ..., bc [B, H, C]
+        G = jnp.cumsum(gc, axis=2)
+        # e^{G_t - G_j} for j <= t; above the diagonal the exponent is
+        # positive and unused
+        diff = G[:, :, :, None, :] - G[:, :, None, :, :]
+        decay = jnp.exp(jnp.where(lower[:, :, None], diff, -jnp.inf))
+        kd = kc[:, :, None, :, :] * decay  # [B, H, t, j, dk]
+        A = (kc[:, :, :, None, :] * kd).sum(-1)
+        Bq = (qc[:, :, :, None, :] * kd).sum(-1)
+        N = jnp.where(strictly_lower, A, 0.0) * bc[..., None]
+        inv, power = eye - N, N
+        for _ in range(max(C - 1, 1).bit_length() - 1):
+            power = mm("bhij,bhjk->bhik", power, power)
+            inv = mm("bhij,bhjk->bhik", inv, eye + power)
+        eG = jnp.exp(G)
+        rhs = bc[..., None] * (vc - mm("bhck,bhkv->bhcv", kc * eG, S0))
+        U = mm("bhij,bhjv->bhiv", inv, rhs)
+        o = mm("bhck,bhkv->bhcv", qc * eG, S0) + mm(
+            "bhij,bhjv->bhiv", jnp.where(lower, Bq, 0.0), U)
+        to_end = jnp.exp(G[:, :, -1:, :] - G)  # e^{G_C - G_j}
+        S1 = eG[:, :, -1, :, None] * S0 + mm(
+            "bhck,bhcv->bhkv", kc * to_end, U)
+        return S1, o
+
+    state, o = jax.lax.scan(
+        step, jnp.zeros((B, H, dk, dv), jnp.float32),
+        tuple(chunks(t) for t in (q, k, v, g, beta)),
+    )
+    o = jnp.swapaxes(jnp.moveaxis(o, 0, 1), 2, 3).reshape(B, n * C, H, dv)
+    return o[:, :L], state
+
+
+# --------------------------------------------------------------------------- #
 # modules
 # --------------------------------------------------------------------------- #
 
@@ -363,6 +568,145 @@ class LatentAttention(nn.Module):
             out.reshape(B, L, H * cfg.v_head_dim))
 
 
+class GroupedQueryAttention(nn.Module):
+    """Softmax attention without positions, ``num_attention_heads`` query
+    heads over ``num_key_value_heads`` key-value heads of ``head_dim``, the
+    attended values gated elementwise by ``sigmoid(W_g x)`` before ``W_o``
+    (``use_gqa_gate``).  ``attend(q, k, v)`` is a cache hook's
+    (``layer_attention(i)``); without one the whole sequence is attended
+    causally."""
+
+    cfg: DecoderConfig
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+    attention: str = "dense"
+
+    @nn.compact
+    def __call__(self, x, attend=None):
+        cfg = self.cfg
+        B, L, _ = x.shape
+        H, G, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                   cfg.head_dim)
+        dense = partial(nn.Dense, use_bias=False, dtype=self.dtype,
+                        param_dtype=self.param_dtype)
+        q = dense(H * D, name="q")(x).reshape(B, L, H, D)
+        k = dense(G * D, name="k")(x).reshape(B, L, G, D)
+        v = dense(G * D, name="v")(x).reshape(B, L, G, D)
+        if attend is None:
+            out = grouped_query_attention(
+                q, k, v, jnp.ones((B, L), bool), self.attention)
+        else:
+            out = attend(q, k, v)
+        out = out.reshape(B, L, H * D)
+        if cfg.use_gqa_gate:
+            gate = dense(H * D, name="g")(x)
+            out = (out.astype(jnp.float32)
+                   * jax.nn.sigmoid(gate.astype(jnp.float32))
+                   ).astype(self.dtype)
+        return dense(cfg.hidden_size, name="o")(out)
+
+
+class DeltaRuleAttention(nn.Module):
+    """Gated delta-rule linear attention with a decay a channel (Kimi Delta
+    Attention): per head a ``[d_k, d_v]`` float32 state, zero at a
+    sequence's start.
+
+    ``q~, k~, v~ = W_q x, W_k x, W_v x``, each through a depthwise causal
+    convolution over the current and the ``linear_conv_kernel - 1`` earlier
+    positions (no bias) and a SiLU; ``q = l2norm(q') d_k^-0.5``, ``k =
+    l2norm(k')``; the log decay ``g = -exp(A_log[h]) softplus(W_f2 (W_f1 x)
+    + dt_bias)``, a value a key channel; ``beta = sigmoid(W_b x)``, a value
+    a head, doubled under ``kda_allow_neg_eigval``; the recurrence is
+    :func:`delta_rule_step`; ``o = RMSNorm_dv(o) sigmoid(W_g2 (W_g1 x))``;
+    ``y = W_o o``.
+
+    ``state`` is a cache hook's accessor (``layer_state(i)``): ``mode``
+    ``"prefill"`` runs the whole-prompt form from zero state
+    (:func:`delta_rule_chunked`), positions at and past ``state.lengths``
+    masked out of the recurrence (``beta = 0``, ``g = 0``) and out of the
+    saved convolution inputs, and writes the state at the prompt's end;
+    ``"decode"`` reads every slot's state, takes one
+    :func:`delta_rule_step`, and writes it back.  Without one the whole
+    sequence runs from zero state."""
+
+    cfg: DecoderConfig
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, state=None):
+        cfg = self.cfg
+        B, L, _ = x.shape
+        H, D, K = (cfg.linear_num_heads, cfg.linear_head_dim,
+                   cfg.linear_conv_kernel)
+        dense = partial(nn.Dense, use_bias=False, dtype=self.dtype,
+                        param_dtype=self.param_dtype)
+        in_f32 = partial(jax.lax.dot_general,
+                         preferred_element_type=jnp.float32)
+        # the convolution's inputs, q, k and v side by side: [B, L, 3 H D]
+        fresh = jnp.concatenate(
+            [dense(H * D, name=n)(x) for n in ("q", "k", "v")], axis=-1)
+        taps = jnp.concatenate([
+            self.param(n + "_conv", nn.initializers.lecun_normal(),
+                       (K, H * D), self.param_dtype)
+            for n in ("q", "k", "v")], axis=-1).astype(jnp.float32)
+        decode = state is not None and state.mode == "decode"
+        if decode:
+            S, before = state.read()  # [B, H, D, D], [B, K - 1, 3 H D]
+        else:
+            before = jnp.zeros((B, K - 1, fresh.shape[-1]), fresh.dtype)
+        padded = jnp.concatenate([before.astype(fresh.dtype), fresh], axis=1)
+        conv = sum(
+            taps[j] * padded[:, j:j + L].astype(jnp.float32)
+            for j in range(K))
+        q, k, v = (t.reshape(B, L, H, D) for t in jnp.split(
+            jax.nn.silu(conv), 3, axis=-1))
+        q = q * jax.lax.rsqrt((q * q).sum(-1, keepdims=True) + 1e-6) * (
+            D ** -0.5)
+        k = k * jax.lax.rsqrt((k * k).sum(-1, keepdims=True) + 1e-6)
+
+        a_log = self.param("A_log", nn.initializers.zeros, (H,), jnp.float32)
+        dt_bias = self.param("dt_bias", nn.initializers.zeros, (H * D,),
+                             jnp.float32)
+        f = dense(H * D, name="f_b", dtype=jnp.float32, dot_general=in_f32)(
+            dense(D, name="f_a")(x))
+        g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+            f + dt_bias).reshape(B, L, H, D)
+        beta = jax.nn.sigmoid(dense(
+            H, name="b", dtype=jnp.float32, dot_general=in_f32)(x))
+        if cfg.kda_allow_neg_eigval:
+            beta = 2.0 * beta
+
+        if decode:
+            o, S = delta_rule_step(
+                S.astype(jnp.float32), q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                beta[:, 0])
+            state.write(S, padded[:, 1:])
+            o = o[:, None]
+        elif state is None:
+            o, _ = delta_rule_chunked(q, k, v, g, beta)
+        else:
+            valid = (jnp.arange(L, dtype=jnp.int32)[None, :]
+                     < state.lengths[:, None].astype(jnp.int32))
+            o, S = delta_rule_chunked(
+                q, k, v, jnp.where(valid[..., None, None], g, 0.0),
+                jnp.where(valid[..., None], beta, 0.0))
+            # the K - 1 inputs before position ``lengths``: in ``padded``
+            # position t sits at row t + K - 1
+            tail = jax.vmap(
+                lambda rows, at: jax.lax.dynamic_slice_in_dim(
+                    rows, at, K - 1, axis=0)
+            )(padded, state.lengths.astype(jnp.int32))
+            state.write(S, tail)
+
+        o = RMSNorm(cfg.rms_norm_eps, jnp.float32, self.param_dtype,
+                    name="o_norm")(o)
+        gate = dense(H * D, name="g_b")(dense(D, name="g_a")(x))
+        o = o.reshape(B, L, H * D) * jax.nn.sigmoid(
+            gate.astype(jnp.float32))
+        return dense(cfg.hidden_size, name="o")(o.astype(self.dtype))
+
+
 class DecoderLayer(nn.Module):
     cfg: DecoderConfig
     index: int
@@ -373,14 +717,29 @@ class DecoderLayer(nn.Module):
 
     @nn.compact
     def __call__(self, h, positions, attend=None):
+        """``attend`` is what the cache hook gives this layer's kind: the
+        attention of an ``"mla"`` or ``"gqa"`` layer, the state accessor
+        of a ``"kda"`` one."""
         cfg = self.cfg
         norm = partial(RMSNorm, cfg.rms_norm_eps, self.dtype,
                        self.param_dtype)
-        with jax.named_scope("mla"):
-            h = h + LatentAttention(
-                cfg, self.dtype, self.param_dtype, self.attention,
-                name="attn",
-            )(norm(name="attn_norm")(h), positions, attend)
+        kind = cfg.layer_kind(self.index)
+        with jax.named_scope(kind):
+            x = norm(name="attn_norm")(h)
+            if kind == "mla":
+                h = h + LatentAttention(
+                    cfg, self.dtype, self.param_dtype, self.attention,
+                    name="attn",
+                )(x, positions, attend)
+            elif kind == "gqa":
+                h = h + GroupedQueryAttention(
+                    cfg, self.dtype, self.param_dtype, self.attention,
+                    name="attn",
+                )(x, attend)
+            else:
+                h = h + DeltaRuleAttention(
+                    cfg, self.dtype, self.param_dtype, name="attn",
+                )(x, attend)
         x = norm(name="ffn_norm")(h)
         if self.index < cfg.first_k_dense_replace:
             return h + SwiGLU(cfg.intermediate_size, self.dtype,
@@ -397,8 +756,9 @@ class DecoderLayer(nn.Module):
 
 class Decoder(nn.Module):
     """A pre-norm decoder-only language model assembled from ``cfg``
-    (:meth:`DecoderConfig.from_dict`): latent attention, SwiGLU, a dense
-    or an expert feed-forward per layer, an untied head.
+    (:meth:`DecoderConfig.from_dict`): an attention kind a layer (latent;
+    or grouped-query and delta-rule), SwiGLU, a dense or an expert
+    feed-forward per layer, an untied head.
 
     Args:
         held_experts: ``(first, count)``: the routed experts of every
@@ -431,14 +791,32 @@ class Decoder(nn.Module):
 
     def cache_spec(self) -> CacheSpec:
         cfg = self.cfg
+        if cfg.gqa_layers is None:
+            return CacheSpec(
+                layers=cfg.num_hidden_layers,
+                planes=(("latent", cfg.latent_row_width),),
+                values=cfg.latent_width,
+                kind="latent",
+                heads=cfg.num_attention_heads,
+                head_dim=cfg.qk_head_dim,
+                max_len=cfg.max_position_embeddings,
+            )
+        H, D = cfg.linear_num_heads, cfg.linear_head_dim
         return CacheSpec(
             layers=cfg.num_hidden_layers,
-            planes=(("latent", cfg.latent_row_width),),
-            values=cfg.latent_width,
-            kind="latent",
+            # a token's keys, then its values, one row: the paged kernel
+            # scores a query against the key lanes of its key-value head
+            # and keeps that head's value lanes of what it attends
+            planes=(("kv", cfg.kv_row_width),),
+            kind="hybrid",
             heads=cfg.num_attention_heads,
-            head_dim=cfg.qk_head_dim,
+            head_dim=cfg.head_dim,
             max_len=cfg.max_position_embeddings,
+            layer_kinds=tuple("rows" if kind == "gqa" else "state"
+                              for kind in cfg.layer_kinds),
+            state=(("state", (H, D, D), "float32"),
+                   ("conv", (cfg.linear_conv_kernel - 1, cfg.conv_width),
+                    "cache")),
         )
 
     @nn.compact
@@ -470,9 +848,11 @@ class Decoder(nn.Module):
             cfg.vocab_size, cfg.hidden_size, dtype=self.dtype,
             param_dtype=self.param_dtype, name="embed_tokens",
         )(input_ids)
+        hook_method = {"mla": "latent_attention", "gqa": "layer_attention",
+                       "kda": "layer_state"}
         for i in range(cfg.num_hidden_layers):
-            attend = (None if kv_cache is None
-                      else kv_cache.latent_attention(i))
+            attend = (None if kv_cache is None else getattr(
+                kv_cache, hook_method[cfg.layer_kind(i)])(i))
             h = DecoderLayer(
                 cfg, i, self.held_experts, self.dtype, self.param_dtype,
                 self.attention, name=f"layer_{i}",

@@ -1241,3 +1241,34 @@ def make_flash_attention(
         )
 
     return attention_fn
+
+
+def grouped_query_attention(q, k, v, key_valid, impl: str = "dense"):
+    """Causal softmax attention of ``q [B, L, H, D]`` over ``k``, ``v [B, L,
+    G, D]``, query head ``h`` reading key-value head ``h // (H / G)``,
+    scaled by ``D ** -0.5``: the training and prefill form of a ``gqa``
+    layer.  ``key_valid [B, L]`` bool masks padding keys.  ``impl="flash"``
+    runs the repo's flash kernel, which wants as many key heads as query
+    heads: each key-value head is repeated for its ``H / G`` query heads
+    (the kernel then fetches a key-value head ``H / G`` times).  Returns
+    ``[B, L, H, D]``."""
+    B, L, H, D = q.shape
+    G = k.shape[2]
+    dtype = q.dtype
+    if impl == "flash":
+        heads_first = functools.partial(jnp.swapaxes, axis1=1, axis2=2)
+        k, v = (jnp.repeat(heads_first(t), H // G, axis=1) for t in (k, v))
+        out = flash_attention(
+            heads_first(q), k, v, key_valid.astype(jnp.int32), causal=True)
+        return heads_first(out)
+    if impl != "dense":
+        raise ValueError(f"unknown attention impl {impl!r}")
+    qg = q.reshape(B, L, G, H // G, D)
+    s = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k,
+                   preferred_element_type=jnp.float32) * D ** -0.5
+    allow = jnp.tril(jnp.ones((L, L), bool))[None, None, None] & (
+        key_valid[:, None, None, None, :])
+    p = jax.nn.softmax(jnp.where(allow, s, _NEG_INF), axis=-1)
+    out = jnp.einsum("bgrqk,bkgd->bqgrd", p.astype(dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(B, L, H, D).astype(dtype)

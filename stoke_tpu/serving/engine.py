@@ -71,6 +71,7 @@ from stoke_tpu.ops.flash_attention import partition_kernels_over
 from stoke_tpu.ops.grouped_matmul import expert_weight_passes
 from stoke_tpu.serving.kv_cache import (
     BlockAllocator,
+    HybridCacheHook,
     LatentAttentionHook,
     PagedAttentionHook,
     PagedKVCache,
@@ -131,9 +132,12 @@ class ServingEngine:
             (``experts_held``).
             :class:`~stoke_tpu.models.gpt.GPT` (dense FFN,
             ``chunked_head=False``; two planes of ``heads * head_dim``) and
-            :class:`~stoke_tpu.models.decoder.Decoder` (one latent plane;
-            its weights are served as given, in its own ``param_dtype``,
-            and live on the device once) do.
+            :class:`~stoke_tpu.models.decoder.Decoder` (one latent plane,
+            or, for a hybrid of grouped-query and delta-rule layers, one
+            plane of keys and values for the layers that cache rows and
+            the per-slot state arrays for the others; its weights are
+            served as given, in its own ``param_dtype``, and live on the
+            device once) do.
         params: the model's ``params`` pytree (NOT the variables dict).
         cfg: :class:`~stoke_tpu.configs.ServeConfig`.
         registry: metrics registry for the ``serve/*`` instruments
@@ -185,9 +189,9 @@ class ServingEngine:
         #: model's ``experts_held``; 0 for a model with none): the decode
         #: program then hands their assignment counts back
         self._experts_held = int(getattr(model, "experts_held", 0))
-        # a latent cache or an expert layer runs through the greedy
-        # serve_prefill and serve_decode programs only, and states its own
-        # compute dtype: its weights are served as given
+        # a latent or hybrid cache or an expert layer runs through the
+        # greedy serve_prefill and serve_decode programs only, and states
+        # its own compute dtype: its weights are served as given
         self._native = spec.kind != "mha" or self._experts_held > 0
         if self._native:
             missing = {
@@ -329,13 +333,25 @@ class ServingEngine:
             if cfg.kv_blocks is not None
             else cfg.max_seqs * max_blocks_per_seq + 1  # +1 scratch
         )
+        # planes for the layers that cache rows only; the others' state a
+        # slot in the second store
         self.cache = PagedKVCache(
-            spec.layers,
+            len(spec.layers_of("rows")),
             num_blocks,
             cfg.kv_block_size,
             dtype=_KV_DTYPES[cfg.kv_dtype],
             sharding=kv_sharding,
             planes=spec.planes,
+            state=spec.state,
+            state_layers=len(spec.layers_of("state")),
+            max_seqs=cfg.max_seqs,
+        )
+        #: bytes of recurrent state a live slot's decode step reads and
+        #: writes: every state layer's, once each way (the few convolution
+        #: inputs beside it, kept in the pool's dtype, are not counted)
+        self._state_bytes_per_slot = 2 * len(spec.layers_of("state")) * sum(
+            int(np.prod(shape)) * jnp.dtype(kind).itemsize
+            for _, shape, kind in spec.state if kind != "cache"
         )
         self.metrics.cache_bytes_per_token.set(self.cache.bytes_per_token)
         if self._experts_held:
@@ -397,7 +413,7 @@ class ServingEngine:
         # donation keeps the page pool in-place in HBM; the CPU backend
         # has no donation (jax warns and copies), so only donate off-CPU
         donate = (
-            tuple(range(1, 1 + len(spec.planes)))
+            tuple(range(1, 1 + len(self.cache.arrays)))
             if jax.default_backend() != "cpu"
             else ()
         )
@@ -506,7 +522,8 @@ class ServingEngine:
                 "params", lambda: tree_resident_bytes(self.qparams)
             )
             self._memory.set_component(
-                "kv_cache", lambda: self.cache.nbytes
+                "kv_cache",
+                lambda: self.cache.nbytes + self.cache.state_nbytes,
             )
             self._memory.preflight("serve")
 
@@ -548,9 +565,11 @@ class ServingEngine:
         )
 
     def _split(self, args: tuple):
-        """A serve program's arguments after the weights: the pool's planes
-        as the model describes them, then the rest."""
-        n = len(self._spec.planes)
+        """A serve program's arguments after the weights: the cache's
+        arrays as the model describes them (the pool's planes, then the
+        state arrays), then the rest."""
+        spec = self._spec
+        n = len(spec.planes) + len(spec.state) * len(spec.layers_of("state"))
         return args[:n], args[n:]
 
     def _weights(self, qparams):
@@ -558,10 +577,18 @@ class ServingEngine:
         as given for a model that states its own compute dtype."""
         return qparams if self._native else dequantize_params(qparams)
 
-    def _make_hook(self, pages, tables, positions, mode, lengths):
+    def _make_hook(self, pages, tables, positions, mode, lengths,
+                   slot=None):
         """The per-trace cache hook with this engine's kernel selection —
         with the default ``decode_kernel="reference"`` the constructed
         graph is op-for-op the pre-ISSUE-13 one."""
+        if self._spec.kind == "hybrid":
+            n = len(self._spec.planes)
+            return HybridCacheHook(
+                pages[:n], pages[n:], tables, positions, mode=mode,
+                lengths=lengths, layer_kinds=self._spec.layer_kinds,
+                slot=slot, attention_impl=self.cfg.attention,
+            )
         if self._spec.kind == "latent":
             return LatentAttentionHook(
                 *pages, tables, positions, mode=mode, lengths=lengths,
@@ -576,27 +603,30 @@ class ServingEngine:
         )
 
     def _prefill_fn(self, qparams, *args):
-        """After the weights and the pool's planes: tokens [1, P] padded
-        prompt; block_row [1, MB]; prompt_len [1].
-        Returns (first generated token [1], updated pages)."""
-        pages, (tokens, block_row, prompt_len) = self._split(args)
+        """After the weights and the cache's arrays: tokens [1, P] padded
+        prompt; block_row [1, MB]; prompt_len [1]; for a model with
+        per-slot state also slot [1], whose state rows the prompt's end
+        state overwrites.
+        Returns (first generated token [1], updated arrays)."""
+        pages, (tokens, block_row, prompt_len, *slot) = self._split(args)
         params = self._weights(qparams)
         P = tokens.shape[1]
         positions = jnp.arange(P, dtype=jnp.int32)[None, :]
         hook = self._make_hook(
-            pages, block_row, positions, "prefill", prompt_len
+            pages, block_row, positions, "prefill", prompt_len, *slot
         )
         logits = self._apply(params, tokens, positions, hook, decode=False)
         last = logits[0, prompt_len[0] - 1]
         return (
             jnp.argmax(last, axis=-1).astype(jnp.int32)[None],
             *hook.pages,
+            *getattr(hook, "state", ()),
         )
 
     def _decode_fn(self, qparams, *args):
-        """After the weights and the pool's planes: tokens/positions [B];
+        """After the weights and the cache's arrays: tokens/positions [B];
         block_tables [B, MB]; context_lens [B].
-        Returns (next tokens [B], updated pages); a model with expert
+        Returns (next tokens [B], updated arrays); a model with expert
         layers hands back their assignment counts (int32[expert layers,
         held]) beside the tokens."""
         pages, (tokens, positions, block_tables, context_lens) = (
@@ -619,6 +649,7 @@ class ServingEngine:
             jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32),
             *counts,
             *hook.pages,
+            *getattr(hook, "state", ()),
         )
 
     # --- sampling-mode programs (ISSUE 13): same forward, the draw added
@@ -792,7 +823,7 @@ class ServingEngine:
         i32 = jnp.int32
         args = (
             jax.tree_util.tree_map(abstract, self.qparams),
-            *map(abstract, self.cache.pages),
+            *map(abstract, self.cache.arrays),
             jax.ShapeDtypeStruct((B,), i32),  # tokens
             jax.ShapeDtypeStruct((B,), i32),  # positions
             jax.ShapeDtypeStruct((B, self._max_blocks_per_seq), i32),
@@ -824,22 +855,26 @@ class ServingEngine:
         return fn(*args)
 
     def _upload(self, host_args: tuple) -> tuple:
-        """A serve program's arguments: the weights, the page pool, and
-        the numpy ``host_args`` put on the device."""
+        """A serve program's arguments: the weights, the cache's arrays,
+        and the numpy ``host_args`` put on the device."""
         return (
             self.qparams,
             *self.cache.pages,
+            *self.cache.state,
             *map(jnp.asarray, host_args),
         )
 
     def _run(self, program: str, fn, args: tuple) -> list:
-        """Dispatch one serve program; the page pool it returns last
-        replaces the cache's.  Returns its other outputs, still on the
+        """Dispatch one serve program; the cache arrays it returns last
+        replace the cache's.  Returns its other outputs, still on the
         device."""
         out = self._dispatch(program, fn, args)
-        n = len(self.cache.pages)
-        self.cache.pages = tuple(out[-n:])
-        return list(out[:-n])
+        cache = self.cache
+        n = len(cache.pages)
+        first = len(out) - n - len(cache.state)
+        cache.pages = tuple(out[first:first + n])
+        cache.state = tuple(out[first + n:])
+        return list(out[:first])
 
     def _launch(self, span: str, program: str, fn, host_args: tuple) -> list:
         """:meth:`_upload` and :meth:`_run`, each under its own child of
@@ -959,6 +994,9 @@ class ServingEngine:
             )
             if self._sampling:
                 host_args += self._sampling_scalar_args(req.params, slot)
+            if self.cache.state:
+                # a state row is addressed by slot, not through the table
+                host_args += (np.array([slot], np.int32),)
             out = self._launch(
                 "serve/prefill", "serve_prefill", self._prefill_jit, host_args
             )
@@ -1272,18 +1310,23 @@ class ServingEngine:
         m.decode_s.inc(now - t0)
         # what the step just read, on the span that closes after the read:
         # the live rows' context lengths (fresh token included), the blocks
-        # of the pool one layer's attention read for them (a latent cache
-        # is read to each slot's own length, the MHA gather takes every
-        # slot's whole table) and, of an expert model, its held experts'
-        # load and how often their products streamed the weights
+        # of the pool one layer's attention read for them (the paged kernel
+        # of a latent or hybrid cache reads to each slot's own length, the
+        # MHA gather takes every slot's whole table), the bytes of per-slot
+        # state the live slots' layers read and wrote and, of an expert
+        # model, its held experts' load and how often their products
+        # streamed the weights
         tables, context = host_args[2], host_args[3][decode_rows]
         step_attrs = {
             "context_tokens": int(context.sum()),
             "window_blocks": (
-                int((-(-context // self.cfg.kv_block_size)).sum())
-                if self._spec.kind == "latent" else tables.size
+                tables.size if self._spec.kind == "mha"
+                else int((-(-context // self.cfg.kv_block_size)).sum())
             ),
         }
+        if self.cache.state:
+            step_attrs["state_bytes"] = (
+                len(decode_rows) * self._state_bytes_per_slot)
         if self._experts_held:
             per_expert = held_counts.sum(axis=0)  # over the expert layers
             total = int(per_expert.sum())

@@ -8,7 +8,7 @@ fixes that: one pool of fixed-size blocks, per-request block tables mapping
 sequence position -> (block, offset), freed blocks refilling mid-flight as
 requests complete.
 
-Three pieces:
+The pieces:
 
 - :class:`BlockAllocator` — host-side free list over the pool.  Block 0 is
   RESERVED as a scratch block: inactive decode slots write their (discarded)
@@ -27,7 +27,13 @@ Three pieces:
   (:func:`stoke_tpu.ops.flash_attention.paged_pool_attention`).  The hook
   carries the updated page arrays across layers within one trace; the
   caller reads them back after ``apply`` and returns them from the jitted
-  program.
+  program.  :class:`LatentAttentionHook` is the same bridge for a
+  latent-attention model's one plane.
+- the second store and :class:`HybridCacheHook` — a model whose layers keep
+  different things (``CacheSpec.layer_kinds``): planes for the layers that
+  cache a row a token, and slot-indexed state arrays for the layers that
+  keep a constant state a slot (a linear-attention layer's recurrent state,
+  its convolution's last inputs), which cache no rows and get no plane.
 """
 
 from __future__ import annotations
@@ -40,6 +46,8 @@ import jax.numpy as jnp
 from stoke_tpu.models.bert import dense_attention
 from stoke_tpu.ops.flash_attention import (
     flash_attention,
+    grouped_query_attention,
+    latent_paged_attention,
     paged_decode_attention_pallas,
     paged_pool_attention,
     paged_verify_attention_pallas,
@@ -149,6 +157,14 @@ class PagedKVCache:
     ``planes`` is the model's ``CacheSpec.planes``: ``(("k", 768), ("v",
     768))`` for 12 heads of 64, ``(("latent", 640),)`` for the latent row.
 
+    ``n_layers`` counts the layers that cache rows.  A model whose other
+    layers keep a constant state a slot (``CacheSpec.layer_kinds``,
+    ``CacheSpec.state``: a linear-attention layer's recurrent state and its
+    convolution's last inputs) gets the second store beside the planes:
+    ``state``, one ``[max_seqs, *shape]`` array an entry a state layer,
+    addressed by slot and not through the block tables; a plane for such a
+    layer would be spent on rows it never caches.
+
     ``sharding`` (optional ``jax.sharding.Sharding``) places the pool on
     the serving mesh — replicated by default (data-parallel serving
     replicas each own a full pool; a model-sharded pool over a heads axis
@@ -163,21 +179,48 @@ class PagedKVCache:
         planes: Sequence,
         dtype=jnp.float32,
         sharding=None,
+        state: Sequence = (),
+        state_layers: int = 0,
+        max_seqs: int = 0,
     ):
         self.n_layers = int(n_layers)
         self.block_size = int(block_size)
         self.num_blocks = int(num_blocks)
         self.dtype = jnp.dtype(dtype)
         self.plane_names = tuple(name for name, _ in planes)
-        pages = []
-        for _, width in planes:
-            plane = jnp.zeros((n_layers, num_blocks, block_size, width), dtype)
-            if sharding is not None:
-                plane = jax.device_put(plane, sharding)
-            pages.append(plane)
+
+        def zeros(shape, dtype):
+            array = jnp.zeros(shape, dtype)
+            return (array if sharding is None
+                    else jax.device_put(array, sharding))
+
         #: the planes, in the model's order; the serve programs take them
         #: (donated) and hand them back
-        self.pages = tuple(pages)
+        self.pages = tuple(
+            zeros((n_layers, num_blocks, block_size, width), dtype)
+            for _, width in planes
+        )
+        #: the second store: what the layers that cache no rows keep, a
+        #: constant size a slot and addressed by slot (``CacheSpec.state``):
+        #: ``[max_seqs, *shape]`` each, an array an entry a state layer
+        #: (layer by layer, the entries in the model's order), threaded
+        #: through the serve programs behind the planes, donated like them.
+        #: An array a layer, so that a decode step replaces each whole: of
+        #: one ``[state layers, max_seqs, ...]`` array the layers' updates
+        #: were slices written in place one after the other, and the v5e's
+        #: compiler, short of memory at 256 slots, rematerialised the first
+        #: layer's update three times over the aliased buffer, applying it
+        #: three times (PERF.md section 6, PR 33)
+        self.state = tuple(
+            zeros((max_seqs,) + tuple(shape),
+                  self.dtype if kind == "cache" else jnp.dtype(kind))
+            for _ in range(state_layers) for _, shape, kind in state
+        )
+
+    @property
+    def arrays(self) -> tuple:
+        """Everything the serve programs thread: planes, then state."""
+        return self.pages + self.state
 
     @property
     def k_pages(self):
@@ -193,8 +236,13 @@ class PagedKVCache:
         return sum(int(p.size) for p in self.pages) * self.dtype.itemsize
 
     @property
+    def state_nbytes(self) -> int:
+        """HBM footprint of the per-slot state arrays."""
+        return sum(int(a.size) * a.dtype.itemsize for a in self.state)
+
+    @property
     def bytes_per_token(self) -> int:
-        """Cached bytes a token, over all layers and planes."""
+        """Cached bytes a token, over all row layers and planes."""
         return self.n_layers * self.dtype.itemsize * sum(
             int(p.shape[-1]) for p in self.pages)
 
@@ -526,5 +574,146 @@ class LatentAttentionHook:
                 q_nope, q_rope, self.latent_pages, layer, self.block_tables,
                 self.lengths, w_kvb, scale,
             )
+
+        return attend
+
+
+class _LayerState:
+    """What :meth:`HybridCacheHook.layer_state` hands a state layer: the
+    hook's ``mode`` and ``lengths``, ``read()`` for the slots' state and
+    ``write(...)`` to give it back."""
+
+    def __init__(self, hook, mine: slice):
+        self._hook, self._mine = hook, mine
+        self.mode, self.lengths = hook.mode, hook.lengths
+
+    def read(self) -> tuple:
+        """Decode: every slot's ``(state, conv)`` rows of this layer."""
+        return self._hook.state[self._mine]
+
+    def write(self, *rows) -> None:
+        """Decode: every slot's rows back, each array replaced whole;
+        prefill: the one request's rows (``[1, ...]`` each) into its
+        slot's."""
+        hook = self._hook
+        if hook.mode == "decode":
+            new = [r.astype(a.dtype) for a, r in zip(self.read(), rows)]
+        else:
+            new = [a.at[hook.slot[0]].set(r[0].astype(a.dtype))
+                   for a, r in zip(self.read(), rows)]
+        state = list(hook.state)
+        state[self._mine] = new
+        hook.state = tuple(state)
+
+
+class HybridCacheHook:
+    """Per-trace cache bridge for a model whose layers keep different
+    things (``CacheSpec.kind == "hybrid"``): grouped-query attention layers
+    that cache a row a token in the pool's one plane, and layers that keep a
+    constant state a slot in the state arrays.  One hook serves both.
+
+    ``layer_attention(i)`` returns a row layer's ``attend(q [B, L, H, D], k,
+    v [B, L, G, D])``.  It writes the call's rows, a token's keys then its
+    values side by side (``2 G D`` lanes), into the slot's blocks with the
+    steering of :class:`PagedAttentionHook` (padding and idle slots land in
+    the scratch block), then attends: in ``"prefill"`` mode causally over
+    the padded prompt (``ops/flash_attention.py`` ``grouped_query_attention``,
+    ``attention_impl`` ``"flash"`` or ``"dense"``); in ``"decode"`` mode
+    through the Pallas kernel ``latent_paged_attention``, which reads each
+    slot's live blocks of the plane in place, to the slot's own length:
+    query head ``h`` rides as a row that holds its ``D`` values in the key
+    lanes of key-value head ``h // (H / G)`` and zeros elsewhere, so its
+    score against a cached row is ``q_h . k_g``, and of the probabilities
+    times the rows it keeps that head's value lanes.  The kernel's cost is
+    the pages it streams, the same bytes for any ``H``; the zeros ride in
+    the matrix unit's spare rows.
+
+    ``layer_state(i)`` returns a state layer's accessor
+    (:class:`_LayerState`): in ``"decode"`` mode every slot's rows of the
+    layer's state arrays, out and back in; in ``"prefill"`` mode the layer
+    starts from zero state and writes the one request's rows at ``slot``.
+    An idle slot's rows may hold anything finite: its prefill overwrites
+    them.
+
+    Args as :class:`PagedAttentionHook`'s, with ``pages`` the one ``kv``
+    plane ``[row layers, NB, BS, 2 G D]``, ``state`` the state arrays
+    (``[max_seqs, ...]`` each, layer by layer), ``layer_kinds`` the model's
+    ``CacheSpec.layer_kinds`` and ``slot [1] int32`` the prefilled
+    request's slot.
+    """
+
+    def __init__(self, pages, state, block_tables, positions, *, mode: str,
+                 lengths, layer_kinds, slot=None,
+                 attention_impl: str = "dense"):
+        if mode not in ("prefill", "decode"):
+            raise NotImplementedError(
+                f"HybridCacheHook has no {mode!r} mode: the state arrays "
+                f"are written and read by the serve_prefill and "
+                f"serve_decode programs only"
+            )
+        (self.kv_pages,) = pages
+        self.state = tuple(state)
+        self.block_tables = block_tables
+        self.positions = positions
+        self.mode = mode
+        self.lengths = lengths
+        self.slot = slot
+        self.attention_impl = attention_impl
+        self.block_size = int(self.kv_pages.shape[2])
+        # layer i's place in the plane or in the state arrays
+        kinds = tuple(layer_kinds)
+        self._place = [kinds[:i].count(kind) for i, kind in enumerate(kinds)]
+        self._kinds = kinds
+
+    @property
+    def pages(self) -> tuple:
+        return (self.kv_pages,)
+
+    def layer_state(self, layer: int) -> _LayerState:
+        if self._kinds[layer] != "state":
+            raise ValueError(f"layer {layer} caches rows, not a state")
+        # the layer's arrays: the state arrays lie layer by layer
+        n = len(self.state) // self._kinds.count("state")
+        place = self._place[layer]
+        return _LayerState(self, slice(place * n, (place + 1) * n))
+
+    def layer_attention(self, layer: int):
+        if self._kinds[layer] != "rows":
+            raise ValueError(f"layer {layer} keeps a state, not rows")
+        place = self._place[layer]
+
+        def attend(q, k, v):
+            B, L, H, D = q.shape
+            G = k.shape[2]
+            rows = jnp.concatenate(
+                [k.reshape(B * L, G * D), v.reshape(B * L, G * D)], axis=-1)
+            pool = self.kv_pages
+            blocks, offs = _write_targets(
+                self.block_tables, self.positions, self.block_size,
+                self.lengths if self.mode == "prefill" else None,
+            )
+            self.kv_pages = pool.at[place, blocks, offs].set(
+                rows.astype(pool.dtype), mode="promise_in_bounds"
+            )
+            if self.mode == "prefill":
+                key_valid = (
+                    jnp.arange(L, dtype=jnp.int32)[None, :]
+                    < self.lengths[:, None].astype(jnp.int32)
+                )
+                return grouped_query_attention(
+                    q, k, v, key_valid, self.attention_impl)
+            # head h's query in the key lanes of its key-value head
+            own = (jnp.arange(H)[:, None] // (H // G)
+                   == jnp.arange(G)[None, :])  # [H, G]
+            q_row = jnp.where(
+                own[None, :, :, None], q[:, 0, :, None, :], 0
+            ).reshape(B, H, G * D)
+            q_row = jnp.pad(q_row, ((0, 0), (0, 0), (0, G * D)))
+            o_row = latent_paged_attention(
+                q_row, self.kv_pages, place, self.block_tables,
+                self.lengths, D ** -0.5)
+            values = o_row[..., G * D:].reshape(B, H, G, D)
+            return jnp.where(own[None, :, :, None], values, 0).sum(
+                axis=2)[:, None].astype(q.dtype)
 
         return attend

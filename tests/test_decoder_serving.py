@@ -1,18 +1,29 @@
-"""The config-driven decoder (MLA, expert share) and its serving path, on the
-CPU at a small size in float32, against the plain reference the benchmark
-keeps for the family (``benchmark/lib/families/axk1.py``: ``jax.numpy`` at
-``highest`` precision, nothing of the program).
+"""The config-driven decoder (MLA; grouped-query and delta-rule layers;
+expert share) and its serving path, on the CPU at a small size in float32,
+against the plain references the benchmark keeps for the two families
+(``benchmark/lib/families/axk1.py``, ``solar_open2.py``: ``jax.numpy`` at
+``highest`` precision, nothing of the program).  A test that holds for both
+families is one test with the family as its parameter.
 
 (a) the decoder's full forward against the reference; (b) prefill then decode
-through the latent paged cache and ``ServingEngine`` against the reference's
-full forward, and what ``serve/commit`` says the step read; (c) absorbed
-decode equals expanded attention, and the paged kernel over the plane equals
-absorbed decode over the gathered window; (d) the routed parts of all shares
-plus the shared expert once add up to the uncut layer; (e) the router against
-a ``numpy`` top-k with groups and a tie; (f) the pool's bytes a token and the
-allocator's accounting; (g) the options without a latent program raise.  (h),
-the cell's rehearsal, is
+through the paged cache (latent rows; rows beside per-slot state) and
+``ServingEngine`` against the reference's full forward, and what
+``serve/commit`` says the step read; (c) absorbed decode equals expanded
+attention, the paged kernel over the plane equals absorbed decode over the
+gathered window, and the delta rule's whole-prompt form equals its one-token
+form stepped; (d) the routed parts of all shares plus the shared expert once
+add up to the uncut layer; (e) the router against a ``numpy`` top-k with
+groups and a tie; (f) the pool's bytes a token, the state arrays and the
+allocator's accounting; (g) the options without a program for such a model
+raise, naming the program.  (h), the cells' rehearsal, is
 ``tests/benchmark/test_benchmark_harness.py::test_cell_rehearsal``.
+
+Tolerances.  2e-5 on logits of a range near 8: program and reference are
+both float32 here and differ in the order of their sums (the program's
+whole-prompt delta rule regroups the recurrence by chunks of 32 positions;
+the largest difference seen is 6e-6).  1e-4 on the delta rule's outputs
+alone, whose values reach 8 and whose chunked form multiplies through a
+32 x 32 inverse (4e-5 seen).
 """
 
 import json
@@ -29,20 +40,23 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark.lib.families import axk1  # noqa: E402
+from benchmark.lib.families import axk1, solar_open2  # noqa: E402
 from stoke_tpu import ServeConfig  # noqa: E402
-from stoke_tpu.models import GPT  # noqa: E402
+from stoke_tpu.models import GPT, decoder  # noqa: E402
 from stoke_tpu.models.decoder import (  # noqa: E402
     Decoder,
     DecoderConfig,
     absorbed_attention,
     absorbed_paged_attention,
+    delta_rule_chunked,
+    delta_rule_step,
     expanded_attention,
 )
 from stoke_tpu.models.moe import ExpertShareFFN, group_limited_topk  # noqa: E402
 from stoke_tpu.serving.engine import ServingEngine  # noqa: E402
 from stoke_tpu.serving.kv_cache import (  # noqa: E402
     BlockAllocator,
+    HybridCacheHook,
     LatentAttentionHook,
     PagedKVCache,
 )
@@ -60,36 +74,63 @@ TINY = {**_read("benchmark/configs/axk1.json"),
         **_read("tests/benchmark/rehearsal/configs/axk1.json")}
 
 
+# and the second family's: one period of the hybrid (a grouped-query layer,
+# three delta-rule layers), hidden 128, 4 query heads over 2 key-value heads
+# of 32, 4 delta-rule heads of 32 x 32 state, 8 experts of which 4 are held
+TINY_SOLAR = {**_read("benchmark/configs/solar-open2.json"),
+              **_read("tests/benchmark/rehearsal/configs/solar-open2.json")}
+FAMILIES = {"axk1": (axk1, TINY), "solar_open2": (solar_open2, TINY_SOLAR)}
+
+
 @pytest.fixture(scope="module")
-def tiny():
-    model = axk1.build_model(TINY)
-    params = axk1.init_params(model, 7, 16)["params"]
-    return model, params
+def built():
+    """``{family: (model, params)}``, each built once."""
+    out = {}
+    for name, (family, config) in FAMILIES.items():
+        model = family.build_model(config)
+        out[name] = (model, family.init_params(model, 7, 16)["params"])
+    return out
 
 
-def _reference_logits(params, ids):
+@pytest.fixture(scope="module")
+def tiny(built):
+    return built["axk1"]
+
+
+@pytest.fixture(scope="module")
+def tiny_solar(built):
+    return built["solar_open2"]
+
+
+def _reference_logits(params, ids, family="axk1"):
+    module, config = FAMILIES[family]
     ids = jnp.asarray(ids, jnp.int32)
     at = jnp.broadcast_to(jnp.arange(ids.shape[1], dtype=jnp.int32), ids.shape)
-    return np.asarray(axk1.reference_logits_at(TINY, params, ids, at))
+    return np.asarray(module.reference_logits_at(config, params, ids, at))
 
 
 # ------------------------------- (a) -------------------------------------- #
 
 
-def test_decoder_forward_matches_reference(tiny):
-    model, params = tiny
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_decoder_forward_matches_reference(built, family):
+    """Cacheless: 40 positions is a chunk of the delta rule and a part of
+    one."""
+    model, params = built[family]
     ids = np.random.default_rng(0).integers(0, 512, (2, 40)).astype(np.int32)
     got = np.asarray(model.apply({"params": params}, ids, train=False))
-    want = _reference_logits(params, ids)
+    want = _reference_logits(params, ids, family)
     assert got.dtype == np.float32 and got.shape == (2, 40, 512)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
 
 
-def test_decoder_loss_reference_is_finite(tiny):
-    _, params = tiny
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_decoder_loss_reference_is_finite(built, family):
+    _, params = built[family]
+    module, config = FAMILIES[family]
     ids = np.random.default_rng(1).integers(0, 512, (2, 24)).astype(np.int32)
-    axk1.build_model(TINY)
-    loss = float(axk1.causal_lm_loss(params, jnp.asarray(ids)))
+    module.build_model(config)
+    loss = float(module.causal_lm_loss(params, jnp.asarray(ids)))
     assert 4.0 < loss < 9.0  # near ln 512 on random weights
 
 
@@ -102,6 +143,26 @@ def test_config_keys_the_decoder_cannot_build_are_errors():
             DecoderConfig.from_dict({**base, key: value})
     with pytest.raises(ValueError, match="yarn"):
         DecoderConfig.from_dict({**base, "rope_scaling": {"type": "linear"}})
+    with pytest.raises(ValueError, match="latent attention needs"):
+        DecoderConfig.from_dict(
+            {k: v for k, v in base.items() if k != "kv_lora_rank"})
+    # the hybrid's keys
+    base = solar_open2.program_config(TINY_SOLAR)
+    cfg = DecoderConfig.from_dict(base)
+    # the source's list of softmax layers outlives a cut in depth
+    assert base["gqa_layers"][:2] == [0, 4] and cfg.gqa_layers == (0,)
+    assert cfg.layer_kinds == ("gqa", "kda", "kda", "kda")
+    linear = base["linear_attn_config"]
+    for key, value, message in (
+            ("use_rope", True, "use_rope"),
+            ("kda_use_full_proj", True, "kda_use_full_proj"),
+            ("linear_attn_config", None, "without linear_attn_config"),
+            ("linear_attn_config", {**linear, "num_kv_heads": 2},
+             "num_kv_heads"),
+            ("num_key_value_heads", 3, "do not divide"),
+            ("head_dim", 0, "needs head_dim")):
+        with pytest.raises(ValueError, match=message):
+            DecoderConfig.from_dict({**base, key: value})
 
 
 # ------------------------------- (b) -------------------------------------- #
@@ -152,11 +213,14 @@ def test_prefill_then_decode_logits_match_reference(tiny, prompt_len):
 
 
 @pytest.mark.parametrize("attention", ["dense", "flash"])
-def test_engine_serves_the_reference_greedy_stream(tiny, attention):
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_engine_serves_the_reference_greedy_stream(built, family, attention):
     """Through ``ServingEngine`` (submit, step, generate; scheduler,
     allocator, ``_launch``): every served token is the argmax of the
-    reference's full forward of the prompt and the tokens before it."""
-    model, params = tiny
+    reference's full forward of the prompt and the tokens before it.  Five
+    requests on three slots: two slots serve a second request after their
+    first, and a state layer's rows there are the second's alone."""
+    model, params = built[family]
     eng = ServingEngine(model, params, ServeConfig(
         max_seqs=3, kv_block_size=BLOCK, max_seq_len=64,
         prefill_pad_multiple=BUCKET, attention=attention))
@@ -167,7 +231,7 @@ def test_engine_serves_the_reference_greedy_stream(tiny, attention):
     outs = eng.generate(prompts, max_new_tokens=5)
     for prompt, tokens in zip(prompts, outs):
         seq = np.concatenate([prompt, tokens]).astype(np.int32)
-        want = _reference_logits(params, seq[None])[0]
+        want = _reference_logits(params, seq[None], family)[0]
         at = len(prompt) - 1 + np.arange(len(tokens))
         assert tokens == list(want[at].argmax(-1))
     m = eng.metrics
@@ -194,12 +258,14 @@ def test_decode_program_hands_back_the_held_experts_counts(tiny):
     assert 0 <= counts.sum() <= 8
 
 
-@pytest.mark.parametrize("family", ["latent", "mha"])
-def test_commit_span_says_what_the_decode_step_read(tiny, monkeypatch, family):
+@pytest.mark.parametrize("family", ["latent", "mha", "hybrid"])
+def test_commit_span_says_what_the_decode_step_read(built, monkeypatch,
+                                                    family):
     """``serve/commit`` carries the decode rows' ``context_tokens`` and the
-    ``window_blocks`` one layer's attention read for them: a latent cache is
-    read to each slot's own length, the MHA gather takes every slot's whole
-    table."""
+    ``window_blocks`` one layer's attention read for them: the paged kernel
+    of a latent or hybrid cache reads to each slot's own length, the MHA
+    gather takes every slot's whole table.  A model with per-slot state
+    also says how many bytes of it the live slots' layers read and wrote."""
     import contextlib
 
     from stoke_tpu.telemetry import tracing
@@ -211,8 +277,8 @@ def test_commit_span_says_what_the_decode_step_read(tiny, monkeypatch, family):
         return contextlib.nullcontext()
 
     monkeypatch.setattr(tracing, "xprof_span", fake_xprof_span)
-    if family == "latent":
-        model, params = tiny
+    if family != "mha":
+        model, params = built["axk1" if family == "latent" else "solar_open2"]
     else:
         model = GPT(vocab_size=64, size_name="tiny", max_len=32)
         params = model.init(jax.random.PRNGKey(0),
@@ -230,12 +296,18 @@ def test_commit_span_says_what_the_decode_step_read(tiny, monkeypatch, family):
     # both decoding, one token each behind them: 9 + 1 + 1 and 17 + 1 + 1
     # cached positions, in 2 and 3 blocks of 8; three slots of four blocks
     assert commits[-1]["context_tokens"] == 11 + 19
-    assert commits[-1]["window_blocks"] == (2 + 3 if family == "latent"
-                                            else 3 * 4)
+    assert commits[-1]["window_blocks"] == (3 * 4 if family == "mha"
+                                            else 2 + 3)
     assert all(c["window_blocks"] > 0 for c in commits)
+    if family == "hybrid":
+        # two live slots x three delta-rule layers, 4 heads of 32 x 32
+        # float32 state read and written once
+        assert commits[-1]["state_bytes"] == 2 * 3 * 2 * 4 * 32 * 32 * 4
+    else:
+        assert not any("state_bytes" in c for c in commits)
     # an expert model says how often its grouped products streamed the held
     # weights: once each, less the experts that drew no row in a layer
-    if family == "latent":
+    if family != "mha":
         assert all(0.0 < c["expert_weight_passes"] <= 1.0 for c in commits)
         assert eng.metrics.expert_weight_passes.value == (
             commits[-1]["expert_weight_passes"])
@@ -399,24 +471,187 @@ def test_expert_products_are_the_grouped_kernels_on_the_stored_weights(
             ), line[:200]
 
 
+def _delta_rule_inputs(rng, B, L, H, d):
+    f = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)  # noqa: E731
+    k = f(B, L, H, d)
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    # log decays from -0.02 to -7 a position: slow channels and ones that
+    # forget within a chunk, whose running product underflows float32
+    g = -jnp.exp(2.0 * f(B, L, H, d) - 1.0)
+    beta = 2.0 * jax.nn.sigmoid(f(B, L, H))  # in (0, 2)
+    return f(B, L, H, d) * d ** -0.5, k, f(B, L, H, d), g, beta
+
+
+def _stepped(q, k, v, g, beta, state):
+    outs = []
+    for t in range(q.shape[1]):
+        o, state = delta_rule_step(
+            state, q[:, t], k[:, t], v[:, t], g[:, t], beta[:, t])
+        outs.append(o)
+    return jnp.stack(outs, axis=1), state
+
+
+@pytest.mark.parametrize("L,chunk,then_stepped", [
+    (1, 32, 0), (31, 32, 0), (32, 32, 0), (33, 32, 7),
+    (70, 32, 0), (70, 32, 19), (12, 5, 4), (64, 64, 0),
+])
+def test_whole_prompt_delta_rule_equals_the_one_token_form_stepped(
+        monkeypatch, L, chunk, then_stepped):
+    """``delta_rule_chunked`` (prefill) against ``delta_rule_step`` (decode)
+    taken ``L`` times from zero state: outputs and the state after the last
+    position, at lengths that are a part of a chunk, a chunk, and chunks and
+    a part; ``then_stepped`` further positions taken a token at a time from
+    the whole-prompt form's state, as decode follows prefill, land where
+    stepping all the way does."""
+    monkeypatch.setattr(decoder, "DELTA_RULE_CHUNK", chunk)
+    rng = np.random.default_rng(L * 100 + chunk)
+    B, H, d = 2, 3, 16
+    q, k, v, g, beta = _delta_rule_inputs(rng, B, L + then_stepped, H, d)
+    want_o, want_S = _stepped(q, k, v, g, beta,
+                              jnp.zeros((B, H, d, d), jnp.float32))
+    got_o, got_S = delta_rule_chunked(
+        *(t[:, :L] for t in (q, k, v, g, beta)))
+    assert got_o.shape == (B, L, H, d) and got_S.shape == (B, H, d, d)
+    if then_stepped:
+        more_o, got_S = _stepped(
+            *(t[:, L:] for t in (q, k, v, g, beta)), got_S)
+        got_o = jnp.concatenate([got_o, more_o], axis=1)
+    assert np.isfinite(np.asarray(got_o)).all()
+    np.testing.assert_allclose(np.asarray(got_o), np.asarray(want_o),
+                               atol=1e-4, rtol=0)
+    np.testing.assert_allclose(np.asarray(got_S), np.asarray(want_S),
+                               atol=1e-4, rtol=0)
+
+
+def test_masked_positions_leave_the_delta_rules_state_as_it_was():
+    """How prefill masks a bucket's padding: ``beta = 0`` and ``g = 0`` at
+    and past a length give the state at that length, whatever the padding
+    holds."""
+    rng = np.random.default_rng(9)
+    B, L, H, d, n = 1, 48, 2, 16, 37
+    q, k, v, g, beta = _delta_rule_inputs(rng, B, L, H, d)
+    _, want = delta_rule_chunked(q[:, :n], k[:, :n], v[:, :n], g[:, :n],
+                                 beta[:, :n])
+    live = jnp.arange(L) < n
+    _, got = delta_rule_chunked(
+        q, k, v, jnp.where(live[None, :, None, None], g, 0.0),
+        jnp.where(live[None, :, None], beta, 0.0))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-6, rtol=0)
+
+
+def test_hybrid_prefill_then_decode_logits_match_reference(tiny_solar):
+    """Prefill and every decode step of two requests through the hybrid
+    cache, logits against the reference's full forward: a prompt shorter
+    than its bucket (11 of 16) and one a position past it (17 of 32); three
+    slots decode together, one of them idle and carrying whatever an
+    earlier request left; then the second request takes over the first's
+    slot, whose state rows hold the first's last state."""
+    model, params = tiny_solar
+    spec = model.cache_spec()
+    assert spec.layers_of("rows") == (0,) and spec.layers_of("state") == (
+        1, 2, 3)
+    rng = np.random.default_rng(4)
+    SLOTS, new = 3, 5
+    cache = PagedKVCache(
+        1, 13, BLOCK, spec.planes, state=spec.state, state_layers=3,
+        max_seqs=SLOTS)
+    # whatever an earlier request left in every slot, finite
+    arrays = cache.pages + tuple(
+        jnp.asarray(rng.standard_normal(a.shape), a.dtype)
+        for a in cache.state)
+    tables = np.zeros((SLOTS, 4), np.int32)  # 4 blocks of 8: 32 positions
+
+    def hook(arrays, tables, positions, mode, lengths, slot=None):
+        return HybridCacheHook(
+            arrays[:1], arrays[1:], jnp.asarray(tables),
+            jnp.asarray(positions, jnp.int32), mode=mode,
+            lengths=jnp.asarray(lengths, jnp.int32),
+            layer_kinds=spec.layer_kinds,
+            slot=None if slot is None else jnp.array([slot], jnp.int32))
+
+    def serve(arrays, slot, blocks, prompt_len):
+        seq = rng.integers(0, 512, prompt_len + new).astype(np.int32)
+        want = _reference_logits(params, seq[None], "solar_open2")[0]
+        tables[slot] = blocks
+        padded = -(-prompt_len // BUCKET) * BUCKET
+        tokens = np.zeros((1, padded), np.int32)
+        # the bucket's padding holds other tokens, not zeros: masked all the same
+        tokens[0] = rng.integers(0, 512, padded)
+        tokens[0, :prompt_len] = seq[:prompt_len]
+        h = hook(arrays, tables[slot:slot + 1], np.arange(padded)[None],
+                 "prefill", [prompt_len], slot)
+        logits = model.apply(
+            {"params": params}, tokens, train=False,
+            positions=jnp.arange(padded, dtype=jnp.int32)[None], kv_cache=h)
+        np.testing.assert_allclose(np.asarray(logits[0, prompt_len - 1]),
+                                   want[prompt_len - 1], atol=2e-5, rtol=0)
+        arrays = h.pages + h.state
+        for t in range(prompt_len, prompt_len + new):
+            # every slot steps: the others feed token 0 at position 0
+            # against an all-scratch table, as the scheduler has idle ones
+            step_tables = np.zeros_like(tables)
+            step_tables[slot] = tables[slot]
+            ids = np.zeros((SLOTS, 1), np.int32)
+            at = np.zeros((SLOTS, 1), np.int32)
+            lens = np.ones(SLOTS, np.int32)
+            ids[slot], at[slot], lens[slot] = seq[t], t, t + 1
+            h = hook(arrays, step_tables, at, "decode", lens)
+            logits = model.apply(
+                {"params": params}, ids, train=False,
+                positions=jnp.asarray(at), decode=True, kv_cache=h)
+            arrays = h.pages + h.state
+            np.testing.assert_allclose(np.asarray(logits[slot, 0]), want[t],
+                                       atol=2e-5, rtol=0)
+        assert all(np.isfinite(np.asarray(a)).all() for a in arrays)
+        return arrays
+
+    arrays = serve(arrays, 1, [3, 9, 7, 5], 11)
+    before = [np.asarray(a) for a in arrays]
+    arrays = serve(arrays, 1, [2, 4, 6, 8], 17)  # the same slot again
+    # a prefill writes its own slot's state rows and no other's
+    h = hook(arrays, tables[2:3], np.arange(BUCKET)[None], "prefill", [3], 2)
+    model.apply({"params": params}, np.zeros((1, BUCKET), np.int32),
+                train=False,
+                positions=jnp.arange(BUCKET, dtype=jnp.int32)[None],
+                kv_cache=h)
+    assert len(h.state) == 3 * 2  # (state, conv) a delta-rule layer
+    for old, now, fresh in zip(before[1:], arrays[1:], h.state):
+        assert not np.array_equal(old[1], np.asarray(now)[1])
+        np.testing.assert_array_equal(np.asarray(fresh)[:2],
+                                      np.asarray(now)[:2])
+        assert not np.array_equal(np.asarray(fresh)[2], np.asarray(now)[2])
+
+
 # ------------------------------- (d) -------------------------------------- #
 
 
-def test_all_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
-    E, shares, hidden, ff = 8, 4, 32, 16
-    config = {**TINY, "hidden_size": hidden, "moe_intermediate_size": ff,
-              "n_routed_experts": E,
+@pytest.mark.parametrize("family,shares,routing", [
+    ("axk1", 4, dict(n_group=2, topk_group=1, routed_scaling_factor=2.5)),
+    # the second family's layer: one group (a plain top-k), factor 1, and
+    # the deployment's 8 shares
+    ("solar_open2", 8, dict(n_group=1, topk_group=1,
+                            routed_scaling_factor=1.0)),
+])
+def test_all_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(
+        family, shares, routing):
+    """The guide's share test: the parts of the result all the shares of
+    one layer give, with the shared expert counted once, add up to what the
+    family's reference gives for the uncut layer."""
+    module, tiny_config = FAMILIES[family]
+    E, hidden, ff = 2 * shares, 32, 16
+    config = {**tiny_config, "hidden_size": hidden,
+              "moe_intermediate_size": ff, "n_routed_experts": E,
               "published": {"n_routed_experts": E},
-              "deployment": {"first_expert": 0}}
-    kwargs = dict(hidden=hidden, ff=ff, num_experts=E, top_k=2, n_group=2,
-                  topk_group=1, routed_scaling_factor=2.5)
+              "deployment": {"first_expert": 0}, **routing}
+    kwargs = dict(hidden=hidden, ff=ff, num_experts=E, top_k=2, **routing)
     x = jnp.asarray(np.random.default_rng(2).standard_normal((3, 7, hidden)),
                     jnp.float32)
     whole = ExpertShareFFN(held=(0, E), **kwargs)
     params = whole.init(jax.random.PRNGKey(0), x)["params"]
     flat = x.reshape(-1, hidden)
     with jax.default_matmul_precision("highest"):
-        uncut = axk1.expert_ffn(config, params, flat, (0, E))
+        uncut = module.expert_ffn(config, params, flat, (0, E))
         shared = axk1._swiglu(flat, *(params["shared"][n]["kernel"]
                                       for n in ("gate", "up", "down")))
     routed_sum, counted = 0.0, 0
@@ -427,7 +662,7 @@ def test_all_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
         out, sown = ExpertShareFFN(held=(s * per, per), **kwargs).apply(
             {"params": mine}, x, mutable=["intermediates"])
         with jax.default_matmul_precision("highest"):
-            want = axk1.expert_ffn(config, mine, flat, (s * per, per))
+            want = module.expert_ffn(config, mine, flat, (s * per, per))
         np.testing.assert_allclose(np.asarray(out.reshape(-1, hidden)),
                                    np.asarray(want), atol=2e-5, rtol=0)
         routed_sum = routed_sum + out.reshape(-1, hidden) - shared
@@ -521,37 +756,78 @@ def test_gpt_describes_its_cache_as_two_planes():
     assert pool.pages[0] is pool.k_pages and pool.pages[1] is pool.v_pages
 
 
+def test_hybrid_cache_holds_planes_for_row_layers_and_state_for_the_rest():
+    published = _read("benchmark/configs/solar-open2.json")
+    model = solar_open2.build_model(published)
+    spec = model.cache_spec()
+    assert spec.kind == "hybrid" and spec.layers == 4
+    assert spec.layer_kinds == ("rows", "state", "state", "state")
+    # a token's keys then its values, 8 heads of 128 each, one row
+    assert spec.planes == (("kv", 2 * 8 * 128),)
+    assert spec.values_per_token == 2048
+    assert spec.state == (("state", (64, 128, 128), "float32"),
+                          ("conv", (3, 3 * 64 * 128), "cache"))
+    assert (spec.heads, spec.head_dim) == (64, 128)
+    assert model.experts_held == 40
+    pool = PagedKVCache(1, 3, 16, spec.planes, dtype=jnp.bfloat16,
+                        state=spec.state, state_layers=3, max_seqs=2)
+    assert [p.shape for p in pool.pages] == [(1, 3, 16, 2048)]
+    # an array an entry a state layer, layer by layer: a decode step
+    # replaces each whole
+    assert [(a.shape, str(a.dtype)) for a in pool.state] == 3 * [
+        ((2, 64, 128, 128), "float32"), ((2, 3, 24576), "bfloat16")]
+    assert pool.arrays == pool.pages + pool.state
+    # one layer in four caches rows; the others 4 MB of state a slot each
+    assert pool.bytes_per_token == 2048 * 2
+    assert pool.nbytes == 3 * 16 * 2048 * 2
+    assert pool.state_nbytes == 3 * 2 * (64 * 128 * 128 * 4 + 3 * 24576 * 2)
+    # an mha or latent model sees the pool it saw: no state arrays
+    plain = PagedKVCache(2, 5, 8, (("k", 128), ("v", 128)))
+    assert plain.state == () and plain.arrays == plain.pages
+
+
 # ------------------------------- (g) -------------------------------------- #
 
 
 @pytest.mark.parametrize("option,program", [
-    ({"sampling": True}, "sampling"),
+    ({"sampling": True}, "sampling serve_prefill / serve_decode"),
     ({"prefill_chunk_tokens": 16}, "serve_prefill_chunk"),
-    ({"sampling": True, "speculative_k": 2}, "sampling"),
-    ({"quant": "int8"}, "quantized"),
-    ({"quant": "bf16"}, "quantized"),
+    ({"sampling": True, "speculative_k": 2},
+     "sampling serve_prefill / serve_decode"),
+    ({"speculative_k": 2}, "serve_verify"),
+    ({"quant": "int8"}, "quantized weight store"),
+    ({"quant": "bf16"}, "quantized weight store"),
 ])
-def test_options_without_a_latent_program_raise(tiny, option, program):
-    model, params = tiny
-    with pytest.raises(NotImplementedError, match=program):
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_options_without_a_program_raise_naming_it(built, family, option,
+                                                   program):
+    """A latent-cache model and a hybrid one (rows beside per-slot state)
+    run through the greedy ``serve_prefill`` and ``serve_decode`` only:
+    chunked prefill, verify with its rollback, sampling and the quantized
+    store each raise, naming the cache kind and the program that is
+    missing."""
+    model, params = built[family]
+    kind = model.cache_spec().kind
+    assert kind == {"axk1": "latent", "solar_open2": "hybrid"}[family]
+    with pytest.raises(NotImplementedError,
+                       match=f"a {kind}-cache model with experts has no "
+                             f"{program}"):
         ServingEngine(model, params, ServeConfig(
             max_seqs=2, kv_block_size=8, max_seq_len=32,
             prefill_pad_multiple=16, **option))
 
 
-def test_speculative_alone_names_the_verify_program(tiny):
-    model, params = tiny
-    cfg = ServeConfig(max_seqs=2, kv_block_size=8, max_seq_len=32,
-                      prefill_pad_multiple=16, speculative_k=2)
-    with pytest.raises(NotImplementedError, match="serve_verify"):
-        ServingEngine(model, params, cfg)
-
-
-def test_latent_hook_has_no_chunk_or_verify_mode():
-    pages = jnp.zeros((1, 2, 8, 128))
+@pytest.mark.parametrize("hook", [
+    lambda mode: LatentAttentionHook(
+        jnp.zeros((1, 2, 8, 128)), None, None, mode=mode, lengths=None),
+    lambda mode: HybridCacheHook(
+        (jnp.zeros((1, 2, 8, 128)),), (), None, None, mode=mode,
+        lengths=None, layer_kinds=("rows", "state")),
+], ids=["latent", "hybrid"])
+def test_latent_and_hybrid_hooks_have_no_chunk_or_verify_mode(hook):
     for mode in ("chunk", "verify"):
         with pytest.raises(NotImplementedError, match=mode):
-            LatentAttentionHook(pages, None, None, mode=mode, lengths=None)
+            hook(mode)
 
 
 def test_a_model_without_the_contract_is_a_type_error(tiny):
@@ -584,6 +860,41 @@ def test_counts_of_the_published_share():
     assert axk1.serve_flops(config, 0, 0, 1) == 6 * 2 * 64 * (576 + 512)
     assert axk1.serve_flops(config, 0, 0, 0, 1) == 6 * 2 * 64 * (192 + 128)
     assert axk1.train_flops_per_token(config, 1024) > 3 * one
+
+
+def test_counts_of_the_second_familys_published_share():
+    config = _read("benchmark/configs/solar-open2.json")
+    n = solar_open2.param_counts(config)
+    # ISSUE 33's table, from the keys
+    assert round(n["kda"] / 1e6, 1) == 137.7
+    assert round(n["gqa"] / 1e6, 1) == 109.1
+    assert round(n["ffn"] / 1e6, 1) == 646.2
+    assert round(2 * n["embedding"] / 1e6, 1) == 201.3
+    assert n["total"] == 3_308_352_064
+    # and re-counted from the built tree
+    model = solar_open2.build_model(config)
+    shapes = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32), train=False))
+    assert n["total"] == sum(
+        int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(shapes))
+    # a decode step moves every held weight once, the live K/V rows of the
+    # one softmax layer, and every live slot's state there and back
+    empty = solar_open2.decode_bytes_per_step(config, 0, 0)
+    assert 6.4e9 < empty < 6.7e9
+    per_row = solar_open2.decode_bytes_per_step(config, 0, 1000) - empty
+    assert per_row == 1000 * 1 * 2 * 8 * 128 * 2
+    per_slot = solar_open2.decode_bytes_per_step(config, 1, 0) - empty
+    assert per_slot == (3 * 2 * (64 * 128 * 128 * 4 + 3 * 24576 * 2)
+                        + 4096 * 2 + 4096)  # state, embedding row, K/V row
+    # 2 FLOPs a matrix parameter met a token and the recurrence's three
+    # passes and decay; attention pairs in the softmax layer only
+    one = solar_open2.serve_flops(config, 0, 1, 0)
+    assert 1.4e9 < one < 1.7e9
+    assert solar_open2.serve_flops(config, 1, 0, 0) == one - 2 * n["embedding"]
+    assert solar_open2.serve_flops(config, 0, 0, 1) == 4 * 64 * 128
+    assert solar_open2.serve_flops(config, 0, 0, 0, 1) == 4 * 64 * 128
+    assert solar_open2.train_flops_per_token(config, 1024) > 3 * (
+        one - 2 * n["embedding"])
 
 
 def test_serve_roofline_reader_finds_nothing_without_a_trace(tmp_path,
