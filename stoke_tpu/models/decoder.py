@@ -58,6 +58,7 @@ import jax.numpy as jnp
 
 from stoke_tpu.models.bert import CacheSpec
 from stoke_tpu.models.moe import ExpertShareFFN, SwiGLU
+from stoke_tpu.ops.delta_rule import delta_rule_step
 from stoke_tpu.ops.flash_attention import grouped_query_attention
 
 _NEG_INF = -1e30
@@ -427,29 +428,6 @@ DELTA_RULE_CHUNK = 32
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def delta_rule_step(state, q, k, v, g, beta):
-    """One position of the gated delta rule, every slot and head at once,
-    in float32 throughout.
-
-    ``state [B, H, dk, dv]``; ``q``, ``k``, ``g [B, H, dk]`` (``g`` the log
-    of the decay, ``<= 0``, a value a key channel); ``v [B, H, dv]``;
-    ``beta [B, H]``.  ``S' = diag(exp g) S``; ``S_new = S' + beta k (v -
-    S'^T k)^T``; ``o = S_new^T q``.  Returns ``(o [B, H, dv], S_new)``.
-
-    ``S'^T k`` and ``S'^T q`` are both taken of the decayed state (``o =
-    S'^T q + (k . q) beta (v - S'^T k)``, the same sum in another order), so
-    that two passes over the state would do: one for the two reductions,
-    one for the update.  As the v5e's compiler fuses it the state is read
-    three times and written once, the two reductions apart (PERF.md
-    section 5)."""
-    decayed = state * jnp.exp(g)[..., None]
-    u = (decayed * k[..., None]).sum(axis=2)
-    p = (decayed * q[..., None]).sum(axis=2)
-    w = beta[..., None] * (v - u)
-    o = p + (k * q).sum(axis=-1, keepdims=True) * w
-    return o, decayed + k[..., None] * w[..., None, :]
-
-
 def delta_rule_chunked(q, k, v, g, beta):
     """:func:`delta_rule_step` over the ``L`` positions of each sequence
     from zero state, ``DELTA_RULE_CHUNK`` positions a step of the scan: the
@@ -625,9 +603,11 @@ class DeltaRuleAttention(nn.Module):
     (:func:`delta_rule_chunked`), positions at and past ``state.lengths``
     masked out of the recurrence (``beta = 0``, ``g = 0``) and out of the
     saved convolution inputs, and writes the state at the prompt's end;
-    ``"decode"`` reads every slot's state, takes one
-    :func:`delta_rule_step`, and writes it back.  Without one the whole
-    sequence runs from zero state."""
+    ``"decode"`` hands every slot's state to one :func:`delta_rule_step`
+    (``ops/delta_rule.py``: a Pallas kernel that reads each slot's and
+    head's state once and writes it over itself) and the new state back:
+    the serve program donates the state arrays, so the update is in place.
+    Without one the whole sequence runs from zero state."""
 
     cfg: DecoderConfig
     dtype: Any = jnp.float32
@@ -679,8 +659,7 @@ class DeltaRuleAttention(nn.Module):
 
         if decode:
             o, S = delta_rule_step(
-                S.astype(jnp.float32), q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                beta[:, 0])
+                S, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
             state.write(S, padded[:, 1:])
             o = o[:, None]
         elif state is None:

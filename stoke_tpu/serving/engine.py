@@ -67,6 +67,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from stoke_tpu.configs import ServeConfig
+from stoke_tpu.ops.delta_rule import state_passes
 from stoke_tpu.ops.flash_attention import partition_kernels_over
 from stoke_tpu.ops.grouped_matmul import expert_weight_passes
 from stoke_tpu.serving.kv_cache import (
@@ -356,6 +357,8 @@ class ServingEngine:
         self.metrics.cache_bytes_per_token.set(self.cache.bytes_per_token)
         if self._experts_held:
             self.metrics.enable_experts()
+        if self.cache.state:
+            self.metrics.enable_state()
         self.allocator = BlockAllocator(num_blocks, cfg.kv_block_size)
 
         # --- continuous-batching scheduler (pillar 2) ---
@@ -1313,9 +1316,10 @@ class ServingEngine:
         # of the pool one layer's attention read for them (the paged kernel
         # of a latent or hybrid cache reads to each slot's own length, the
         # MHA gather takes every slot's whole table), the bytes of per-slot
-        # state the live slots' layers read and wrote and, of an expert
-        # model, its held experts' load and how often their products
-        # streamed the weights
+        # state the live slots' layers read and wrote and how often the
+        # state layers' kernel moved them (its grid walks every slot) and,
+        # of an expert model, its held experts' load and how often their
+        # products streamed the weights
         tables, context = host_args[2], host_args[3][decode_rows]
         step_attrs = {
             "context_tokens": int(context.sum()),
@@ -1325,8 +1329,12 @@ class ServingEngine:
             ),
         }
         if self.cache.state:
-            step_attrs["state_bytes"] = (
-                len(decode_rows) * self._state_bytes_per_slot)
+            passes = state_passes(len(decode_rows), self.cfg.max_seqs)
+            m.state_passes.set(passes)
+            step_attrs.update(
+                state_bytes=len(decode_rows) * self._state_bytes_per_slot,
+                state_passes=passes,
+            )
         if self._experts_held:
             per_expert = held_counts.sum(axis=0)  # over the expert layers
             total = int(per_expert.sum())

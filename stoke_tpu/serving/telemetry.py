@@ -173,6 +173,9 @@ class ServeMetrics:
         self.expert_assignments = None
         self.expert_load_max_over_mean = None
         self.expert_weight_passes = None
+        # per-slot recurrent state: created by enable_state() for a model
+        # whose cache keeps one, so no other engine registers the series
+        self.state_passes = None
 
     def enable_experts(self) -> None:
         """Arm the expert-layer instruments, called at engine construction
@@ -197,6 +200,21 @@ class ServeMetrics:
             "serve/expert_weight_passes",
             help="last decode step: bytes of held expert weight the grouped "
             "products read over the bytes they hold",
+        )
+
+    def enable_state(self) -> None:
+        """Arm the per-slot-state instrument, called at engine construction
+        for a model whose cache keeps a recurrent state a slot: the bytes
+        of state the last decode step's kernels moved over the bytes its
+        live slots' state holds once each way (1.0: each read once and
+        written once; ``ops/delta_rule.py`` ``state_passes``)."""
+        if self.state_passes is not None:
+            return
+        self.state_passes = self.registry.gauge(
+            "serve/state_passes",
+            help="last decode step: bytes of per-slot state the state "
+            "layers' kernels moved over the bytes the live slots' state "
+            "holds, once each way",
         )
 
     def enable_speculative(self) -> None:

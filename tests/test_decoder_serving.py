@@ -53,6 +53,7 @@ from stoke_tpu.models.decoder import (  # noqa: E402
     expanded_attention,
 )
 from stoke_tpu.models.moe import ExpertShareFFN, group_limited_topk  # noqa: E402
+from stoke_tpu.ops.delta_rule import delta_rule_reference  # noqa: E402
 from stoke_tpu.serving.engine import ServingEngine  # noqa: E402
 from stoke_tpu.serving.kv_cache import (  # noqa: E402
     BlockAllocator,
@@ -303,8 +304,14 @@ def test_commit_span_says_what_the_decode_step_read(built, monkeypatch,
         # two live slots x three delta-rule layers, 4 heads of 32 x 32
         # float32 state read and written once
         assert commits[-1]["state_bytes"] == 2 * 3 * 2 * 4 * 32 * 32 * 4
+        # and how often the state layers' kernel moved them: its grid walks
+        # all three slots for the two that are live
+        assert commits[-1]["state_passes"] == pytest.approx(3 / 2)
+        assert eng.metrics.state_passes.value == commits[-1]["state_passes"]
     else:
-        assert not any("state_bytes" in c for c in commits)
+        assert not any("state_bytes" in c or "state_passes" in c
+                       for c in commits)
+        assert eng.metrics.state_passes is None
     # an expert model says how often its grouped products streamed the held
     # weights: once each, less the experts that drew no row in a layer
     if family != "mha":
@@ -469,6 +476,80 @@ def test_expert_products_are_the_grouped_kernels_on_the_stored_weights(
                 r"(%\S+ = )?(func\.func |call @_grouped\w*\(|"
                 r"stablehlo\.custom_call @tpu_custom_call\()", line,
             ), line[:200]
+
+
+def test_hybrid_decode_lowers_to_one_state_kernel_a_layer_in_place(
+        tiny_solar, monkeypatch):
+    """The hybrid's decode program as it lowers for the TPU: each of the
+    three delta-rule layers is one ``delta_rule_step`` Mosaic call that
+    takes the layer's state array and hands it back aliased, and the
+    program's own state arguments are donated: nothing on the way holds a
+    second copy of a layer's state."""
+    model, params = tiny_solar
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    eng = ServingEngine(model, params, ServeConfig(
+        max_seqs=3, kv_block_size=BLOCK, max_seq_len=40,
+        prefill_pad_multiple=BUCKET))
+    text = eng._decode_jit.trace(
+        params, *eng.cache.pages, *eng.cache.state,
+        *eng.scheduler.decode_batch()).lower(
+            lowering_platforms=("tpu",)).as_text()
+    # the three layers call one function, which holds the one Mosaic call
+    assert len(re.findall(r"call @_step\w*\(", text)) == 3
+    (kernel,) = [line for line in text.splitlines()
+                 if 'kernel_name = "delta_rule_step"' in line]
+    assert "operand_index = 3" in kernel
+    # [slots, blocks, heads, dk, dv]
+    assert kernel.rsplit(" : (", 1)[1].split(") -> ")[0].split(
+        ", ")[3] == "tensor<3x1x4x32x32xf32>"
+    main = next(line for line in text.splitlines()
+                if "func.func public @main" in line)
+    donated = re.findall(
+        r"tensor<3x4x32x32xf32> \{[^}]*tf\.aliasing_output", main)
+    assert len(donated) == 3, main[:600]
+
+
+def test_served_decode_steps_equal_the_plain_delta_rule_stepped(
+        tiny_solar, monkeypatch):
+    """Two engines over the same three requests on two slots, one decoding
+    through the kernel and one through the plain form in its place: the
+    same tokens, and after every engine step the same state arrays (the
+    recurrent state and the convolution's inputs of each delta-rule layer,
+    every slot's rows, the one idle at first too).  What stands between the
+    kernel and the cache is under test: ``_LayerState.write``, the program's
+    threading of the state arrays and, where the backend has it, their
+    donation."""
+    model, params = tiny_solar
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, 512, n).astype(np.int32) for n in (7, 18, 12)]
+
+    def engine():
+        eng = ServingEngine(model, params, ServeConfig(
+            max_seqs=2, kv_block_size=BLOCK, max_seq_len=48,
+            prefill_pad_multiple=BUCKET))
+        return eng, [eng.submit(prompt, 6) for prompt in prompts]
+
+    got, got_ids = engine()
+    stepped = []
+    while got.scheduler.has_work:
+        got.step()
+        stepped.append([np.asarray(a) for a in got.cache.state])
+
+    monkeypatch.setattr(decoder, "delta_rule_step", delta_rule_reference)
+    want, want_ids = engine()
+    for step, arrays in enumerate(stepped):
+        want.step()
+        assert len(arrays) == len(want.cache.state) == 6
+        for layer, (a, b) in enumerate(zip(arrays, want.cache.state)):
+            np.testing.assert_allclose(
+                a, np.asarray(b), atol=1e-5, rtol=0,
+                err_msg=f"engine step {step}, state array {layer}")
+    assert not want.scheduler.has_work
+    assert len(stepped) > 6  # the third request waited for a slot
+    for a, b in zip(got_ids, want_ids):
+        tokens = got.scheduler.finished[a].tokens
+        assert len(tokens) == 6
+        assert list(tokens) == list(want.scheduler.finished[b].tokens)
 
 
 def _delta_rule_inputs(rng, B, L, H, d):
