@@ -48,7 +48,8 @@ class CacheSpec:
     which hook method the model's layers call: ``"mha"`` (``layer_attention``:
     per-head K and V rows, two planes of ``heads * head_dim``),
     ``"latent"`` (``latent_attention``: one row a token, the normed latent
-    and the roped shared key side by side) or ``"hybrid"``
+    and the roped shared key side by side; ``layers`` then counts latent
+    sublayers, two a layer in a model of double layers) or ``"hybrid"``
     (``layer_attention`` for the layers that cache rows, ``layer_state`` for
     the others).
 

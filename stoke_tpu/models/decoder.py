@@ -1,12 +1,18 @@
 """A pre-norm decoder assembled from a ``config.json``-shaped description.
 
-Two families' blocks, keyed by their sources' own names, so a published
-configuration builds the model as it stands.  Both: per layer ``h +=
-Attn(RMSNorm(h))``, ``h += FFN(RMSNorm(h))``; a final RMSNorm; an untied
-``lm_head``; no biases; the feed-forward a dense SwiGLU in the first
-``first_k_dense_replace`` layers and an expert layer after
-(:class:`stoke_tpu.models.moe.ExpertShareFFN`: the router's published
-width, the experts this chip holds, the shared expert).  The attention kind
+Three families' blocks, keyed by their sources' own names, so a published
+configuration builds the model as it stands.  All: a final RMSNorm; an
+untied ``lm_head``; no biases.  The first two: per layer ``h +=
+Attn(RMSNorm(h))``, ``h += FFN(RMSNorm(h))``, the feed-forward a dense
+SwiGLU in the first ``first_k_dense_replace`` layers and an expert layer
+after (:class:`stoke_tpu.models.moe.ExpertShareFFN`: the router's published
+width, the experts this chip holds, the shared expert).  The third
+(``attention_method``, ``ffn_hidden_size``, ``zero_expert_num`` ...; the
+LongCat-Flash family) is made of *double layers*
+(:class:`ShortcutDoubleLayer`): two latent-attention sublayers and two
+dense feed-forwards in series and ONE expert layer that reads the first
+sublayer's output and is added back at the double layer's end, its router a
+softmax over the routed and the zero-compute experts.  The attention kind
 of layer ``i`` is :meth:`DecoderConfig.layer_kind`:
 
 - ``"mla"``, the DeepSeek-V3 family's (``q_lora_rank``, ``kv_lora_rank``,
@@ -16,7 +22,9 @@ of layer ``i`` is :meth:`DecoderConfig.layer_kind`:
   part; keys and values from one latent a token, ``[c, k_r] = W_kva x``
   with ``c`` RMS-normed and ``k_r`` roped once for all heads, ``[k_nope, v]
   = W_kvb c``.  Rotary positions with YaRN frequencies, pairs ``(2i,
-  2i+1)``;
+  2i+1)``.  Under ``mla_scale_q_lora`` / ``mla_scale_kv_lora`` the query
+  is scaled by ``(hidden_size / q_lora_rank) ** 0.5`` and the normed latent
+  by ``(hidden_size / kv_lora_rank) ** 0.5`` (the roped key is not);
 - ``"gqa"`` and ``"kda"``, a hybrid keyed by ``gqa_layers`` and
   ``linear_attn_config`` (Solar-Open2, ``model_type: "solar_open2"``): the
   layers in ``gqa_layers`` are softmax attention without positions,
@@ -29,9 +37,10 @@ of layer ``i`` is :meth:`DecoderConfig.layer_kind`:
 
 The same ``__call__(input_ids, train, positions, decode, kv_cache)``
 contract as :class:`stoke_tpu.models.gpt.GPT`, and :meth:`Decoder.cache_spec`
-for ``ServingEngine``.  A latent model caches one row a token a layer
-(``kv_lora_rank + qk_rope_head_dim`` values, stored padded to whole
-128-lane tiles); a hybrid one a row of keys and values a token in its
+for ``ServingEngine``.  A latent model caches one row a token a latent
+sublayer (``kv_lora_rank + qk_rope_head_dim`` values, stored padded to whole
+128-lane tiles; a double layer has two sublayers, so two rows of the plane,
+``2 l`` and ``2 l + 1``); a hybrid one a row of keys and values a token in its
 ``gqa`` layers only and a constant state a slot in its ``kda`` layers
 (``CacheSpec.layer_kinds``, ``CacheSpec.state``).  With a cache hook each
 layer's attention is the hook's (``kv_cache.latent_attention(i)``: the row
@@ -112,6 +121,16 @@ class DecoderConfig:
     linear_head_dim: int = 0
     linear_conv_kernel: int = 4
     kda_allow_neg_eigval: bool = False
+    # the double-layer family: every layer two latent sublayers, two dense
+    # feed-forwards and a shortcut expert branch; its router's scoring, the
+    # zero-compute outputs after the routed ones, the learned bias on the
+    # choice; the two scales of its latent attention
+    shortcut_double_layers: bool = False
+    scoring_func: str = "sigmoid"
+    zero_expert_num: int = 0
+    router_choice_bias: bool = False
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
 
     @classmethod
     def from_dict(cls, config: dict) -> "DecoderConfig":
@@ -141,14 +160,18 @@ class DecoderConfig:
         if "gqa_layers" in config:
             kwargs.update(cls._hybrid_keys(config))
         else:
-            missing = [k for k in ("q_lora_rank", "kv_lora_rank",
-                                   "qk_nope_head_dim", "qk_rope_head_dim",
-                                   "v_head_dim", "n_group", "topk_group")
-                       if k not in config]
+            needed = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                      "qk_rope_head_dim", "v_head_dim")
+            if "attention_method" in config:
+                kwargs.update(cls._double_layer_keys(config))
+            else:
+                needed += ("n_group", "topk_group")
+            missing = [k for k in needed if k not in config]
             if missing:
                 raise ValueError(
                     f"latent attention needs {missing}: a configuration "
-                    f"without gqa_layers is the DeepSeek-V3 family's")
+                    f"without gqa_layers is the DeepSeek-V3 family's, or "
+                    f"with attention_method the double-layer family's")
         made = cls(**kwargs)
         if made.first_k_dense_replace and not made.intermediate_size:
             raise ValueError("the dense layers need intermediate_size")
@@ -188,6 +211,39 @@ class DecoderConfig:
             "linear_conv_kernel": int(linear["short_conv_kernel_size"]),
         }
 
+    @staticmethod
+    def _double_layer_keys(config: dict) -> dict:
+        """The double-layer family's keys (``num_layers``,
+        ``ffn_hidden_size``, ``expert_ffn_hidden_size``, ``moe_topk``,
+        ``zero_expert_num`` ...) under the fields the decoder reads, checked:
+        latent attention and identity zero-compute experts are what is
+        built here.  The family's defaults where the source is silent: a
+        softmax router without a bias on its logits, weights not
+        renormalised, a learned bias on the choice, no shared expert."""
+        if config["attention_method"] != "MLA":
+            raise ValueError(
+                f"attention_method {config['attention_method']!r}: the "
+                f"double layer's two attentions are latent attention here")
+        if config.get("zero_expert_type", "identity") != "identity":
+            raise ValueError(
+                f"zero_expert_type {config['zero_expert_type']!r}: a "
+                f"zero-compute expert here is the identity")
+        if config.get("router_bias", False):
+            raise ValueError("router_bias: the router's logits carry no "
+                             "bias here")
+        return {
+            "num_hidden_layers": int(config["num_layers"]),
+            "intermediate_size": int(config["ffn_hidden_size"]),
+            "moe_intermediate_size": int(config["expert_ffn_hidden_size"]),
+            "num_experts_per_tok": int(config["moe_topk"]),
+            "shortcut_double_layers": True,
+            "scoring_func": "softmax",
+            "router_choice_bias": True,
+            "norm_topk_prob": bool(config.get("norm_topk_prob", False)),
+            "n_shared_experts": 0,
+            "first_k_dense_replace": 0,
+        }
+
     def layer_kind(self, i: int) -> str:
         """Layer ``i``'s attention: ``"mla"``, ``"gqa"`` or ``"kda"``."""
         if self.gqa_layers is None:
@@ -212,6 +268,13 @@ class DecoderConfig:
     @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_sublayers(self) -> int:
+        """Latent-attention sublayers, each a row of the latent plane: two
+        a double layer, else one a layer."""
+        return self.num_hidden_layers * (
+            2 if self.shortcut_double_layers else 1)
 
     @property
     def latent_width(self) -> int:
@@ -283,9 +346,13 @@ def apply_rope(x, positions, cfg: DecoderConfig):
 
 
 class RMSNorm(nn.Module):
+    """``gain`` (a constant, not a parameter) multiplies the normed values
+    in float32, before they are rounded to ``dtype``."""
+
     eps: float = 1e-6
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
+    gain: float = 1.0
 
     @nn.compact
     def __call__(self, x):
@@ -293,7 +360,10 @@ class RMSNorm(nn.Module):
                            self.param_dtype)
         y = x.astype(jnp.float32)
         y = y * jax.lax.rsqrt((y * y).mean(-1, keepdims=True) + self.eps)
-        return (y * scale.astype(jnp.float32)).astype(self.dtype)
+        y = y * scale.astype(jnp.float32)
+        if self.gain != 1.0:
+            y = y * self.gain
+        return y.astype(self.dtype)
 
 
 # --------------------------------------------------------------------------- #
@@ -507,6 +577,13 @@ def delta_rule_chunked(q, k, v, g, beta):
 
 
 class LatentAttention(nn.Module):
+    """Multi-head latent attention (the module docstring's ``"mla"``).  The
+    two scales of ``mla_scale_q_lora`` / ``mla_scale_kv_lora`` ride on the
+    norms they follow: ``W_qb`` is linear, so ``s_q W_qb n = W_qb (s_q n)``,
+    and the latent is scaled before it is cached, so the cached row is
+    ``[s_kv RMSNorm(c~), rope(k_r)]`` and both forms of the attention read
+    it as it is."""
+
     cfg: DecoderConfig
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
@@ -515,6 +592,10 @@ class LatentAttention(nn.Module):
     @nn.compact
     def __call__(self, x, positions, attend=None):
         cfg = self.cfg
+        s_q = ((cfg.hidden_size / cfg.q_lora_rank) ** 0.5
+               if cfg.mla_scale_q_lora else 1.0)
+        s_kv = ((cfg.hidden_size / cfg.kv_lora_rank) ** 0.5
+                if cfg.mla_scale_kv_lora else 1.0)
         B, L, _ = x.shape
         H, dn, dr = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                      cfg.qk_rope_head_dim)
@@ -523,12 +604,13 @@ class LatentAttention(nn.Module):
         norm = partial(RMSNorm, cfg.rms_norm_eps, self.dtype,
                        self.param_dtype)
         q = dense(cfg.q_lora_rank, name="q_a")(x)
-        q = dense(H * cfg.qk_head_dim, name="q_b")(norm(name="q_a_norm")(q))
+        q = dense(H * cfg.qk_head_dim, name="q_b")(
+            norm(gain=s_q, name="q_a_norm")(q))
         q = q.reshape(B, L, H, cfg.qk_head_dim)
         q_nope = q[..., :dn]
         q_rope = apply_rope(q[..., dn:], positions[:, :, None], cfg)
         kv = dense(cfg.latent_width, name="kv_a")(x)
-        c = norm(name="kv_a_norm")(kv[..., : cfg.kv_lora_rank])
+        c = norm(gain=s_kv, name="kv_a_norm")(kv[..., : cfg.kv_lora_rank])
         k_rope = apply_rope(kv[..., cfg.kv_lora_rank:], positions, cfg)
         w_kvb = self.param(
             "kv_b", nn.initializers.lecun_normal(),
@@ -723,21 +805,81 @@ class DecoderLayer(nn.Module):
         if self.index < cfg.first_k_dense_replace:
             return h + SwiGLU(cfg.intermediate_size, self.dtype,
                               self.param_dtype, name="ffn")(x)
-        return h + ExpertShareFFN(
-            cfg.hidden_size, cfg.moe_intermediate_size,
-            cfg.n_routed_experts,
-            self.held_experts or (0, cfg.n_routed_experts),
-            cfg.num_experts_per_tok, cfg.n_group, cfg.topk_group,
-            cfg.routed_scaling_factor, cfg.norm_topk_prob,
-            cfg.n_shared_experts, self.dtype, self.param_dtype, name="ffn",
-        )(x)
+        return h + _expert_share(cfg, self.held_experts, self.dtype,
+                                 self.param_dtype, "ffn")(x)
+
+
+def _expert_share(cfg: DecoderConfig, held_experts, dtype, param_dtype,
+                  name: str) -> ExpertShareFFN:
+    """The expert layer ``cfg`` describes, holding ``held_experts``."""
+    return ExpertShareFFN(
+        cfg.hidden_size, cfg.moe_intermediate_size, cfg.n_routed_experts,
+        held_experts or (0, cfg.n_routed_experts), cfg.num_experts_per_tok,
+        cfg.n_group, cfg.topk_group, cfg.routed_scaling_factor,
+        cfg.norm_topk_prob, cfg.n_shared_experts, dtype, param_dtype,
+        cfg.scoring_func, cfg.router_choice_bias, cfg.zero_expert_num,
+        name=name,
+    )
+
+
+class ShortcutDoubleLayer(nn.Module):
+    """A double layer with a shortcut-connected expert branch: two
+    latent-attention sublayers (own weights each) and two dense SwiGLU
+    feed-forwards in series, and one expert layer that reads the first
+    sublayer's normed output and is added back only at the end::
+
+        a1 = h  + MLA_0(N_in0(h))
+        x1 = N_post0(a1)
+        m  = MoE(x1)
+        d1 = a1 + FFN_0(x1)
+        a2 = d1 + MLA_1(N_in1(d1))
+        x2 = N_post1(a2)
+        h' = a2 + FFN_1(x2) + m
+
+    ``attend`` is the cache hook's pair, sublayer ``j``'s at ``[j]``.
+    Nothing between ``m``'s definition and its use depends on it: in a
+    deployment its exchange runs behind the first dense feed-forward and
+    the second attention."""
+
+    cfg: DecoderConfig
+    held_experts: Optional[Tuple[int, int]] = None
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+    attention: str = "dense"
+
+    @nn.compact
+    def __call__(self, h, positions, attend=(None, None)):
+        cfg = self.cfg
+        norm = partial(RMSNorm, cfg.rms_norm_eps, self.dtype,
+                       self.param_dtype)
+
+        def mla(j, h):
+            with jax.named_scope("mla"):
+                return h + LatentAttention(
+                    cfg, self.dtype, self.param_dtype, self.attention,
+                    name=f"attn_{j}",
+                )(norm(name=f"attn_norm_{j}")(h), positions, attend[j])
+
+        def dense(j, x):
+            with jax.named_scope("dense"):
+                return SwiGLU(cfg.intermediate_size, self.dtype,
+                              self.param_dtype, name=f"ffn_{j}")(x)
+
+        a1 = mla(0, h)
+        x1 = norm(name="ffn_norm_0")(a1)
+        m = _expert_share(cfg, self.held_experts, self.dtype,
+                          self.param_dtype, "moe")(x1)
+        a2 = mla(1, a1 + dense(0, x1))
+        x2 = norm(name="ffn_norm_1")(a2)
+        return a2 + dense(1, x2) + m
 
 
 class Decoder(nn.Module):
     """A pre-norm decoder-only language model assembled from ``cfg``
     (:meth:`DecoderConfig.from_dict`): an attention kind a layer (latent;
     or grouped-query and delta-rule), SwiGLU, a dense or an expert
-    feed-forward per layer, an untied head.
+    feed-forward per layer, or double layers with a shortcut expert branch;
+    an untied head.
 
     Args:
         held_experts: ``(first, count)``: the routed experts of every
@@ -768,11 +910,22 @@ class Decoder(nn.Module):
             return 0
         return (self.held_experts or (0, cfg.n_routed_experts))[1]
 
+    @property
+    def zero_experts(self) -> int:
+        """Zero-compute outputs of each expert layer's router (0: none).  A
+        model with some also sows each token's count of picks among them,
+        and ``ServingEngine`` hands those back beside the held experts'."""
+        return self.cfg.zero_expert_num if self.experts_held else 0
+
+    @property
+    def experts_per_token(self) -> int:
+        return self.cfg.num_experts_per_tok
+
     def cache_spec(self) -> CacheSpec:
         cfg = self.cfg
         if cfg.gqa_layers is None:
             return CacheSpec(
-                layers=cfg.num_hidden_layers,
+                layers=cfg.latent_sublayers,
                 planes=(("latent", cfg.latent_row_width),),
                 values=cfg.latent_width,
                 kind="latent",
@@ -830,6 +983,15 @@ class Decoder(nn.Module):
         hook_method = {"mla": "latent_attention", "gqa": "layer_attention",
                        "kda": "layer_state"}
         for i in range(cfg.num_hidden_layers):
+            if cfg.shortcut_double_layers:
+                # sublayer j of double layer i is row 2 i + j of the plane
+                attend = (None, None) if kv_cache is None else tuple(
+                    kv_cache.latent_attention(2 * i + j) for j in (0, 1))
+                h = ShortcutDoubleLayer(
+                    cfg, self.held_experts, self.dtype, self.param_dtype,
+                    self.attention, name=f"layer_{i}",
+                )(h, positions, attend)
+                continue
             attend = (None if kv_cache is None else getattr(
                 kv_cache, hook_method[cfg.layer_kind(i)])(i))
             h = DecoderLayer(

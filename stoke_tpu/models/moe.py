@@ -172,6 +172,25 @@ def group_limited_topk(scores, n_group: int, topk_group: int, top_k: int,
     return experts.astype(jnp.int32), weights * scale
 
 
+def softmax_topk(logits, bias, top_k: int, scale: float = 1.0,
+                 norm: bool = False):
+    """Softmax routing with a choice bias (the LongCat-Flash family's):
+    ``logits [N, E]`` float32, one column a router output.  ``s =
+    softmax(logits)`` over all ``E``; the token's experts are the ``top_k``
+    largest of ``s + bias`` (``bias [E]`` or None: the score-correction bias
+    moves the choice and not the weight); its weights are the chosen ``s``,
+    unbiased, renormalised over the ``top_k`` when ``norm``, times
+    ``scale``.  Returns ``(experts [N, top_k] int32, weights [N, top_k]
+    float32)``.  Ties go to the lower index."""
+    scores = jax.nn.softmax(logits, axis=-1)
+    _, experts = jax.lax.top_k(
+        scores if bias is None else scores + bias, top_k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if norm:
+        weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), weights * scale
+
+
 class SwiGLU(nn.Module):
     """``W_d(silu(W_g x) * W_u x)``, no biases; products accumulate in
     float32 and come back in ``dtype``."""
@@ -197,26 +216,42 @@ class SwiGLU(nn.Module):
 class ExpertShareFFN(nn.Module):
     """One chip's share of a sparse expert layer: a router of the model's
     full width, the ``held = (first, count)`` routed experts that live here,
-    and the shared expert.
+    the shared experts if the model has any, and the zero-compute experts'
+    part if its router has such outputs.
 
-    Every token is routed over all ``num_experts`` (sigmoid scores,
-    :func:`group_limited_topk`).  Assignments to experts outside ``held``
-    are left out: their part of the sum is what the chip that holds them
-    would add, and nothing here stands in for it.  Assignments to held
-    experts are all computed, none dropped: the ``N * top_k`` assignments
-    are sorted by expert (absent ones last), the held experts' SwiGLU runs
-    as three grouped products over the sorted rows
-    (:mod:`stoke_tpu.ops.grouped_matmul`: gate and up in one kernel, down
-    in a second, each weight streamed once as stored; rows past the last
-    group belong to no expert, are not computed and are never read back),
-    and each token sums its own rows by the inverse permutation, weighted.
-    The shared expert is computed for every token.  Sows the
-    per-held-expert assignment counts (``int32[count]``) into the
-    ``intermediates`` collection as ``expert_counts``.
+    Every token is routed over all the router's outputs: ``num_experts``
+    routed experts and, after them, ``zero_experts`` zero-compute ones
+    (identity experts: an assignment to one adds ``weight * x`` and has no
+    weights).  ``scoring`` is ``"sigmoid"`` (:func:`group_limited_topk`) or
+    ``"softmax"`` (:func:`softmax_topk`, with the learned
+    ``e_score_correction_bias`` on the choice under ``choice_bias``).  An
+    assignment falls in one of three classes:
 
-    Router logits, sigmoid, top-k and weights are float32 (the logits at
+    - held (``first <= e < first + count``): all computed, none dropped.
+      The ``N * top_k`` assignments are sorted by expert (every other
+      assignment last), the held experts' SwiGLU runs as three grouped
+      products over the sorted rows (:mod:`stoke_tpu.ops.grouped_matmul`:
+      gate and up in one kernel, down in a second, each weight streamed
+      once as stored; rows past the last group belong to no expert, are not
+      computed and are never read back), and each token sums its own rows
+      by the inverse permutation, weighted;
+    - absent (another chip's routed expert): left out.  Its part of the sum
+      is what the chip that holds it would add, and nothing here stands in
+      for it;
+    - zero-compute (``e >= num_experts``): ``weight * x``, summed a token
+      over its such picks without a product; it needs no weight and no
+      exchange, so it is computed here for every token, like a shared
+      expert.
+
+    The shared expert (``n_shared_experts`` > 0) is computed for every
+    token.  Sows into the ``intermediates`` collection the per-held-expert
+    assignment counts (``int32[count]``) as ``expert_counts`` and, where the
+    router has zero-compute outputs, each token's count of picks among them
+    (``int32[N]``) as ``zero_expert_count``.
+
+    Router logits, scores, top-k and weights are float32 (the logits at
     ``Precision.HIGHEST``); expert products take ``dtype`` inputs and
-    accumulate in float32."""
+    accumulate in float32; the zero-compute part is summed in float32."""
 
     hidden: int
     ff: int
@@ -230,6 +265,9 @@ class ExpertShareFFN(nn.Module):
     n_shared_experts: int = 1
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
+    scoring: str = "sigmoid"
+    choice_bias: bool = False
+    zero_experts: int = 0
 
     @nn.compact
     def __call__(self, x):
@@ -242,20 +280,48 @@ class ExpertShareFFN(nn.Module):
                 f"ExpertShareFFN: held={self.held} is no range of the "
                 f"{self.num_experts} routed experts"
             )
+        if self.scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"ExpertShareFFN: scoring {self.scoring!r}")
+        if self.scoring == "sigmoid" and (self.choice_bias
+                                          or self.zero_experts):
+            raise ValueError(
+                "ExpertShareFFN: the sigmoid router has no choice bias and "
+                "no zero-compute outputs here")
+        if self.scoring == "softmax" and self.n_group != 1:
+            raise ValueError(
+                "ExpertShareFFN: the softmax router has no groups here")
         xf = x.reshape(N, H)
         with jax.named_scope("router"):
             w_r = self.param(
                 "router", nn.initializers.lecun_normal(),
-                (H, self.num_experts), jnp.float32,
+                (H, self.num_experts + self.zero_experts), jnp.float32,
             )
-            scores = jax.nn.sigmoid(jnp.dot(
+            logits = jnp.dot(
                 xf.astype(jnp.float32), w_r,
                 precision=jax.lax.Precision.HIGHEST,
-            ))
-            experts, weights = group_limited_topk(
-                scores, self.n_group, self.topk_group, k,
-                self.routed_scaling_factor, self.norm_topk_prob,
             )
+            if self.scoring == "sigmoid":
+                experts, weights = group_limited_topk(
+                    jax.nn.sigmoid(logits), self.n_group, self.topk_group,
+                    k, self.routed_scaling_factor, self.norm_topk_prob,
+                )
+            else:
+                bias = self.param(
+                    "e_score_correction_bias", nn.initializers.zeros,
+                    (self.num_experts + self.zero_experts,), jnp.float32,
+                ) if self.choice_bias else None
+                experts, weights = softmax_topk(
+                    logits, bias, k, self.routed_scaling_factor,
+                    self.norm_topk_prob,
+                )
+        zero_part = None
+        if self.zero_experts:
+            with jax.named_scope("zero"):
+                is_zero = experts >= self.num_experts  # [N, k]
+                zero_part = jnp.where(is_zero, weights, 0.0).sum(
+                    axis=1, keepdims=True) * xf.astype(jnp.float32)
+            self.sow("intermediates", "zero_expert_count",
+                     is_zero.sum(axis=1, dtype=jnp.int32))
         with jax.named_scope("moe"):
             local = experts - first
             is_held = (local >= 0) & (local < count)  # [N, k]
@@ -280,11 +346,15 @@ class ExpertShareFFN(nn.Module):
             # a row of no group holds whatever the grouped product left
             y = jnp.where(is_held[:, :, None], y, 0.0)
             routed = (y * weights[:, :, None]).sum(axis=1)
+            if zero_part is not None:
+                routed = routed + zero_part
             shared = SwiGLU(
                 self.ff * self.n_shared_experts, self.dtype,
                 self.param_dtype, name="shared",
-            )(x)
-            out = shared + routed.reshape(B, L, H).astype(self.dtype)
+            )(x) if self.n_shared_experts else None
+            out = routed.reshape(B, L, H).astype(self.dtype)
+            if shared is not None:
+                out = shared + out
         self.sow("intermediates", "expert_counts", counts)
         return out
 

@@ -190,6 +190,10 @@ class ServingEngine:
         #: model's ``experts_held``; 0 for a model with none): the decode
         #: program then hands their assignment counts back
         self._experts_held = int(getattr(model, "experts_held", 0))
+        #: zero-compute outputs of its routers (the model's
+        #: ``zero_experts``; 0 for none): the decode program then hands
+        #: back each row's count of picks among them too
+        self._zero_experts = int(getattr(model, "zero_experts", 0))
         # a latent or hybrid cache or an expert layer runs through the
         # greedy serve_prefill and serve_decode programs only, and states
         # its own compute dtype: its weights are served as given
@@ -356,7 +360,7 @@ class ServingEngine:
         )
         self.metrics.cache_bytes_per_token.set(self.cache.bytes_per_token)
         if self._experts_held:
-            self.metrics.enable_experts()
+            self.metrics.enable_experts(zero_compute=self._zero_experts > 0)
         if self.cache.state:
             self.metrics.enable_state()
         self.allocator = BlockAllocator(num_blocks, cfg.kv_block_size)
@@ -561,11 +565,17 @@ class ServingEngine:
             )
         if not expert_counts:
             return out
-        # what the expert layers sowed: int32[held] a layer
+        # what the expert layers sowed, a row a layer: ``expert_counts``
+        # int32[held] and, of a router with zero-compute outputs,
+        # ``zero_expert_count`` int32[rows]
         logits, sown = out
-        return logits, jnp.stack(
-            jax.tree_util.tree_leaves(sown["intermediates"])
-        )
+        by_name = {}
+        for path, leaf in jax.tree_util.tree_leaves_with_path(
+                sown["intermediates"]):
+            by_name.setdefault(path[-2].key, []).append(leaf)
+        names = ("expert_counts",) + (
+            ("zero_expert_count",) if self._zero_experts else ())
+        return logits, tuple(jnp.stack(by_name[name]) for name in names)
 
     def _split(self, args: tuple):
         """A serve program's arguments after the weights: the cache's
@@ -631,7 +641,8 @@ class ServingEngine:
         block_tables [B, MB]; context_lens [B].
         Returns (next tokens [B], updated arrays); a model with expert
         layers hands back their assignment counts (int32[expert layers,
-        held]) beside the tokens."""
+        held]) beside the tokens, and one whose routers have zero-compute
+        outputs each row's picks among them (int32[expert layers, B])."""
         pages, (tokens, positions, block_tables, context_lens) = (
             self._split(args)
         )
@@ -646,8 +657,7 @@ class ServingEngine:
         )
         counts = ()
         if counting:
-            logits, held_counts = logits
-            counts = (held_counts,)
+            logits, counts = logits
         return (
             jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32),
             *counts,
@@ -1284,6 +1294,8 @@ class ServingEngine:
                 if self._experts_held:
                     # the held experts' assignment counts ride beside
                     held_counts = np.asarray(out[1])
+                    if self._zero_experts:
+                        zero_counts = np.asarray(out[2])
                 if self._sampling:
                     # advance ONLY the decoding slots' key streams: a
                     # request's draw sequence depends on its own seed and
@@ -1318,8 +1330,11 @@ class ServingEngine:
         # MHA gather takes every slot's whole table), the bytes of per-slot
         # state the live slots' layers read and wrote and how often the
         # state layers' kernel moved them (its grid walks every slot) and,
-        # of an expert model, its held experts' load and how often their
-        # products streamed the weights
+        # of an expert model, its held experts' load, how often their
+        # products streamed the weights and, where its routers have
+        # zero-compute outputs, the share of the live rows' assignments
+        # that went to those (an idle slot's row is routed too, and left
+        # out here)
         tables, context = host_args[2], host_args[3][decode_rows]
         step_attrs = {
             "context_tokens": int(context.sum()),
@@ -1351,6 +1366,12 @@ class ServingEngine:
                 expert_load_max_over_mean=imbalance,
                 expert_weight_passes=passes,
             )
+            if self._zero_experts:
+                share = float(zero_counts[:, decode_rows].sum()) / max(
+                    len(decode_rows) * held_counts.shape[0]
+                    * self.model.experts_per_token, 1)
+                m.zero_expert_share.set(share)
+                step_attrs["zero_expert_share"] = share
         with trace_span("serve/commit", track="serve", attrs=step_attrs):
             n_sampled = sum(
                 1

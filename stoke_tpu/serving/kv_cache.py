@@ -500,11 +500,14 @@ class PagedAttentionHook:
 
 class LatentAttentionHook:
     """Per-trace cache bridge for a latent-attention model
-    (``Decoder(..., kv_cache=hook)``): one plane, one row a token a layer,
-    the normed latent and the roped shared key side by side.
+    (``Decoder(..., kv_cache=hook)``): one plane, one row a token a latent
+    sublayer, the normed latent and the roped shared key side by side.
 
-    ``latent_attention(i)`` returns layer ``i``'s ``attend(q_nope, q_rope,
-    c, k_rope, w_kvb, scale)``.  It writes the call's rows into the slot's
+    ``latent_attention(k)`` returns latent sublayer ``k``'s ``attend(q_nope,
+    q_rope, c, k_rope, w_kvb, scale)``: ``k`` is the row of the plane, a
+    model's layer index where a layer has one latent attention, ``2 l + j``
+    for sublayer ``j`` of a double layer ``l`` (``CacheSpec.layers`` counts
+    sublayers).  It writes the call's rows into the slot's
     blocks (the same steering as :class:`PagedAttentionHook`: padding and
     idle slots land in the scratch block), then attends: in ``"prefill"``
     mode the expanded form, causal over the padded prompt, through

@@ -173,19 +173,29 @@ class ServeMetrics:
         self.expert_assignments = None
         self.expert_load_max_over_mean = None
         self.expert_weight_passes = None
+        self.zero_expert_share = None
         # per-slot recurrent state: created by enable_state() for a model
         # whose cache keeps one, so no other engine registers the series
         self.state_passes = None
 
-    def enable_experts(self) -> None:
+    def enable_experts(self, zero_compute: bool = False) -> None:
         """Arm the expert-layer instruments, called at engine construction
         for a model whose ``experts_held`` is above 0:
         assignments the held experts computed, over all expert layers and
         decode steps, the last decode step's busiest held expert over
         the mean (1.0: even), and the bytes of held weight its grouped
-        products read over the bytes held (1.0: each streamed once)."""
+        products read over the bytes held (1.0: each streamed once); with
+        ``zero_compute`` (a router with zero-compute outputs) also the
+        share of the live tokens' assignments that went to those."""
         if self.expert_assignments is not None:
             return
+        if zero_compute:
+            self.zero_expert_share = self.registry.gauge(
+                "serve/zero_expert_share",
+                help="last decode step: the live tokens' assignments to "
+                "zero-compute experts over all their assignments (all "
+                "expert layers)",
+            )
         self.expert_assignments = self.registry.counter(
             "serve/expert_assignments_total",
             help="token-to-expert assignments computed by the held experts "

@@ -1,9 +1,10 @@
 """The config-driven decoder (MLA; grouped-query and delta-rule layers;
 expert share) and its serving path, on the CPU at a small size in float32,
-against the plain references the benchmark keeps for the two families
-(``benchmark/lib/families/axk1.py``, ``solar_open2.py``: ``jax.numpy`` at
-``highest`` precision, nothing of the program).  A test that holds for both
-families is one test with the family as its parameter.
+against the plain references the benchmark keeps for the three families
+(``benchmark/lib/families/axk1.py``, ``solar_open2.py``,
+``longcat_flash.py``: ``jax.numpy`` at ``highest`` precision, nothing of
+the program).  A test that holds for several families is one test with the
+family as its parameter.
 
 (a) the decoder's full forward against the reference; (b) prefill then decode
 through the paged cache (latent rows; rows beside per-slot state) and
@@ -40,7 +41,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark.lib.families import axk1, solar_open2  # noqa: E402
+from benchmark.lib.families import axk1, longcat_flash, solar_open2  # noqa: E402
 from stoke_tpu import ServeConfig  # noqa: E402
 from stoke_tpu.models import GPT, decoder  # noqa: E402
 from stoke_tpu.models.decoder import (  # noqa: E402
@@ -52,7 +53,11 @@ from stoke_tpu.models.decoder import (  # noqa: E402
     delta_rule_step,
     expanded_attention,
 )
-from stoke_tpu.models.moe import ExpertShareFFN, group_limited_topk  # noqa: E402
+from stoke_tpu.models.moe import (  # noqa: E402
+    ExpertShareFFN,
+    group_limited_topk,
+    softmax_topk,
+)
 from stoke_tpu.ops.delta_rule import delta_rule_reference  # noqa: E402
 from stoke_tpu.serving.engine import ServingEngine  # noqa: E402
 from stoke_tpu.serving.kv_cache import (  # noqa: E402
@@ -80,7 +85,15 @@ TINY = {**_read("benchmark/configs/axk1.json"),
 # of 32, 4 delta-rule heads of 32 x 32 state, 8 experts of which 4 are held
 TINY_SOLAR = {**_read("benchmark/configs/solar-open2.json"),
               **_read("tests/benchmark/rehearsal/configs/solar-open2.json")}
-FAMILIES = {"axk1": (axk1, TINY), "solar_open2": (solar_open2, TINY_SOLAR)}
+# and the third's: two double layers (four latent sublayers), hidden 128, a
+# softmax router over 8 routed + 4 zero-compute outputs, 3 a token, 4 held
+TINY_LONGCAT = {
+    **_read("benchmark/configs/longcat-flash-omni.json"),
+    **_read("tests/benchmark/rehearsal/configs/longcat-flash-omni.json")}
+FAMILIES = {"axk1": (axk1, TINY), "solar_open2": (solar_open2, TINY_SOLAR),
+            "longcat_flash": (longcat_flash, TINY_LONGCAT)}
+# the families whose cache is the latent plane
+LATENT = ["axk1", "longcat_flash"]
 
 
 @pytest.fixture(scope="module")
@@ -166,22 +179,63 @@ def test_config_keys_the_decoder_cannot_build_are_errors():
             DecoderConfig.from_dict({**base, key: value})
 
 
+def test_double_layer_keys_the_decoder_cannot_build_are_errors():
+    base = longcat_flash.program_config(TINY_LONGCAT)
+    cfg = DecoderConfig.from_dict(base)
+    # the source's names, under the fields the decoder reads
+    assert (cfg.num_hidden_layers, cfg.intermediate_size,
+            cfg.moe_intermediate_size, cfg.num_experts_per_tok) == (
+                2, 256, 64, 3)
+    assert cfg.shortcut_double_layers and cfg.latent_sublayers == 4
+    assert (cfg.scoring_func, cfg.zero_expert_num, cfg.n_shared_experts,
+            cfg.norm_topk_prob, cfg.router_choice_bias) == (
+                "softmax", 4, 0, False, True)
+    assert cfg.layer_kinds == ("mla", "mla")
+    for key, value, message in (
+            ("attention_method", "GQA", "attention_method"),
+            ("zero_expert_type", "copy", "zero_expert_type"),
+            ("router_bias", True, "router_bias"),
+            ("rope_scaling", {"type": "linear"}, "yarn")):
+        with pytest.raises(ValueError, match=message):
+            DecoderConfig.from_dict({**base, key: value})
+    with pytest.raises(ValueError, match="latent attention needs"):
+        DecoderConfig.from_dict(
+            {k: v for k, v in base.items() if k != "q_lora_rank"})
+
+
 # ------------------------------- (b) -------------------------------------- #
 
 BLOCK, BUCKET = 8, 16
 
 
+class _SwappedSublayers:
+    """A latent hook whose double layers' second sublayer reads and writes
+    the first's row of the plane, and the first the second's: the planted
+    addressing fault."""
+
+    def __init__(self, hook):
+        self._hook = hook
+
+    def latent_attention(self, k):
+        return self._hook.latent_attention(k ^ 1)
+
+
 @pytest.mark.parametrize("prompt_len", [5, 8, 16, 17, 23])
-def test_prefill_then_decode_logits_match_reference(tiny, prompt_len):
+@pytest.mark.parametrize("family", LATENT)
+def test_prefill_then_decode_logits_match_reference(built, family,
+                                                    prompt_len):
     """Prompts that end inside a block, on a block boundary, on a bucket
-    boundary and past both; logits of the prefill's last row and of every
-    decode step against the reference's full forward."""
-    model, params = tiny
+    boundary and past both (5 is shorter than its bucket of 16); logits of
+    the prefill's last row and of every decode step against the
+    reference's full forward.  A double layer's two sublayers keep two
+    rows of the plane."""
+    model, params = built[family]
     spec = model.cache_spec()
+    assert spec.layers == {"axk1": 3, "longcat_flash": 4}[family]
     rng = np.random.default_rng(prompt_len)
     new = 6
     seq = rng.integers(0, 512, prompt_len + new).astype(np.int32)
-    want = _reference_logits(params, seq[None])[0]
+    want = _reference_logits(params, seq[None], family)[0]
     cache = PagedKVCache(spec.layers, 9, BLOCK, planes=spec.planes)
     table = np.array([[3, 1, 7, 5]], np.int32)  # 4 blocks of 8: 32 positions
     padded = -(-prompt_len // BUCKET) * BUCKET
@@ -211,6 +265,24 @@ def test_prefill_then_decode_logits_match_reference(tiny, prompt_len):
         (pages,) = hook.pages
         np.testing.assert_allclose(np.asarray(logits[0, 0]), want[t],
                                    atol=2e-5, rtol=0)
+    if family != "longcat_flash":
+        return
+    # every sublayer wrote rows of its own, and the two of a double layer
+    # are distinct: a decode step that reads them swapped moves the logits
+    # a thousand tolerances (the rows are the same positions' latents of
+    # other weights, so the result stays a plausible logit row)
+    rows = np.asarray(pages)[:, 3, :5]
+    assert all(rows[k].any() for k in range(4))
+    assert not np.allclose(rows[0], rows[1], atol=1e-3)
+    t = prompt_len + new - 1
+    hook = LatentAttentionHook(
+        pages, jnp.asarray(table), jnp.array([[t]], jnp.int32),
+        mode="decode", lengths=jnp.array([t + 1], jnp.int32))
+    swapped = model.apply(
+        {"params": params}, seq[None, t:t + 1], train=False,
+        positions=jnp.array([[t]], jnp.int32), decode=True,
+        kv_cache=_SwappedSublayers(hook))
+    assert np.abs(np.asarray(swapped[0, 0]) - want[t]).max() > 2e-2
 
 
 @pytest.mark.parametrize("attention", ["dense", "flash"])
@@ -240,6 +312,12 @@ def test_engine_serves_the_reference_greedy_stream(built, family, attention):
     assert m.expert_assignments.value > 0
     assert m.expert_load_max_over_mean.value >= 1.0
     assert eng.allocator.used_blocks == 0
+    # a router with zero-compute outputs says what share of the live
+    # tokens' assignments went to them; no other engine has the series
+    if family == "longcat_flash":
+        assert 0.0 < m.zero_expert_share.value < 1.0
+    else:
+        assert m.zero_expert_share is None
 
 
 def test_decode_program_hands_back_the_held_experts_counts(tiny):
@@ -259,7 +337,28 @@ def test_decode_program_hands_back_the_held_experts_counts(tiny):
     assert 0 <= counts.sum() <= 8
 
 
-@pytest.mark.parametrize("family", ["latent", "mha", "hybrid"])
+def test_decode_program_hands_back_the_zero_compute_picks_a_row(built):
+    model, params = built["longcat_flash"]
+    assert (model.experts_held, model.zero_experts,
+            model.experts_per_token) == (4, 4, 3)
+    eng = ServingEngine(model, params, ServeConfig(
+        max_seqs=2, kv_block_size=BLOCK, max_seq_len=32,
+        prefill_pad_multiple=BUCKET))
+    eng.submit(np.arange(6, dtype=np.int32), 3)
+    eng.step()
+    tokens, positions, tables, context = eng.scheduler.decode_batch()
+    out = eng._decode_jit(params, *eng.cache.pages, tokens, positions,
+                          tables, context)
+    assert len(out) == 4  # tokens, held counts, zero picks, the one plane
+    held, zero = np.asarray(out[1]), np.asarray(out[2])
+    # a row an expert layer: the held experts' counts; each slot's picks
+    # among the zero-compute outputs, of its 3 a layer
+    assert held.shape == (2, 4) and zero.shape == (2, 2)
+    assert zero.dtype == np.int32 and (0 <= zero).all() and (zero <= 3).all()
+    assert (held.sum(axis=1) + zero.sum(axis=1) <= 2 * 3).all()
+
+
+@pytest.mark.parametrize("family", ["latent", "mha", "hybrid", "double"])
 def test_commit_span_says_what_the_decode_step_read(built, monkeypatch,
                                                     family):
     """``serve/commit`` carries the decode rows' ``context_tokens`` and the
@@ -279,7 +378,8 @@ def test_commit_span_says_what_the_decode_step_read(built, monkeypatch,
 
     monkeypatch.setattr(tracing, "xprof_span", fake_xprof_span)
     if family != "mha":
-        model, params = built["axk1" if family == "latent" else "solar_open2"]
+        model, params = built[{"latent": "axk1", "hybrid": "solar_open2",
+                               "double": "longcat_flash"}[family]]
     else:
         model = GPT(vocab_size=64, size_name="tiny", max_len=32)
         params = model.init(jax.random.PRNGKey(0),
@@ -321,6 +421,18 @@ def test_commit_span_says_what_the_decode_step_read(built, monkeypatch,
     else:
         assert not any("expert_weight_passes" in c for c in commits)
         assert eng.metrics.expert_weight_passes is None
+    # ``window_blocks`` above is ONE latent sublayer's, 2 + 3, though a
+    # double layer reads two rows of the plane; and a router with
+    # zero-compute outputs says what share of the live rows' assignments
+    # (2 rows x 3 a token x 2 expert layers) went to them
+    if family == "double":
+        assert all(0.0 <= c["zero_expert_share"] <= 1.0 for c in commits)
+        assert round(commits[-1]["zero_expert_share"] * 12, 6) % 1 == 0
+        assert any(c["zero_expert_share"] > 0 for c in commits)
+        assert eng.metrics.zero_expert_share.value == (
+            commits[-1]["zero_expert_share"])
+    else:
+        assert not any("zero_expert_share" in c for c in commits)
 
 
 # ------------------------------- (c) -------------------------------------- #
@@ -707,36 +819,52 @@ def test_hybrid_prefill_then_decode_logits_match_reference(tiny_solar):
 # ------------------------------- (d) -------------------------------------- #
 
 
-@pytest.mark.parametrize("family,shares,routing", [
-    ("axk1", 4, dict(n_group=2, topk_group=1, routed_scaling_factor=2.5)),
+@pytest.mark.parametrize("family,shares,top_k,layer,keys", [
+    ("axk1", 4, 2,
+     dict(n_group=2, topk_group=1, routed_scaling_factor=2.5), {}),
     # the second family's layer: one group (a plain top-k), factor 1, and
     # the deployment's 8 shares
-    ("solar_open2", 8, dict(n_group=1, topk_group=1,
-                            routed_scaling_factor=1.0)),
+    ("solar_open2", 8, 2,
+     dict(n_group=1, topk_group=1, routed_scaling_factor=1.0), {}),
+    # the third's: 4 shares of 8 over 32 routed experts + 16 zero-compute
+    # outputs, a softmax router with a choice bias, 6 a token not
+    # renormalised, no shared expert
+    ("longcat_flash", 4, 6,
+     dict(routed_scaling_factor=6.0, norm_topk_prob=False,
+          n_shared_experts=0, scoring="softmax", choice_bias=True,
+          zero_experts=16),
+     dict(routed_scaling_factor=6.0, zero_expert_num=16, moe_topk=6)),
 ])
-def test_all_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(
-        family, shares, routing):
+def test_all_shares_and_the_common_part_once_add_up_to_the_uncut_layer(
+        family, shares, top_k, layer, keys):
     """The guide's share test: the parts of the result all the shares of
-    one layer give, with the shared expert counted once, add up to what the
+    one layer give, with what every chip computes alike (the shared expert;
+    the zero-compute experts' part) counted once, add up to what the
     family's reference gives for the uncut layer."""
     module, tiny_config = FAMILIES[family]
-    E, hidden, ff = 2 * shares, 32, 16
+    per = 8 if family == "longcat_flash" else 2
+    E, hidden, ff = per * shares, 32, 16
     config = {**tiny_config, "hidden_size": hidden,
               "moe_intermediate_size": ff, "n_routed_experts": E,
               "published": {"n_routed_experts": E},
-              "deployment": {"first_expert": 0}, **routing}
-    kwargs = dict(hidden=hidden, ff=ff, num_experts=E, top_k=2, **routing)
+              "deployment": {"first_expert": 0},
+              **(keys or layer)}
+    kwargs = dict(hidden=hidden, ff=ff, num_experts=E, top_k=top_k, **layer)
     x = jnp.asarray(np.random.default_rng(2).standard_normal((3, 7, hidden)),
                     jnp.float32)
     whole = ExpertShareFFN(held=(0, E), **kwargs)
     params = whole.init(jax.random.PRNGKey(0), x)["params"]
+    if "e_score_correction_bias" in params:
+        # the size of a mean score, so that it moves the choice
+        params = {**params, "e_score_correction_bias": jnp.asarray(
+            np.random.default_rng(3).normal(0, 1 / 48, 48), jnp.float32)}
     flat = x.reshape(-1, hidden)
     with jax.default_matmul_precision("highest"):
         uncut = module.expert_ffn(config, params, flat, (0, E))
-        shared = axk1._swiglu(flat, *(params["shared"][n]["kernel"]
-                                      for n in ("gate", "up", "down")))
-    routed_sum, counted = 0.0, 0
-    per = E // shares
+        # no expert held: what every chip adds alike
+        common = module.expert_ffn(config, params, flat, (0, 0))
+    assert float(jnp.abs(common).max()) > 0.01
+    routed_sum, counted, zero_picks = 0.0, 0, []
     for s in range(shares):
         mine = {**params, **{n: params[n][s * per:(s + 1) * per]
                              for n in ("w_gate", "w_up", "w_down")}}
@@ -746,11 +874,21 @@ def test_all_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(
             want = module.expert_ffn(config, mine, flat, (s * per, per))
         np.testing.assert_allclose(np.asarray(out.reshape(-1, hidden)),
                                    np.asarray(want), atol=2e-5, rtol=0)
-        routed_sum = routed_sum + out.reshape(-1, hidden) - shared
+        routed_sum = routed_sum + out.reshape(-1, hidden) - common
         counted += int(sown["intermediates"]["expert_counts"][0].sum())
-    np.testing.assert_allclose(np.asarray(routed_sum + shared),
+        zero_picks.append(sown["intermediates"].get("zero_expert_count"))
+    np.testing.assert_allclose(np.asarray(routed_sum + common),
                                np.asarray(uncut), atol=5e-5, rtol=0)
-    assert counted == 3 * 7 * 2  # every assignment computed once, none dropped
+    if family == "longcat_flash":
+        # every share counts the same zero-compute picks: once
+        zero = np.asarray(zero_picks[0][0])
+        assert all((np.asarray(z[0]) == zero).all() for z in zero_picks)
+        assert zero.shape == (21,) and 0 < zero.sum() < 21 * top_k
+        counted += int(zero.sum())
+    else:
+        assert zero_picks == [None] * shares
+    # every assignment computed once, none dropped
+    assert counted == 3 * 7 * top_k
 
 
 # ------------------------------- (e) -------------------------------------- #
@@ -795,6 +933,75 @@ def test_router_matches_numpy_topk_with_groups_and_a_tie():
     np.testing.assert_allclose(dense, want_dense, rtol=1e-6)
 
 
+def test_softmax_router_chooses_by_the_biased_score_and_weighs_by_the_plain():
+    """The choice follows ``s + b``, the weights are ``6 s`` of the chosen,
+    unbiased and not renormalised; ties go to the lower index; the
+    reference's router gives the same as dense weights."""
+    rng = np.random.default_rng(13)
+    logits = rng.standard_normal((64, 24)).astype(np.float32)
+    logits[1, :] = 0.25                      # every score equal
+    logits[2, 5] = logits[2, 17] = 3.0       # a tie at the top
+    bias = rng.normal(0, 1 / 24, 24).astype(np.float32)
+    bias[7] = 1.0                            # always chosen, whatever s
+    bias[5] = bias[17] = 0.5                 # the tie stays one with them
+    got_e, got_w = softmax_topk(jnp.asarray(logits), jnp.asarray(bias), 4, 6.0)
+    z = np.exp(logits.astype(np.float64)
+               - logits.max(-1, keepdims=True).astype(np.float64))
+    scores = z / z.sum(-1, keepdims=True)
+    biased = (scores.astype(np.float32) + bias).astype(np.float64)
+    want_e = np.argsort(-biased, axis=-1, kind="stable")[:, :4]
+    np.testing.assert_array_equal(np.asarray(got_e), want_e)
+    np.testing.assert_allclose(
+        np.asarray(got_w), 6.0 * np.take_along_axis(scores, want_e, -1),
+        rtol=1e-5)
+    assert (np.asarray(got_e)[:, 0] == 7).all()
+    assert np.asarray(got_w)[1].sum() == pytest.approx(6 * 4 / 24, rel=1e-5)
+    # equal scores: the bias alone chooses; equal biased scores: the lower
+    assert list(np.asarray(got_e)[1]) == list(
+        np.argsort(-bias, kind="stable")[:4])
+    assert list(np.asarray(got_e)[2][:3]) == [7, 5, 17]
+    # without a bias the choice is by the score alone
+    plain_e, _ = softmax_topk(jnp.asarray(logits), None, 4, 6.0)
+    assert list(np.asarray(plain_e)[1]) == [0, 1, 2, 3]
+    assert list(np.asarray(plain_e)[2][:2]) == [5, 17]
+    dense = np.asarray(longcat_flash.route(
+        {"moe_topk": 4, "routed_scaling_factor": 6.0},
+        jnp.asarray(logits), jnp.asarray(bias)))
+    want_dense = np.zeros_like(dense)
+    np.put_along_axis(want_dense, want_e, np.asarray(got_w), axis=1)
+    np.testing.assert_allclose(dense, want_dense, rtol=1e-6)
+
+
+def test_a_token_with_only_zero_compute_picks_gets_its_weights_times_itself():
+    """Twelve picks, all among the zero-compute outputs (the bias puts them
+    first): the layer's result is ``sum w * x`` and nothing else, no held
+    expert draws a row, and the picks are counted a token."""
+    kwargs = dict(
+        hidden=32, ff=16, num_experts=16, held=(4, 8), top_k=12,
+        routed_scaling_factor=6.0, norm_topk_prob=False, n_shared_experts=0,
+        scoring="softmax", choice_bias=True, zero_experts=12)
+    layer = ExpertShareFFN(**kwargs)
+    x = jnp.asarray(np.random.default_rng(4).standard_normal((2, 5, 32)),
+                    jnp.float32)
+    params = layer.init(jax.random.PRNGKey(1), x)["params"]
+    assert "shared" not in params and params["router"].shape == (32, 28)
+    params = {**params, "e_score_correction_bias": jnp.asarray(
+        np.r_[np.zeros(16), np.ones(12)], jnp.float32)}
+    out, sown = layer.apply({"params": params}, x, mutable=["intermediates"])
+    with jax.default_matmul_precision("highest"):
+        scores = jax.nn.softmax(x.reshape(10, 32) @ params["router"], -1)
+    want = 6.0 * scores[:, 16:].sum(-1, keepdims=True) * x.reshape(10, 32)
+    np.testing.assert_allclose(np.asarray(out.reshape(10, 32)),
+                               np.asarray(want), atol=2e-6, rtol=0)
+    assert not np.asarray(sown["intermediates"]["expert_counts"][0]).any()
+    assert (np.asarray(sown["intermediates"]["zero_expert_count"][0])
+            == 12).all()
+    # what this layer is not built with raises
+    for bad in (dict(scoring="sigmoid"), dict(n_group=2), dict(scoring="x")):
+        with pytest.raises(ValueError, match="ExpertShareFFN"):
+            ExpertShareFFN(**{**kwargs, **bad}).init(jax.random.PRNGKey(1), x)
+
+
 # ------------------------------- (f) -------------------------------------- #
 
 
@@ -825,6 +1032,25 @@ def test_latent_pool_bytes_a_token_and_block_accounting(tiny):
     assert alloc.used_blocks == alloc.blocks_for(9 + 4) == 2
     eng.run()
     assert alloc.used_blocks == 0 and alloc.occupancy == 0.0
+
+
+def test_a_double_layer_model_keeps_a_row_of_the_plane_a_sublayer():
+    published = _read("benchmark/configs/longcat-flash-omni.json")
+    model = longcat_flash.build_model(published)
+    spec = model.cache_spec()
+    # one plane as axk1's, a row a latent sublayer: two a double layer
+    assert spec.kind == "latent" and spec.layers == 2 * 4
+    assert spec.planes == (("latent", 640),) and spec.values_per_token == 576
+    assert (spec.heads, spec.head_dim) == (64, 192)
+    assert spec.layer_kinds is None and spec.state == ()
+    assert (model.experts_held, model.zero_experts,
+            model.experts_per_token) == (8, 256, 12)
+    pool = PagedKVCache(spec.layers, 3, 16, dtype=jnp.bfloat16,
+                        planes=spec.planes)
+    assert [p.shape for p in pool.pages] == [(8, 3, 16, 640)]
+    assert pool.bytes_per_token == 8 * 640 * 2 and pool.state == ()
+    # the cell's pool: 160 slots of 3072 positions and the scratch block
+    assert 8 * (160 * 192 + 1) * 16 * 640 * 2 == 5_033_328_640
 
 
 def test_gpt_describes_its_cache_as_two_planes():
@@ -889,7 +1115,8 @@ def test_options_without_a_program_raise_naming_it(built, family, option,
     missing."""
     model, params = built[family]
     kind = model.cache_spec().kind
-    assert kind == {"axk1": "latent", "solar_open2": "hybrid"}[family]
+    assert kind == {"axk1": "latent", "solar_open2": "hybrid",
+                    "longcat_flash": "latent"}[family]
     with pytest.raises(NotImplementedError,
                        match=f"a {kind}-cache model with experts has no "
                              f"{program}"):
