@@ -38,7 +38,8 @@ The pieces:
 
 from __future__ import annotations
 
-from typing import List, Optional, Sequence
+from collections import deque
+from typing import Deque, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -66,6 +67,14 @@ class BlockAllocator:
     cap), so a mid-flight decode step can never fail on an empty pool;
     freed blocks return to the tail and are reused by later admissions
     (tests assert occupancy returns to 0 after drain).
+
+    The order of reuse is first in, first out: ``alloc`` takes from the
+    head, ``free`` appends to the tail in the order given, so a sequence of
+    calls hands out the same ids whatever the pool's size.  ``_free`` keeps
+    only that order; whether a block is free is one flag a block
+    (``_is_free``), so ``alloc`` and ``free`` cost the blocks of the one
+    request and nothing that grows with the pool: an eviction runs inside
+    the engine's step with the device idle and every other slot waiting.
     """
 
     def __init__(self, num_blocks: int, block_size: int):
@@ -78,7 +87,8 @@ class BlockAllocator:
             raise ValueError(f"block_size must be >= 1, got {block_size}")
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
-        self._free: List[int] = list(range(1, num_blocks))
+        self._free: Deque[int] = deque(range(1, num_blocks))
+        self._is_free = bytearray([0]) + bytearray([1]) * (num_blocks - 1)
 
     def blocks_for(self, n_tokens: int) -> int:
         """Blocks needed to hold ``n_tokens`` cache entries."""
@@ -109,16 +119,32 @@ class BlockAllocator:
         cannot supply them — the scheduler then keeps the request queued."""
         if n > len(self._free):
             return None
-        taken, self._free = self._free[:n], self._free[n:]
+        taken = [self._free.popleft() for _ in range(n)]
+        for b in taken:
+            self._is_free[b] = 0
         return taken
 
     def free(self, blocks: Sequence[int]) -> None:
-        for b in blocks:
+        """Give ``blocks`` back, to the tail in the order given.  Raises
+        ``ValueError`` and leaves the allocator as it found it when one of
+        them is the scratch block, no block of the pool, or free already
+        (twice in ``blocks`` included)."""
+        blocks = [int(b) for b in blocks]
+        for i, b in enumerate(blocks):
+            if 0 < b < self.num_blocks and not self._is_free[b]:
+                self._is_free[b] = 1
+                continue
+            for marked in blocks[:i]:
+                self._is_free[marked] = 0
             if b == SCRATCH_BLOCK:
-                raise ValueError("cannot free the reserved scratch block")
-            if b in self._free:
-                raise ValueError(f"double free of KV block {b}")
-            self._free.append(int(b))
+                why = "cannot free the reserved scratch block"
+            elif 0 < b < self.num_blocks:
+                why = f"double free of KV block {b}"
+            else:
+                why = (f"KV block {b} is outside the pool's "
+                       f"1..{self.num_blocks - 1}")
+            raise ValueError(why)
+        self._free.extend(blocks)
 
 
 class PagedKVCache:

@@ -4,6 +4,7 @@ discipline — all on the 8-device CPU mesh."""
 
 import json
 import os
+import time
 
 import numpy as np
 import pytest
@@ -99,6 +100,95 @@ def test_block_allocator_reuse_and_guards():
     a.free(again)
     with pytest.raises(ValueError):
         a.free([again[0], again[0]])  # double free
+
+
+class _ListAllocator:
+    """The allocator as it was until ISSUE 36, one plain list: the order of
+    reuse :class:`BlockAllocator` has to keep."""
+
+    def __init__(self, num_blocks):
+        self.free = list(range(1, num_blocks))
+
+    def alloc(self, n):
+        if n > len(self.free):
+            return None
+        taken, self.free = self.free[:n], self.free[n:]
+        return taken
+
+    def release(self, blocks):
+        self.free.extend(blocks)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2, 36])
+def test_block_allocator_keeps_the_order_of_reuse(seed):
+    # 64 slots of 3072 tokens in blocks of 16, requests of 5-192 blocks:
+    # the pool runs dry now and then, so Nones are part of the sequence
+    rng = np.random.default_rng(seed)
+    num_blocks = 64 * 24 + 1
+    a, model = BlockAllocator(num_blocks, 16), _ListAllocator(num_blocks)
+    live, refused = [], 0
+    for _ in range(4000):
+        if live and rng.random() < 0.5:
+            blocks = live.pop(int(rng.integers(len(live))))
+            a.free(blocks)
+            model.release(blocks)
+        else:
+            n = int(rng.integers(5, 193))
+            got = a.alloc(n)
+            assert got == model.alloc(n)
+            if got is None:
+                refused += 1
+            else:
+                live.append(got)
+        assert a.free_blocks == len(model.free)
+        assert a.used_blocks == sum(map(len, live))
+    assert refused  # the sequence held alloc's None
+    for blocks in live:
+        a.free(blocks)
+    assert a.occupancy == 0.0 and a.free_blocks == a.capacity
+
+
+@pytest.mark.parametrize(
+    "bad, match",
+    [
+        (lambda held, freed: [held[0], SCRATCH_BLOCK], "scratch"),
+        (lambda held, freed: [held[0], freed[0]], "double free"),
+        (lambda held, freed: [held[0], held[1], held[0]], "double free"),
+        (lambda held, freed: [held[0], 30], "double free"),
+        (lambda held, freed: [held[0], 32], "outside the pool"),
+        (lambda held, freed: [held[0], -1], "outside the pool"),
+    ],
+    ids=["scratch", "freed_in_an_earlier_call", "twice_in_one_call",
+         "never_allocated", "past_the_pool", "negative"],
+)
+def test_block_allocator_guards_leave_it_unchanged(bad, match):
+    a = BlockAllocator(num_blocks=32, block_size=8)
+    freed, held = a.alloc(4), a.alloc(6)
+    a.free(freed)
+    with pytest.raises(ValueError, match=match):
+        a.free(bad(held, freed))
+    # the failed call freed nothing, not even the good block before the
+    # bad one: what is free, and in which order, is as it was
+    assert a.free_blocks == 31 - 6
+    a.free(held)
+    assert a.alloc(31) == list(range(11, 32)) + freed + held
+
+
+def test_block_allocator_cost_is_the_requests_not_the_pools():
+    """300 evictions of 200 blocks and their re-admissions on a pool of a
+    million blocks, nearly all of them free.  At a list scan a block freed
+    that is 60,000 walks of a 940,000-entry list, tens of minutes; at a
+    flag a block it is tens of milliseconds, so the bound is no tight
+    clock: a hundred times what the work needs."""
+    a = BlockAllocator(num_blocks=1_000_001, block_size=16)
+    held = [a.alloc(200) for _ in range(300)]
+    t0 = time.perf_counter()
+    for i in range(300):
+        a.free(held[i])
+        held[i] = a.alloc(200)
+    elapsed = time.perf_counter() - t0
+    assert a.used_blocks == 300 * 200
+    assert elapsed < 5.0, f"{elapsed:.1f} s for 300 evictions"
 
 
 def test_allocator_blocks_for():
