@@ -112,6 +112,11 @@ from stoke_tpu.telemetry.tracing import (
 _KV_DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
 
 
+def _nbytes(arrays) -> int:
+    """Bytes of the host arrays one upload or one read moves."""
+    return sum(a.nbytes for a in arrays)
+
+
 def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
@@ -992,24 +997,30 @@ class ServingEngine:
         (the pre-ISSUE-13 path, sampling-aware when enabled)."""
         sched, m = self.scheduler, self.metrics
         t0 = time.perf_counter()
+        host_args = (
+            padded,
+            sched.block_tables[slot : slot + 1],
+            np.array([plen], np.int32),
+        )
+        if self._sampling:
+            host_args += self._sampling_scalar_args(req.params, slot)
+        if self.cache.state:
+            # a state row is addressed by slot, not through the table
+            host_args += (np.array([slot], np.int32),)
         with trace_span(
             "serve/prefill", track="serve", request_id=req.rid,
             attrs={
                 "padded_len": int(padded.shape[1]),
                 "prompt_len": int(plen),
                 "queue_wait_us": 1e6 * (req.admit_ts - req.arrival_ts),
+                # what is known at the opening: the bytes the upload will
+                # put on the device and the fetches the read will make
+                "upload_bytes": _nbytes(host_args),
+                "read_fetches": 1 + (
+                    1 + bool(self.capture_logits) if self._sampling else 0
+                ),
             },
         ):
-            host_args = (
-                padded,
-                sched.block_tables[slot : slot + 1],
-                np.array([plen], np.int32),
-            )
-            if self._sampling:
-                host_args += self._sampling_scalar_args(req.params, slot)
-            if self.cache.state:
-                # a state row is addressed by slot, not through the table
-                host_args += (np.array([slot], np.int32),)
             out = self._launch(
                 "serve/prefill", "serve_prefill", self._prefill_jit, host_args
             )
@@ -1291,25 +1302,34 @@ class ServingEngine:
             )
             with trace_span("serve/decode_step/read", track="serve"):
                 next_host = np.asarray(out[0])  # sync: tokens stream out
+                # the fetch above waited for the device; each one below is
+                # a round trip more with the device already idle
+                t_first = time.perf_counter()
+                fetched = [next_host]
                 if self._experts_held:
                     # the held experts' assignment counts ride beside
                     held_counts = np.asarray(out[1])
+                    fetched.append(held_counts)
                     if self._zero_experts:
                         zero_counts = np.asarray(out[2])
+                        fetched.append(zero_counts)
                 if self._sampling:
                     # advance ONLY the decoding slots' key streams: a
                     # request's draw sequence depends on its own seed and
                     # token count, never on who else rode the batch
                     kd = np.asarray(out[1])
+                    fetched.append(kd)
                     for i in decode_rows:
                         self._key_data[i] = kd[i]
                     if self.capture_logits:
                         larr = np.asarray(out[2])
+                        fetched.append(larr)
                         for i in decode_rows:
                             rid = sched.slots[i].request.rid
                             self.captured_logits.setdefault(rid, []).append(
                                 larr[i].copy()
                             )
+                read_extra_s = time.perf_counter() - t_first
         now = time.perf_counter()
         if live_rids:
             # per-request decode slices: every live request's timeline
@@ -1342,6 +1362,14 @@ class ServingEngine:
                 tables.size if self._spec.kind == "mha"
                 else int((-(-context // self.cfg.kv_block_size)).sum())
             ),
+            # the step's round trip, counted where it happened: what the
+            # read fetched and what its fetches after the first cost, and
+            # what the upload put on the device
+            "read_fetches": len(fetched),
+            "read_bytes": _nbytes(fetched),
+            "read_extra_us": 1e6 * read_extra_s,
+            "upload_arrays": len(host_args),
+            "upload_bytes": _nbytes(host_args),
         }
         if self.cache.state:
             passes = state_passes(len(decode_rows), self.cfg.max_seqs)
@@ -1372,6 +1400,9 @@ class ServingEngine:
                     * self.model.experts_per_token, 1)
                 m.zero_expert_share.set(share)
                 step_attrs["zero_expert_share"] = share
+        # everything since the read returned, this dictionary included, is
+        # the step's own accounting: paid every step, with the device idle
+        step_attrs["account_us"] = 1e6 * (time.perf_counter() - now)
         with trace_span("serve/commit", track="serve", attrs=step_attrs):
             n_sampled = sum(
                 1

@@ -358,14 +358,11 @@ def test_decode_program_hands_back_the_zero_compute_picks_a_row(built):
     assert (held.sum(axis=1) + zero.sum(axis=1) <= 2 * 3).all()
 
 
-@pytest.mark.parametrize("family", ["latent", "mha", "hybrid", "double"])
-def test_commit_span_says_what_the_decode_step_read(built, monkeypatch,
-                                                    family):
-    """``serve/commit`` carries the decode rows' ``context_tokens`` and the
-    ``window_blocks`` one layer's attention read for them: the paged kernel
-    of a latent or hybrid cache reads to each slot's own length, the MHA
-    gather takes every slot's whole table.  A model with per-slot state
-    also says how many bytes of it the live slots' layers read and wrote."""
+def _engine_under_a_listening_profiler(built, monkeypatch, family, **config):
+    """An engine of three slots of four blocks for ``family`` (``"mha"``: a
+    tiny GPT; ``"latent"``, ``"hybrid"``, ``"double"``: the three decoder
+    families) and the list every span it opens lands in with its
+    attributes, in place of the profiler's annotation."""
     import contextlib
 
     from stoke_tpu.telemetry import tracing
@@ -386,7 +383,19 @@ def test_commit_span_says_what_the_decode_step_read(built, monkeypatch,
                             jnp.zeros((1, 8), jnp.int32))["params"]
     eng = ServingEngine(model, params, ServeConfig(
         max_seqs=3, kv_block_size=BLOCK, max_seq_len=32,
-        prefill_pad_multiple=BUCKET))
+        prefill_pad_multiple=BUCKET, **config))
+    return eng, seen
+
+
+@pytest.mark.parametrize("family", ["latent", "mha", "hybrid", "double"])
+def test_commit_span_says_what_the_decode_step_read(built, monkeypatch,
+                                                    family):
+    """``serve/commit`` carries the decode rows' ``context_tokens`` and the
+    ``window_blocks`` one layer's attention read for them: the paged kernel
+    of a latent or hybrid cache reads to each slot's own length, the MHA
+    gather takes every slot's whole table.  A model with per-slot state
+    also says how many bytes of it the live slots' layers read and wrote."""
+    eng, seen = _engine_under_a_listening_profiler(built, monkeypatch, family)
     eng.submit(np.arange(9, dtype=np.int32), 4)
     eng.submit(np.arange(17, dtype=np.int32), 4)
     while not any(name == "serve/commit" and stats["context_tokens"] == 30
@@ -433,6 +442,104 @@ def test_commit_span_says_what_the_decode_step_read(built, monkeypatch,
             commits[-1]["zero_expert_share"])
     else:
         assert not any("zero_expert_share" in c for c in commits)
+
+
+@pytest.mark.parametrize("family,sampling,fetches", [
+    ("mha", False, 1), ("latent", False, 2), ("hybrid", False, 2),
+    ("double", False, 3), ("mha", True, 2),
+])
+def test_commit_span_counts_the_steps_round_trip(built, monkeypatch, family,
+                                                 sampling, fetches):
+    """``serve/commit`` says what the step's round trip was made of: the
+    fetches of the read (the tokens; the held experts' counts; the
+    zero-compute picks; key data when sampling) with their bytes and what
+    the fetches after the first cost, the arrays the upload put on the
+    device with theirs, and what the step's own accounting cost between the
+    read's return and the commit.  ``serve/prefill`` says at its opening
+    what it will upload and fetch."""
+    eng, seen = _engine_under_a_listening_profiler(
+        built, monkeypatch, family, sampling=sampling)
+    eng.submit(np.arange(9, dtype=np.int32), 4)
+    eng.submit(np.arange(17, dtype=np.int32), 4)
+    eng.run()
+    commits = [stats for name, stats in seen if name == "serve/commit"]
+    assert len(commits) >= 3
+    B, MB, int32 = 3, 4, 4
+    read = B * int32  # the tokens, one a slot
+    layers = {"mha": 0, "latent": 2, "hybrid": 4, "double": 2}[family]
+    read += layers * 4 * int32  # 4 held experts' counts an expert layer
+    if family == "double":
+        read += layers * B * int32  # each slot's zero-compute picks a layer
+    # tokens, positions, block tables, context lengths
+    uploads, upload = 4, 3 * B * int32 + B * MB * int32
+    if sampling:
+        key_data = eng._key_data
+        read += key_data.nbytes
+        # key data, temperatures, top-ks, top-ps
+        uploads, upload = 8, upload + key_data.nbytes + 3 * B * 4
+    for c in commits:
+        assert c["read_fetches"] == fetches and c["read_bytes"] == read
+        assert c["upload_arrays"] == uploads and c["upload_bytes"] == upload
+        assert isinstance(c["read_extra_us"], float)
+        assert isinstance(c["account_us"], float)
+        # two clock readings apart at least, and no step of this size
+        # spends a quarter second on either
+        assert 0 < c["read_extra_us"] < 250e3 and 0 < c["account_us"] < 250e3
+    prefills = [stats for name, stats in seen if name == "serve/prefill"]
+    assert [p["prompt_len"] for p in prefills] == [9, 17]
+    for p in prefills:
+        # the padded prompt, the slot's table row, its length, a state
+        # model's slot; when sampling the slot's key data and three knobs
+        want = p["padded_len"] * int32 + MB * int32 + int32
+        want += int32 * (family == "hybrid")
+        want += (key_data[:1].nbytes + 3 * 4) if sampling else 0
+        assert p["upload_bytes"] == want
+        assert p["read_fetches"] == 1 + sampling
+    # no new span: the decode step's tree is what it was, the commit bare
+    names = {name for name, _ in seen}
+    assert names == {
+        "serve/step", "serve/admit", "serve/prefill", "serve/prefill/upload",
+        "serve/prefill/dispatch", "serve/prefill/read", "serve/decode_step",
+        "serve/decode_step/batch", "serve/decode_step/upload",
+        "serve/decode_step/dispatch", "serve/decode_step/read",
+        "serve/commit", "serve/gauges"}
+
+
+# sha256 (its first 16 digits) of each program's StableHLO as it lowered at
+# PR 36 (e5636b3) for the engine of three slots above: PR 37 put counters
+# around the dispatch and the read and left the programs alone.  A PR that
+# changes a program on purpose records the new digest here.
+LOWERED = {
+    ("latent", "serve_decode"): "c211a2049c4993ec",
+    ("latent", "serve_prefill"): "87de03baaba77ccd",
+    ("hybrid", "serve_decode"): "95109edbc7f339f8",
+    ("hybrid", "serve_prefill"): "cc0829215b8ae2b1",
+    ("double", "serve_decode"): "26701ad972a93506",
+    ("double", "serve_prefill"): "f0167de255ba8a8b",
+    ("mha", "serve_decode"): "b73d1d664954d802",
+    ("mha", "serve_prefill"): "641004fed3c148bb",
+}
+
+
+@pytest.mark.parametrize("family,program", list(LOWERED))
+def test_serve_programs_lower_to_the_stablehlo_they_did(built, monkeypatch,
+                                                        family, program):
+    import hashlib
+
+    eng, _ = _engine_under_a_listening_profiler(built, monkeypatch, family)
+    batch = eng.scheduler.decode_batch()
+    if program == "serve_decode":
+        jitted, args = eng._decode_jit, batch
+    else:
+        jitted = eng._prefill_jit
+        args = (np.zeros((1, BUCKET), np.int32), batch[2][:1],
+                np.array([5], np.int32))
+        if eng.cache.state:
+            args += (np.array([0], np.int32),)
+    text = jitted.lower(eng.qparams, *eng.cache.pages, *eng.cache.state,
+                        *args).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == LOWERED[
+        family, program]
 
 
 # ------------------------------- (c) -------------------------------------- #
