@@ -54,13 +54,30 @@ decides how many draws one dispatch keeps).  The same multi-token-query
 shape packs all prefilling slots' chunks into one dispatch
 (``serve_prefill_chunk_packed``).  ``speculative_k=None`` engines compile
 the PR-13 programs verbatim.
+
+One decode step in flight (ISSUE 38): a greedy engine dispatches decode
+step n+1 BEFORE it reads step n, so the runtime always holds the next
+program when the running one ends and the host's read, commit, admission
+and upload run under the device's work.  The next token never leaves the
+device on its way to the next program (the decode program takes the rows
+the host has not read from the vector the last step left there; a prefill's
+first token is written into that vector on the device), every output the
+commit reads is started home at dispatch, and a request is finished only
+when its last token is on the host.  The lag is a number the engine derives
+at construction, ``ServingEngine._lag``: 1 for greedy engines; 0, today's
+read-then-dispatch order of the same code, where the host must see a step's
+result to build the next (sampling: the key streams live on the host;
+speculative verification: how many drafts were accepted decides the next
+positions).
 """
 
 from __future__ import annotations
 
 import contextlib
 import time
-from typing import Any, Dict, List, Optional, Sequence
+from collections import deque
+from dataclasses import dataclass
+from typing import Any, Callable, Deque, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -92,7 +109,7 @@ from stoke_tpu.serving.sampling import (
     split_key_data,
     validate_sampling_params,
 )
-from stoke_tpu.serving.scheduler import Request, Scheduler
+from stoke_tpu.serving.scheduler import FROM_DEVICE, Request, Scheduler
 from stoke_tpu.serving.slo import (
     RequestSLO,
     SLOTracker,
@@ -119,6 +136,16 @@ def _nbytes(arrays) -> int:
 
 def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
+
+
+@dataclass
+class _DecodeInFlight:
+    """One decode step between its dispatch and its commit."""
+
+    out: list  # what the commit reads of it, on the device
+    host_args: tuple  # the batch as uploaded
+    rows: list  # the (slot, rid) pairs that rode it
+    t0: float  # when the host began to build it
 
 
 class ServingEngine:
@@ -393,6 +420,21 @@ class ServingEngine:
             else None
         )
         self._sampling = bool(cfg.sampling)
+        #: decode steps the dispatch runs ahead of the read: 1 unless the
+        #: host must see a step's result to build the next.  A sampling
+        #: engine advances its key streams on the host from what the step
+        #: hands back, and a speculative one (sampling too) learns from the
+        #: verify step how many drafts were accepted, which sets the next
+        #: positions.  No configuration sets it.
+        self._lag = 0 if self._sampling else 1
+        #: the last decode step's tokens, on the device, with each fresh
+        #: prefill's first token written in (``_first_token_jit``): what
+        #: the next step feeds the rows whose token the host has not read
+        self._prev_tokens = jnp.zeros((cfg.max_seqs,), jnp.int32)
+        self._inflight: Deque[_DecodeInFlight] = deque()
+        # (slot, request, read) of the prefill programs dispatched in this
+        # step and not yet read
+        self._awaited: List[tuple] = []
         # config-level default knobs (requests may override per-submit);
         # greedy when sampling is off — those engines never consult them
         self._default_sampling = (
@@ -457,6 +499,14 @@ class ServingEngine:
             if cfg.prefill_chunk_tokens is not None
             else None
         )
+
+        def serve_first_token(tokens, token, slot):
+            return jax.lax.dynamic_update_slice(tokens, token, (slot,))
+
+        # a prefill's first token [1] into the slot's row of the token
+        # vector, without a host read between the prefill and the decode
+        # step behind it
+        self._first_token_jit = jax.jit(serve_first_token)
         # speculative decoding (ISSUE 17): the verify program replaces the
         # per-token decode program, and chunk packing replaces the
         # one-chunk-per-iteration schedule with the same multi-token-query
@@ -643,14 +693,19 @@ class ServingEngine:
 
     def _decode_fn(self, qparams, *args):
         """After the weights and the cache's arrays: tokens/positions [B];
-        block_tables [B, MB]; context_lens [B].
+        block_tables [B, MB]; context_lens [B]; then, where a step is
+        dispatched before the one before it is read, that step's tokens
+        [B], still on the device: a row whose token the host has not seen
+        (``scheduler.FROM_DEVICE``) takes its token from there.
         Returns (next tokens [B], updated arrays); a model with expert
         layers hands back their assignment counts (int32[expert layers,
         held]) beside the tokens, and one whose routers have zero-compute
         outputs each row's picks among them (int32[expert layers, B])."""
-        pages, (tokens, positions, block_tables, context_lens) = (
+        pages, (tokens, positions, block_tables, context_lens, *prev) = (
             self._split(args)
         )
+        if prev:
+            tokens = jnp.where(tokens == FROM_DEVICE, prev[0], tokens)
         params = self._weights(qparams)
         hook = self._make_hook(
             pages, block_tables, positions[:, None], "decode", context_lens
@@ -854,6 +909,8 @@ class ServingEngine:
                 jax.ShapeDtypeStruct((B,), i32),  # top_ks
                 jax.ShapeDtypeStruct((B,), jnp.float32),  # top_ps
             )
+        else:
+            args += (abstract(self._prev_tokens),)
         return args
 
     def _dispatch(self, program: str, fn, args: tuple):
@@ -872,14 +929,16 @@ class ServingEngine:
             fn = cc.executable(program, (program, self._sig(args)), fn, args)
         return fn(*args)
 
-    def _upload(self, host_args: tuple) -> tuple:
+    def _upload(self, host_args: tuple, *on_device) -> tuple:
         """A serve program's arguments: the weights, the cache's arrays,
-        and the numpy ``host_args`` put on the device."""
+        the numpy ``host_args`` put on the device, and what is
+        ``on_device`` already."""
         return (
             self.qparams,
             *self.cache.pages,
             *self.cache.state,
             *map(jnp.asarray, host_args),
+            *on_device,
         )
 
     def _run(self, program: str, fn, args: tuple) -> list:
@@ -894,14 +953,15 @@ class ServingEngine:
         cache.state = tuple(out[first + n:])
         return list(out[:first])
 
-    def _launch(self, span: str, program: str, fn, host_args: tuple) -> list:
+    def _launch(self, span: str, program: str, fn, host_args: tuple,
+                *on_device) -> list:
         """:meth:`_upload` and :meth:`_run`, each under its own child of
         ``span`` (``<span>/upload``, ``<span>/dispatch``).  The caller
         fetches what comes back under ``<span>/read``, so a profiler trace
         tells the blocking read from the upload and from the enqueue.  For
         the two sites the benchmark's serve cell runs (prefill, decode)."""
         with trace_span(f"{span}/upload", track="serve"):
-            args = self._upload(host_args)
+            args = self._upload(host_args, *on_device)
         with trace_span(f"{span}/dispatch", track="serve"):
             return self._run(program, fn, args)
 
@@ -992,9 +1052,56 @@ class ServingEngine:
         if req.finished:
             self._finish(req)
 
+    def _first_token_dispatched(self, slot: int, token) -> None:
+        """The program that makes the slot's first token (``token`` [1], on
+        the device) is on its way: the slot rides the next decode step on
+        that count, and where that step is dispatched before the token is
+        read, the token goes into the slot's row of the vector the step
+        feeds from, on the device."""
+        self.scheduler.note_prefill_dispatched(slot)
+        if self._lag:
+            self._prev_tokens = self._first_token_jit(
+                self._prev_tokens, token, np.int32(slot)
+            )
+
+    def _read_or_await(self, slot, req, token,
+                       read: Callable[[], Optional[int]]) -> Optional[int]:
+        """A prefill program is dispatched; ``read()`` blocks for it and
+        returns the first token it made (None for a chunk that makes
+        none).  At lag 0 read now; at lag 1 start ``token`` home and leave
+        the read to :meth:`_settle`, behind the decode step's dispatch."""
+        if not self._lag:
+            return read()
+        token.copy_to_host_async()
+        self._awaited.append((slot, req, read))
+        return None
+
+    def _settle(self) -> None:
+        """Read what this step's prefill programs made, in dispatch order:
+        each first token is emitted when the host has it.  Runs after the
+        decode step behind them is dispatched and the one before is
+        committed, so the device has D(k-1), the prefills and D(k) back to
+        back while the host waits here.  The wait is a span of the ring
+        only (``serve/prefill_wait`` on the request's row): the profiler's
+        ``serve/step`` keeps the children its readers know."""
+        m = self.metrics
+        for slot, req, read in self._awaited:
+            t0 = time.perf_counter()
+            with trace_span("serve/prefill_wait", track="serve",
+                            request_id=req.rid, annotate=False):
+                tok_host = read()
+            now = time.perf_counter()
+            m.prefill_s.inc(now - t0)
+            if tok_host is not None:
+                self._emit_first_token(slot, req, tok_host, now)
+        self._awaited.clear()
+
     def _prefill_one(self, slot, req, padded, plen) -> None:
         """Unchunked prefill: one program over the bucket-padded prompt
-        (the pre-ISSUE-13 path, sampling-aware when enabled)."""
+        (the pre-ISSUE-13 path, sampling-aware when enabled).  At lag 0
+        its read waits for the first token and emits it; at lag 1 the read
+        only starts the token home, and :meth:`_settle` emits it once the
+        decode step behind the prefill is dispatched."""
         sched, m = self.scheduler, self.metrics
         t0 = time.perf_counter()
         host_args = (
@@ -1007,6 +1114,16 @@ class ServingEngine:
         if self.cache.state:
             # a state row is addressed by slot, not through the table
             host_args += (np.array([slot], np.int32),)
+
+        def read() -> int:
+            if self._sampling:
+                self._key_data[slot] = np.asarray(out[1])[0]
+                if self.capture_logits:
+                    self.captured_logits.setdefault(req.rid, []).append(
+                        np.asarray(out[2])[0].copy()
+                    )
+            return int(np.asarray(out[0])[0])  # sync: the TTFT point
+
         with trace_span(
             "serve/prefill", track="serve", request_id=req.rid,
             attrs={
@@ -1024,28 +1141,40 @@ class ServingEngine:
             out = self._launch(
                 "serve/prefill", "serve_prefill", self._prefill_jit, host_args
             )
+            self._first_token_dispatched(slot, out[0])
             with trace_span("serve/prefill/read", track="serve"):
-                if self._sampling:
-                    self._key_data[slot] = np.asarray(out[1])[0]
-                    if self.capture_logits:
-                        self.captured_logits.setdefault(req.rid, []).append(
-                            np.asarray(out[2])[0].copy()
-                        )
-                tok_host = int(np.asarray(out[0])[0])  # sync: the TTFT point
+                tok_host = self._read_or_await(slot, req, out[0], read)
         now = time.perf_counter()
         m.prefills.inc()
         m.prefill_s.inc(now - t0)
-        self._emit_first_token(slot, req, tok_host, now)
+        if tok_host is not None:
+            self._emit_first_token(slot, req, tok_host, now)
 
     def _run_chunk(self, slot, req, toks, positions, is_final,
                    logit_idx) -> None:
         """One chunked-prefill step (ISSUE 13): dispatch the fixed-shape
         chunk program for ``slot``; the final chunk produces the TTFT
-        token.  Only the final chunk syncs to host and advances the
-        request's key stream — one split per emitted token, the same
-        recurrence as unchunked prefill."""
+        token.  Every chunk is waited for, at lag 1 in :meth:`_settle`;
+        only the final chunk advances the request's key stream, one split
+        per emitted token, the same recurrence as unchunked prefill."""
         sched, m = self.scheduler, self.metrics
         t0 = time.perf_counter()
+
+        def read() -> Optional[int]:
+            # EVERY chunk syncs (one [1] token fetch): dispatch is async,
+            # and without the sync the chunk's compute would be charged to
+            # a later decode step's fetch — the serve/prefill_chunk spans
+            # and the prefill goodput bucket must own their real wall
+            tok_host = int(np.asarray(tok)[0])
+            if not is_final:
+                return None
+            self._key_data[slot] = np.asarray(key_out)[0]
+            if self.capture_logits:
+                self.captured_logits.setdefault(req.rid, []).append(
+                    np.asarray(row)[0].copy()
+                )
+            return tok_host
+
         with trace_span(
             "serve/prefill_chunk", track="serve", request_id=req.rid,
             attrs={
@@ -1064,21 +1193,16 @@ class ServingEngine:
                     np.array([logit_idx], np.int32),
                 ) + self._sampling_scalar_args(req.params, slot)),
             )
-            # EVERY chunk syncs (one [1] token fetch): dispatch is async,
-            # and without the sync the chunk's compute would be charged to
-            # the NEXT decode step's fetch — the serve/prefill_chunk spans
-            # and the prefill goodput bucket must own their real wall
-            tok_host = int(np.asarray(tok)[0])
+            # the cursor moves at dispatch: a final chunk's slot rides the
+            # decode step built next
+            sched.note_chunk(slot)
+            if is_final:
+                self._first_token_dispatched(slot, tok)
+            tok_host = self._read_or_await(slot, req, tok, read)
         now = time.perf_counter()
         m.prefill_chunks.inc()
         m.prefill_s.inc(now - t0)
-        sched.note_chunk(slot)
-        if is_final:
-            self._key_data[slot] = np.asarray(key_out)[0]
-            if self.capture_logits:
-                self.captured_logits.setdefault(req.rid, []).append(
-                    np.asarray(row)[0].copy()
-                )
+        if tok_host is not None:
             self._emit_first_token(slot, req, tok_host, now)
 
     def _run_packed_chunks(self, tokens, positions, tables, lengths,
@@ -1125,6 +1249,7 @@ class ServingEngine:
                 )
             sched.note_chunk(i)
             if is_final:
+                sched.note_prefill_dispatched(i)
                 self._key_data[i] = kd[i]
                 if larr is not None:
                     self.captured_logits.setdefault(req.rid, []).append(
@@ -1213,7 +1338,16 @@ class ServingEngine:
         prefill chunk, then one decode step over the fully-prefilled slot
         batch.  Bounding per-iteration prefill work by the chunk size is
         what keeps in-flight TPOT flat while a long prompt admits.
-        Returns True while work remains.
+        Returns True while work remains, a decode step in flight included.
+
+        At lag 1 (greedy engines) the decode step this call dispatches is
+        read by the NEXT call: a call hands the caller the tokens of the
+        decode step dispatched one call earlier and the first token of
+        every prefill dispatched in this one, and a request is ``finished``
+        when its last token is on the host.  Order inside the call: admit;
+        upload and dispatch each prefill (behind the decode step in
+        flight); build, upload and dispatch this call's decode step; read
+        the step before and commit it; read each prefill's first token.
 
         One span tree per iteration (docs/observability.md): ``serve/step``
         holds ``serve/admit``, each ``serve/prefill``, the decode step with
@@ -1256,11 +1390,11 @@ class ServingEngine:
                 if nxt is not None:
                     self._run_chunk(*nxt)
 
-            if sched.decoding > 0:
-                if self._verify_jit is not None:
-                    self._step_verify()
-                else:
-                    self._step_decode()
+            if self._verify_jit is None:
+                self._step_decode()
+                self._settle()
+            elif sched.decoding > 0:
+                self._step_verify()
 
             with trace_span("serve/gauges", track="serve"):
                 self._iterations += 1
@@ -1273,77 +1407,108 @@ class ServingEngine:
         return sched.has_work
 
     def _step_decode(self) -> None:
-        """One decode step over the fully-prefilled slot batch: build the
-        batch, upload, dispatch, read the tokens back, commit them."""
+        """One decode step over the slots that ride it: build the batch,
+        upload, dispatch; then read and commit the step dispatched
+        ``_lag`` steps ago.  At lag 0 that is the step just dispatched;
+        at lag 1 the one before it, whose read the device's work on this
+        one hides: the first step after an empty engine then has nothing
+        to read, and the step after the last nothing to dispatch."""
         sched, m = self.scheduler, self.metrics
-        # rows in the decode batch (fully-prefilled slots) BEFORE the
-        # commit evicts any — each gets a per-request decode-slice
-        # span below, and sampling key writebacks target exactly them
-        decode_rows = [
-            i
-            for i, s in enumerate(sched.slots)
-            if s.request is not None and s.prefill_pos is None
-        ]
-        live_rids = (
-            [sched.slots[i].request.rid for i in decode_rows]
-            if tracing_active()
-            else None
-        )
+        inflight = self._inflight
         t0 = time.perf_counter()
-        with trace_span("serve/decode_step", track="serve",
-                        attrs={"active": sched.decoding}):
-            with trace_span("serve/decode_step/batch", track="serve"):
-                host_args = sched.decode_batch()
+        riding = sched.riding
+        read = None
+        if riding:
+            # dispatched while the step before is unread
+            ahead = int(bool(inflight))
+            with trace_span("serve/decode_step", track="serve",
+                            attrs={"active": riding, "ahead": ahead}):
+                with trace_span("serve/decode_step/batch", track="serve"):
+                    host_args = sched.decode_batch()
+                    if self._sampling:
+                        host_args += (
+                            (self._key_data,) + sched.sampling_batch()
+                        )
+                out = self._launch(
+                    "serve/decode_step", "serve_decode", self._decode_jit,
+                    host_args,
+                    *(() if self._sampling else (self._prev_tokens,)),
+                )
                 if self._sampling:
-                    host_args += (self._key_data,) + sched.sampling_batch()
-            out = self._launch(
-                "serve/decode_step", "serve_decode", self._decode_jit,
-                host_args,
-            )
-            with trace_span("serve/decode_step/read", track="serve"):
-                next_host = np.asarray(out[0])  # sync: tokens stream out
-                # the fetch above waited for the device; each one below is
-                # a round trip more with the device already idle
-                t_first = time.perf_counter()
-                fetched = [next_host]
-                if self._experts_held:
-                    # the held experts' assignment counts ride beside
-                    held_counts = np.asarray(out[1])
-                    fetched.append(held_counts)
-                    if self._zero_experts:
-                        zero_counts = np.asarray(out[2])
-                        fetched.append(zero_counts)
-                if self._sampling:
-                    # advance ONLY the decoding slots' key streams: a
-                    # request's draw sequence depends on its own seed and
-                    # token count, never on who else rode the batch
-                    kd = np.asarray(out[1])
-                    fetched.append(kd)
-                    for i in decode_rows:
-                        self._key_data[i] = kd[i]
-                    if self.capture_logits:
-                        larr = np.asarray(out[2])
-                        fetched.append(larr)
-                        for i in decode_rows:
-                            rid = sched.slots[i].request.rid
-                            self.captured_logits.setdefault(rid, []).append(
-                                larr[i].copy()
-                            )
-                read_extra_s = time.perf_counter() - t_first
+                    # tokens, key streams and, asked for, the logits
+                    out = out[:2 + bool(self.capture_logits)]
+                else:
+                    self._prev_tokens = out[0]
+                # everything the commit reads starts home now, so the
+                # read finds it on the host
+                for a in out:
+                    a.copy_to_host_async()
+                inflight.append(_DecodeInFlight(
+                    out, host_args, sched.note_decode_dispatched(), t0
+                ))
+                with trace_span("serve/decode_step/read", track="serve"):
+                    if len(inflight) > self._lag:
+                        read = self._read_decode(inflight.popleft())
+            m.decode_steps.inc()
+            m.decode_steps_ahead.inc(ahead)
+        elif inflight:
+            # the step after the last: nothing to dispatch
+            read = self._read_decode(inflight.popleft())
+        else:
+            return
+        m.decode_s.inc(time.perf_counter() - t0)
+        if read is not None:
+            self._commit_decode(*read)
+
+    def _read_decode(self, step: _DecodeInFlight):
+        """Fetch what the commit reads of one decode step.  Returns the
+        step, the host arrays and the seconds the fetches after the first
+        took."""
+        next_host = np.asarray(step.out[0])  # sync: tokens stream out
+        # the fetch above waited for the device (at lag 0; at lag 1 for
+        # the step's copy home); each one below is one more
+        t_first = time.perf_counter()
+        fetched = [next_host]
+        if self._experts_held:
+            # the held experts' assignment counts ride beside, and the
+            # rows' zero-compute picks where the routers have those
+            fetched += [np.asarray(a) for a in step.out[1:]]
+        if self._sampling:
+            # advance ONLY the decoding slots' key streams: a
+            # request's draw sequence depends on its own seed and
+            # token count, never on who else rode the batch
+            kd = np.asarray(step.out[1])
+            fetched.append(kd)
+            for i, _ in step.rows:
+                self._key_data[i] = kd[i]
+            if self.capture_logits:
+                larr = np.asarray(step.out[2])
+                fetched.append(larr)
+                for i, rid in step.rows:
+                    self.captured_logits.setdefault(rid, []).append(
+                        larr[i].copy()
+                    )
+        return step, fetched, time.perf_counter() - t_first
+
+    def _commit_decode(self, step: _DecodeInFlight, fetched: list,
+                       read_extra_s: float) -> None:
+        """Fold one decode step that was read into the slots, under
+        ``serve/commit`` with the step's counts as its attributes."""
+        sched, m = self.scheduler, self.metrics
         now = time.perf_counter()
-        if live_rids:
+        host_args = step.host_args
+        decode_rows = [i for i, _ in step.rows]
+        if tracing_active():
             # per-request decode slices: every live request's timeline
-            # row shows the batch decode interval it rode (the TPOT
-            # structure the histograms only summarize).
+            # row shows the batch decode interval it rode, dispatch to
+            # read (the TPOT structure the histograms only summarize).
             # count_self=False: all slices share ONE interval the
-            # serve/decode_step span above already owns — charging
+            # serve/decode_step spans already own — charging
             # each would multiply-count the window by batch depth
-            for rid in live_rids:
-                trace_add("serve/decode", t0, now, track="serve",
+            for _, rid in step.rows:
+                trace_add("serve/decode", step.t0, now, track="serve",
                           request_id=rid, count_self=False)
-        m.decode_steps.inc()
-        m.decode_s.inc(now - t0)
-        # what the step just read, on the span that closes after the read:
+        # what the step read, on the span that closes after the read:
         # the live rows' context lengths (fresh token included), the blocks
         # of the pool one layer's attention read for them (the paged kernel
         # of a latent or hybrid cache reads to each slot's own length, the
@@ -1379,6 +1544,7 @@ class ServingEngine:
                 state_passes=passes,
             )
         if self._experts_held:
+            held_counts = fetched[1]
             per_expert = held_counts.sum(axis=0)  # over the expert layers
             total = int(per_expert.sum())
             imbalance = (
@@ -1395,22 +1561,24 @@ class ServingEngine:
                 expert_weight_passes=passes,
             )
             if self._zero_experts:
-                share = float(zero_counts[:, decode_rows].sum()) / max(
+                share = float(fetched[2][:, decode_rows].sum()) / max(
                     len(decode_rows) * held_counts.shape[0]
                     * self.model.experts_per_token, 1)
                 m.zero_expert_share.set(share)
                 step_attrs["zero_expert_share"] = share
         # everything since the read returned, this dictionary included, is
-        # the step's own accounting: paid every step, with the device idle
+        # the step's own accounting, paid every step
         step_attrs["account_us"] = 1e6 * (time.perf_counter() - now)
         with trace_span("serve/commit", track="serve", attrs=step_attrs):
+            # a sampling engine commits the step it just dispatched, so
+            # the slots still hold the requests that rode it
             n_sampled = sum(
                 1
                 for i in decode_rows
                 if not sched.slots[i].request.params.is_greedy
-            )
+            ) if self._sampling else 0
             was_finished = set(sched.finished)
-            live = sched.commit_decode(next_host, now)
+            live = sched.commit_decode(fetched[0], now)
             m.tokens_out.inc(live)
             if n_sampled:
                 m.sampled_tokens.inc(n_sampled)

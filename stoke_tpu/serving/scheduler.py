@@ -24,6 +24,14 @@ Slot invariants the compiled decode program relies on:
 - admission reserves ``ceil((prompt_len + max_new_tokens) / block_size)``
   blocks up front, so a mid-flight decode step can never fail on an empty
   pool.
+
+The engine may dispatch a decode step before it has read the one before
+(ISSUE 38).  Everything a batch needs but the tokens is a count the host
+has before it sees a token, so a slot counts its tokens in flight
+(``_Slot.ahead``): :meth:`Scheduler.decode_batch` builds a step with the
+dispatched ones counted as done, a row whose token the host has not seen
+feeds ``FROM_DEVICE``, and :meth:`Scheduler.commit_decode` matches each row
+to the request it was dispatched with.
 """
 
 from __future__ import annotations
@@ -40,6 +48,10 @@ from stoke_tpu.serving.kv_cache import SCRATCH_BLOCK, BlockAllocator
 from stoke_tpu.serving.sampling import SamplingParams
 from stoke_tpu.serving.slo import RequestSLO
 from stoke_tpu.serving.speculative import propose_draft
+
+#: a decode row's token when the host has not read it yet: the decode
+#: program takes the row from the token vector the device kept
+FROM_DEVICE = -1
 
 
 @dataclass
@@ -94,6 +106,9 @@ class _Slot:
     blocks: List[int] = field(default_factory=list)
     context_len: int = 0       # cached tokens (prompt + committed decode)
     next_token: int = 0        # token the next decode step feeds
+    # tokens of this request that dispatched programs will produce and the
+    # host has not read: the prefill's first token, a decode step in flight
+    ahead: int = 0
     # chunked prefill (ISSUE 13): prompt tokens already written to the
     # cache; None = prefill complete (the slot decodes).  While a slot is
     # prefilling it occupies capacity but is excluded from decode_batch —
@@ -138,6 +153,9 @@ class Scheduler:
             (max_seqs, max_blocks_per_seq), SCRATCH_BLOCK, np.int32
         )
         self.finished: Dict[int, Request] = {}
+        # the (slot, rid) rows of each decode step dispatched and not yet
+        # committed, oldest first
+        self._in_flight: Deque[List[Tuple[int, int]]] = deque()
         self._next_rid = 0
         self.preempt_denials = 0  # admissions deferred on an empty pool
 
@@ -219,8 +237,13 @@ class Scheduler:
         return len(self.queue)
 
     @property
+    def in_flight(self) -> int:
+        """Decode steps dispatched and not yet committed."""
+        return len(self._in_flight)
+
+    @property
     def has_work(self) -> bool:
-        return self.active > 0 or self.queued > 0
+        return self.active > 0 or self.queued > 0 or self.in_flight > 0
 
     @property
     def batch_fill(self) -> float:
@@ -452,13 +475,35 @@ class Scheduler:
 
     # --------------------------- decode state -------------------------- #
 
+    def _rides(self, s: _Slot) -> bool:
+        """Whether the slot's request has a row in the next decode step: it
+        is fully prefilled and, with everything in flight counted as done,
+        still short of its ``max_new_tokens``.  (A request that will end on
+        ``eos_id`` is not known to yet: it rides one step too many, and
+        :meth:`commit_decode` drops that row.)"""
+        req = s.request
+        return (
+            req is not None
+            and s.prefill_pos is None
+            and len(req.tokens) + s.ahead < req.max_new_tokens
+        )
+
+    @property
+    def riding(self) -> int:
+        """Rows the next decode step would carry (:meth:`decode_batch`)."""
+        return sum(1 for s in self.slots if self._rides(s))
+
     def decode_batch(self):
         """Fixed-shape decode inputs: ``(tokens [B], positions [B],
-        block_tables [B, MB], context_lens [B])``.  Inactive slots feed
-        token 0 at position 0 against an all-scratch table; slots still
-        chunk-prefilling get the SAME treatment (their real table is
-        swapped for scratch here) so the decode step's position-0 write
-        can never clobber their half-written prompt K/V."""
+        block_tables [B, MB], context_lens [B])``, with every program in
+        flight counted as done.  Inactive slots feed token 0 at position 0
+        against an all-scratch table; slots still chunk-prefilling get the
+        SAME treatment (their real table is swapped for scratch here) so
+        the decode step's position-0 write can never clobber their
+        half-written prompt K/V, and so does a request whose last token is
+        in flight.  A row whose token the host has not read feeds
+        ``FROM_DEVICE``.  Changes nothing: the engine calls
+        :meth:`note_decode_dispatched` once the step is on its way."""
         B = self.max_seqs
         tokens = np.zeros(B, np.int32)
         positions = np.zeros(B, np.int32)
@@ -467,13 +512,31 @@ class Scheduler:
         for i, s in enumerate(self.slots):
             if s.request is None:
                 continue
-            if s.prefill_pos is not None:
+            if not self._rides(s):
                 tables[i, :] = SCRATCH_BLOCK
                 continue
-            tokens[i] = s.next_token
-            positions[i] = s.context_len
-            context[i] = s.context_len + 1
+            tokens[i] = FROM_DEVICE if s.ahead else s.next_token
+            # the fed token is the request's newest: its position follows
+            # the prompt and the tokens before it
+            positions[i] = (
+                s.request.prompt.size + len(s.request.tokens) + s.ahead - 1
+            )
+            context[i] = positions[i] + 1
         return tokens, positions, tables, context
+
+    def note_decode_dispatched(self) -> List[Tuple[int, int]]:
+        """The step :meth:`decode_batch` built is dispatched: its rows'
+        tokens are in flight until :meth:`commit_decode` folds them in.
+        Returns the rows, ``(slot, rid)`` each."""
+        rows = [
+            (i, s.request.rid)
+            for i, s in enumerate(self.slots)
+            if self._rides(s)
+        ]
+        for i, _ in rows:
+            self.slots[i].ahead += 1
+        self._in_flight.append(rows)
+        return rows
 
     def sampling_batch(self):
         """Fixed-shape per-slot sampling knobs aligned with
@@ -491,6 +554,11 @@ class Scheduler:
 
     # --------------------------- commit/evict --------------------------- #
 
+    def note_prefill_dispatched(self, slot: int) -> None:
+        """The program that produces the slot's first token is dispatched:
+        the slot rides the next decode step on that count."""
+        self.slots[slot].ahead += 1
+
     def note_prefill_token(self, slot: int, token: int, now: float) -> None:
         """Record the prefill-produced first token (the TTFT point) and
         arm the slot for decode (or finish immediately at cap 1/eos)."""
@@ -499,21 +567,27 @@ class Scheduler:
         req.first_token_ts = now
         req.tokens.append(int(token))
         s.next_token = int(token)
+        s.ahead -= 1
         if self._done(req):
             self._finish(slot, now)
 
     def commit_decode(self, next_tokens: np.ndarray, now: float) -> int:
-        """Fold one decode step's outputs into the slots; evict finished
-        requests (blocks freed back to the pool).  Returns the number of
-        LIVE tokens committed (inactive-slot outputs are discarded)."""
+        """Fold the oldest decode step in flight into the slots; evict
+        finished requests (blocks freed back to the pool).  A row is its
+        request's only while the slot still holds the rid it was dispatched
+        with: a request that ended on ``eos_id`` a step ago rode this one
+        too, and that output is dropped.  Returns the number of LIVE tokens
+        committed (inactive-slot outputs are discarded)."""
         live = 0
-        for i, s in enumerate(self.slots):
-            if s.request is None or s.prefill_pos is not None:
+        for i, rid in self._in_flight.popleft():
+            s = self.slots[i]
+            if s.request is None or s.request.rid != rid:
                 continue
             tok = int(next_tokens[i])
-            s.context_len += 1  # the token we just fed is now cached
+            s.context_len += 1  # the token we fed is cached
             s.request.tokens.append(tok)
             s.next_token = tok
+            s.ahead -= 1
             live += 1
             if self._done(s.request):
                 self._finish(i, now)

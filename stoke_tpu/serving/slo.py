@@ -40,8 +40,11 @@ from typing import Any, Dict, List, Optional
 from stoke_tpu.serving.telemetry import LATENCY_BUCKETS, _Reservoir
 
 #: span names whose wall belongs to the prefill phase of a request's
-#: timeline (the PR-10 request track)
-_PREFILL_SPANS = ("serve/prefill", "serve/prefill_chunk")
+#: timeline (the PR-10 request track); ``serve/prefill_wait`` is the host's
+#: wait for the token of a prefill that a decode step was dispatched behind
+_PREFILL_SPANS = (
+    "serve/prefill", "serve/prefill_chunk", "serve/prefill_wait",
+)
 
 #: finished-request attributions kept per tracker (oldest evicted) — the
 #: bounded-ring discipline every other host-side store here follows

@@ -107,6 +107,12 @@ class ServeMetrics:
         self.decode_steps = registry.counter(
             "serve/decode_steps_total", help="decode program dispatches"
         )
+        self.decode_steps_ahead = registry.counter(
+            "serve/decode_steps_ahead_total",
+            help="decode program dispatches made while the step before "
+            "was unread (one step in flight: the read, the commit and the "
+            "next admission run under the device's work)",
+        )
         self.sampled_tokens = registry.counter(
             "serve/sampled_tokens_total",
             help="tokens drawn through the sampling path "

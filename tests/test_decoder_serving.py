@@ -320,6 +320,44 @@ def test_engine_serves_the_reference_greedy_stream(built, family, attention):
         assert m.zero_expert_share is None
 
 
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_a_step_in_flight_serves_the_tokens_of_read_then_dispatch(built,
+                                                                  family):
+    """One case a cache kind (latent rows, per-slot state beside rows,
+    zero-compute picks handed back beside the counts): with a decode step
+    dispatched before the one before it is read (lag 1, what a greedy
+    engine derives) every request gets the tokens of the read-then-dispatch
+    order (lag 0, reached through the private attribute), ending by count
+    and on an ``eos_id``; the slots turn over, so a state row and a freed
+    block are met again."""
+    model, params = built[family]
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, 512, n).astype(np.int32)
+               for n in (5, 17, 9, 12)]
+    caps = [6, 3, 1, 5]
+
+    def serve(eng, lag, eos):
+        eng._lag = lag
+        rids = [eng.submit(p, c, eos_id=eos) for p, c in zip(prompts, caps)]
+        eng.run()
+        assert eng.scheduler.in_flight == 0 and eng.allocator.used_blocks == 0
+        return [list(eng.result(r).tokens) for r in rids]
+
+    eng = ServingEngine(model, params, ServeConfig(
+        max_seqs=2, kv_block_size=BLOCK, max_seq_len=64,
+        prefill_pad_multiple=BUCKET))
+    assert eng._lag == 1
+    by_count = serve(eng, 1, None)
+    assert [len(s) for s in by_count] == caps
+    assert serve(eng, 0, None) == by_count
+    assert eng.metrics.decode_steps_ahead.value > 0
+    # a token inside the first request's stream ends it early
+    eos = by_count[0][2]
+    on_eos = serve(eng, 1, eos)
+    assert len(on_eos[0]) <= 3 and on_eos[0][-1] == eos
+    assert serve(eng, 0, eos) == on_eos
+
+
 def test_decode_program_hands_back_the_held_experts_counts(tiny):
     model, params = tiny
     eng = ServingEngine(model, params, ServeConfig(

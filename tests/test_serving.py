@@ -1625,3 +1625,278 @@ def test_serve_programs_address_the_whole_pool(program, rng):
     # decode reads K and V of each layer's window; prefill only writes
     expected = 2 * n_layers if program == "serve_decode" else 0
     assert pool_gathers == expected
+
+
+# --------------------------------------------------------------------------- #
+# one decode step in flight (ISSUE 38)
+# --------------------------------------------------------------------------- #
+
+
+def _at_lag(lag, model, params, cfg):
+    """An engine at ``lag``: no public configuration sets it, a greedy
+    engine derives 1; the tests reach 0 through the private attribute."""
+    eng = ServingEngine(model, params, cfg)
+    assert eng._lag == 1
+    eng._lag = lag
+    return eng
+
+
+def _lively(params):
+    """The tiny model's matrices three times as large: at the initialiser's
+    scale it repeats its last token whatever came before; at this one the
+    next token depends on the context, so a token fed to the wrong row, a
+    wrong position or a stale cache row changes the stream."""
+    return jax.tree_util.tree_map(
+        lambda leaf: leaf * 3.0 if leaf.ndim >= 2 else leaf, params
+    )
+
+
+def _turnover_mix(rng, n=9):
+    """Prompts and caps that turn three slots over several times, a cap of
+    1 (finished by its prefill) and a cap of 2 among them."""
+    lengths = rng.integers(3, 15, size=n)
+    caps = [1, 2, 9, 5, 3, 7, 2, 6, 4][:n]
+    return [rng.integers(1, VOCAB, size=int(L)).astype(np.int32)
+            for L in lengths], caps
+
+
+@pytest.mark.parametrize("ending", ["max_new_tokens", "eos_id"])
+def test_lag1_serves_the_tokens_lag0_serves(ending, rng):
+    """(a) Dispatching a step before the one before it is read changes no
+    token: nine requests through three slots, with staggered submissions,
+    ending by count or on an ``eos_id`` that cuts streams short (learned
+    one step late at lag 1: the extra row's output is dropped)."""
+    model, params = _gpt("dense")
+    params = _lively(params)
+    prompts, caps = _turnover_mix(rng)
+    eos = None
+    if ending == "eos_id":
+        free = ServingEngine(model, params, _cfg(max_seqs=3))
+        streams = free.generate(prompts, max_new_tokens=9)
+        # a token that ends several streams early, and not with the first
+        eos = max(
+            {t for s in streams for t in s[1:]},
+            key=lambda t: sum(t in s[1:-1] for s in streams),
+        )
+        caps = [9] * len(prompts)
+    served = []
+    for lag in (0, 1):
+        eng = _at_lag(lag, model, params, _cfg(max_seqs=3, eos_id=eos))
+        rids = [eng.submit(p, c) for p, c in zip(prompts[:4], caps)]
+        eng.step()
+        eng.step()
+        rids += [eng.submit(p, c) for p, c in zip(prompts[4:7], caps[4:])]
+        eng.step()
+        rids += [eng.submit(p, c) for p, c in zip(prompts[7:], caps[7:])]
+        eng.run()
+        served.append([list(eng.result(r).tokens) for r in rids])
+        assert eng.allocator.occupancy == 0.0
+        assert eng.metrics.completed.value == len(prompts)
+        assert eng.metrics.tokens_out.value == sum(map(len, served[-1]))
+    assert served[0] == served[1]
+    assert len({t for s in served[1] for t in s}) > 9  # lively streams
+    if eos is None:
+        assert [len(s) for s in served[1]] == caps
+    else:
+        cut = [s for s in served[1] if len(s) < 9]
+        assert len(cut) >= 2 and all(s[-1] == eos for s in cut)
+        assert all(eos not in s[:-1] for s in served[1])
+
+
+def test_eos_while_a_step_is_in_flight_and_a_request_to_the_last_position(rng):
+    """(b) A request that ends on ``eos_id`` has ridden the step in
+    flight: that row's output is dropped, its blocks are freed when its
+    last token is on the host and re-used by the next admission, whose
+    tokens are a fresh engine's.  Beside it a request with ``prompt +
+    max_new_tokens == max_seq_len``: finished by count, it rides no step
+    past its last position."""
+    model, params = _gpt("dense")
+    params = _lively(params)
+    cfg = dict(max_seqs=2, kv_block_size=4, max_seq_len=24,
+               max_new_tokens=8, prefill_pad_multiple=8)
+    fresh = ServingEngine(model, params, ServeConfig(**cfg))
+    alone = lambda p: fresh.generate([p], max_new_tokens=8)[0]  # noqa: E731
+    # a prompt whose third token is not among its first two ends there
+    first = next(
+        p for p in (rng.integers(1, VOCAB, size=6).astype(np.int32)
+                    for _ in range(20))
+        if alone(p)[2] not in alone(p)[:2]
+    )
+    full = rng.integers(1, VOCAB, size=16).astype(np.int32)  # 16 + 8 = 24
+    late = rng.integers(1, VOCAB, size=5).astype(np.int32)
+    want_first, want_full, want_late = alone(first), alone(full), alone(late)
+    eos = want_first[2]  # ends `first` at its third token
+    cut = lambda s: s[: s.index(eos) + 1] if eos in s else s  # noqa: E731
+    # blocks for `first` (4) and `full` (6) only: `late` waits for blocks
+    eng = ServingEngine(
+        model, params, ServeConfig(**cfg, eos_id=eos, kv_blocks=4 + 6 + 1))
+    rid_first, rid_full = eng.submit(first, 8), eng.submit(full, 8)
+    rid_late = eng.submit(late, 8)
+    sched = eng.scheduler
+    held = None
+    rode_past = False
+    while sched.has_work:
+        eng.step()
+        _, positions, _, _ = sched.decode_batch()
+        assert positions.max() < cfg["max_seq_len"]
+        if held is None and sched.slots[0].request is not None:
+            held = list(sched.slots[0].blocks)  # `first`'s
+        done = eng.result(rid_first)
+        if done is not None and sched.in_flight and not rode_past:
+            # finished, and the step in flight was dispatched with its row
+            rode_past = (0, rid_first) in sched._in_flight[0]
+    assert rode_past
+    assert list(eng.result(rid_first).tokens) == want_first[:3]
+    assert list(eng.result(rid_full).tokens) == cut(want_full)
+    assert list(eng.result(rid_late).tokens) == cut(want_late)
+    late_req = eng.result(rid_late)
+    assert late_req.admit_ts >= eng.result(rid_first).finish_ts
+    assert eng.allocator.occupancy == 0.0 and sched.in_flight == 0
+    # the newcomer got the blocks the finished request held
+    relay = ServingEngine(
+        model, params, ServeConfig(**cfg, eos_id=eos, kv_blocks=4 + 6 + 1))
+    relay.submit(first, 8), relay.submit(full, 8), relay.submit(late, 8)
+    while relay.result(0) is None:
+        relay.step()
+    relay.step()
+    newcomer = next(s for s in relay.scheduler.slots
+                    if s.request is not None and s.request.rid == 2)
+    assert set(newcomer.blocks) & set(held)
+
+
+@pytest.mark.parametrize("driver", ["run", "generate", "step"])
+def test_every_driver_ends_with_nothing_in_flight(driver, rng):
+    """(c) ``run()``, ``generate()`` and a caller looping on ``step()``
+    while it returns True all end with every token committed and no step
+    in flight, also when the last request ended on ``eos_id`` with a step
+    dispatched behind it."""
+    model, params = _gpt("dense")
+    params = _lively(params)
+    prompts = [rng.integers(1, VOCAB, size=n).astype(np.int32)
+               for n in (6, 11, 4)]
+    free = ServingEngine(model, params, _cfg(max_seqs=2))
+    streams = free.generate(prompts, max_new_tokens=6)
+    eos = streams[2][3]  # the last request to be admitted ends on it
+    want = [s[: s.index(eos) + 1] if eos in s else s for s in streams]
+    eng = ServingEngine(model, params, _cfg(max_seqs=2, eos_id=eos))
+    if driver == "generate":
+        got = eng.generate(prompts, max_new_tokens=6)
+    else:
+        rids = [eng.submit(p, 6) for p in prompts]
+        if driver == "run":
+            eng.run()
+        else:
+            steps = 0
+            while eng.step():
+                steps += 1
+            assert not eng.scheduler.has_work and steps < 40
+        got = [list(eng.result(r).tokens) for r in rids]
+    assert got == want
+    assert eng.scheduler.in_flight == 0 and not eng._inflight
+    assert not eng._awaited and eng.scheduler.active == 0
+    assert all(s.ahead == 0 for s in eng.scheduler.slots)
+    assert eng.allocator.occupancy == 0.0
+    assert eng.metrics.tokens_out.value == sum(map(len, want))
+    # an engine that drained serves again, and starts with nothing to read
+    assert eng.generate(prompts[:1], max_new_tokens=6)[0] == want[0]
+
+
+def _recorded(fn):
+    from stoke_tpu.telemetry.tracing import (
+        TraceRecorder,
+        register_recorder,
+        unregister_recorder,
+    )
+
+    rec = TraceRecorder(ring_size=4096)
+    register_recorder(rec)
+    try:
+        fn()
+    finally:
+        unregister_recorder(rec)
+    return sorted(rec.spans(), key=lambda s: s.t_start)
+
+
+def test_ahead_attribute_and_counter(rng):
+    """(d) ``serve/decode_step`` says whether it was dispatched while the
+    step before was unread: 0 on the first step of an empty engine (and
+    again after a drain), 1 on the steps after; an engine that has to see
+    a step's result to build the next (sampling, speculative) never is."""
+    model, params = _gpt("dense")
+    prompts = [rng.integers(1, VOCAB, size=n).astype(np.int32)
+               for n in (5, 9)]
+    eng = ServingEngine(model, params, _cfg(max_seqs=2))
+
+    def serve():
+        eng.generate(prompts, max_new_tokens=5)
+        eng.generate(prompts[:1], max_new_tokens=3)
+
+    steps = [s for s in _recorded(serve) if s.name == "serve/decode_step"]
+    # 4 dispatches serve 5 tokens a request (the prefill makes the first),
+    # then 2 serve 3
+    assert [s.attrs["ahead"] for s in steps] == [0, 1, 1, 1, 0, 1]
+    assert all(s.attrs["active"] >= 1 for s in steps)
+    m = eng.metrics
+    assert m.decode_steps.value == 6 and m.decode_steps_ahead.value == 4
+    for cfg in (_cfg(max_seqs=2, sampling=True),
+                _cfg(max_seqs=2, sampling=True, speculative_k=2)):
+        held = ServingEngine(model, params, cfg)
+        assert held._lag == 0
+        spans = _recorded(lambda: held.generate(prompts, max_new_tokens=5))
+        steps = [s for s in spans if s.name == "serve/decode_step"]
+        assert all(s.attrs["ahead"] == 0 for s in steps)
+        assert bool(steps) == (cfg.speculative_k is None)
+        assert held.metrics.decode_steps.value > 0
+        assert held.metrics.decode_steps_ahead.value == 0
+        assert held.scheduler.in_flight == 0
+
+
+@pytest.mark.parametrize("lag", [1, 0])
+def test_span_order_of_a_step_that_prefilled(lag, rng):
+    """(e) A step that admits a request while another decodes: at lag 1 the
+    prefill is dispatched, then the decode step behind it, then the step
+    before is read and committed, and only then the host waits for the
+    prefill's token (``serve/prefill_wait``, a span of the ring only); at
+    lag 0 the prefill's read waits for the token before the decode step is
+    built."""
+    model, params = _gpt("dense")
+    eng = _at_lag(lag, model, params, _cfg(max_seqs=2))
+    eng.submit(rng.integers(1, VOCAB, size=7).astype(np.int32), 8)
+    eng.step()
+    eng.step()
+    rid = eng.submit(rng.integers(1, VOCAB, size=5).astype(np.int32), 4)
+    spans = _recorded(eng.step)
+    # (a request's queue wait and the decode slices of its row are added
+    # after the fact, from stamps)
+    spans = [s for s in spans
+             if s.name not in ("serve/admission", "serve/decode")]
+    names = [s.name for s in spans]
+    assert names[0] == "serve/step" and names[1] == "serve/admit"
+    assert names[-1] == "serve/gauges"
+    at = {n: names.index(n) for n in set(names)}
+    order = ["serve/prefill", "serve/prefill/upload",
+             "serve/prefill/dispatch", "serve/prefill/read",
+             "serve/decode_step", "serve/decode_step/batch",
+             "serve/decode_step/upload", "serve/decode_step/dispatch",
+             "serve/decode_step/read", "serve/commit"]
+    if lag:
+        order.append("serve/prefill_wait")
+    else:
+        assert "serve/prefill_wait" not in names
+    assert [at[n] for n in order] == sorted(at[n] for n in order)
+    assert all(names.count(n) == 1 for n in order)
+    first = eng.scheduler.slots[1].request
+    # its first token; at lag 0 also the token of the decode step behind
+    assert first.rid == rid and len(first.tokens) == (1 if lag else 2)
+    if lag:
+        assert spans[at["serve/prefill_wait"]].request_id == rid
+        # the token was stamped when the wait returned, after the commit
+        commit = spans[at["serve/commit"]]
+        assert first.first_token_ts >= commit.t_start + commit.dur_s
+        assert eng.scheduler.in_flight == 1
+        assert eng.scheduler.slots[1].ahead == 1  # rides the step in flight
+    else:
+        assert eng.scheduler.in_flight == 0
+        assert len(eng.scheduler.slots[0].request.tokens) == 4
+    eng.run()
+    assert eng.allocator.occupancy == 0.0
