@@ -59,7 +59,14 @@ class CacheSpec:
     layer caches rows.  ``state`` lists the per-slot arrays a state layer
     keeps, ``(name, shape a slot, dtype name)`` each, held as ``[max_seqs,
     *shape]``, an array a state layer; a ``cache`` dtype is the pool's
-    (``ServeConfig.kv_dtype``)."""
+    (``ServeConfig.kv_dtype``).
+
+    A plane may carry options as a third element (``PagedKVCache``):
+    ``"layers"``, the layers that keep a row of it, and ``"packed"``.
+    ``"sparse_latent"`` (``sparse_attention``) is the latent cache of a
+    model with learned sparse attention: a packed latent plane in every
+    layer and an ``index`` plane in the layers with an indexer; each query
+    attends ``index_topk`` of its keys."""
 
     layers: int
     planes: tuple
@@ -70,13 +77,22 @@ class CacheSpec:
     values: Optional[int] = None
     layer_kinds: Optional[tuple] = None
     state: tuple = ()
+    index_topk: int = 0
+
+    def plane_layers(self, name: str) -> tuple:
+        """The layers that keep a row of plane ``name``."""
+        for plane in self.planes:
+            if plane[0] == name:
+                options = plane[2] if len(plane) > 2 else {}
+                return tuple(options.get("layers", range(self.layers)))
+        raise KeyError(name)
 
     @property
     def values_per_token(self) -> int:
         """Cached values a token a row layer, all planes together."""
         if self.values is not None:
             return self.values
-        return sum(width for _, width in self.planes)
+        return sum(plane[1] for plane in self.planes)
 
     def layers_of(self, kind: str) -> tuple:
         """The indices of the layers that keep ``kind`` (``"rows"`` or

@@ -1,6 +1,6 @@
 """A pre-norm decoder assembled from a ``config.json``-shaped description.
 
-Three families' blocks, keyed by their sources' own names, so a published
+Four families' blocks, keyed by their sources' own names, so a published
 configuration builds the model as it stands.  All: a final RMSNorm; an
 untied ``lm_head``; no biases.  The first two: per layer ``h +=
 Attn(RMSNorm(h))``, ``h += FFN(RMSNorm(h))``, the feed-forward a dense
@@ -35,6 +35,18 @@ of layer ``i`` is :meth:`DecoderConfig.layer_kind`:
   :class:`DeltaRuleAttention`): a ``[d_k, d_v]`` float32 state a head in
   place of cached rows, a depthwise causal convolution on q, k and v.
 
+The fourth (``use_dsa``, ``indexer_types``, ``hc_mult`` ...; Hy4,
+``model_type: "hy_v4"``) is the first's latent attention made sparse and
+its residual path widened (:class:`StreamLayer`): each query attends only
+the ``index_topk`` keys a learned indexer scores highest (DeepSeek-V3.2's
+lightning indexer, :class:`Indexer`; a ``full`` layer has one, the
+``shared`` layers after it reuse its choice), a sink a head joins the
+softmax's denominator, the attended values are gated elementwise, the
+residual stream is ``hc_mult`` rows mixed around each sublayer by
+manifold-constrained hyper-connections (:class:`HyperConnection`, Sinkhorn
+on the mixing matrix), every SwiGLU is limited (``swiglu_limit``), the
+router chooses by sigmoid scores plus a bias, and the head is float32.
+
 The same ``__call__(input_ids, train, positions, decode, kv_cache)``
 contract as :class:`stoke_tpu.models.gpt.GPT`, and :meth:`Decoder.cache_spec`
 for ``ServingEngine``.  A latent model caches one row a token a latent
@@ -47,7 +59,9 @@ layer's attention is the hook's (``kv_cache.latent_attention(i)``: the row
 written once, then the expanded form in prefill and the absorbed form in
 decode; ``kv_cache.layer_attention(i)`` for a ``gqa`` layer;
 ``kv_cache.layer_state(i)`` hands a ``kda`` layer its slots' state and
-takes it back); without one it is the whole-sequence form.
+takes it back; ``kv_cache.sparse_attention(i)`` a DSA layer its attention
+over the chosen rows, ``CacheSpec.kind == "sparse_latent"``); without one
+it is the whole-sequence form.
 
 Parameters are ``param_dtype``, products take ``dtype`` inputs and
 accumulate in float32; router, softmax, RMSNorm statistics and the whole of
@@ -69,6 +83,7 @@ from stoke_tpu.models.bert import CacheSpec
 from stoke_tpu.models.moe import ExpertShareFFN, SwiGLU
 from stoke_tpu.ops.delta_rule import delta_rule_step
 from stoke_tpu.ops.flash_attention import grouped_query_attention
+from stoke_tpu.ops.sparse_attention import selection_mask
 
 _NEG_INF = -1e30
 
@@ -131,6 +146,24 @@ class DecoderConfig:
     router_choice_bias: bool = False
     mla_scale_q_lora: bool = False
     mla_scale_kv_lora: bool = False
+    # the DSA family (``use_dsa``, ``index_*``, ``hc_*``; Hy4, ``model_type:
+    # "hy_v4"``): a learned sparse selection of each query's keys (an
+    # indexer in the ``full`` layers of ``indexer_types``, whose selection
+    # the ``shared`` layers after it reuse), an elementwise gate and a sink a
+    # head on the latent attention, the residual stream as ``hc_mult``
+    # hyper-connections, a limit on every SwiGLU, a float32 head
+    use_dsa: bool = False
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    indexer_types: Optional[Tuple[str, ...]] = None
+    mla_gate: bool = False
+    learnable_sink: bool = False
+    hc_mult: int = 0
+    hc_magnitude: float = 2.0
+    hc_eps: float = 1e-6
+    swiglu_limit: float = 0.0
+    lm_head_fp32: bool = False
 
     @classmethod
     def from_dict(cls, config: dict) -> "DecoderConfig":
@@ -166,6 +199,10 @@ class DecoderConfig:
                 kwargs.update(cls._double_layer_keys(config))
             else:
                 needed += ("n_group", "topk_group")
+            if config.get("use_dsa"):
+                kwargs.update(cls._sparse_keys(config))
+                needed += ("index_n_heads", "index_head_dim", "index_topk",
+                           "indexer_types", "hc_mult")
             missing = [k for k in needed if k not in config]
             if missing:
                 raise ValueError(
@@ -244,6 +281,67 @@ class DecoderConfig:
             "first_k_dense_replace": 0,
         }
 
+    @staticmethod
+    def _sparse_keys(config: dict) -> dict:
+        """The DSA family's keys (``use_dsa``, ``index_n_heads``,
+        ``indexer_types``, ``gated_mla`` / ``gating_type``,
+        ``learnable_sink``, ``hc_mult`` ..., ``swiglu_limit``,
+        ``enable_lm_head_fp32``, ``rope_parameters``, ``mlp_layer_types``)
+        under the fields the decoder reads, checked: latent attention in
+        every layer, an elementwise gate, ``full`` and ``shared`` indexer
+        layers with a ``full`` one first, leading dense feed-forwards, plain
+        rotary frequencies and hyper-connections around both sublayers are
+        what is built here."""
+        n = int(config["num_hidden_layers"])
+        if not config.get("use_mla", True):
+            raise ValueError("use_mla false: the attention here is latent")
+        kinds = set(config.get("layer_types", ["deepseek_sparse_attention"]))
+        if kinds != {"deepseek_sparse_attention"}:
+            raise ValueError(f"layer_types {sorted(kinds)}: every layer is "
+                             f"deepseek_sparse_attention here")
+        rope = config.get("rope_parameters") or {}
+        if rope.get("rope_type", "default") != "default":
+            raise ValueError(f"rope_parameters {rope!r}: plain rotary "
+                             f"frequencies only")
+        qk = config.get("qk_head_dim")
+        if qk is not None and qk != (config["qk_nope_head_dim"]
+                                     + config["qk_rope_head_dim"]):
+            raise ValueError(f"qk_head_dim {qk}: the nope and rope parts "
+                             f"make the query-key head")
+        gated = bool(config.get("gated_mla", False))
+        if gated and config.get("gating_type", "elementwise") != "elementwise":
+            raise ValueError(f"gating_type {config['gating_type']!r}: the "
+                             f"gate here is elementwise")
+        mlp = list(config.get("mlp_layer_types", ["dense"] + ["sparse"] * n))
+        dense = next((i for i, t in enumerate(mlp) if t != "dense"), len(mlp))
+        if set(mlp[dense:]) - {"sparse"}:
+            raise ValueError("mlp_layer_types: the dense feed-forwards lead "
+                             "and every later layer is an expert layer here")
+        out = {
+            "first_k_dense_replace": dense,
+            "mla_gate": gated,
+            "learnable_sink": bool(config.get("learnable_sink", False)),
+            "swiglu_limit": float(config.get("swiglu_limit") or 0.0),
+            "lm_head_fp32": bool(config.get("enable_lm_head_fp32", False)),
+            "router_choice_bias": True,
+        }
+        if "rope_theta" in rope:
+            out["rope_theta"] = float(rope["rope_theta"])
+        if "indexer_types" in config:
+            types = tuple(config["indexer_types"])[:n]
+            if set(types) - {"full", "shared"}:
+                raise ValueError(f"indexer_types {sorted(set(types))}: an "
+                                 f"indexer layer is full or shared here")
+            if types[0] != "full":
+                raise ValueError("indexer_types: the first layer is shared "
+                                 "and has no full layer to reuse")
+            out["indexer_types"] = types
+        if not config.get("hc_mult") or not config.get("enable_ihc", True):
+            raise ValueError("hyper-connections around both sublayers of "
+                             "every layer (hc_mult, enable_ihc) are what "
+                             "the DSA family is built with here")
+        return out
+
     def layer_kind(self, i: int) -> str:
         """Layer ``i``'s attention: ``"mla"``, ``"gqa"`` or ``"kda"``."""
         if self.gqa_layers is None:
@@ -268,6 +366,18 @@ class DecoderConfig:
     @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    def indexes(self, i: int) -> bool:
+        """Whether layer ``i`` has an indexer of its own (a ``full`` layer of
+        a DSA model); a ``shared`` layer reuses the selection of the nearest
+        ``full`` layer before it."""
+        return self.use_dsa and self.indexer_types[i] == "full"
+
+    @property
+    def full_layers(self) -> Tuple[int, ...]:
+        """The layers that have an indexer, in order."""
+        return tuple(i for i in range(self.num_hidden_layers)
+                     if self.indexes(i))
 
     @property
     def latent_sublayers(self) -> int:
@@ -487,6 +597,68 @@ def absorbed_paged_attention(q_nope, q_rope, plane, layer, block_tables,
 
 
 # --------------------------------------------------------------------------- #
+# learned sparse attention: the indexer's selection, and attention over it
+# --------------------------------------------------------------------------- #
+
+
+def indexer_selection(q, k, w, k_top: int, key_valid):
+    """The keys each query attends (DeepSeek-V3.2's lightning indexer), the
+    whole-sequence form: ``q [B, L, Hi, D]``, ``k [B, L, D]`` (both roped),
+    ``w [B, L, Hi]`` float32, ``key_valid [B, L]``.  ``I_ts = D^-0.5 sum_j
+    w_tj ReLU(q_tj . k_s)`` for ``s <= t``; a query attends its ``k_top``
+    keys of highest ``I``, equal scores lower position first (all of them
+    while it has no more).  Returns bool ``[B, L, L]``."""
+    B, L, _, D = q.shape
+    s = jnp.einsum("bqhd,bkd->bqhk", q, k.astype(q.dtype),
+                   preferred_element_type=jnp.float32)
+    s = (jnp.maximum(s, 0.0) * w[..., None]).sum(axis=2) * D ** -0.5
+    allow = jnp.tril(jnp.ones((L, L), bool))[None] & key_valid[:, None, :]
+    s = jnp.where(allow, s, -jnp.inf)
+    return selection_mask(s.reshape(B * L, L), k_top).reshape(B, L, L)
+
+
+def sparse_expanded_attention(q_nope, q_rope, c, k_rope, w_kvb, scale, sinks,
+                              selection):
+    """:func:`expanded_attention` over each query's selected keys only
+    (``selection [B, L, L]`` bool), with a sink a head (``sinks [H]``
+    float32, ``-inf`` for none): ``p_ts = e^{z_ts} / (e^{sink} + sum_{s in
+    S_t} e^{z_ts})``.  The whole-sequence form; returns ``[B, L, H, dv]``."""
+    dn = q_nope.shape[-1]
+    dtype = q_nope.dtype
+    kv = _einsum("blc,chd->blhd", c, w_kvb.astype(dtype)).astype(dtype)
+    k_nope, v = kv[..., :dn], kv[..., dn:]
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
+                                  k_nope.shape[:-1] + k_rope.shape[-1:])],
+        axis=-1,
+    )
+    s = _einsum("bqhd,bkhd->bhqk", q, k) * scale
+    s = jnp.where(selection[:, None], s, -jnp.inf)
+    sink = sinks[None, :, None, None]
+    m = jnp.maximum(s.max(-1, keepdims=True), sink)
+    p = jnp.exp(s - m)
+    p = p / (p.sum(-1, keepdims=True) + jnp.exp(sink - m))
+    return _einsum("bhqk,bkhd->bqhd", p.astype(dtype), v).astype(dtype)
+
+
+#: Sinkhorn iterations of a hyper-connection's residual mix: mHC's t_max
+#: (the configuration has no key for it)
+SINKHORN_ITERS = 20
+
+
+def sinkhorn(logits, iters: int, eps: float):
+    """``exp(logits)`` ``[..., n, n]`` made doubly stochastic by ``iters``
+    alternations of row and column normalisation (Sinkhorn-Knopp, ``eps`` in
+    each denominator)."""
+    m = jnp.exp(logits)
+    for _ in range(iters):
+        m = m / (m.sum(-1, keepdims=True) + eps)
+        m = m / (m.sum(-2, keepdims=True) + eps)
+    return m
+
+
+# --------------------------------------------------------------------------- #
 # the delta rule with a decay a channel: one token, and a whole prompt
 # --------------------------------------------------------------------------- #
 
@@ -576,21 +748,65 @@ def delta_rule_chunked(q, k, v, g, beta):
 # --------------------------------------------------------------------------- #
 
 
+class Indexer(nn.Module):
+    """DeepSeek-V3.2's lightning indexer, the ``q``, ``k`` and ``w`` of
+    :func:`indexer_selection`: ``q = W_q c_q`` (``c_q`` the normed query
+    latent), ``index_n_heads`` heads of ``index_head_dim``; ``k =
+    LayerNorm(W_k x)``, one head; ``w = W_w x * index_n_heads^-0.5``,
+    float32; rotary positions on the first ``qk_rope_head_dim`` values of
+    ``q`` and ``k``."""
+
+    cfg: DecoderConfig
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, q_latent, positions):
+        cfg = self.cfg
+        B, L, _ = x.shape
+        Hi, D, dr = cfg.index_n_heads, cfg.index_head_dim, cfg.qk_rope_head_dim
+        dense = partial(nn.Dense, use_bias=False, dtype=self.dtype,
+                        param_dtype=self.param_dtype)
+        q = dense(Hi * D, name="wq")(q_latent).reshape(B, L, Hi, D)
+        k = nn.LayerNorm(epsilon=1e-6, dtype=self.dtype,
+                         param_dtype=self.param_dtype, name="k_norm")(
+            dense(D, name="wk")(x))
+        w = nn.Dense(Hi, use_bias=False, dtype=jnp.float32,
+                     param_dtype=self.param_dtype, name="weights_proj")(
+            x) * Hi ** -0.5
+        q = jnp.concatenate(
+            [apply_rope(q[..., :dr], positions[:, :, None], cfg),
+             q[..., dr:]], axis=-1)
+        k = jnp.concatenate(
+            [apply_rope(k[..., :dr], positions, cfg), k[..., dr:]], axis=-1)
+        return q, k, w
+
+
 class LatentAttention(nn.Module):
     """Multi-head latent attention (the module docstring's ``"mla"``).  The
     two scales of ``mla_scale_q_lora`` / ``mla_scale_kv_lora`` ride on the
     norms they follow: ``W_qb`` is linear, so ``s_q W_qb n = W_qb (s_q n)``,
     and the latent is scaled before it is cached, so the cached row is
     ``[s_kv RMSNorm(c~), rope(k_r)]`` and both forms of the attention read
-    it as it is."""
+    it as it is.
+
+    In the DSA family (``use_dsa``) each query attends the keys its layer's
+    selection chose: an ``indexer`` layer makes the selection
+    (:class:`Indexer`), a layer without one is given the selection of the
+    ``full`` layer before it; a sink a head joins the softmax's denominator
+    (``learnable_sink``) and the attended values are gated elementwise,
+    ``o = W_o (attn * sigmoid(W_gate x))`` (``mla_gate``).  Returns ``(out,
+    selection)`` there (the selection of the whole-sequence form; None
+    through a cache hook, which keeps its own)."""
 
     cfg: DecoderConfig
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
     attention: str = "dense"
+    indexer: bool = False
 
     @nn.compact
-    def __call__(self, x, positions, attend=None):
+    def __call__(self, x, positions, attend=None, selection=None):
         cfg = self.cfg
         s_q = ((cfg.hidden_size / cfg.q_lora_rank) ** 0.5
                if cfg.mla_scale_q_lora else 1.0)
@@ -604,8 +820,8 @@ class LatentAttention(nn.Module):
         norm = partial(RMSNorm, cfg.rms_norm_eps, self.dtype,
                        self.param_dtype)
         q = dense(cfg.q_lora_rank, name="q_a")(x)
-        q = dense(H * cfg.qk_head_dim, name="q_b")(
-            norm(gain=s_q, name="q_a_norm")(q))
+        q_latent = norm(gain=s_q, name="q_a_norm")(q)
+        q = dense(H * cfg.qk_head_dim, name="q_b")(q_latent)
         q = q.reshape(B, L, H, cfg.qk_head_dim)
         q_nope = q[..., :dn]
         q_rope = apply_rope(q[..., dn:], positions[:, :, None], cfg)
@@ -617,15 +833,96 @@ class LatentAttention(nn.Module):
             (cfg.kv_lora_rank, H * (dn + cfg.v_head_dim)), self.param_dtype,
         ).reshape(cfg.kv_lora_rank, H, dn + cfg.v_head_dim)
         scale = softmax_scale(cfg)
+        if not cfg.use_dsa:
+            if attend is None:
+                out = expanded_attention(
+                    q_nope, q_rope, c, k_rope, w_kvb, scale,
+                    jnp.ones((B, L), bool), self.attention,
+                )
+            else:
+                out = attend(q_nope, q_rope, c, k_rope, w_kvb, scale)
+            return dense(cfg.hidden_size, name="o")(
+                out.reshape(B, L, H * cfg.v_head_dim))
+        index = None
+        if self.indexer:
+            with jax.named_scope("index"):
+                index = Indexer(cfg, self.dtype, self.param_dtype,
+                                name="indexer")(x, q_latent, positions)
+        sinks = (self.param("sinks", nn.initializers.zeros, (H,),
+                            jnp.float32)
+                 if cfg.learnable_sink else jnp.full((H,), -jnp.inf))
         if attend is None:
-            out = expanded_attention(
-                q_nope, q_rope, c, k_rope, w_kvb, scale,
-                jnp.ones((B, L), bool), self.attention,
-            )
+            if index is not None:
+                with jax.named_scope("select"):
+                    selection = indexer_selection(
+                        *index, cfg.index_topk, jnp.ones((B, L), bool))
+            out = sparse_expanded_attention(
+                q_nope, q_rope, c, k_rope, w_kvb, scale, sinks, selection)
         else:
-            out = attend(q_nope, q_rope, c, k_rope, w_kvb, scale)
-        return dense(cfg.hidden_size, name="o")(
-            out.reshape(B, L, H * cfg.v_head_dim))
+            out, selection = attend(q_nope, q_rope, c, k_rope, w_kvb, scale,
+                                    sinks, index), None
+        out = out.reshape(B, L, H * cfg.v_head_dim)
+        if cfg.mla_gate:
+            with jax.named_scope("gate"):
+                gate = dense(H * cfg.v_head_dim, name="gate")(x)
+                out = (out.astype(jnp.float32)
+                       * jax.nn.sigmoid(gate.astype(jnp.float32))
+                       ).astype(self.dtype)
+        return dense(cfg.hidden_size, name="o")(out), selection
+
+
+class HyperConnection(nn.Module):
+    """Manifold-constrained hyper-connections (DeepSeek's mHC) around one
+    sublayer ``F`` of a residual stream of ``n`` rows, ``X [B, L, n, H]``:
+    with ``x = RMSNorm(vec X)`` (no gain: it folds into ``phi``),
+    ``H_pre = sigmoid(a_pre x phi_pre + b_pre)``, ``H_post = magnitude *
+    sigmoid(a_post x phi_post + b_post)``, ``H_res = Sinkhorn(exp(a_res
+    mat(x phi_res) + b_res))``; the layer is ``X' = H_res X + H_post^T
+    F(H_pre X)``.  Called, it returns ``(H_pre X, combine)``, ``combine(y)``
+    being ``X'``.  Coefficients float32, ``x phi`` at ``highest``
+    precision."""
+
+    n: int
+    magnitude: float = 2.0
+    eps: float = 1e-6
+    norm_eps: float = 1e-6
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, X):
+        B, L, n, H = X.shape
+        f32 = jnp.float32
+        # the norm is a scalar a token, so it is applied after the product:
+        # x phi = (vec X phi) / rms(vec X), and no float32 copy of the
+        # stream is made (at a 24,576-token prompt it is 2.4 GB)
+        rms = jnp.sqrt(jnp.mean(jnp.square(X.astype(f32)), axis=(2, 3))
+                       + self.norm_eps)[..., None]
+        width = 2 * n + n * n
+        phi = self.param("phi", nn.initializers.lecun_normal(),
+                         (n * H, width), f32)
+        alpha = self.param("alpha", nn.initializers.ones, (3,), f32)
+        bias = self.param("bias", nn.initializers.normal(1.0), (width,), f32)
+        z = jnp.dot(X.reshape(B, L, n * H), phi,
+                    precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=f32) / rms
+        pre = jax.nn.sigmoid(alpha[0] * z[..., :n] + bias[:n])
+        post = self.magnitude * jax.nn.sigmoid(
+            alpha[1] * z[..., n:2 * n] + bias[n:2 * n])
+        res = sinkhorn(
+            (alpha[2] * z[..., 2 * n:] + bias[2 * n:]).reshape(B, L, n, n),
+            SINKHORN_ITERS, self.eps)
+        # the mixes as sums of n rows, each an elementwise pass over the
+        # stream in its own dtype
+        u = sum(pre[..., j, None] * X[:, :, j].astype(f32) for j in range(n))
+
+        def combine(y):
+            y = y.astype(f32)
+            return jnp.stack([
+                sum(res[..., i, j, None] * X[:, :, j].astype(f32)
+                    for j in range(n)) + post[..., i, None] * y
+                for i in range(n)], axis=2).astype(self.dtype)
+
+        return u.astype(self.dtype), combine
 
 
 class GroupedQueryAttention(nn.Module):
@@ -809,6 +1106,11 @@ class DecoderLayer(nn.Module):
                                  self.param_dtype, "ffn")(x)
 
 
+#: tokens of a long prompt a DSA model's expert layer routes at a time: its
+#: assignments' rows, gathered and in float32, would not fit whole
+EXPERT_TOKEN_CHUNK = 4096
+
+
 def _expert_share(cfg: DecoderConfig, held_experts, dtype, param_dtype,
                   name: str) -> ExpertShareFFN:
     """The expert layer ``cfg`` describes, holding ``held_experts``."""
@@ -818,8 +1120,62 @@ def _expert_share(cfg: DecoderConfig, held_experts, dtype, param_dtype,
         cfg.n_group, cfg.topk_group, cfg.routed_scaling_factor,
         cfg.norm_topk_prob, cfg.n_shared_experts, dtype, param_dtype,
         cfg.scoring_func, cfg.router_choice_bias, cfg.zero_expert_num,
+        cfg.swiglu_limit, EXPERT_TOKEN_CHUNK if cfg.use_dsa else 0,
         name=name,
     )
+
+
+class StreamLayer(nn.Module):
+    """A layer of the DSA family: gated sparse latent attention, then the
+    dense or expert feed-forward, each pre-normed and inside its own
+    :class:`HyperConnection` over the ``hc_mult`` rows of the residual
+    stream.  ``attend`` is the cache hook's
+    ``sparse_attention(i)``; ``selection`` the whole-sequence form's
+    selection of the ``full`` layer before this one.  Returns ``(h,
+    selection)``."""
+
+    cfg: DecoderConfig
+    index: int
+    held_experts: Optional[Tuple[int, int]] = None
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+    attention: str = "dense"
+
+    @nn.compact
+    def __call__(self, h, positions, attend=None, selection=None):
+        cfg = self.cfg
+        norm = partial(RMSNorm, cfg.rms_norm_eps, self.dtype,
+                       self.param_dtype)
+        found = {}
+
+        def attn(u):
+            out, found["selection"] = LatentAttention(
+                cfg, self.dtype, self.param_dtype, self.attention,
+                cfg.indexes(self.index), name="attn",
+            )(norm(name="attn_norm")(u), positions, attend, selection)
+            return out
+
+        def ffn(u):
+            x = norm(name="ffn_norm")(u)
+            if self.index < cfg.first_k_dense_replace:
+                return SwiGLU(cfg.intermediate_size, self.dtype,
+                              self.param_dtype, cfg.swiglu_limit,
+                              name="ffn")(x)
+            return _expert_share(cfg, self.held_experts, self.dtype,
+                                 self.param_dtype, "ffn")(x)
+
+        def residual(name, f, h):
+            with jax.named_scope("hc"):
+                u, combine = HyperConnection(
+                    cfg.hc_mult, cfg.hc_magnitude, cfg.hc_eps,
+                    cfg.rms_norm_eps, self.dtype, name=name + "_hc")(h)
+            y = f(u)
+            with jax.named_scope("hc"):
+                return combine(y)
+
+        with jax.named_scope("mla"):
+            h = residual("attn", attn, h)
+        return residual("ffn", ffn, h), found["selection"]
 
 
 class ShortcutDoubleLayer(nn.Module):
@@ -923,6 +1279,22 @@ class Decoder(nn.Module):
 
     def cache_spec(self) -> CacheSpec:
         cfg = self.cfg
+        if cfg.use_dsa:
+            # the latent rows kept as 32-bit words, a row a whole-tile
+            # slice (``ops/sparse_attention.py``), in every layer; the
+            # indexer's keys in the ``full`` layers only
+            return CacheSpec(
+                layers=cfg.num_hidden_layers,
+                planes=(("latent", cfg.latent_width, {"packed": True}),
+                        ("index", cfg.index_head_dim,
+                         {"layers": cfg.full_layers})),
+                values=cfg.latent_width,
+                kind="sparse_latent",
+                heads=cfg.num_attention_heads,
+                head_dim=cfg.qk_head_dim,
+                max_len=cfg.max_position_embeddings,
+                index_topk=cfg.index_topk,
+            )
         if cfg.gqa_layers is None:
             return CacheSpec(
                 layers=cfg.latent_sublayers,
@@ -982,6 +1354,8 @@ class Decoder(nn.Module):
         )(input_ids)
         hook_method = {"mla": "latent_attention", "gqa": "layer_attention",
                        "kda": "layer_state"}
+        if cfg.use_dsa:
+            return self._streams(h, positions, kv_cache)
         for i in range(cfg.num_hidden_layers):
             if cfg.shortcut_double_layers:
                 # sublayer j of double layer i is row 2 i + j of the plane
@@ -1004,6 +1378,33 @@ class Decoder(nn.Module):
             return nn.Dense(
                 cfg.vocab_size, use_bias=False, dtype=self.dtype,
                 param_dtype=self.param_dtype, name="lm_head",
+                dot_general=partial(jax.lax.dot_general,
+                                    preferred_element_type=jnp.float32),
+            )(h)
+
+    def _streams(self, h, positions, kv_cache):
+        """The DSA family's layers and head: ``hc_mult`` copies of the
+        embedding as the residual stream, summed before the final norm; the
+        head in float32 under ``lm_head_fp32``."""
+        cfg = self.cfg
+        h = jnp.broadcast_to(h[:, :, None, :], h.shape[:2] + (
+            cfg.hc_mult, h.shape[-1]))
+        selection = None
+        for i in range(cfg.num_hidden_layers):
+            attend = (None if kv_cache is None
+                      else kv_cache.sparse_attention(i))
+            h, selection = StreamLayer(
+                cfg, i, self.held_experts, self.dtype, self.param_dtype,
+                self.attention, name=f"layer_{i}",
+            )(h, positions, attend, selection)
+        head_dtype = jnp.float32 if cfg.lm_head_fp32 else self.dtype
+        with jax.named_scope("head"):
+            h = RMSNorm(cfg.rms_norm_eps, head_dtype, self.param_dtype,
+                        name="norm")(h.astype(jnp.float32).sum(axis=2))
+            return nn.Dense(
+                cfg.vocab_size, use_bias=False, dtype=head_dtype,
+                param_dtype=(jnp.float32 if cfg.lm_head_fp32
+                             else self.param_dtype), name="lm_head",
                 dot_general=partial(jax.lax.dot_general,
                                     preferred_element_type=jnp.float32),
             )(h)

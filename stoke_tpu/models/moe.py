@@ -146,7 +146,7 @@ class MoEFFN(nn.Module):
 
 
 def group_limited_topk(scores, n_group: int, topk_group: int, top_k: int,
-                       scale: float = 1.0, norm: bool = True):
+                       scale: float = 1.0, norm: bool = True, bias=None):
     """Group-limited top-k routing (the DeepSeek-V3 family's, without a
     score-correction bias): ``scores [N, E]`` float32, one column an expert,
     the experts in ``n_group`` contiguous groups.  A group's score is the sum
@@ -154,10 +154,15 @@ def group_limited_topk(scores, n_group: int, topk_group: int, top_k: int,
     ``top_k`` highest scores within them are the token's experts.  Returns
     ``(experts [N, top_k] int32, weights [N, top_k] float32)``: the chosen
     scores, renormalised over the ``top_k`` when ``norm``, times ``scale``.
-    Ties go to the lower index, at both levels."""
+    Ties go to the lower index, at both levels.  With a score-correction
+    ``bias [E]`` (the family's ``noaux_tc``) groups and experts are chosen
+    by ``scores + bias`` and weighed by the plain scores."""
     N, E = scores.shape
     if E % n_group:
         raise ValueError(f"{E} experts do not split into {n_group} groups")
+    if bias is not None:
+        return _biased_group_topk(scores, bias, n_group, topk_group, top_k,
+                                  scale, norm)
     grouped = scores.reshape(N, n_group, E // n_group)
     group_score = jax.lax.top_k(grouped, min(2, E // n_group))[0].sum(-1)
     _, kept = jax.lax.top_k(group_score, topk_group)  # [N, topk_group]
@@ -167,6 +172,24 @@ def group_limited_topk(scores, n_group: int, topk_group: int, top_k: int,
     # scores are sigmoids, above 0: -1 ranks every masked expert last
     masked = jnp.where(in_kept[:, :, None], grouped, -1.0).reshape(N, E)
     weights, experts = jax.lax.top_k(masked, top_k)
+    if norm:
+        weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), weights * scale
+
+
+def _biased_group_topk(scores, bias, n_group, topk_group, top_k, scale,
+                       norm):
+    """:func:`group_limited_topk` choosing by ``scores + bias``."""
+    N, E = scores.shape
+    choice = (scores + bias).reshape(N, n_group, E // n_group)
+    group_score = jax.lax.top_k(choice, min(2, E // n_group))[0].sum(-1)
+    _, kept = jax.lax.top_k(group_score, topk_group)
+    in_kept = (
+        kept[:, :, None] == jnp.arange(n_group, dtype=kept.dtype)
+    ).any(axis=1)
+    masked = jnp.where(in_kept[:, :, None], choice, -jnp.inf).reshape(N, E)
+    _, experts = jax.lax.top_k(masked, top_k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
     if norm:
         weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
     return experts.astype(jnp.int32), weights * scale
@@ -193,11 +216,13 @@ def softmax_topk(logits, bias, top_k: int, scale: float = 1.0,
 
 class SwiGLU(nn.Module):
     """``W_d(silu(W_g x) * W_u x)``, no biases; products accumulate in
-    float32 and come back in ``dtype``."""
+    float32 and come back in ``dtype``.  With a ``limit`` (gpt-oss's form):
+    ``W_d(silu(min(W_g x, limit)) * clip(W_u x, -limit, limit))``."""
 
     width: int
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
+    limit: float = 0.0
 
     @nn.compact
     def __call__(self, x):
@@ -207,9 +232,14 @@ class SwiGLU(nn.Module):
                 param_dtype=self.param_dtype, name=name,
             )(y)
 
-        gated = jax.nn.silu(proj("gate", self.width, x)) * proj(
-            "up", self.width, x
-        )
+        if self.limit:
+            gated = jax.nn.silu(jnp.minimum(
+                proj("gate", self.width, x), self.limit)) * jnp.clip(
+                    proj("up", self.width, x), -self.limit, self.limit)
+        else:
+            gated = jax.nn.silu(proj("gate", self.width, x)) * proj(
+                "up", self.width, x
+            )
         return proj("down", x.shape[-1], gated)
 
 
@@ -268,6 +298,8 @@ class ExpertShareFFN(nn.Module):
     scoring: str = "sigmoid"
     choice_bias: bool = False
     zero_experts: int = 0
+    swiglu_limit: float = 0.0
+    token_chunk: int = 0
 
     @nn.compact
     def __call__(self, x):
@@ -282,11 +314,10 @@ class ExpertShareFFN(nn.Module):
             )
         if self.scoring not in ("sigmoid", "softmax"):
             raise ValueError(f"ExpertShareFFN: scoring {self.scoring!r}")
-        if self.scoring == "sigmoid" and (self.choice_bias
-                                          or self.zero_experts):
+        if self.scoring == "sigmoid" and self.zero_experts:
             raise ValueError(
-                "ExpertShareFFN: the sigmoid router has no choice bias and "
-                "no zero-compute outputs here")
+                "ExpertShareFFN: the sigmoid router has no zero-compute "
+                "outputs here")
         if self.scoring == "softmax" and self.n_group != 1:
             raise ValueError(
                 "ExpertShareFFN: the softmax router has no groups here")
@@ -300,16 +331,16 @@ class ExpertShareFFN(nn.Module):
                 xf.astype(jnp.float32), w_r,
                 precision=jax.lax.Precision.HIGHEST,
             )
+            bias = self.param(
+                "e_score_correction_bias", nn.initializers.zeros,
+                (self.num_experts + self.zero_experts,), jnp.float32,
+            ) if self.choice_bias else None
             if self.scoring == "sigmoid":
                 experts, weights = group_limited_topk(
                     jax.nn.sigmoid(logits), self.n_group, self.topk_group,
-                    k, self.routed_scaling_factor, self.norm_topk_prob,
+                    k, self.routed_scaling_factor, self.norm_topk_prob, bias,
                 )
             else:
-                bias = self.param(
-                    "e_score_correction_bias", nn.initializers.zeros,
-                    (self.num_experts + self.zero_experts,), jnp.float32,
-                ) if self.choice_bias else None
                 experts, weights = softmax_topk(
                     logits, bias, k, self.routed_scaling_factor,
                     self.norm_topk_prob,
@@ -323,14 +354,6 @@ class ExpertShareFFN(nn.Module):
             self.sow("intermediates", "zero_expert_count",
                      is_zero.sum(axis=1, dtype=jnp.int32))
         with jax.named_scope("moe"):
-            local = experts - first
-            is_held = (local >= 0) & (local < count)  # [N, k]
-            key = jnp.where(is_held, local, count).reshape(N * k)
-            counts = (
-                key[:, None] == jnp.arange(count, dtype=key.dtype)
-            ).sum(axis=0, dtype=jnp.int32)
-            order = jnp.argsort(key, stable=True)  # held first, by expert
-            rows = xf.astype(self.dtype)[order // k]  # [N*k, H]
             init = nn.initializers.lecun_normal(batch_axis=(0,))
             w_gate, w_up = (
                 self.param(name, init, (count, H, self.ff), self.param_dtype)
@@ -340,17 +363,41 @@ class ExpertShareFFN(nn.Module):
                 "w_down", init, (count, self.ff, H), self.param_dtype
             )
 
-            mid = grouped_swiglu(rows, w_gate, w_up, counts)
-            y = grouped_matmul(mid, w_down, counts)  # [N*k, H] float32
-            y = y[jnp.argsort(order)].reshape(N, k, H)
-            # a row of no group holds whatever the grouped product left
-            y = jnp.where(is_held[:, :, None], y, 0.0)
-            routed = (y * weights[:, :, None]).sum(axis=1)
+            def held_part(xf, experts, weights):
+                n = xf.shape[0]
+                local = experts - first
+                is_held = (local >= 0) & (local < count)  # [n, k]
+                key = jnp.where(is_held, local, count).reshape(n * k)
+                counts = (
+                    key[:, None] == jnp.arange(count, dtype=key.dtype)
+                ).sum(axis=0, dtype=jnp.int32)
+                order = jnp.argsort(key, stable=True)  # held first
+                rows = xf.astype(self.dtype)[order // k]  # [n*k, H]
+                mid = grouped_swiglu(rows, w_gate, w_up, counts,
+                                     limit=self.swiglu_limit)
+                y = grouped_matmul(mid, w_down, counts)  # [n*k, H] float32
+                y = y[jnp.argsort(order)].reshape(n, k, H)
+                # a row of no group holds whatever the grouped product left
+                y = jnp.where(is_held[:, :, None], y, 0.0)
+                return (y * weights[:, :, None]).sum(axis=1), counts
+
+            chunk = self.token_chunk
+            if chunk and N > chunk and N % chunk == 0:
+                # a long prompt's assignments a chunk of tokens at a time:
+                # its N x k rows, gathered and in float32, would not fit
+                routed, counts = jax.lax.map(
+                    lambda a: held_part(*a),
+                    (xf.reshape(N // chunk, chunk, H),
+                     experts.reshape(N // chunk, chunk, k),
+                     weights.reshape(N // chunk, chunk, k)))
+                routed, counts = routed.reshape(N, H), counts.sum(axis=0)
+            else:
+                routed, counts = held_part(xf, experts, weights)
             if zero_part is not None:
                 routed = routed + zero_part
             shared = SwiGLU(
                 self.ff * self.n_shared_experts, self.dtype,
-                self.param_dtype, name="shared",
+                self.param_dtype, self.swiglu_limit, name="shared",
             )(x) if self.n_shared_experts else None
             out = routed.reshape(B, L, H).astype(self.dtype)
             if shared is not None:

@@ -76,10 +76,11 @@ def _visits(group_sizes, m: int, tm: int):
 
 
 def _kernel(offsets_ref, group_ids_ref, tile_ids_ref, lhs_ref, *refs, tm,
-            swiglu):
+            swiglu, limit=None):
     """One visit: the row tile times the group's resident weight tile (two,
-    gate and up, for a SwiGLU), stored over the group's own rows only; the
-    tile's other rows keep what their groups' visits wrote."""
+    gate and up, for a SwiGLU, the gate at most ``limit`` and the up
+    product within it where there is one), stored over the group's own rows
+    only; the tile's other rows keep what their groups' visits wrote."""
     *rhs_refs, out_ref = refs
     v = pl.program_id(1)
     g = group_ids_ref[v]
@@ -91,7 +92,11 @@ def _kernel(offsets_ref, group_ids_ref, tile_ids_ref, lhs_ref, *refs, tm,
         )
         for w in rhs_refs
     ]
-    y = jax.nn.silu(y[0]) * y[1] if swiglu else y[0]
+    if swiglu and limit:
+        y = jax.nn.silu(jnp.minimum(y[0], limit)) * jnp.clip(y[1], -limit,
+                                                             limit)
+    else:
+        y = jax.nn.silu(y[0]) * y[1] if swiglu else y[0]
     row = tile_ids_ref[v] * tm + jax.lax.broadcasted_iota(
         jnp.int32, y.shape, 0)
     mine = (row >= offsets_ref[g]) & (row < offsets_ref[g + 1])
@@ -99,10 +104,11 @@ def _kernel(offsets_ref, group_ids_ref, tile_ids_ref, lhs_ref, *refs, tm,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("out_dtype", "name", "interpret", "part")
+    jax.jit, static_argnames=("out_dtype", "name", "interpret", "part",
+                              "limit")
 )
 def _grouped(lhs, rhs: Sequence, group_sizes, out_dtype, name: str,
-             interpret: bool, part):
+             interpret: bool, part, limit=None):
     m, k = lhs.shape
     G, _, n = rhs[0].shape
     for w in rhs:
@@ -124,7 +130,8 @@ def _grouped(lhs, rhs: Sequence, group_sizes, out_dtype, name: str,
         (None, k, tn), lambda j, v, off, gid, tid: (gid[v], 0, j)
     )
     call = pl.pallas_call(
-        functools.partial(_kernel, tm=tm, swiglu=len(rhs) == 2),
+        functools.partial(_kernel, tm=tm, swiglu=len(rhs) == 2,
+                          limit=limit),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,  # offsets, the visits' groups and tiles
             grid=(n // tn, n_visits),
@@ -191,12 +198,15 @@ def grouped_matmul(lhs, rhs, group_sizes, *,
 
 
 def grouped_swiglu(lhs, w_gate, w_up, group_sizes, *,
-                   interpret: Optional[bool] = None):
+                   interpret: Optional[bool] = None,
+                   limit: Optional[float] = None):
     """``silu(lhs @ w_gate[g]) * (lhs @ w_up[g])`` over the same groups as
     :func:`grouped_matmul`, both products in one walk over the rows:
-    float32 products, the result written once, in ``lhs``'s dtype."""
+    float32 products, the result written once, in ``lhs``'s dtype.  With a
+    ``limit``: ``silu(min(gate, limit)) * clip(up, -limit, limit)``."""
     return _grouped(lhs, (w_gate, w_up), group_sizes, lhs.dtype,
-                    "grouped_swiglu", *_where(interpret))
+                    "grouped_swiglu", *_where(interpret),
+                    float(limit) if limit else None)
 
 
 def expert_weight_passes(counts) -> float:

@@ -87,10 +87,12 @@ from stoke_tpu.configs import ServeConfig
 from stoke_tpu.ops.delta_rule import state_passes
 from stoke_tpu.ops.flash_attention import partition_kernels_over
 from stoke_tpu.ops.grouped_matmul import expert_weight_passes
+from stoke_tpu.ops.sparse_attention import sparse_rows_fetched
 from stoke_tpu.serving.kv_cache import (
     BlockAllocator,
     HybridCacheHook,
     LatentAttentionHook,
+    SparseLatentHook,
     PagedAttentionHook,
     PagedKVCache,
 )
@@ -656,6 +658,12 @@ class ServingEngine:
                 pages[:n], pages[n:], tables, positions, mode=mode,
                 lengths=lengths, layer_kinds=self._spec.layer_kinds,
                 slot=slot, attention_impl=self.cfg.attention,
+            )
+        if self._spec.kind == "sparse_latent":
+            return SparseLatentHook(
+                pages, tables, positions, mode=mode, lengths=lengths,
+                index_topk=self._spec.index_topk,
+                index_layers=self._spec.plane_layers("index"),
             )
         if self._spec.kind == "latent":
             return LatentAttentionHook(
@@ -1536,6 +1544,8 @@ class ServingEngine:
             "upload_arrays": len(host_args),
             "upload_bytes": _nbytes(host_args),
         }
+        if self._spec.kind == "sparse_latent":
+            step_attrs.update(self._sparse_counts(host_args[3], decode_rows))
         if self.cache.state:
             passes = state_passes(len(decode_rows), self.cfg.max_seqs)
             m.state_passes.set(passes)
@@ -1584,6 +1594,27 @@ class ServingEngine:
                 m.sampled_tokens.inc(n_sampled)
             for rid in set(sched.finished) - was_finished:
                 self._finish(sched.finished[rid])
+
+    def _sparse_counts(self, context, live) -> dict:
+        """What a decode step of a sparse-attention model read: the keys
+        its indexers scored (every live slot to its length, in each layer
+        with an indexer), the rows its selections chose (``min(context,
+        index_topk)`` a live slot and layer), and the bytes of latent plane
+        the row kernel fetched over the chosen rows' bytes (its grid walks
+        every slot, whole steps of rows, a row as stored in words)."""
+        spec = self._spec
+        chosen = np.minimum(context, spec.index_topk)
+        selected = spec.layers * int(chosen[live].sum())
+        words = self.cache.pages[0].shape[-1]
+        fetched = spec.layers * sparse_rows_fetched(
+            chosen, spec.index_topk) * words * 4
+        row_bytes = spec.values_per_token * self.cache.dtype.itemsize
+        return {
+            "indexer_keys": len(spec.plane_layers("index"))
+            * int(context[live].sum()),
+            "selected_rows": selected,
+            "sparse_row_passes": fetched / max(selected * row_bytes, 1),
+        }
 
     def run(self, max_steps: Optional[int] = None) -> int:
         """Drive :meth:`step` until drained (or ``max_steps``); emits a

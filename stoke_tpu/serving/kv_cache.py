@@ -34,11 +34,15 @@ The pieces:
   cache a row a token, and slot-indexed state arrays for the layers that
   keep a constant state a slot (a linear-attention layer's recurrent state,
   its convolution's last inputs), which cache no rows and get no plane.
+- :class:`SparseLatentHook` — a model with learned sparse attention: a
+  packed latent plane read row by row, and a plane of indexer keys that
+  only the layers with an indexer have.
 """
 
 from __future__ import annotations
 
 from collections import deque
+from functools import partial
 from typing import Deque, List, Optional, Sequence
 
 import jax
@@ -53,10 +57,24 @@ from stoke_tpu.ops.flash_attention import (
     paged_pool_attention,
     paged_verify_attention_pallas,
 )
+from stoke_tpu.ops.sparse_attention import (
+    index_scores,
+    pack_rows,
+    packed_width,
+    prefill_selection,
+    row_addresses,
+    select_topk,
+    sparse_flash_attention,
+    sparse_latent_attention,
+    write_rows,
+)
 
 #: block id every unused block-table entry (and every inactive slot) points
 #: at — allocated to no request, read by nothing meaningful
 SCRATCH_BLOCK = 0
+
+#: heads :class:`SparseLatentHook` expands and attends at a time in prefill
+PREFILL_HEADS = 16
 
 
 class BlockAllocator:
@@ -182,6 +200,13 @@ class PagedKVCache:
 
     ``planes`` is the model's ``CacheSpec.planes``: ``(("k", 768), ("v",
     768))`` for 12 heads of 64, ``(("latent", 640),)`` for the latent row.
+    A third element of an entry gives the plane's options: ``"layers"``,
+    the model's layers that keep a row of it (a plane a layer SET: the
+    indexer keys of a sparse model's ``full`` layers; default every row
+    layer), and ``"packed"``: the row's ``width`` values kept as 32-bit
+    words, ``[layers, NB, BS, 1, words]`` ``uint32``
+    (``ops/sparse_attention.py`` ``pack_rows``), so that one row is a whole
+    tile of the array's layout and a kernel can fetch it alone.
 
     ``n_layers`` counts the layers that cache rows.  A model whose other
     layers keep a constant state a slot (``CacheSpec.layer_kinds``,
@@ -213,19 +238,24 @@ class PagedKVCache:
         self.block_size = int(block_size)
         self.num_blocks = int(num_blocks)
         self.dtype = jnp.dtype(dtype)
-        self.plane_names = tuple(name for name, _ in planes)
+        self.plane_names = tuple(plane[0] for plane in planes)
 
         def zeros(shape, dtype):
             array = jnp.zeros(shape, dtype)
             return (array if sharding is None
                     else jax.device_put(array, sharding))
 
+        def plane(name, width, options=None):
+            options = options or {}
+            layers = len(options.get("layers", range(n_layers)))
+            if options.get("packed"):
+                return zeros((layers, num_blocks, block_size, 1,
+                              packed_width(width, self.dtype)), jnp.uint32)
+            return zeros((layers, num_blocks, block_size, width), dtype)
+
         #: the planes, in the model's order; the serve programs take them
         #: (donated) and hand them back
-        self.pages = tuple(
-            zeros((n_layers, num_blocks, block_size, width), dtype)
-            for _, width in planes
-        )
+        self.pages = tuple(plane(*spec) for spec in planes)
         #: the second store: what the layers that cache no rows keep, a
         #: constant size a slot and addressed by slot (``CacheSpec.state``):
         #: ``[max_seqs, *shape]`` each, an array an entry a state layer
@@ -259,7 +289,7 @@ class PagedKVCache:
     @property
     def nbytes(self) -> int:
         """HBM footprint of the pool (all planes, unpadded)."""
-        return sum(int(p.size) for p in self.pages) * self.dtype.itemsize
+        return sum(int(p.size) * p.dtype.itemsize for p in self.pages)
 
     @property
     def state_nbytes(self) -> int:
@@ -268,9 +298,8 @@ class PagedKVCache:
 
     @property
     def bytes_per_token(self) -> int:
-        """Cached bytes a token, over all row layers and planes."""
-        return self.n_layers * self.dtype.itemsize * sum(
-            int(p.shape[-1]) for p in self.pages)
+        """Cached bytes a token, over all planes, each at its own layers."""
+        return self.nbytes // (self.num_blocks * self.block_size)
 
 
 def _write_targets(block_tables, positions, block_size: int, lengths=None):
@@ -744,5 +773,149 @@ class HybridCacheHook:
             values = o_row[..., G * D:].reshape(B, H, G, D)
             return jnp.where(own[None, :, :, None], values, 0).sum(
                 axis=2)[:, None].astype(q.dtype)
+
+        return attend
+
+
+class SparseLatentHook:
+    """Per-trace cache bridge for a model with learned sparse attention
+    (``CacheSpec.kind == "sparse_latent"``, ``Decoder`` of the DSA family):
+    the latent rows of every layer in a packed plane (32-bit words, a row a
+    whole-tile ``[1, words]`` slice), the indexer's keys of the layers that
+    have an indexer in a second plane that only those layers have
+    (``index_layers``: the model's layer of each of its rows).
+
+    ``sparse_attention(i)`` returns layer ``i``'s ``attend(q_nope, q_rope,
+    c, k_rope, w_kvb, scale, sinks, index)``.  It writes the call's latent
+    rows (and, where ``index = (q, k, w)`` is given, the indexer keys) into
+    the slot's blocks with :class:`PagedAttentionHook`'s steering, then:
+
+    - a layer with an indexer makes the selection, which the layers after
+      it without one reuse within the same program: in ``"decode"`` mode
+      each slot's query scored against its keys read in place to the slot's
+      own length (``index_scores``), the exact top ``index_topk``
+      (``select_topk``) and their rows' places in the pool; in
+      ``"prefill"`` mode the prompt's queries a block at a time
+      (``prefill_selection``), as bits;
+    - attends: in ``"decode"`` mode the absorbed form over the chosen rows,
+      each fetched alone (``sparse_latent_attention``); in ``"prefill"``
+      mode the expanded form under the selection (``sparse_flash_attention``,
+      flash attention that reads the bits), a sink a head in both.
+
+    Args as :class:`LatentAttentionHook`'s, with ``pages`` the pool's two
+    planes ``(latent words [layers, NB, BS, 1, W], index keys [index
+    layers, NB, BS, D])``.
+    """
+
+    def __init__(self, pages, block_tables, positions, *, mode: str,
+                 lengths, index_topk: int, index_layers):
+        if mode not in ("prefill", "decode"):
+            raise NotImplementedError(
+                f"SparseLatentHook has no {mode!r} mode: the sparse cache "
+                f"is written and read by the serve_prefill and serve_decode "
+                f"programs only"
+            )
+        self.latent_words, self.index_keys = pages
+        self.block_tables = block_tables
+        self.positions = positions
+        self.mode = mode
+        self.lengths = lengths
+        self.index_topk = int(index_topk)
+        self._index_row = {layer: row for row, layer in enumerate(index_layers)}
+        self.block_size = int(self.index_keys.shape[2])
+        #: the last selection made: ``(addresses, counts)`` in decode, the
+        #: bits in prefill
+        self.selection = None
+
+    @property
+    def pages(self) -> tuple:
+        return (self.latent_words, self.index_keys)
+
+    def _select(self, row, q, k, w):
+        if self.mode == "decode":
+            scores = index_scores(
+                q[:, 0], w[:, 0, :, None], self.index_keys, row,
+                self.block_tables, self.lengths, q.shape[-1] ** -0.5)
+            chosen, count = select_topk(scores[:, 0], self.lengths,
+                                        self.index_topk)
+            return row_addresses(chosen, count, self.block_tables,
+                                 self.block_size), count
+        return prefill_selection(
+            jnp.swapaxes(q[0], 0, 1), jnp.swapaxes(w[0], 0, 1)[..., None],
+            k[0], self.index_topk, q.shape[-1] ** -0.5)
+
+    def _prefill_attention(self, q_nope, q_rope, c, k_rope, w_kvb, scale,
+                           sinks):
+        """The expanded form under the selection, ``PREFILL_HEADS`` heads
+        at a time: a group's keys and values, expanded from the latent over
+        the whole prompt, are what a call holds (all 64 heads' would be 1.4
+        GB at a 24,576-token prompt)."""
+        dn, H = q_nope.shape[-1], q_nope.shape[2]
+        dv = w_kvb.shape[-1] - dn
+        group = max(g for g in range(1, min(H, PREFILL_HEADS) + 1)
+                    if H % g == 0)
+        heads_first = partial(jnp.swapaxes, axis1=0, axis2=1)
+        c, q_nope, q_rope, k_rope = c[0], q_nope[0], q_rope[0], k_rope[0]
+        L = c.shape[0]
+
+        def heads(g, out):
+            # one group at a time, in a loop: unrolled, the compiler keeps
+            # several groups' keys and values at once
+            take = partial(jax.lax.dynamic_slice_in_dim, start_index=g * group,
+                           slice_size=group)
+            kv = jnp.einsum("lc,chd->lhd", c,
+                            take(w_kvb, axis=1).astype(c.dtype),
+                            preferred_element_type=jnp.float32
+                            ).astype(c.dtype)
+            k = jnp.concatenate(
+                [kv[..., :dn], jnp.broadcast_to(
+                    k_rope[:, None, :], kv.shape[:-1] + k_rope.shape[-1:])],
+                axis=-1)
+            q = jnp.concatenate([take(q_nope, axis=1), take(q_rope, axis=1)],
+                                axis=-1)
+            o = sparse_flash_attention(
+                heads_first(q), heads_first(k), heads_first(kv[..., dn:]),
+                self.selection, take(sinks, axis=0), scale)
+            return jax.lax.dynamic_update_slice_in_dim(out, o, g * group, 0)
+
+        out = jax.lax.fori_loop(0, H // group, heads,
+                                jnp.zeros((H, L, dv), c.dtype))
+        return heads_first(out)[None]
+
+    def sparse_attention(self, layer: int):
+        from stoke_tpu.models.decoder import _absorbed_output, _absorbed_query
+
+        def attend(q_nope, q_rope, c, k_rope, w_kvb, scale, sinks, index):
+            B, L = self.positions.shape
+            dtype = self.index_keys.dtype
+            blocks, offs = _write_targets(
+                self.block_tables, self.positions, self.block_size,
+                self.lengths if self.mode == "prefill" else None,
+            )
+            words = self.latent_words
+            rows = jnp.concatenate([c, k_rope], axis=-1).reshape(B * L, -1)
+            self.latent_words = write_rows(
+                words, layer, blocks * self.block_size + offs,
+                pack_rows(rows, words.shape[-1], dtype))
+            if index is not None:
+                q, k, w = index
+                row = self._index_row[layer]
+                self.index_keys = self.index_keys.at[row, blocks, offs].set(
+                    k.reshape(B * L, -1).astype(dtype),
+                    mode="promise_in_bounds")
+                with jax.named_scope("select"):
+                    self.selection = self._select(row, q, k, w)
+            if self.mode == "decode":
+                per_word = 4 // jnp.dtype(dtype).itemsize
+                q_row = _absorbed_query(q_nope, q_rope, w_kvb,
+                                        per_word * words.shape[-1])
+                addr, count = self.selection
+                o_row = sparse_latent_attention(
+                    q_row[:, 0], self.latent_words, layer, addr, count,
+                    sinks, scale)
+                return _absorbed_output(o_row[:, None], w_kvb,
+                                        q_nope.shape[-1])
+            return self._prefill_attention(q_nope, q_rope, c, k_rope, w_kvb,
+                                           scale, sinks)
 
         return attend
