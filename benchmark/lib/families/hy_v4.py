@@ -594,9 +594,10 @@ def kernel_bytes_per_step(config: dict, kernel: str, step: dict,
       indexer keys to its length (``window_blocks`` pages of ``block_size``
       keys), and the whole ``[max_seqs, max_blocks * block_size]`` float32
       scores written;
-    - ``sparse_latent_attention``: in each layer the rows its grid fetches
-      (``sparse_row_passes x selected_rows x`` the row's bytes), each slot's
-      absorbed queries read and its rows written."""
+    - ``sparse_latent_attention``: in each layer the rows it fetched
+      (``sparse_row_passes x selected_rows x`` the row's bytes: the kernel's
+      own count of the words its row DMAs fetched), each slot's absorbed queries read and its
+      rows written."""
     n_layers, _, full, _ = _layout(config)
     cache = jnp.dtype(config["cache_dtype"]).itemsize
     BS = int(serve_config["kv_block_size"])

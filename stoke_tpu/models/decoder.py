@@ -1106,11 +1106,6 @@ class DecoderLayer(nn.Module):
                                  self.param_dtype, "ffn")(x)
 
 
-#: tokens of a long prompt a DSA model's expert layer routes at a time: its
-#: assignments' rows, gathered and in float32, would not fit whole
-EXPERT_TOKEN_CHUNK = 4096
-
-
 def _expert_share(cfg: DecoderConfig, held_experts, dtype, param_dtype,
                   name: str) -> ExpertShareFFN:
     """The expert layer ``cfg`` describes, holding ``held_experts``."""
@@ -1120,8 +1115,7 @@ def _expert_share(cfg: DecoderConfig, held_experts, dtype, param_dtype,
         cfg.n_group, cfg.topk_group, cfg.routed_scaling_factor,
         cfg.norm_topk_prob, cfg.n_shared_experts, dtype, param_dtype,
         cfg.scoring_func, cfg.router_choice_bias, cfg.zero_expert_num,
-        cfg.swiglu_limit, EXPERT_TOKEN_CHUNK if cfg.use_dsa else 0,
-        name=name,
+        cfg.swiglu_limit, name=name,
     )
 
 

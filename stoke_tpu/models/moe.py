@@ -243,6 +243,12 @@ class SwiGLU(nn.Module):
         return proj("down", x.shape[-1], gated)
 
 
+#: tokens of a long prompt an expert layer routes at a time, where the
+#: prompt is longer and a multiple of it: its assignments' rows, gathered
+#: and in float32, would not fit whole
+EXPERT_TOKEN_CHUNK = 4096
+
+
 class ExpertShareFFN(nn.Module):
     """One chip's share of a sparse expert layer: a router of the model's
     full width, the ``held = (first, count)`` routed experts that live here,
@@ -299,7 +305,6 @@ class ExpertShareFFN(nn.Module):
     choice_bias: bool = False
     zero_experts: int = 0
     swiglu_limit: float = 0.0
-    token_chunk: int = 0
 
     @nn.compact
     def __call__(self, x):
@@ -381,8 +386,8 @@ class ExpertShareFFN(nn.Module):
                 y = jnp.where(is_held[:, :, None], y, 0.0)
                 return (y * weights[:, :, None]).sum(axis=1), counts
 
-            chunk = self.token_chunk
-            if chunk and N > chunk and N % chunk == 0:
+            chunk = EXPERT_TOKEN_CHUNK
+            if N > chunk and N % chunk == 0:
                 # a long prompt's assignments a chunk of tokens at a time:
                 # its N x k rows, gathered and in float32, would not fit
                 routed, counts = jax.lax.map(

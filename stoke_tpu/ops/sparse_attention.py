@@ -34,6 +34,7 @@ index first), not ``approx_max_k``.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -410,28 +411,30 @@ def sparse_latent_reference(q_row, words, layer, addr, count, sinks, scale):
 
 
 def _sparse_kernel(count_ref, addr_ref, q_ref, sink_ref, plane_ref, o_ref,
-                   buf, sems, acc, m_sc, l_sc, *, layer, block_size, group,
-                   pairs, scale):
+                   issued_ref, buf, sems, acc, m_sc, l_sc, issued, *, layer,
+                   block_size, group, pairs, scale):
     """One slot of :func:`sparse_latent_attention`: its chosen rows,
     ``group`` a step, each row by its own DMA and the step's rows waited for
     at once (the semaphore counts bytes); step ``g + 1``'s rows fetched while
     step ``g`` is scored.  The last step's rows past ``count`` are row 0 of
-    the scratch block (``row_addresses``), fetched and masked."""
+    the scratch block (``row_addresses``), fetched and masked.  Every row
+    DMA started adds the words of its source to the slot's count, written
+    to ``issued_ref``."""
     b = pl.program_id(0)
     n = count_ref[b]
     n_groups = pl.cdiv(n, group)
+    issued[0] = 0
 
     def fetch(g, half):
         first = g * group
 
-        def one(i, carry):
+        def one(i, words):
             a = addr_ref[0, first + i]
-            pltpu.make_async_copy(
-                plane_ref.at[layer, a // block_size, a % block_size],
-                buf.at[half, i], sems.at[half]).start()
-            return carry
+            src = plane_ref.at[layer, a // block_size, a % block_size]
+            pltpu.make_async_copy(src, buf.at[half, i], sems.at[half]).start()
+            return words + math.prod(src.shape)
 
-        jax.lax.fori_loop(0, group, one, None)
+        issued[0] += jax.lax.fori_loop(0, group, one, 0)
 
     @pl.when(b == 0)
     def _():
@@ -482,6 +485,7 @@ def _sparse_kernel(count_ref, addr_ref, q_ref, sink_ref, plane_ref, o_ref,
     m = m_sc[:, 0:1]
     l = l_sc[:, 0:1] + jnp.exp(sink_ref[...] - m)
     o_ref[...] = (acc[...] / l).astype(o_ref.dtype)
+    issued_ref[...] = jnp.full(issued_ref.shape, issued[0], jnp.int32)
 
 
 def sparse_latent_attention(q_row, words, layer, addr, count, sinks, scale,
@@ -501,8 +505,11 @@ def sparse_latent_attention(q_row, words, layer, addr, count, sinks, scale,
         sinks: ``[H]`` float32.
         scale: on the float32 scores.
 
-    Returns ``[B, H, per_word * W]`` in the query dtype: the probabilities
-    times the whole rows (the caller keeps the latent lanes)."""
+    Returns ``([B, H, per_word * W]`` in the query dtype: the probabilities
+    times the whole rows (the caller keeps the latent lanes), ``[B] int32``:
+    the words of ``words`` the kernel's DMAs fetched for each slot).  The
+    count is the kernel's own, summed over the copies it started, each at
+    its source's size: it is whatever its grid and steps fetched."""
     B, H, width = q_row.shape
     BS, W = int(words.shape[2]), int(words.shape[-1])
     pairs = width == 2 * W
@@ -526,33 +533,33 @@ def sparse_latent_attention(q_row, words, layer, addr, count, sinks, scale,
                 pl.BlockSpec((H, 1), lambda b, n: (0, 0)),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=slot_rows,
+            out_specs=[
+                slot_rows,
+                pl.BlockSpec((None, 1, 128), lambda b, n: (b, 0, 0)),
+            ],
             scratch_shapes=[
                 pltpu.VMEM((2, group, 1, W), jnp.uint32),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.VMEM((H, width), jnp.float32),
                 pltpu.VMEM((H, 128), jnp.float32),
                 pltpu.VMEM((H, 128), jnp.float32),
+                pltpu.SMEM((1,), jnp.int32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, H, width), q_row.dtype),
+        out_shape=[
+            jax.ShapeDtypeStruct((B, H, width), q_row.dtype),
+            jax.ShapeDtypeStruct((B, 1, 128), jnp.int32),
+        ],
         interpret=_interpret(interpret),
         name="sparse_latent_attention",
     )
     part = _current_partition()
     part = None if part is None else (part[0], ())
-    return _partition_rows(call, part, B)(
+    out, issued = _partition_rows(call, part, B)(
         count.astype(jnp.int32), addr, q_row,
         sinks.astype(jnp.float32).reshape(H, 1), words,
     )
-
-
-def sparse_rows_fetched(count, index_topk: int):
-    """Rows :func:`sparse_latent_attention` fetches for slots of ``count``
-    chosen rows (numpy or ints) of ``index_topk``: whole steps of its
-    group."""
-    group = min(SPARSE_ROWS_PER_STEP, index_topk)
-    return sum(-(-int(n) // group) * group for n in count)
+    return out, issued[:, 0, 0]
 
 
 # --------------------------------------------------------------------------- #

@@ -87,7 +87,6 @@ from stoke_tpu.configs import ServeConfig
 from stoke_tpu.ops.delta_rule import state_passes
 from stoke_tpu.ops.flash_attention import partition_kernels_over
 from stoke_tpu.ops.grouped_matmul import expert_weight_passes
-from stoke_tpu.ops.sparse_attention import sparse_rows_fetched
 from stoke_tpu.serving.kv_cache import (
     BlockAllocator,
     HybridCacheHook,
@@ -707,8 +706,10 @@ class ServingEngine:
         (``scheduler.FROM_DEVICE``) takes its token from there.
         Returns (next tokens [B], updated arrays); a model with expert
         layers hands back their assignment counts (int32[expert layers,
-        held]) beside the tokens, and one whose routers have zero-compute
-        outputs each row's picks among them (int32[expert layers, B])."""
+        held]) beside the tokens, one whose routers have zero-compute
+        outputs each row's picks among them (int32[expert layers, B]), and
+        one with a sparse latent cache the words of latent plane its
+        kernels' row DMAs fetched, a slot over the layers (int32[B])."""
         pages, (tokens, positions, block_tables, context_lens, *prev) = (
             self._split(args)
         )
@@ -726,6 +727,8 @@ class ServingEngine:
         counts = ()
         if counting:
             logits, counts = logits
+        if self._spec.kind == "sparse_latent":
+            counts += (hook.words_fetched,)
         return (
             jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32),
             *counts,
@@ -1477,9 +1480,10 @@ class ServingEngine:
         # the step's copy home); each one below is one more
         t_first = time.perf_counter()
         fetched = [next_host]
-        if self._experts_held:
-            # the held experts' assignment counts ride beside, and the
-            # rows' zero-compute picks where the routers have those
+        if self._experts_held or self._spec.kind == "sparse_latent":
+            # the held experts' assignment counts ride beside, the rows'
+            # zero-compute picks where the routers have those, and the
+            # words the sparse kernels fetched where the cache is read by row
             fetched += [np.asarray(a) for a in step.out[1:]]
         if self._sampling:
             # advance ONLY the decoding slots' key streams: a
@@ -1545,7 +1549,8 @@ class ServingEngine:
             "upload_bytes": _nbytes(host_args),
         }
         if self._spec.kind == "sparse_latent":
-            step_attrs.update(self._sparse_counts(host_args[3], decode_rows))
+            step_attrs.update(self._sparse_counts(
+                host_args[3], decode_rows, fetched[-1]))
         if self.cache.state:
             passes = state_passes(len(decode_rows), self.cfg.max_seqs)
             m.state_passes.set(passes)
@@ -1595,19 +1600,18 @@ class ServingEngine:
             for rid in set(sched.finished) - was_finished:
                 self._finish(sched.finished[rid])
 
-    def _sparse_counts(self, context, live) -> dict:
+    def _sparse_counts(self, context, live, issued) -> dict:
         """What a decode step of a sparse-attention model read: the keys
         its indexers scored (every live slot to its length, in each layer
         with an indexer), the rows its selections chose (``min(context,
         index_topk)`` a live slot and layer), and the bytes of latent plane
-        the row kernel fetched over the chosen rows' bytes (its grid walks
-        every slot, whole steps of rows, a row as stored in words)."""
+        the row kernels fetched over the chosen rows' bytes: ``issued`` is
+        the kernels' own count of the plane's words their DMAs fetched, a
+        slot over the layers."""
         spec = self._spec
         chosen = np.minimum(context, spec.index_topk)
         selected = spec.layers * int(chosen[live].sum())
-        words = self.cache.pages[0].shape[-1]
-        fetched = spec.layers * sparse_rows_fetched(
-            chosen, spec.index_topk) * words * 4
+        fetched = int(np.sum(issued)) * self.cache.pages[0].dtype.itemsize
         row_bytes = spec.values_per_token * self.cache.dtype.itemsize
         return {
             "indexer_keys": len(spec.plane_layers("index"))

@@ -826,6 +826,9 @@ class SparseLatentHook:
         #: the last selection made: ``(addresses, counts)`` in decode, the
         #: bits in prefill
         self.selection = None
+        #: ``[B] int32``: the words of latent plane the decode kernels'
+        #: row DMAs fetched for each slot, summed over the layers
+        self.words_fetched = jnp.zeros(block_tables.shape[:1], jnp.int32)
 
     @property
     def pages(self) -> tuple:
@@ -910,9 +913,10 @@ class SparseLatentHook:
                 q_row = _absorbed_query(q_nope, q_rope, w_kvb,
                                         per_word * words.shape[-1])
                 addr, count = self.selection
-                o_row = sparse_latent_attention(
+                o_row, issued = sparse_latent_attention(
                     q_row[:, 0], self.latent_words, layer, addr, count,
                     sinks, scale)
+                self.words_fetched = self.words_fetched + issued
                 return _absorbed_output(o_row[:, None], w_kvb,
                                         q_nope.shape[-1])
             return self._prefill_attention(q_nope, q_rope, c, k_rope, w_kvb,

@@ -32,25 +32,34 @@ def built():
 
 @pytest.fixture(scope="module")
 def served(built):
-    """``(engine, prompts, streams, selections)``: the three prompts served
-    4 tokens each, and the ``(addresses, counts)`` every layer's decode
-    kernel call was handed while the decode program was traced."""
+    """``(engine, prompts, streams, selections, handed)``: the three
+    prompts served 4 tokens each, the ``(addresses, counts)`` every layer's
+    decode kernel call was handed while the decode program was traced, and
+    the ``(context, words fetched)`` each committed decode step handed
+    ``_sparse_counts``."""
     model, params = built
-    seen = {}
+    seen, handed = {}, []
     real = kv_cache.sparse_latent_attention
+    real_counts = ServingEngine._sparse_counts
 
     def record(q_row, words, layer, addr, count, sinks, scale):
         seen[layer] = (addr, count)
         return real(q_row, words, layer, addr, count, sinks, scale)
 
+    def record_counts(self, context, live, issued):
+        handed.append((np.asarray(context).copy(),
+                       np.asarray(issued).copy()))
+        return real_counts(self, context, live, issued)
+
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(kv_cache, "sparse_latent_attention", record)
+        mp.setattr(ServingEngine, "_sparse_counts", record_counts)
         eng = ServingEngine(model, params, _serve_config())
         rng = np.random.default_rng(2)
         prompts = [rng.integers(0, TINY["vocab_size"], n).astype(np.int32)
                    for n in PROMPTS]
         out = eng.generate(prompts, max_new_tokens=4)
-    return eng, prompts, out, dict(seen)
+    return eng, prompts, out, dict(seen), handed
 
 
 def _serve_config(**kw):
@@ -78,7 +87,7 @@ def test_shared_layers_have_no_indexer_and_reuse_the_full_layers_choice(
 def test_engine_serves_the_reference_greedy_stream_and_counts_its_reads(
         built, served):
     _, params = built
-    eng, prompts, out, _ = served
+    eng, prompts, out, _, _ = served
     for prompt, tokens in zip(prompts, out):
         seq = np.concatenate([prompt, tokens])
         logits = _reference(params, seq[None])[0]
@@ -89,9 +98,22 @@ def test_engine_serves_the_reference_greedy_stream_and_counts_its_reads(
     words = sa.packed_width(40, jnp.float32)
     assert eng.cache.bytes_per_token == 5 * words * 4 + 2 * 16 * 4
     assert eng.cache.pages[1].shape[0] == 2
-    counts = eng._sparse_counts(np.asarray([41, 5, 1]), [0, 1])
+    issued = np.full(3, 5 * 8 * words, np.int32)
+    counts = eng._sparse_counts(np.asarray([41, 5, 1]), [0, 1], issued)
     assert counts["indexer_keys"] == 2 * (41 + 5)
     assert counts["selected_rows"] == 5 * (8 + 5)
-    # every slot the kernel walks, whole steps of min(128, index_topk) rows
-    fetched = 5 * (8 + 8 + 8) * words * 4
+    fetched = 3 * 5 * 8 * words * 4
     assert counts["sparse_row_passes"] == fetched / (5 * 13 * 40 * 4)
+
+
+def test_the_words_a_step_reports_are_the_kernels_own(served):
+    # the decode program hands back, a slot, the words its five layers'
+    # kernels fetched: a whole step of min(128, index_topk) = 8 rows of
+    # packed words a layer for a slot with any context
+    handed = served[4]
+    words = sa.packed_width(40, jnp.float32)
+    assert handed
+    for context, issued in handed:
+        assert issued.dtype == np.int32
+        assert issued.tolist() == [5 * 8 * words if c else 0
+                                   for c in context]

@@ -100,13 +100,18 @@ def test_sparse_latent_attention_equals_the_gathered_rows(dtype, atol):
     per_word = 4 // jnp.dtype(dtype).itemsize
     q = jnp.asarray(rng.normal(size=(B, H, per_word * width)), dtype)
     sinks = jnp.asarray([0.5, -1.0, -jnp.inf, 2.0], jnp.float32)
-    got = sa.sparse_latent_attention(q, words, 1, addr, count, sinks, 0.07)
+    got, issued = sa.sparse_latent_attention(q, words, 1, addr, count, sinks,
+                                             0.07)
     want = sa.sparse_latent_reference(q, words, 1, addr, count, sinks, 0.07)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=atol, rtol=0)
-    # whole steps of rows a slot, every slot the grid walks
-    assert sa.sparse_rows_fetched([24, 24, 5], 8) == 24 + 24 + 8
-    assert sa.sparse_rows_fetched([2048, 5], 2048) == 2048 + 128
+    # the kernel's own count of the words its row DMAs fetched: a whole
+    # step of 24 rows a slot, the 5-row slot's too, and none for a slot
+    # with none
+    assert issued.tolist() == [24 * width] * 3
+    _, issued = sa.sparse_latent_attention(q, words, 1, addr,
+                                           count.at[2].set(0), sinks, 0.07)
+    assert issued.tolist() == [24 * width, 24 * width, 0]
 
 
 def test_write_rows_writes_each_row_in_place():
