@@ -22,7 +22,13 @@ to, and interpreted off the TPU:
   row is whole tiles), two 16-bit values a word
   (:func:`pack_rows`); the kernel unpacks a word into its two values
   exactly.  A step takes a group of rows, all issued on one semaphore and
-  waited for once;
+  waited for once.  Issuing the rows, not their bytes, bounds the kernel,
+  so a row costs a few scalar operations: its place a multiply and an add
+  in the plane viewed as ``[layers, NB * BS, 1, W]``, its address clamped
+  to the plane in place of Mosaic's bounds checks, and the starts written
+  out ``SPARSE_ISSUE_UNROLL`` to a trip of the issue loop, by hand, because
+  Mosaic lowers ``fori_loop(..., unroll=k)`` only for ``k`` 1 or the whole
+  loop;
 - :func:`sparse_flash_attention` (prefill): causal flash attention of
   expanded heads with the selection as a bit mask, 32 queries a word
   (:func:`selection_bits`), and a sink a head.
@@ -53,6 +59,9 @@ from stoke_tpu.ops.flash_attention import (
 _INDEX_GROUP_BYTES = 512 * 1024
 #: rows :func:`sparse_latent_attention` fetches a step
 SPARSE_ROWS_PER_STEP = 128
+#: row DMAs one trip of a step's issue loop starts, written out at most
+#: (:func:`issue_unroll`): a whole step's, the fewest scalar bundles a row
+SPARSE_ISSUE_UNROLL = 128
 #: query and key block of :func:`sparse_flash_attention`
 _FLASH_BLOCK_Q = 512
 _FLASH_BLOCK_K = 1024
@@ -120,6 +129,9 @@ def _write_rows_kernel(addr_ref, rows_ref, plane_in, plane_ref, sem, *,
                        layer, block_size, n):
     del plane_in  # aliased to ``plane_ref``
 
+    # a row's place by block and offset, not through the pool-row view that
+    # :func:`_sparse_kernel` reads: the interpreter writes through no
+    # reshaped ref, and a few rows a step cost nothing seen
     def one(i, carry):
         a = addr_ref[i]
         pltpu.make_async_copy(
@@ -410,31 +422,60 @@ def sparse_latent_reference(q_row, words, layer, addr, count, sinks, scale):
     return out.astype(q_row.dtype)
 
 
+def issue_unroll(group: int) -> int:
+    """Row DMAs one trip of :func:`sparse_latent_attention`'s issue loop
+    starts for a step of ``group`` rows: the largest divisor of ``group``
+    not above ``SPARSE_ISSUE_UNROLL``."""
+    return max(u for u in range(1, min(SPARSE_ISSUE_UNROLL, group) + 1)
+               if group % u == 0)
+
+
 def _sparse_kernel(count_ref, addr_ref, q_ref, sink_ref, plane_ref, o_ref,
                    issued_ref, buf, sems, acc, m_sc, l_sc, issued, *, layer,
-                   block_size, group, pairs, scale):
+                   group, unroll, pairs, scale):
     """One slot of :func:`sparse_latent_attention`: its chosen rows,
     ``group`` a step, each row by its own DMA and the step's rows waited for
     at once (the semaphore counts bytes); step ``g + 1``'s rows fetched while
     step ``g`` is scored.  The last step's rows past ``count`` are row 0 of
     the scratch block (``row_addresses``), fetched and masked.  Every row
     DMA started adds the words of its source to the slot's count, written
-    to ``issued_ref``."""
+    to ``issued_ref``.
+
+    Issuing a row is what bounds the kernel, not its bytes, so a row costs
+    a few scalar operations.  Its place is a multiply and an add: the plane
+    is viewed as ``[layers, NB * BS, 1, W]`` and indexed by pool row, which
+    moves nothing (blocks and offsets are untiled leading dimensions) and
+    needs no block and offset: those take a signed division and remainder
+    with a dozen sign fix-ups.  ``unroll`` starts are written out in each trip of the
+    issue loop, by hand: Mosaic lowers ``fori_loop(..., unroll=k)`` only for
+    ``k`` 1 or the whole loop.  And the kernel is compiled without Mosaic's
+    bounds checks, which took more scalar operations a row than the rest of
+    its issue: the one index no loop bounds, a row's address, is clamped to
+    the plane as an unsigned number instead, so no DMA reads outside it (the
+    kernel only reads the plane)."""
     b = pl.program_id(0)
     n = count_ref[b]
     n_groups = pl.cdiv(n, group)
     issued[0] = 0
+    L, NB, BS, one, W = plane_ref.shape
+    plane = plane_ref.reshape(L, NB * BS, one, W)
+    last_row = jnp.uint32(NB * BS - 1)
 
     def fetch(g, half):
         first = g * group
 
-        def one(i, words):
-            a = addr_ref[0, first + i]
-            src = plane_ref.at[layer, a // block_size, a % block_size]
-            pltpu.make_async_copy(src, buf.at[half, i], sems.at[half]).start()
-            return words + math.prod(src.shape)
+        def rows(t, words):
+            at = t * unroll
+            for i in range(unroll):
+                a = addr_ref[0, first + at + i].astype(jnp.uint32)
+                src = plane.at[layer,
+                               jnp.minimum(a, last_row).astype(jnp.int32)]
+                pltpu.make_async_copy(src, buf.at[half, at + i],
+                                      sems.at[half]).start()
+                words += math.prod(src.shape)
+            return words
 
-        issued[0] += jax.lax.fori_loop(0, group, one, 0)
+        issued[0] += jax.lax.fori_loop(0, group // unroll, rows, 0)
 
     @pl.when(b == 0)
     def _():
@@ -449,7 +490,6 @@ def _sparse_kernel(count_ref, addr_ref, q_ref, sink_ref, plane_ref, o_ref,
         fetch(0, 0)
 
     q = q_ref[...]  # [H, per_word * W]
-    W = buf.shape[-1]
 
     def step(g, carry):
         half = g % 2
@@ -511,15 +551,15 @@ def sparse_latent_attention(q_row, words, layer, addr, count, sinks, scale,
     count is the kernel's own, summed over the copies it started, each at
     its source's size: it is whatever its grid and steps fetched."""
     B, H, width = q_row.shape
-    BS, W = int(words.shape[2]), int(words.shape[-1])
+    W = int(words.shape[-1])
     pairs = width == 2 * W
     K = addr.shape[1]
     group = min(SPARSE_ROWS_PER_STEP, K)
     pad = -K % group
     addr = jnp.pad(addr.astype(jnp.int32), ((0, 0), (0, pad)))[:, None, :]
     kernel = functools.partial(
-        _sparse_kernel, layer=int(layer), block_size=BS, group=group,
-        pairs=pairs, scale=float(scale))
+        _sparse_kernel, layer=int(layer), group=group,
+        unroll=issue_unroll(group), pairs=pairs, scale=float(scale))
     slot_rows = pl.BlockSpec((None, H, width), lambda b, n: (b, 0, 0))
     call = pl.pallas_call(
         kernel,
@@ -550,6 +590,7 @@ def sparse_latent_attention(q_row, words, layer, addr, count, sinks, scale,
             jax.ShapeDtypeStruct((B, H, width), q_row.dtype),
             jax.ShapeDtypeStruct((B, 1, 128), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(disable_bounds_checks=True),
         interpret=_interpret(interpret),
         name="sparse_latent_attention",
     )

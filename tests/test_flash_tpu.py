@@ -225,6 +225,45 @@ def test_paged_kernels_compile_and_match_on_tpu(pool_dtype, n_q):
     )
 
 
+def test_sparse_latent_attention_at_the_long_document_decode_shape_on_tpu():
+    """`sparse_latent_attention` compiled by Mosaic at the long-document
+    serve cell's decode shape: 24 slots of 64 heads, up to 2,048 chosen
+    384-word rows of one layer of a `[5, 4000, 16, 1, 384]` plane, with one
+    slot of 700 rows, one of 1 and an empty one. Same tolerance as the
+    interpreter's bfloat16 case (tests/test_sparse_attention.py)."""
+    from stoke_tpu.ops import sparse_attention as sa
+
+    B, H, L, NB, BS, W, K = 24, 64, 5, 4000, 16, 384, 2048
+    counts = [K] * 21 + [700, 1, 0]
+    k = jax.random.split(jax.random.PRNGKey(7), 4)
+    values = jax.random.normal(k[0], (L * NB * BS, 2 * W), jnp.bfloat16)
+    words = sa.pack_rows(values, W, jnp.bfloat16).reshape(L, NB, BS, 1, W)
+    count = jnp.asarray(counts, jnp.int32)
+    addr = jax.random.randint(k[1], (B, K), BS, NB * BS, jnp.int32)
+    addr = jnp.where(jnp.arange(K)[None] < count[:, None], addr, 0)
+    q = jax.random.normal(k[2], (B, H, 2 * W), jnp.bfloat16)
+    sinks = jax.random.normal(k[3], (H,), jnp.float32)
+    out, issued = sa.sparse_latent_attention(q, words, 1, addr, count, sinks,
+                                             0.07, interpret=False)
+    want = sa.sparse_latent_reference(q, words, 1, addr, count, sinks, 0.07)
+    err = jnp.max(jnp.abs(out.astype(jnp.float32) - want.astype(jnp.float32)))
+    assert float(err) < 2e-2
+    # the kernel's own count: whole steps of rows a slot, none when empty
+    step = sa.SPARSE_ROWS_PER_STEP
+    assert issued.tolist() == [-(-c // step) * step * W for c in counts]
+    # addresses outside the layer read its last row: the kernel runs
+    # without Mosaic's bounds checks and clamps them itself (these lie in
+    # the neighbouring layers, so a missing clamp fails here, not the chip)
+    rows = NB * BS
+    addr = addr.at[21, :3].set(jnp.asarray([rows, rows + 4000, -1]))
+    out, _ = sa.sparse_latent_attention(q, words, 1, addr, count, sinks, 0.07,
+                                        interpret=False)
+    inside = jnp.where((addr < 0) | (addr >= rows), rows - 1, addr)
+    want = sa.sparse_latent_reference(q, words, 1, inside, count, sinks, 0.07)
+    err = jnp.max(jnp.abs(out.astype(jnp.float32) - want.astype(jnp.float32)))
+    assert float(err) < 2e-2
+
+
 @pytest.mark.skipif(
     jax.device_count() < 2, reason="needs several chips"
 )

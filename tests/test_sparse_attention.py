@@ -82,20 +82,58 @@ def test_rows_pack_into_words_and_back_exactly(dtype):
     assert (back[:, :200] == values).all() and (back[:, 200:] == 0).all()
 
 
-@pytest.mark.parametrize("dtype,atol", [(jnp.float32, 1e-5),
-                                        (jnp.bfloat16, 2e-2)])
-def test_sparse_latent_attention_equals_the_gathered_rows(dtype, atol):
+STEP = sa.SPARSE_ROWS_PER_STEP
+# selections a slot: more than a step, and no multiple of the issue unroll
+K_ROWS = STEP + 45
+# an unroll below the module's, so that a step takes several trips
+SMALL_UNROLL = 8
+assert STEP % SMALL_UNROLL == 0 and K_ROWS % SMALL_UNROLL
+
+
+def _edges(unroll):
+    """Selection counts at the edges of the unroll and of a step."""
+    return sorted({0, 1, unroll - 1, unroll, STEP - 1, STEP, STEP + 1,
+                   K_ROWS})
+
+
+ROW_CASES = [(dtype, atol, None, n)
+             for dtype, atol in [(jnp.float32, 1e-5), (jnp.bfloat16, 2e-2)]
+             for n in _edges(sa.issue_unroll(STEP)) + [24]] + [
+    (jnp.float32, 1e-5, SMALL_UNROLL, n) for n in _edges(SMALL_UNROLL)]
+
+
+def _row_case_id(dtype, atol, unroll, n):
+    if n == 24:  # the case this test had before it took counts: its old id
+        return f"dtype{int(dtype == jnp.bfloat16)}-{atol}"
+    return f"{jnp.dtype(dtype).name}-unroll{unroll or 'module'}-{n}"
+
+
+@pytest.mark.parametrize("dtype,atol,unroll,n", ROW_CASES,
+                         ids=[_row_case_id(*c) for c in ROW_CASES])
+def test_sparse_latent_attention_equals_the_gathered_rows(dtype, atol, unroll,
+                                                          n, monkeypatch):
+    """The first slot chooses ``n`` rows, at the edges of the issue loop's
+    unroll (the module's, or ``unroll`` a trip) and of a step; the second
+    all ``K`` of a selection that is ``K`` = 24 when ``n`` is 24 (a step
+    shorter than ``STEP``) and ``K_ROWS`` otherwise; the third 5."""
+    if unroll:
+        monkeypatch.setattr(sa, "SPARSE_ISSUE_UNROLL", unroll)
+        assert sa.issue_unroll(STEP) == unroll
     rng = np.random.default_rng(2)
-    C, H, K = 200, 4, 24
+    C, H = 200, 4
+    K = 24 if n == 24 else K_ROWS
+    nb, mb = 40, 12  # 3 slots x 12 blocks of the 39 past the scratch block
     width = sa.packed_width(C, dtype)
-    values = jnp.asarray(rng.normal(size=(LAYERS * NB * BS, C)), dtype)
+    values = jnp.asarray(rng.normal(size=(LAYERS * nb * BS, C)), dtype)
     words = sa.pack_rows(values, width, dtype).reshape(
-        LAYERS, NB, BS, 1, width)
-    tables, lens = _tables(rng), jnp.asarray(LENS, jnp.int32)
-    scores = jnp.where(jnp.arange(MB * BS)[None] < lens[:, None],
-                       jnp.asarray(rng.normal(size=(B, MB * BS))), -jnp.inf)
+        LAYERS, nb, BS, 1, width)
+    tables = jnp.asarray(
+        rng.permutation(np.arange(1, nb))[:B * mb].reshape(B, mb), jnp.int32)
+    lens = jnp.asarray([n, mb * BS, 5], jnp.int32)
+    scores = jnp.where(jnp.arange(mb * BS)[None] < lens[:, None],
+                       jnp.asarray(rng.normal(size=(B, mb * BS))), -jnp.inf)
     chosen, count = sa.select_topk(scores, lens, K)
-    assert count.tolist() == [24, 24, 5]
+    assert count.tolist() == [min(n, K), K, 5]
     addr = sa.row_addresses(chosen, count, tables, BS)
     per_word = 4 // jnp.dtype(dtype).itemsize
     q = jnp.asarray(rng.normal(size=(B, H, per_word * width)), dtype)
@@ -105,13 +143,76 @@ def test_sparse_latent_attention_equals_the_gathered_rows(dtype, atol):
     want = sa.sparse_latent_reference(q, words, 1, addr, count, sinks, 0.07)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=atol, rtol=0)
-    # the kernel's own count of the words its row DMAs fetched: a whole
-    # step of 24 rows a slot, the 5-row slot's too, and none for a slot
-    # with none
-    assert issued.tolist() == [24 * width] * 3
-    _, issued = sa.sparse_latent_attention(q, words, 1, addr,
-                                           count.at[2].set(0), sinks, 0.07)
-    assert issued.tolist() == [24 * width, 24 * width, 0]
+    # the kernel's own count of the words its row DMAs fetched: whole steps
+    # of min(STEP, K) rows a slot, the 5-row slot's too, and none for a
+    # slot with none
+    group = min(STEP, K)
+    assert issued.tolist() == [-(-int(c) // group) * group * width
+                               for c in count]
+    assert (issued[0] == 0) == (n == 0)
+
+
+@pytest.mark.parametrize("limit,group,want", [
+    (128, 128, 128), (128, 24, 24), (16, 24, 12), (8, 128, 8), (5, 24, 4),
+    (1, 128, 1)])
+def test_issue_unroll_is_the_largest_divisor_of_the_step_not_above_the_limit(
+        limit, group, want, monkeypatch):
+    monkeypatch.setattr(sa, "SPARSE_ISSUE_UNROLL", limit)
+    assert sa.issue_unroll(group) == want
+
+
+def _rows_case(rng, block_size, K=24):
+    """A plane of ``block_size``-row blocks, three slots' addresses of ``K``
+    rows (24, 9 and none chosen), queries and sinks."""
+    nb, C, H = 12, 200, 4
+    width = sa.packed_width(C, jnp.bfloat16)
+    values = jnp.asarray(rng.normal(size=(LAYERS * nb * block_size, C)),
+                         jnp.bfloat16)
+    words = sa.pack_rows(values, width, jnp.bfloat16).reshape(
+        LAYERS, nb, block_size, 1, width)
+    count = jnp.asarray([K, 9, 0], jnp.int32)
+    addr = jnp.asarray(rng.integers(block_size, nb * block_size, (B, K)),
+                       jnp.int32)
+    addr = jnp.where(jnp.arange(K)[None] < count[:, None], addr, 0)
+    q = jnp.asarray(rng.normal(size=(B, H, 2 * width)), jnp.bfloat16)
+    sinks = jnp.asarray([0.5, -1.0, -jnp.inf, 2.0], jnp.float32)
+    return words, addr, count, q, sinks
+
+
+@pytest.mark.parametrize("block_size", [12, 24])
+def test_row_kernels_take_a_block_size_that_is_no_power_of_two(block_size):
+    """A row's place is its pool row in the plane viewed as ``[layers, NB *
+    BS, 1, W]``: no block size is refused or mislaid."""
+    rng = np.random.default_rng(5)
+    words, addr, count, q, sinks = _rows_case(rng, block_size)
+    got, issued = sa.sparse_latent_attention(q, words, 1, addr, count, sinks,
+                                             0.07)
+    want = sa.sparse_latent_reference(q, words, 1, addr, count, sinks, 0.07)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=2e-2, rtol=0)
+    assert issued.tolist() == [24 * words.shape[-1]] * 2 + [0]
+    L, nb, _, _, W = words.shape
+    rows = jnp.asarray(rng.integers(0, 2**31, (3, W)), jnp.uint32)
+    at = jnp.asarray([5, nb * block_size - 1, block_size + 7], jnp.int32)
+    wrote = sa.write_rows(words, 1, at, rows)
+    flat = words.reshape(L, nb * block_size, W)
+    assert (wrote.reshape(flat.shape) == flat.at[1, at].set(rows)).all()
+
+
+def test_sparse_latent_attention_reads_no_row_outside_the_plane():
+    """The kernel runs without Mosaic's bounds checks, so it clamps each row
+    address to the plane as an unsigned number: an address past the plane
+    or below zero reads the plane's last row, as the reference does given
+    that row."""
+    rng = np.random.default_rng(8)
+    words, addr, count, q, sinks = _rows_case(rng, BS)
+    rows = words.shape[1] * BS
+    addr = addr.at[0, :4].set(jnp.asarray([rows, rows + 5000, -1, -rows]))
+    got, _ = sa.sparse_latent_attention(q, words, 1, addr, count, sinks, 0.07)
+    inside = jnp.where((addr < 0) | (addr >= rows), rows - 1, addr)
+    want = sa.sparse_latent_reference(q, words, 1, inside, count, sinks, 0.07)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=2e-2, rtol=0)
 
 
 def test_write_rows_writes_each_row_in_place():
