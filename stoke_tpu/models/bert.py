@@ -18,7 +18,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import partial
-from typing import Callable, Optional
+from typing import Any, Callable, Optional
 
 import flax.linen as nn
 import jax
@@ -61,6 +61,14 @@ class CacheSpec:
     *shape]``, an array a state layer; a ``cache`` dtype is the pool's
     (``ServeConfig.kv_dtype``).
 
+    ``slot_rows`` lists the stores a row layer keeps beside its rows,
+    addressed by slot, ``(name, stride, width)`` each: ``[max_seqs,
+    ceil(max_seq_len / stride), width]`` in the pool's dtype, a row a
+    ``stride`` positions; ``slot_state`` the per-slot arrays a row layer
+    keeps, ``(name, shape a slot, dtype name)`` each; ``block_sparse`` the
+    row layers' block selection (``ops/block_sparse.py`` ``BlockSparse``),
+    None where they attend every row.
+
     A plane may carry options as a third element (``PagedKVCache``):
     ``"layers"``, the layers that keep a row of it, and ``"packed"``.
     ``"sparse_latent"`` (``sparse_attention``) is the latent cache of a
@@ -78,6 +86,9 @@ class CacheSpec:
     layer_kinds: Optional[tuple] = None
     state: tuple = ()
     index_topk: int = 0
+    slot_rows: tuple = ()
+    slot_state: tuple = ()
+    block_sparse: Optional[Any] = None
 
     def plane_layers(self, name: str) -> tuple:
         """The layers that keep a row of plane ``name``."""

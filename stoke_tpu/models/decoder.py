@@ -1,6 +1,6 @@
 """A pre-norm decoder assembled from a ``config.json``-shaped description.
 
-Four families' blocks, keyed by their sources' own names, so a published
+Five families' blocks, keyed by their sources' own names, so a published
 configuration builds the model as it stands.  All: a final RMSNorm; an
 untied ``lm_head``; no biases.  The first two: per layer ``h +=
 Attn(RMSNorm(h))``, ``h += FFN(RMSNorm(h))``, the feed-forward a dense
@@ -47,6 +47,25 @@ manifold-constrained hyper-connections (:class:`HyperConnection`, Sinkhorn
 on the mixing matrix), every SwiGLU is limited (``swiglu_limit``), the
 router chooses by sigmoid scores plus a bias, and the head is float32.
 
+The fifth (``mixer_types``, ``lightning_*``, ``qk_norm``, ``scale_emb``,
+``scale_depth`` ...; MiniCPM-SALA, ``model_type: "minicpm_sala"``) is all
+dense (:class:`MixerLayer`: every feed-forward a SwiGLU) with MiniCPM's muP
+scalings (the embedding times ``scale_emb``, each residual branch times
+``scale_depth / sqrt(mup_denominator)``, the head's input times
+``dim_model_base / hidden_size``) and two mixers, q and k RMS-normed per
+head in both:
+
+- ``"sparse_gqa"`` (``minicpm4``; InfLLM-v2,
+  :class:`SparseGroupedAttention`): grouped-query softmax attention without
+  positions, each key-value group attending the ``topk`` blocks it chose
+  from pooled (compressed) keys, the first and the most recent blocks
+  always among them, every block of a context up to ``dense_len``
+  (``ops/block_sparse.py`` has the selection); the output gated;
+- ``"lightning"`` (``lightning-attn``; :class:`LightningAttention`): linear
+  attention with a scalar decay a head and a ``[d, d]`` float32 state, no
+  normaliser (``ops/lightning.py``), rotary positions, the output
+  RMS-normed over all heads and gated.
+
 The same ``__call__(input_ids, train, positions, decode, kv_cache)``
 contract as :class:`stoke_tpu.models.gpt.GPT`, and :meth:`Decoder.cache_spec`
 for ``ServingEngine``.  A latent model caches one row a token a latent
@@ -60,8 +79,11 @@ written once, then the expanded form in prefill and the absorbed form in
 decode; ``kv_cache.layer_attention(i)`` for a ``gqa`` layer;
 ``kv_cache.layer_state(i)`` hands a ``kda`` layer its slots' state and
 takes it back; ``kv_cache.sparse_attention(i)`` a DSA layer its attention
-over the chosen rows, ``CacheSpec.kind == "sparse_latent"``); without one
-it is the whole-sequence form.
+over the chosen rows, ``CacheSpec.kind == "sparse_latent"``); the mixer
+family is a ``"hybrid"`` cache whose row layers attend the blocks they chose
+(``CacheSpec.block_sparse``) and keep their compressed keys in a store a
+slot (``CacheSpec.slot_rows``), its lightning layers a state a slot; without
+one it is the whole-sequence form.
 
 Parameters are ``param_dtype``, products take ``dtype`` inputs and
 accumulate in float32; router, softmax, RMSNorm statistics and the whole of
@@ -80,12 +102,28 @@ import jax
 import jax.numpy as jnp
 
 from stoke_tpu.models.bert import CacheSpec
-from stoke_tpu.models.moe import ExpertShareFFN, SwiGLU
+from stoke_tpu.models.moe import ExpertShareFFN, SwiGLU, chunked_swiglu
+from stoke_tpu.ops.block_sparse import (
+    BlockSparse,
+    block_scores,
+    choose_blocks,
+    compress_keys,
+    window_scores,
+)
 from stoke_tpu.ops.delta_rule import delta_rule_step
 from stoke_tpu.ops.flash_attention import grouped_query_attention
+from stoke_tpu.ops.lightning import (
+    LIGHTNING_CHUNK,
+    alibi_decay,
+    lightning_chunked,
+    lightning_step,
+)
 from stoke_tpu.ops.sparse_attention import selection_mask
 
 _NEG_INF = -1e30
+
+#: the mixer family's layer kinds, by the source's ``mixer_types`` names
+MIXERS = {"minicpm4": "sparse_gqa", "lightning-attn": "lightning"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -99,9 +137,9 @@ class DecoderConfig:
     hidden_size: int
     num_hidden_layers: int
     num_attention_heads: int
-    moe_intermediate_size: int
-    n_routed_experts: int
-    num_experts_per_tok: int
+    moe_intermediate_size: int = 0
+    n_routed_experts: int = 0
+    num_experts_per_tok: int = 0
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -164,6 +202,21 @@ class DecoderConfig:
     hc_eps: float = 1e-6
     swiglu_limit: float = 0.0
     lm_head_fp32: bool = False
+    # the mixer family (``mixer_types``; MiniCPM-SALA, ``model_type:
+    # "minicpm_sala"``): block-sparse softmax layers and lightning layers,
+    # every feed-forward a dense SwiGLU; MiniCPM's scalings (the embedding
+    # times ``scale_emb``, each residual branch times ``residual_scale``,
+    # the head's input times ``head_scale``); the block selection's
+    # settings from ``sparse_config``; the lightning layers' decay by their
+    # published index among ``lightning_decay_depth`` layers
+    mixer_types: Optional[Tuple[str, ...]] = None
+    lightning_nh: int = 0
+    lightning_head_dim: int = 0
+    lightning_decay_depth: int = 0
+    scale_emb: float = 1.0
+    residual_scale: float = 1.0
+    head_scale: float = 1.0
+    block_sparse: Optional[BlockSparse] = None
 
     @classmethod
     def from_dict(cls, config: dict) -> "DecoderConfig":
@@ -190,7 +243,9 @@ class DecoderConfig:
                         "mscale_all_dim", "original_max_position_embeddings"):
                 if key in scaling:
                     kwargs["rope_" + key] = scaling[key]
-        if "gqa_layers" in config:
+        if "mixer_types" in config:
+            kwargs.update(cls._mixer_keys(config))
+        elif "gqa_layers" in config:
             kwargs.update(cls._hybrid_keys(config))
         else:
             needed = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
@@ -247,6 +302,74 @@ class DecoderConfig:
             "linear_head_dim": int(linear["head_dim"]),
             "linear_conv_kernel": int(linear["short_conv_kernel_size"]),
         }
+
+    @staticmethod
+    def _mixer_keys(config: dict) -> dict:
+        """The mixer family's keys (``mixer_types``, ``lightning_*``,
+        ``qk_norm``, ``attn_use_rope``, the gates and norms, the muP
+        scalings, ``sparse_config``) under the fields the decoder reads,
+        checked: block-sparse layers without positions, lightning layers
+        with a state a head, rotary and the ``1/sqrt(d)`` scale, q and k
+        normed and the outputs gated (and the lightning output normed) in
+        both kinds, and dense feed-forwards are what is built here."""
+        n = int(config["num_hidden_layers"])
+        kinds = tuple(config["mixer_types"])[:n]
+        unknown = set(kinds) - set(MIXERS)
+        if unknown or len(kinds) < n:
+            raise ValueError(f"mixer_types {sorted(unknown)}: a layer is "
+                             f"{' or '.join(MIXERS)} here, one a layer")
+        if config.get("attn_use_rope", False):
+            raise ValueError("attn_use_rope: the block-sparse layers carry "
+                             "no positions here")
+        for key in ("qk_norm", "attn_use_output_gate", "use_output_gate",
+                    "use_output_norm", "lightning_use_rope"):
+            if not config.get(key, False):
+                raise ValueError(f"{key}: the mixer family is built with "
+                                 f"it on")
+        heads = int(config["lightning_nh"])
+        if int(config.get("lightning_nkv", heads)) != heads:
+            raise ValueError("lightning_nkv: the lightning layers keep a "
+                             "state a head")
+        if config.get("lightning_scale", "1/sqrt(d)") != "1/sqrt(d)":
+            raise ValueError(f"lightning_scale "
+                             f"{config['lightning_scale']!r}: 1/sqrt(d) here")
+        for key in ("num_key_value_heads", "head_dim", "intermediate_size"):
+            if not config.get(key):
+                raise ValueError(f"the mixer family needs {key}")
+        if config["num_attention_heads"] % config["num_key_value_heads"]:
+            raise ValueError("the query heads do not divide over the "
+                             "key-value heads")
+        out = {
+            "mixer_types": kinds,
+            "lightning_nh": heads,
+            "lightning_head_dim": int(config["lightning_head_dim"]),
+            "lightning_decay_depth": int(
+                config.get("lightning_decay_depth", n)),
+            "scale_emb": float(config.get("scale_emb", 1.0)),
+            "residual_scale": float(config.get("scale_depth", 1.0)) / math.sqrt(
+                float(config.get("mup_denominator", n))),
+            "head_scale": float(config.get("dim_model_base",
+                                           config["hidden_size"]))
+            / float(config["hidden_size"]),
+            "first_k_dense_replace": n,
+        }
+        if "minicpm4" in kinds:
+            sparse = config.get("sparse_config")
+            keys = [f.name for f in dataclasses.fields(BlockSparse)]
+            if not sparse or set(keys) - set(sparse):
+                raise ValueError(f"the block-sparse layers need a "
+                                 f"sparse_config with {keys}")
+            made = BlockSparse(**{k: int(sparse[k]) for k in keys})
+            if (made.block_size % made.kernel_stride
+                    or made.kernel_size % made.kernel_stride):
+                raise ValueError("sparse_config: the stride divides the "
+                                 "block and the kernel")
+            if (made.init_blocks + made.window_size // made.block_size + 1
+                    > made.topk):
+                raise ValueError("sparse_config: the forced blocks exceed "
+                                 "topk")
+            out["block_sparse"] = made
+        return out
 
     @staticmethod
     def _double_layer_keys(config: dict) -> dict:
@@ -343,7 +466,10 @@ class DecoderConfig:
         return out
 
     def layer_kind(self, i: int) -> str:
-        """Layer ``i``'s attention: ``"mla"``, ``"gqa"`` or ``"kda"``."""
+        """Layer ``i``'s attention: ``"mla"``, ``"gqa"``, ``"kda"``,
+        ``"sparse_gqa"`` or ``"lightning"``."""
+        if self.mixer_types is not None:
+            return MIXERS[self.mixer_types[i]]
         if self.gqa_layers is None:
             return "mla"
         return "gqa" if i in self.gqa_layers else "kda"
@@ -1065,6 +1191,197 @@ class DeltaRuleAttention(nn.Module):
         return dense(cfg.hidden_size, name="o")(o.astype(self.dtype))
 
 
+# --------------------------------------------------------------------------- #
+# the mixer family: block-sparse softmax layers and lightning layers
+# --------------------------------------------------------------------------- #
+
+def rope_half(x, positions, theta: float):
+    """Rotary positions in the halves convention (value ``i`` turns with
+    value ``i + D/2``, frequency ``theta^(-2i/D)``): ``x [B, L, H, D]``,
+    ``positions [B, L]``; angles and the rotation in float32."""
+    D = x.shape[-1]
+    inv = 1.0 / theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D)
+    angle = positions[..., None, None].astype(jnp.float32) * inv
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def block_sparse_whole(q, k, v, cfg: BlockSparse):
+    """Block-sparse attention over one whole sequence a row (the module
+    docstring of ``ops/block_sparse.py`` has the selection): ``q [B, L, H,
+    D]``, ``k``, ``v [B, L, G, D]``, all positions real.  The selection from
+    the sequence's own compressed keys, then softmax over each query's
+    chosen positions ``<=`` its own.  Returns ``[B, L, H, D]``."""
+    B, L, H, D = q.shape
+    G = k.shape[2]
+    unit = math.lcm(cfg.block_size, cfg.kernel_size)
+    pad = -L % unit
+    kp = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    n_blocks = (L + pad) // cfg.block_size
+    t = jnp.arange(L, dtype=jnp.int32)
+    with jax.named_scope("compress"):
+        c = jax.vmap(lambda kb: compress_keys(kb, cfg))(kp).astype(k.dtype)
+    with jax.named_scope("select"):
+        s = jax.vmap(lambda qb, cb: window_scores(qb, cb, t + 1, cfg))(q, c)
+        chosen = choose_blocks(block_scores(s, n_blocks, cfg),
+                               jnp.broadcast_to((t + 1)[None, :, None],
+                                                (B, L, G)), cfg)
+        mask = jnp.repeat(chosen, cfg.block_size, axis=-1)[..., :L] & (
+            t[None, :, None, None] >= t[None, None, None, :])  # [B, L, G, L]
+    with jax.named_scope("sparse_attn"):
+        qg = q.reshape(B, L, G, H // G, D)
+        scores = _einsum("bqghd,bkgd->bghqk", qg, k) * D ** -0.5
+        scores = jnp.where(jnp.moveaxis(mask, 1, 2)[:, :, None], scores,
+                           _NEG_INF)
+        p = jax.nn.softmax(scores, axis=-1)
+        out = _einsum("bghqk,bkgd->bqghd", p.astype(q.dtype), v)
+    return out.reshape(B, L, H, D).astype(q.dtype)
+
+
+class SparseGroupedAttention(nn.Module):
+    """A ``"sparse_gqa"`` layer (MiniCPM4's InfLLM-v2): ``num_attention_heads``
+    query heads over ``num_key_value_heads`` key-value heads of
+    ``head_dim``, no positions, q and k through a per-head RMSNorm, each
+    group attending the blocks it chose (:func:`block_sparse_whole`;
+    ``attend(q, k, v)`` is a cache hook's), the attended values gated by
+    ``sigmoid(W_g x)`` before ``W_o``."""
+
+    cfg: DecoderConfig
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, attend=None):
+        cfg = self.cfg
+        B, L, _ = x.shape
+        H, G, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                   cfg.head_dim)
+        dense = partial(nn.Dense, use_bias=False, dtype=self.dtype,
+                        param_dtype=self.param_dtype)
+        norm = partial(RMSNorm, cfg.rms_norm_eps, self.dtype,
+                       self.param_dtype)
+        q = dense(H * D, name="q")(x).reshape(B, L, H, D)
+        k = dense(G * D, name="k")(x).reshape(B, L, G, D)
+        v = dense(G * D, name="v")(x).reshape(B, L, G, D)
+        q, k = norm(name="q_norm")(q), norm(name="k_norm")(k)
+        out = (block_sparse_whole(q, k, v, cfg.block_sparse)
+               if attend is None else attend(q, k, v))
+        gate = dense(H * D, name="g")(x)
+        out = (out.reshape(B, L, H * D).astype(jnp.float32)
+               * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(self.dtype)
+        return dense(cfg.hidden_size, name="o")(out)
+
+
+class LightningAttention(nn.Module):
+    """A ``"lightning"`` layer (Lightning Attention as MiniMax-01 serves it):
+    ``q, k, v = W_q x, W_k x, W_v x``, ``lightning_nh`` heads of
+    ``lightning_head_dim``; q and k through a per-head RMSNorm then rotary
+    positions (the halves convention); per head the float32 state ``S_t =
+    lam_h S_{t-1} + k_t v_t^T`` from zero, ``o_t = (q_t / sqrt(d)) S_t``,
+    ``lam_h`` MiniMax-01's decay of the layer's published ``index``
+    (``ops/lightning.py`` ``alibi_decay``); ``o = W_o(RMSNorm(o) *
+    sigmoid(W_g x))``, the norm over all heads' channels.
+
+    ``state`` is a cache hook's accessor (``layer_state(i)``): ``"prefill"``
+    runs the whole-prompt form from zero state (``lightning_chunked``),
+    positions at and past ``state.lengths`` leaving the state as it was, and
+    writes the state at the prompt's end; ``"decode"`` hands every slot's
+    state to one ``lightning_step`` (a Pallas kernel, in place) and the new
+    state back.  Without one the whole sequence runs from zero state."""
+
+    cfg: DecoderConfig
+    index: int
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, positions, state=None):
+        cfg = self.cfg
+        B, L, _ = x.shape
+        H, D = cfg.lightning_nh, cfg.lightning_head_dim
+        dense = partial(nn.Dense, use_bias=False, dtype=self.dtype,
+                        param_dtype=self.param_dtype)
+        norm = partial(RMSNorm, cfg.rms_norm_eps, self.dtype,
+                       self.param_dtype)
+        q, k, v = (dense(H * D, name=n)(x).reshape(B, L, H, D)
+                   for n in ("q", "k", "v"))
+        q, k = norm(name="q_norm")(q), norm(name="k_norm")(k)
+
+        # a chunk of q or k at positions from start; the positions padded
+        # as far as the chunked form pads the sequence (its last chunk's
+        # rows past L are masked out)
+        padded = jnp.pad(positions, ((0, 0), (0, LIGHTNING_CHUNK)))
+
+        def rotate(t, start):
+            return rope_half(t, jax.lax.dynamic_slice_in_dim(
+                padded, start, t.shape[1], axis=1), cfg.rope_theta)
+
+        decay = alibi_decay(H, self.index, cfg.lightning_decay_depth
+                            or cfg.num_hidden_layers)
+        with jax.named_scope("lightning"):
+            if state is not None and state.mode == "decode":
+                (S,) = state.read()
+                q, k = (rotate(t.astype(jnp.float32), 0)[:, 0]
+                        for t in (q, k))
+                o, S = lightning_step(S, q * D ** -0.5, k, v[:, 0], decay)
+                state.write(S)
+                o = o[:, None]
+            else:
+                o, S = lightning_chunked(
+                    q, k, v, decay, D ** -0.5,
+                    None if state is None else state.lengths, rotate)
+                if state is not None:
+                    state.write(S)
+        o = RMSNorm(cfg.rms_norm_eps, jnp.float32, self.param_dtype,
+                    name="o_norm")(o.reshape(B, L, H * D))
+        o = o.astype(jnp.float32) * jax.nn.sigmoid(
+            dense(H * D, name="g")(x).astype(jnp.float32))
+        return dense(cfg.hidden_size, name="o")(o.astype(self.dtype))
+
+
+class MixerLayer(nn.Module):
+    """A layer of the mixer family: ``h += a Mixer(RMSNorm(h))``, ``h += a
+    SwiGLU(RMSNorm(h))`` with ``a = residual_scale``; the mixer is
+    :class:`SparseGroupedAttention` or :class:`LightningAttention` as
+    ``mixer_types`` says.  ``attend`` is the cache hook's: the attention of a
+    sparse layer, the state accessor of a lightning one."""
+
+    cfg: DecoderConfig
+    index: int
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, h, positions, attend=None):
+        cfg = self.cfg
+        norm = partial(RMSNorm, cfg.rms_norm_eps, self.dtype,
+                       self.param_dtype)
+        a = cfg.residual_scale
+
+        def add(h, y):  # in the stream's dtype, as the other families add
+            return h + (a * y).astype(h.dtype)
+
+        kind = cfg.layer_kind(self.index)
+        with jax.named_scope(kind):
+            x = norm(name="attn_norm")(h)
+            if kind == "sparse_gqa":
+                y = SparseGroupedAttention(cfg, self.dtype, self.param_dtype,
+                                           name="attn")(x, attend)
+            else:
+                y = LightningAttention(cfg, self.index, self.dtype,
+                                       self.param_dtype, name="attn")(
+                    x, positions, attend)
+            h = add(h, y)
+        x = norm(name="ffn_norm")(h)
+        # a long prompt's tokens a chunk at a time: a 49,152-token prompt's
+        # two [tokens, intermediate] products whole would be 3.2 GB
+        return add(h, chunked_swiglu(SwiGLU(
+            cfg.intermediate_size, self.dtype, self.param_dtype, name="ffn"),
+            x))
+
+
 class DecoderLayer(nn.Module):
     cfg: DecoderConfig
     index: int
@@ -1289,6 +1606,33 @@ class Decoder(nn.Module):
                 max_len=cfg.max_position_embeddings,
                 index_topk=cfg.index_topk,
             )
+        if cfg.mixer_types is not None:
+            H, D = cfg.lightning_nh, cfg.lightning_head_dim
+            sparse = cfg.block_sparse
+            return CacheSpec(
+                layers=cfg.num_hidden_layers,
+                planes=(("kv", cfg.kv_row_width),),
+                kind="hybrid",
+                heads=cfg.num_attention_heads,
+                head_dim=cfg.head_dim,
+                max_len=cfg.max_position_embeddings,
+                layer_kinds=tuple("rows" if kind == "sparse_gqa" else "state"
+                                  for kind in cfg.layer_kinds),
+                state=(("state", (H, D, D), "float32"),),
+                # a sparse layer's compressed keys, a row a stride of
+                # positions, addressed by slot
+                slot_rows=(() if sparse is None else (
+                    ("compressed", sparse.kernel_stride,
+                     cfg.kv_row_width // 2),)),
+                # and the float32 key sums of each stride of the window a
+                # slot's decode is filling, so that a completed window's
+                # mean is written without reading its keys back
+                slot_state=(() if sparse is None else (
+                    ("window_sums",
+                     (sparse.kernel_size // sparse.kernel_stride,
+                      cfg.kv_row_width // 2), "float32"),)),
+                block_sparse=sparse,
+            )
         if cfg.gqa_layers is None:
             return CacheSpec(
                 layers=cfg.latent_sublayers,
@@ -1350,6 +1694,8 @@ class Decoder(nn.Module):
                        "kda": "layer_state"}
         if cfg.use_dsa:
             return self._streams(h, positions, kv_cache)
+        if cfg.mixer_types is not None:
+            return self._mixers(h, positions, kv_cache)
         for i in range(cfg.num_hidden_layers):
             if cfg.shortcut_double_layers:
                 # sublayer j of double layer i is row 2 i + j of the plane
@@ -1369,6 +1715,35 @@ class Decoder(nn.Module):
         with jax.named_scope("head"):
             h = RMSNorm(cfg.rms_norm_eps, self.dtype, self.param_dtype,
                         name="norm")(h)
+            return nn.Dense(
+                cfg.vocab_size, use_bias=False, dtype=self.dtype,
+                param_dtype=self.param_dtype, name="lm_head",
+                dot_general=partial(jax.lax.dot_general,
+                                    preferred_element_type=jnp.float32),
+            )(h)
+
+    def _mixers(self, h, positions, kv_cache):
+        """The mixer family's layers and head: the embedding times
+        ``scale_emb``; the final norm times ``head_scale``.  Through a cache
+        hook in prefill the head reads the prompt's last position only:
+        logits ``[B, 1, vocab]`` (a 49,152-token prompt's logits at every
+        position would be 14.4 GB in float32)."""
+        cfg = self.cfg
+        h = (h.astype(jnp.float32) * cfg.scale_emb).astype(self.dtype)
+        for i in range(cfg.num_hidden_layers):
+            attend = None
+            if kv_cache is not None:
+                attend = (kv_cache.layer_attention(i)
+                          if cfg.layer_kind(i) == "sparse_gqa"
+                          else kv_cache.layer_state(i))
+            h = MixerLayer(cfg, i, self.dtype, self.param_dtype,
+                           name=f"layer_{i}")(h, positions, attend)
+        if kv_cache is not None and kv_cache.mode == "prefill":
+            last = kv_cache.lengths.astype(jnp.int32) - 1
+            h = jnp.take_along_axis(h, last[:, None, None], axis=1)
+        with jax.named_scope("head"):
+            h = RMSNorm(cfg.rms_norm_eps, self.dtype, self.param_dtype,
+                        gain=cfg.head_scale, name="norm")(h)
             return nn.Dense(
                 cfg.vocab_size, use_bias=False, dtype=self.dtype,
                 param_dtype=self.param_dtype, name="lm_head",

@@ -243,10 +243,31 @@ class SwiGLU(nn.Module):
         return proj("down", x.shape[-1], gated)
 
 
-#: tokens of a long prompt an expert layer routes at a time, where the
-#: prompt is longer and a multiple of it: its assignments' rows, gathered
-#: and in float32, would not fit whole
-EXPERT_TOKEN_CHUNK = 4096
+#: tokens of a long prompt a feed-forward takes at a time, where the prompt
+#: is longer and a multiple of it (:func:`token_chunks`): an expert layer's
+#: assignments' rows, gathered and in float32, or a dense SwiGLU's two
+#: ``[tokens, width]`` products would not fit whole
+TOKEN_CHUNK = 4096
+
+
+def token_chunks(n: int) -> int:
+    """The chunks of ``TOKEN_CHUNK`` tokens a feed-forward takes ``n``
+    tokens in, one after another: ``n / TOKEN_CHUNK`` where ``n`` is longer
+    and a multiple of it, else 1 (all at once)."""
+    return n // TOKEN_CHUNK if n > TOKEN_CHUNK and n % TOKEN_CHUNK == 0 else 1
+
+
+def chunked_swiglu(ffn: SwiGLU, x):
+    """``ffn(x)`` over ``x [..., hidden]``, its tokens :func:`token_chunks`
+    chunks at a time in a loop that shares ``ffn``'s parameters."""
+    rows = x.reshape(-1, x.shape[-1])
+    parts = token_chunks(rows.shape[0])
+    if parts == 1:
+        return ffn(x)
+    loop = nn.scan(lambda module, carry, chunk: (carry, module(chunk)),
+                   variable_broadcast="params", split_rngs={"params": False})
+    _, out = loop(ffn, None, rows.reshape(parts, -1, rows.shape[-1]))
+    return out.reshape(x.shape)
 
 
 class ExpertShareFFN(nn.Module):
@@ -386,15 +407,16 @@ class ExpertShareFFN(nn.Module):
                 y = jnp.where(is_held[:, :, None], y, 0.0)
                 return (y * weights[:, :, None]).sum(axis=1), counts
 
-            chunk = EXPERT_TOKEN_CHUNK
-            if N > chunk and N % chunk == 0:
+            parts = token_chunks(N)
+            if parts > 1:
                 # a long prompt's assignments a chunk of tokens at a time:
                 # its N x k rows, gathered and in float32, would not fit
+                chunk = N // parts
                 routed, counts = jax.lax.map(
                     lambda a: held_part(*a),
-                    (xf.reshape(N // chunk, chunk, H),
-                     experts.reshape(N // chunk, chunk, k),
-                     weights.reshape(N // chunk, chunk, k)))
+                    (xf.reshape(parts, chunk, H),
+                     experts.reshape(parts, chunk, k),
+                     weights.reshape(parts, chunk, k)))
                 routed, counts = routed.reshape(N, H), counts.sum(axis=0)
             else:
                 routed, counts = held_part(xf, experts, weights)

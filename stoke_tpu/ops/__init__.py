@@ -15,7 +15,14 @@ The kernels, a module each, every one interpreted off the TPU and
   ``[B, H, dk, dv]`` float32 state (``q``, ``k``, ``g [B, H, dk]``, ``v
   [B, H, dv]``, ``beta [B, H]``), the state aliased in to out so that a
   caller that donates it is updated in place; a call moves ``2 *
-  state.nbytes`` and is bound by that stream; counter ``state_passes``.
+  state.nbytes`` and is bound by that stream; counter ``state_passes``;
+- ``sparse_attention``: learned sparse attention over the latent cache
+  (an indexer's scores, the exact top-k, attention over the chosen rows);
+- ``block_sparse``: block-sparse attention over the paged rows (pooled
+  keys' scores, the choice of blocks, attention over the chosen pages in
+  decode and over each query tile's chosen blocks in prefill);
+- ``lightning``: one position of lightning attention over every slot's
+  float32 state, in place, and its whole-prompt chunked form.
 """
 
 from stoke_tpu.ops.attention import (

@@ -383,6 +383,9 @@ class ServingEngine:
             state=spec.state,
             state_layers=len(spec.layers_of("state")),
             max_seqs=cfg.max_seqs,
+            slot_rows=spec.slot_rows,
+            max_seq_len=cfg.max_seq_len,
+            slot_state=spec.slot_state,
         )
         #: bytes of recurrent state a live slot's decode step reads and
         #: writes: every state layer's, once each way (the few convolution
@@ -590,6 +593,9 @@ class ServingEngine:
             )
             self._memory.preflight("serve")
 
+        #: key tiles visited and causal tiles of the block-sparse prefills
+        #: read since the last ``serve/commit``, which carries them
+        self._prefill_tiles = None
         self._iterations = 0
         self._last_emit_iter = 0
         self._t_start = time.perf_counter()
@@ -635,10 +641,9 @@ class ServingEngine:
 
     def _split(self, args: tuple):
         """A serve program's arguments after the weights: the cache's
-        arrays as the model describes them (the pool's planes, then the
-        state arrays), then the rest."""
-        spec = self._spec
-        n = len(spec.planes) + len(spec.state) * len(spec.layers_of("state"))
+        arrays as the model describes them (the pool's planes and slot
+        stores, then the state arrays), then the rest."""
+        n = len(self.cache.pages) + len(self.cache.state)
         return args[:n], args[n:]
 
     def _weights(self, qparams):
@@ -652,11 +657,12 @@ class ServingEngine:
         with the default ``decode_kernel="reference"`` the constructed
         graph is op-for-op the pre-ISSUE-13 one."""
         if self._spec.kind == "hybrid":
-            n = len(self._spec.planes)
+            n = len(self.cache.pages)
             return HybridCacheHook(
                 pages[:n], pages[n:], tables, positions, mode=mode,
                 lengths=lengths, layer_kinds=self._spec.layer_kinds,
                 slot=slot, attention_impl=self.cfg.attention,
+                block_sparse=self._spec.block_sparse,
             )
         if self._spec.kind == "sparse_latent":
             return SparseLatentHook(
@@ -682,7 +688,9 @@ class ServingEngine:
         prompt; block_row [1, MB]; prompt_len [1]; for a model with
         per-slot state also slot [1], whose state rows the prompt's end
         state overwrites.
-        Returns (first generated token [1], updated arrays)."""
+        Returns (first generated token [1], updated arrays); a model with
+        block-sparse layers hands back its prefill's key tiles visited and
+        causal tiles (int32[2]) beside the token."""
         pages, (tokens, block_row, prompt_len, *slot) = self._split(args)
         params = self._weights(qparams)
         P = tokens.shape[1]
@@ -691,9 +699,13 @@ class ServingEngine:
             pages, block_row, positions, "prefill", prompt_len, *slot
         )
         logits = self._apply(params, tokens, positions, hook, decode=False)
-        last = logits[0, prompt_len[0] - 1]
+        # a model whose head reads the prompt's last position only hands
+        # back one position
+        last = logits[0, 0 if logits.shape[1] == 1 else prompt_len[0] - 1]
+        counts = (hook.key_tiles,) if self._spec.block_sparse else ()
         return (
             jnp.argmax(last, axis=-1).astype(jnp.int32)[None],
+            *counts,
             *hook.pages,
             *getattr(hook, "state", ()),
         )
@@ -709,7 +721,9 @@ class ServingEngine:
         held]) beside the tokens, one whose routers have zero-compute
         outputs each row's picks among them (int32[expert layers, B]), and
         one with a sparse latent cache the words of latent plane its
-        kernels' row DMAs fetched, a slot over the layers (int32[B])."""
+        kernels' row DMAs fetched, a slot over the layers (int32[B]), and
+        one with block-sparse layers the words of the row plane its
+        kernels fetched, a slot over the layers (int32[B])."""
         pages, (tokens, positions, block_tables, context_lens, *prev) = (
             self._split(args)
         )
@@ -727,7 +741,7 @@ class ServingEngine:
         counts = ()
         if counting:
             logits, counts = logits
-        if self._spec.kind == "sparse_latent":
+        if self._spec.kind == "sparse_latent" or self._spec.block_sparse:
             counts += (hook.words_fetched,)
         return (
             jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32),
@@ -1133,7 +1147,14 @@ class ServingEngine:
                     self.captured_logits.setdefault(req.rid, []).append(
                         np.asarray(out[2])[0].copy()
                     )
-            return int(np.asarray(out[0])[0])  # sync: the TTFT point
+            token = int(np.asarray(out[0])[0])  # sync: the TTFT point
+            if self._spec.block_sparse:
+                # the key tiles it visited, for the next serve/commit
+                tiles = np.asarray(out[1])
+                self._prefill_tiles = (
+                    tiles if self._prefill_tiles is None
+                    else self._prefill_tiles + tiles)
+            return token
 
         with trace_span(
             "serve/prefill", track="serve", request_id=req.rid,
@@ -1480,10 +1501,12 @@ class ServingEngine:
         # the step's copy home); each one below is one more
         t_first = time.perf_counter()
         fetched = [next_host]
-        if self._experts_held or self._spec.kind == "sparse_latent":
+        if (self._experts_held or self._spec.kind == "sparse_latent"
+                or self._spec.block_sparse):
             # the held experts' assignment counts ride beside, the rows'
             # zero-compute picks where the routers have those, and the
-            # words the sparse kernels fetched where the cache is read by row
+            # words the sparse kernels fetched where the cache is read by
+            # row or by chosen block
             fetched += [np.asarray(a) for a in step.out[1:]]
         if self._sampling:
             # advance ONLY the decoding slots' key streams: a
@@ -1551,6 +1574,15 @@ class ServingEngine:
         if self._spec.kind == "sparse_latent":
             step_attrs.update(self._sparse_counts(
                 host_args[3], decode_rows, fetched[-1]))
+        if self._spec.block_sparse:
+            step_attrs.update(self._block_sparse_counts(
+                host_args[3], decode_rows, fetched[-1]))
+            if self._prefill_tiles is not None:
+                visited, causal = (int(x) for x in self._prefill_tiles)
+                step_attrs.update(key_tiles_visited=visited,
+                                  key_tiles_causal=causal,
+                                  key_tile_share=visited / max(causal, 1))
+                self._prefill_tiles = None
         if self.cache.state:
             passes = state_passes(len(decode_rows), self.cfg.max_seqs)
             m.state_passes.set(passes)
@@ -1618,6 +1650,35 @@ class ServingEngine:
             * int(context[live].sum()),
             "selected_rows": selected,
             "sparse_row_passes": fetched / max(selected * row_bytes, 1),
+        }
+
+    def _block_sparse_counts(self, context, live, issued) -> dict:
+        """What a decode step of a model with block-sparse layers read: the
+        blocks its selections chose (every block of a context of at most
+        ``dense_len``, else ``min(blocks, topk)``, a live slot, key-value
+        head and block-sparse layer), the compressed keys it scored (every
+        live slot's windows that exist, a key-value head and layer), and
+        the bytes of row plane the block kernels fetched over the chosen
+        blocks' bytes of one key-value head (``block_size`` keys and values
+        of ``head_dim``): ``issued`` is the kernels' own count of the words
+        their DMAs fetched, a slot over the layers."""
+        spec, sparse = self._spec, self._spec.block_sparse
+        layers = len(spec.layers_of("rows"))
+        groups = spec.planes[0][1] // (2 * spec.head_dim)
+        ctx = context[live].astype(np.int64)
+        blocks = -(-ctx // sparse.block_size)
+        chosen = np.where(ctx <= sparse.dense_len, blocks,
+                          np.minimum(blocks, sparse.topk))
+        selected = layers * groups * int(chosen.sum())
+        windows = np.maximum(
+            (ctx - sparse.kernel_size) // sparse.kernel_stride + 1, 0)
+        block_bytes = (sparse.block_size * 2 * spec.head_dim
+                       * self.cache.dtype.itemsize)
+        return {
+            "selected_blocks": selected,
+            "compressed_keys": layers * groups * int(windows.sum()),
+            "sparse_block_passes": 4 * int(np.sum(issued))
+            / max(selected * block_bytes, 1),
         }
 
     def run(self, max_steps: Optional[int] = None) -> int:

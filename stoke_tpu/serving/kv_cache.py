@@ -33,7 +33,10 @@ The pieces:
   different things (``CacheSpec.layer_kinds``): planes for the layers that
   cache a row a token, and slot-indexed state arrays for the layers that
   keep a constant state a slot (a linear-attention layer's recurrent state,
-  its convolution's last inputs), which cache no rows and get no plane.
+  its convolution's last inputs), which cache no rows and get no plane;
+  with block-sparse row layers (``CacheSpec.block_sparse``) each also
+  keeps its compressed keys in a store addressed by slot
+  (``CacheSpec.slot_rows``), and its queries attend the pages they chose.
 - :class:`SparseLatentHook` — a model with learned sparse attention: a
   packed latent plane read row by row, and a plane of indexer keys that
   only the layers with an indexer have.
@@ -49,6 +52,16 @@ import jax
 import jax.numpy as jnp
 
 from stoke_tpu.models.bert import dense_attention
+from stoke_tpu.ops.block_sparse import (
+    block_scores,
+    block_sparse_attention,
+    block_sparse_flash,
+    choice_lists,
+    choose_blocks,
+    compress_keys,
+    compressed_scores,
+    prefill_choices,
+)
 from stoke_tpu.ops.flash_attention import (
     flash_attention,
     grouped_query_attention,
@@ -233,6 +246,9 @@ class PagedKVCache:
         state: Sequence = (),
         state_layers: int = 0,
         max_seqs: int = 0,
+        slot_rows: Sequence = (),
+        max_seq_len: int = 0,
+        slot_state: Sequence = (),
     ):
         self.n_layers = int(n_layers)
         self.block_size = int(block_size)
@@ -253,9 +269,18 @@ class PagedKVCache:
                               packed_width(width, self.dtype)), jnp.uint32)
             return zeros((layers, num_blocks, block_size, width), dtype)
 
-        #: the planes, in the model's order; the serve programs take them
-        #: (donated) and hand them back
-        self.pages = tuple(plane(*spec) for spec in planes)
+        #: the planes, in the model's order, then the row layers' slot
+        #: stores (``CacheSpec.slot_rows``: ``[max_seqs, ceil(max_seq_len /
+        #: stride), width]`` each, store by store and row layer by row
+        #: layer), then their per-slot state (``CacheSpec.slot_state``:
+        #: ``[max_seqs, *shape]`` each, in the same order); the serve
+        #: programs take them (donated) and hand them back
+        self.pages = tuple(plane(*spec) for spec in planes) + tuple(
+            zeros((max_seqs, -(-max_seq_len // stride), width), dtype)
+            for _, stride, width in slot_rows for _ in range(n_layers)
+        ) + tuple(
+            zeros((max_seqs,) + tuple(shape), jnp.dtype(kind))
+            for _, shape, kind in slot_state for _ in range(n_layers))
         #: the second store: what the layers that cache no rows keep, a
         #: constant size a slot and addressed by slot (``CacheSpec.state``):
         #: ``[max_seqs, *shape]`` each, an array an entry a state layer
@@ -288,7 +313,8 @@ class PagedKVCache:
 
     @property
     def nbytes(self) -> int:
-        """HBM footprint of the pool (all planes, unpadded)."""
+        """HBM footprint of the pool (all planes and slot stores,
+        unpadded)."""
         return sum(int(p.size) * p.dtype.itemsize for p in self.pages)
 
     @property
@@ -693,8 +719,27 @@ class HybridCacheHook:
     An idle slot's rows may hold anything finite: its prefill overwrites
     them.
 
+    With ``block_sparse`` (``ops/block_sparse.py`` ``BlockSparse``) a row
+    layer's queries attend the blocks their group chose, and each row layer
+    keeps its compressed keys in a store of its own, ``[max_seqs, windows,
+    G D]`` addressed by slot, each written once when its window completes,
+    and beside it the float32 key sums of the strides of each slot's window
+    in progress: in ``"prefill"`` mode every window of the prompt and the
+    sums at its end, the selection of every
+    query a block of queries at a time (``prefill_choices``) and flash
+    attention over each query tile's union of chosen blocks
+    (``block_sparse_flash``), whose tiles visited and causal tiles add to
+    ``key_tiles``; in ``"decode"`` mode the fresh token's keys added to its
+    stride's sum and the window it completes written as the mean of its
+    strides' sums (no key is read back), each slot's query scored against
+    its compressed keys in place (``compressed_scores``), the exact choice
+    with the forced blocks, and attention over the chosen pages
+    (``block_sparse_attention``), whose fetched words add to
+    ``words_fetched``.
+
     Args as :class:`PagedAttentionHook`'s, with ``pages`` the one ``kv``
-    plane ``[row layers, NB, BS, 2 G D]``, ``state`` the state arrays
+    plane ``[row layers, NB, BS, 2 G D]`` and then the row layers'
+    compressed-key stores and window sums, ``state`` the state arrays
     (``[max_seqs, ...]`` each, layer by layer), ``layer_kinds`` the model's
     ``CacheSpec.layer_kinds`` and ``slot [1] int32`` the prefilled
     request's slot.
@@ -702,14 +747,30 @@ class HybridCacheHook:
 
     def __init__(self, pages, state, block_tables, positions, *, mode: str,
                  lengths, layer_kinds, slot=None,
-                 attention_impl: str = "dense"):
+                 attention_impl: str = "dense", block_sparse=None):
         if mode not in ("prefill", "decode"):
             raise NotImplementedError(
                 f"HybridCacheHook has no {mode!r} mode: the state arrays "
                 f"are written and read by the serve_prefill and "
                 f"serve_decode programs only"
             )
-        (self.kv_pages,) = pages
+        kinds = tuple(layer_kinds)
+        self.kv_pages, *slot_arrays = pages
+        rows = kinds.count("rows") if slot_arrays else 0
+        #: the row layers' compressed-key stores, then the float32 sums of
+        #: the key strides of each slot's last window so far (``[max_seqs,
+        #: kernel_size / kernel_stride, G D]``: the strides before the
+        #: current one, oldest first, then the current one's keys so far);
+        #: block-sparse only
+        self.compressed = tuple(slot_arrays[:rows])
+        self.window_sums = tuple(slot_arrays[rows:])
+        self.block_sparse = block_sparse
+        #: ``[B] int32``: the words of the plane the block-sparse decode
+        #: kernels fetched for each slot, summed over the layers
+        self.words_fetched = jnp.zeros(block_tables.shape[:1], jnp.int32)
+        #: ``[2] int32``: the prefill's key tiles visited and causal tiles,
+        #: summed over the block-sparse layers
+        self.key_tiles = jnp.zeros((2,), jnp.int32)
         self.state = tuple(state)
         self.block_tables = block_tables
         self.positions = positions
@@ -719,13 +780,12 @@ class HybridCacheHook:
         self.attention_impl = attention_impl
         self.block_size = int(self.kv_pages.shape[2])
         # layer i's place in the plane or in the state arrays
-        kinds = tuple(layer_kinds)
         self._place = [kinds[:i].count(kind) for i, kind in enumerate(kinds)]
         self._kinds = kinds
 
     @property
     def pages(self) -> tuple:
-        return (self.kv_pages,)
+        return (self.kv_pages,) + self.compressed + self.window_sums
 
     def layer_state(self, layer: int) -> _LayerState:
         if self._kinds[layer] != "state":
@@ -739,20 +799,13 @@ class HybridCacheHook:
         if self._kinds[layer] != "rows":
             raise ValueError(f"layer {layer} keeps a state, not rows")
         place = self._place[layer]
+        if self.block_sparse is not None:
+            return partial(self._sparse_attend, place)
 
         def attend(q, k, v):
             B, L, H, D = q.shape
             G = k.shape[2]
-            rows = jnp.concatenate(
-                [k.reshape(B * L, G * D), v.reshape(B * L, G * D)], axis=-1)
-            pool = self.kv_pages
-            blocks, offs = _write_targets(
-                self.block_tables, self.positions, self.block_size,
-                self.lengths if self.mode == "prefill" else None,
-            )
-            self.kv_pages = pool.at[place, blocks, offs].set(
-                rows.astype(pool.dtype), mode="promise_in_bounds"
-            )
+            self._write_rows(place, k, v)
             if self.mode == "prefill":
                 key_valid = (
                     jnp.arange(L, dtype=jnp.int32)[None, :]
@@ -775,6 +828,100 @@ class HybridCacheHook:
                 axis=2)[:, None].astype(q.dtype)
 
         return attend
+
+    def _write_rows(self, place, k, v):
+        """A row layer's keys and values, a token's side by side, into its
+        plane at the tokens' places (padding and idle slots to scratch)."""
+        B, L, G, D = k.shape
+        rows = jnp.concatenate(
+            [k.reshape(B * L, G * D), v.reshape(B * L, G * D)], axis=-1)
+        blocks, offs = _write_targets(
+            self.block_tables, self.positions, self.block_size,
+            self.lengths if self.mode == "prefill" else None,
+        )
+        self.kv_pages = self.kv_pages.at[place, blocks, offs].set(
+            rows.astype(self.kv_pages.dtype), mode="promise_in_bounds")
+
+    def _sparse_attend(self, place, q, k, v):
+        """A block-sparse row layer: ``q [B, L, H, D]``, ``k``, ``v [B, L,
+        G, D]`` (normed, no positions)."""
+        cfg = self.block_sparse
+        B, L, H, D = q.shape
+        G = k.shape[2]
+        self._write_rows(place, k, v)
+        store, sums = self.compressed[place], self.window_sums[place]
+        S, m = cfg.kernel_stride, cfg.kernel_size // cfg.kernel_stride
+        # the keys as the plane holds them, in float32
+        keys = k.astype(self.kv_pages.dtype).astype(jnp.float32).reshape(
+            B, L, G * D)
+        if self.mode == "prefill":
+            with jax.named_scope("compress"):
+                c = compress_keys(k[0].astype(store.dtype), cfg).astype(
+                    store.dtype)  # [L / S, G, D]
+                n = min(c.shape[0], store.shape[1])
+                store = jax.lax.dynamic_update_slice(
+                    store, c[:n].reshape(1, n, G * D),
+                    (self.slot[0], 0, 0))
+                # the slot's window sums at the prompt's end: the sums of
+                # its last m - 1 whole strides and of the stride it has
+                # begun (zeros before the first stride and past the end)
+                length = self.lengths[0].astype(jnp.int32)
+                real = jnp.where(
+                    jnp.arange(L, dtype=jnp.int32)[:, None] < length,
+                    keys[0], 0.0)
+                strides = jnp.pad(real, ((0, -L % S), (0, 0))).reshape(
+                    -1, S, G * D).sum(axis=1)
+                strides = jnp.pad(strides, ((m - 1, 1), (0, 0)))
+                sums = sums.at[self.slot[0]].set(jax.lax.dynamic_slice_in_dim(
+                    strides, length // S, m))
+            with jax.named_scope("select"):
+                visited, words, count, tiles = prefill_choices(
+                    q[0], c, self.lengths[0], cfg)
+            self.key_tiles = self.key_tiles + tiles
+            with jax.named_scope("sparse_attn"):
+                out = block_sparse_flash(
+                    q[0].reshape(L, G, H // G, D),
+                    jnp.swapaxes(k[0], 0, 1).astype(q.dtype),
+                    jnp.swapaxes(v[0], 0, 1).astype(q.dtype),
+                    visited, words, count, D ** -0.5, cfg.block_size)
+            out = out.reshape(1, L, H, D)
+        else:
+            with jax.named_scope("compress"):
+                # the fresh token at ``lengths - 1`` joins its stride's
+                # sum; where it ends a stride that ends a window, the
+                # window's mean is the sum of its strides' (a slot that
+                # completes none writes past the store, which drops it),
+                # and the sums move on a stride
+                length = self.lengths.astype(jnp.int32)
+                sums = sums.at[:, m - 1].add(keys[:, 0])
+                ended = length % S == 0
+                window = jnp.where(ended & (length >= cfg.kernel_size),
+                                   length // S - m, store.shape[1])
+                c = sums.sum(axis=1) / cfg.kernel_size
+                store = store.at[jnp.arange(B), window].set(
+                    c.astype(store.dtype), mode="drop")
+                sums = jnp.where(ended[:, None, None], jnp.pad(
+                    sums[:, 1:], ((0, 0), (0, 1), (0, 0))), sums)
+            with jax.named_scope("select"):
+                scores = compressed_scores(q[:, 0], store, self.lengths, cfg)
+                nb = self.block_tables.shape[1]
+                chosen = choose_blocks(
+                    block_scores(scores, nb, cfg),
+                    jnp.broadcast_to(self.lengths[:, None], (B, G)), cfg)
+                logical, count = choice_lists(chosen, min(cfg.list_len, nb))
+                blocks = jnp.take_along_axis(
+                    self.block_tables[:, None, :], logical, axis=2)
+            with jax.named_scope("sparse_attn"):
+                out, words = block_sparse_attention(
+                    q[:, 0], self.kv_pages, place, blocks, logical, count,
+                    self.lengths, D ** -0.5)
+            self.words_fetched = self.words_fetched + words
+            out = out[:, None]
+        compressed, window_sums = list(self.compressed), list(self.window_sums)
+        compressed[place], window_sums[place] = store, sums
+        self.compressed = tuple(compressed)
+        self.window_sums = tuple(window_sums)
+        return out
 
 
 class SparseLatentHook:

@@ -117,3 +117,29 @@ def test_the_words_a_step_reports_are_the_kernels_own(served):
         assert issued.dtype == np.int32
         assert issued.tolist() == [5 * 8 * words if c else 0
                                    for c in context]
+
+
+# sha256 (its first 16 digits) of each serve program's StableHLO as it
+# lowered at commit a78fec6 for the engine of three slots above, the
+# programs other families' changes must leave alone.  A change to a program
+# on purpose records the new digest here.
+LOWERED = {"serve_decode": "429b3fe7d70c566f",
+           "serve_prefill": "c54c17f7dba3b351"}
+
+
+@pytest.mark.parametrize("program", list(LOWERED))
+def test_serve_programs_lower_to_the_stablehlo_they_did(built, program):
+    import hashlib
+
+    model, params = built
+    eng = ServingEngine(model, params, _serve_config())
+    batch = eng.scheduler.decode_batch()
+    if program == "serve_decode":
+        jitted, args = eng._decode_jit, batch
+    else:
+        jitted = eng._prefill_jit
+        args = (np.zeros((1, 256), np.int32), batch[2][:1],
+                np.array([5], np.int32))
+    text = jitted.lower(eng.qparams, *eng.cache.pages, *eng.cache.state,
+                        *args).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == LOWERED[program]
