@@ -721,9 +721,11 @@ class ServingEngine:
         held]) beside the tokens, one whose routers have zero-compute
         outputs each row's picks among them (int32[expert layers, B]), and
         one with a sparse latent cache the words of latent plane its
-        kernels' row DMAs fetched, a slot over the layers (int32[B]), and
-        one with block-sparse layers the words of the row plane its
-        kernels fetched, a slot over the layers (int32[B])."""
+        kernels' row DMAs fetched and of indexer plane its indexer kernels'
+        page DMAs fetched, a slot over the layers (int32[B, 2], one array
+        so the read fetches no more), and one with block-sparse layers the
+        words of the row plane its kernels fetched, a slot over the layers
+        (int32[B])."""
         pages, (tokens, positions, block_tables, context_lens, *prev) = (
             self._split(args)
         )
@@ -741,7 +743,10 @@ class ServingEngine:
         counts = ()
         if counting:
             logits, counts = logits
-        if self._spec.kind == "sparse_latent" or self._spec.block_sparse:
+        if self._spec.kind == "sparse_latent":
+            counts += (jnp.stack([hook.words_fetched, hook.index_words],
+                                 axis=-1),)
+        elif self._spec.block_sparse:
             counts += (hook.words_fetched,)
         return (
             jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32),
@@ -1636,20 +1641,27 @@ class ServingEngine:
         """What a decode step of a sparse-attention model read: the keys
         its indexers scored (every live slot to its length, in each layer
         with an indexer), the rows its selections chose (``min(context,
-        index_topk)`` a live slot and layer), and the bytes of latent plane
-        the row kernels fetched over the chosen rows' bytes: ``issued`` is
-        the kernels' own count of the plane's words their DMAs fetched, a
-        slot over the layers."""
+        index_topk)`` a live slot and layer), the bytes of latent plane
+        the row kernels fetched over the chosen rows' bytes, and the bytes
+        of indexer plane the indexer kernels fetched over the live slots'
+        pages of it (``window_blocks`` a layer with an indexer): ``issued``
+        is the kernels' own count of the two planes' 32-bit words their
+        DMAs fetched, ``[B, 2]``, a slot over the layers."""
         spec = self._spec
         chosen = np.minimum(context, spec.index_topk)
         selected = spec.layers * int(chosen[live].sum())
-        fetched = int(np.sum(issued)) * self.cache.pages[0].dtype.itemsize
+        rows, index = (4 * int(n) for n in np.sum(issued, axis=0))
         row_bytes = spec.values_per_token * self.cache.dtype.itemsize
+        index_layers = len(spec.plane_layers("index"))
+        plane = self.cache.pages[1]  # [index layers, NB, BS, D]
+        block_size = plane.shape[2]
+        pages = index_layers * int((-(-context[live] // block_size)).sum())
+        page_bytes = block_size * plane.shape[3] * plane.dtype.itemsize
         return {
-            "indexer_keys": len(spec.plane_layers("index"))
-            * int(context[live].sum()),
+            "indexer_keys": index_layers * int(context[live].sum()),
             "selected_rows": selected,
-            "sparse_row_passes": fetched / max(selected * row_bytes, 1),
+            "sparse_row_passes": rows / max(selected * row_bytes, 1),
+            "index_page_passes": index / max(pages * page_bytes, 1),
         }
 
     def _block_sparse_counts(self, context, live, issued) -> dict:

@@ -71,7 +71,7 @@ from stoke_tpu.ops.flash_attention import (
     paged_verify_attention_pallas,
 )
 from stoke_tpu.ops.sparse_attention import (
-    index_scores,
+    counted_index_scores,
     pack_rows,
     packed_width,
     prefill_selection,
@@ -940,8 +940,9 @@ class SparseLatentHook:
     - a layer with an indexer makes the selection, which the layers after
       it without one reuse within the same program: in ``"decode"`` mode
       each slot's query scored against its keys read in place to the slot's
-      own length (``index_scores``), the exact top ``index_topk``
-      (``select_topk``) and their rows' places in the pool; in
+      own length (``counted_index_scores``, whose fetched words add to
+      ``index_words``), the exact top ``index_topk`` (``select_topk``) and
+      their rows' places in the pool; in
       ``"prefill"`` mode the prompt's queries a block at a time
       (``prefill_selection``), as bits;
     - attends: in ``"decode"`` mode the absorbed form over the chosen rows,
@@ -976,6 +977,9 @@ class SparseLatentHook:
         #: ``[B] int32``: the words of latent plane the decode kernels'
         #: row DMAs fetched for each slot, summed over the layers
         self.words_fetched = jnp.zeros(block_tables.shape[:1], jnp.int32)
+        #: ``[B] int32``: the words of indexer plane the decode kernels'
+        #: page DMAs fetched for each slot, summed over the layers
+        self.index_words = jnp.zeros(block_tables.shape[:1], jnp.int32)
 
     @property
     def pages(self) -> tuple:
@@ -983,9 +987,10 @@ class SparseLatentHook:
 
     def _select(self, row, q, k, w):
         if self.mode == "decode":
-            scores = index_scores(
+            scores, words = counted_index_scores(
                 q[:, 0], w[:, 0, :, None], self.index_keys, row,
                 self.block_tables, self.lengths, q.shape[-1] ** -0.5)
+            self.index_words = self.index_words + words
             chosen, count = select_topk(scores[:, 0], self.lengths,
                                         self.index_topk)
             return row_addresses(chosen, count, self.block_tables,

@@ -264,6 +264,45 @@ def test_sparse_latent_attention_at_the_long_document_decode_shape_on_tpu():
     assert float(err) < 2e-2
 
 
+def test_index_scores_at_the_long_document_decode_shape_on_tpu():
+    """`index_scores` compiled by Mosaic at the long-document serve cell's
+    decode shape: 24 slots of 32 indexer heads, tables of 1,664 blocks of
+    16 keys of 128 bfloat16 in layer 1 of a `[2, 39937, 16, 128]` plane,
+    lengths of 6k-26,624 with a full table, one key and an empty slot.
+    Table entries past a slot's live blocks are ids outside the plane,
+    which the kernel, compiled without Mosaic's bounds checks, never
+    fetches.  Scores within float32 sums in another order of the plain
+    form's."""
+    from stoke_tpu.ops import sparse_attention as sa
+
+    B, H, L, NB, BS, D, MB = 24, 32, 2, 39937, 16, 128, 1664
+    lens = np.concatenate([
+        np.random.default_rng(45).integers(6144, MB * BS, B - 3),
+        [MB * BS, 1, 0]])
+    live = -(-lens // BS)
+    tables = np.random.default_rng(46).permutation(np.arange(1, NB))
+    tables = np.where(np.arange(MB)[None] < live[:, None],
+                      tables[:B * MB].reshape(B, MB),
+                      np.asarray([NB, 2**31 - 1, -1, -NB])[np.arange(MB) % 4])
+    k = jax.random.split(jax.random.PRNGKey(45), 3)
+    plane = jax.random.normal(k[0], (L, NB, BS, D), jnp.bfloat16)
+    q = jax.random.normal(k[1], (B, H, D), jnp.bfloat16)
+    w = jax.random.normal(k[2], (B, H, 1), jnp.float32)
+    tables, lens = jnp.asarray(tables, jnp.int32), jnp.asarray(lens, jnp.int32)
+    got, words = sa.counted_index_scores(q, w, plane, 1, tables, lens,
+                                         D ** -0.5, interpret=False)
+    want = np.asarray(sa.index_scores_reference(q, w, plane, 1, tables, lens,
+                                                D ** -0.5))
+    got = np.asarray(got)
+    finite = np.isfinite(want)
+    assert (np.isfinite(got) == finite).all()
+    assert finite.sum(-1)[:, 0].tolist() == lens.tolist()
+    np.testing.assert_allclose(got[finite], want[finite], rtol=1e-5,
+                               atol=1e-5 * np.abs(want[finite]).max())
+    # the kernel's own count: a slot's live pages, each of BS x D bfloat16
+    assert words.tolist() == (live * BS * D // 2).tolist()
+
+
 @pytest.mark.skipif(
     jax.device_count() < 2, reason="needs several chips"
 )

@@ -98,32 +98,42 @@ def test_engine_serves_the_reference_greedy_stream_and_counts_its_reads(
     words = sa.packed_width(40, jnp.float32)
     assert eng.cache.bytes_per_token == 5 * words * 4 + 2 * 16 * 4
     assert eng.cache.pages[1].shape[0] == 2
-    issued = np.full(3, 5 * 8 * words, np.int32)
-    counts = eng._sparse_counts(np.asarray([41, 5, 1]), [0, 1], issued)
+    # a slot's words of each plane: 5 layers of 8 rows, and 2 layers of its
+    # 3, 1 and 1 indexer pages of [16, 16] float32
+    issued = np.stack([np.full(3, 5 * 8 * words),
+                       2 * np.asarray([3, 1, 1]) * 256], axis=-1)
+    counts = eng._sparse_counts(np.asarray([41, 5, 1]), [0, 1],
+                                issued.astype(np.int32))
     assert counts["indexer_keys"] == 2 * (41 + 5)
     assert counts["selected_rows"] == 5 * (8 + 5)
     fetched = 3 * 5 * 8 * words * 4
     assert counts["sparse_row_passes"] == fetched / (5 * 13 * 40 * 4)
+    # every slot's fetched pages over the live slots' 3 + 1 a layer
+    assert counts["index_page_passes"] == 2 * 5 * 1024 / (2 * 4 * 1024)
 
 
 def test_the_words_a_step_reports_are_the_kernels_own(served):
     # the decode program hands back, a slot, the words its five layers'
-    # kernels fetched: a whole step of min(128, index_topk) = 8 rows of
-    # packed words a layer for a slot with any context
+    # row kernels fetched: a whole step of min(128, index_topk) = 8 rows of
+    # packed words a layer for a slot with any context; and beside them the
+    # words its two indexer kernels fetched: the slot's pages of [16, 16]
+    # float32 a layer
     handed = served[4]
     words = sa.packed_width(40, jnp.float32)
     assert handed
     for context, issued in handed:
-        assert issued.dtype == np.int32
-        assert issued.tolist() == [5 * 8 * words if c else 0
+        assert issued.dtype == np.int32 and issued.shape == (3, 2)
+        assert issued.tolist() == [[5 * 8 * words if c else 0,
+                                    2 * -(-int(c) // 16) * 16 * 16]
                                    for c in context]
 
 
 # sha256 (its first 16 digits) of each serve program's StableHLO as it
-# lowered at commit a78fec6 for the engine of three slots above, the
-# programs other families' changes must leave alone.  A change to a program
-# on purpose records the new digest here.
-LOWERED = {"serve_decode": "429b3fe7d70c566f",
+# lowered for the engine of three slots above (serve_prefill at commit
+# a78fec6; serve_decode since it hands back the indexer kernels' fetched
+# words), the programs other families' changes must leave alone.  A change
+# to a program on purpose records the new digest here.
+LOWERED = {"serve_decode": "b4e6840906a6ad86",
            "serve_prefill": "c54c17f7dba3b351"}
 
 

@@ -43,6 +43,61 @@ def test_index_scores_equal_the_gathered_window():
                                atol=1e-5, rtol=0)
 
 
+# a step of 4 pages of float32 [BS, 128] over tables of 12 blocks: three
+# steps for a slot whose table is full
+INDEX_GROUP, INDEX_MB = 4, 12
+INDEX_LENS = {
+    "empty": 0, "one": 1, "a-page": BS,
+    "a-page-short-of-a-group": (INDEX_GROUP - 1) * BS,
+    "a-group": INDEX_GROUP * BS,
+    "a-page-past-a-group": (INDEX_GROUP + 1) * BS,
+    "the-table": INDEX_MB * BS,
+}
+
+
+@pytest.mark.parametrize("unroll", [None, 2], ids=["unroll-module",
+                                                   "unroll-2"])
+@pytest.mark.parametrize("n", list(INDEX_LENS.values()), ids=list(INDEX_LENS))
+def test_index_scores_fetch_each_live_page_once(
+        n, unroll, monkeypatch):
+    """The first slot holds ``n`` keys, at the edges of a page, of a step of
+    ``INDEX_GROUP`` pages and of the table; the second ends inside its third
+    page, the third a few keys short of its table.  Entries of a table past
+    its slot's live blocks are ids outside the plane, which no DMA fetches.
+    ``unroll`` starts a trip of a whole step's issue loop (the module's: a
+    whole step's)."""
+    page = BS * 128 * 4
+    monkeypatch.setattr(sa, "_INDEX_GROUP_BYTES", INDEX_GROUP * page)
+    if unroll:
+        monkeypatch.setattr(sa, "SPARSE_ISSUE_UNROLL", unroll)
+    heads, D, nb = 4, 128, 2 * B * INDEX_MB
+    assert sa.index_group(BS, D, jnp.float32, INDEX_MB) == INDEX_GROUP
+    rng = np.random.default_rng(3)
+    plane = jnp.asarray(rng.normal(size=(LAYERS, nb, BS, D)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(B, heads, D)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(B, heads, 1)), jnp.float32)
+    lens = np.asarray([n, 37, INDEX_MB * BS - 5])
+    live = -(-lens // BS)
+    outside = np.asarray([nb, nb + 7, 2**31 - 1, -1, -nb])
+    tables = np.where(
+        np.arange(INDEX_MB)[None] < live[:, None],
+        rng.permutation(np.arange(1, nb))[:B * INDEX_MB].reshape(B, -1),
+        rng.choice(outside, (B, INDEX_MB)))
+    tables, lens = jnp.asarray(tables, jnp.int32), jnp.asarray(lens, jnp.int32)
+    got, words = sa.counted_index_scores(q, w, plane, 1, tables, lens, 0.1)
+    want = sa.index_scores_reference(q, w, plane, 1, tables, lens, 0.1)
+    assert got.shape == (B, 1, INDEX_MB * BS)
+    finite = np.isfinite(np.asarray(want))
+    assert (np.isfinite(np.asarray(got)) == finite).all()
+    assert finite.sum(-1)[:, 0].tolist() == lens.tolist()
+    np.testing.assert_allclose(np.asarray(got)[finite],
+                               np.asarray(want)[finite], atol=1e-5, rtol=0)
+    # the kernel's own count: a slot's live pages, each at its words, none
+    # for an empty slot
+    assert words.tolist() == (live * page // 4).tolist()
+    assert (words[0] == 0) == (n == 0)
+
+
 def test_selection_is_the_top_k_with_ties_to_the_lower_position():
     scores = jnp.asarray([[3.0, 1.0, 2.0, 2.0, 2.0, -jnp.inf],
                           [1.0, 1.0, 1.0, 1.0, -jnp.inf, -jnp.inf]])
